@@ -430,6 +430,17 @@ class Topology:
             locs = lo.locations.get(vid)
             if locs:
                 return list(locs)
+        # no layout has the vid: an EC-encoded volume is served by the
+        # holders of its shards (reference topology.go Lookup falls back
+        # to ecShardMap the same way), any of which reads a needle
+        # local -> remote -> degraded
+        shards = self.ec_shard_map.get(vid)
+        if shards:
+            seen: dict[str, DataNode] = {}
+            for holders in shards:
+                for n in holders:
+                    seen.setdefault(n.url, n)
+            return list(seen.values())
         return []
 
     def lookup_ec_shards(self, vid: int) -> Optional[list[list[DataNode]]]:

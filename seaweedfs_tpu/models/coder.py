@@ -221,7 +221,13 @@ def make_coder(name: str = "cpu", scheme: RSScheme = DEFAULT_SCHEME) -> ErasureC
         from seaweedfs_tpu.ops import lrc  # noqa: F401
         if not isinstance(scheme, LrcScheme):
             scheme = LrcScheme()
-    if name in ("jax", "tpu", "pallas", "mxu"):
+    if name in ("jax", "tpu", "pallas", "mxu", "mesh"):
+        # a device coder: refuse (with the reason) where JAX found only
+        # the CPU and the CPU was not asked for by name, and place the
+        # compile cache before the first jit
+        from seaweedfs_tpu.parallel import mesh as mesh_mod
+        mesh_mod.ensure_compile_cache()
+        mesh_mod.require_accelerator(f"coder {name!r}")
         from seaweedfs_tpu.ops import rs_jax  # noqa: F401
     if name == "pallas":
         from seaweedfs_tpu.ops import rs_pallas  # noqa: F401

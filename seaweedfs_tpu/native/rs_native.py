@@ -18,9 +18,16 @@ _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "rs_cpu.cpp")
 _SO = os.path.join(_DIR, "_rs_cpu.so")
 
+# No -march=native: the SIMD tiers carry their own target attributes
+# and a __builtin_cpu_supports dispatch (rs_cpu.cpp), so the baseline
+# code stays runnable on whatever CPU loads the library next.
+CXX = "g++"
+CXXFLAGS = ("-O3", "-shared", "-fPIC")
+
 _lock = threading.Lock()
 _lib = None
 _tried = False
+_build_error = ""
 
 
 def _build() -> bool:
@@ -28,19 +35,20 @@ def _build() -> bool:
     Building in place would rewrite an inode that may already be mmapped
     by this process (stale-symbol retry path) — dlopen would then dedup to
     the corrupted old mapping; a fresh inode gives a fresh mapping."""
+    global _build_error
     tmp = _SO + f".build.{os.getpid()}"  # unique per process: two
     # concurrent builders must not truncate each other's half-written file
     try:
-        for flags in (["-O3", "-march=native"], ["-O3"]):
-            try:
-                subprocess.run(["g++", *flags, "-shared", "-fPIC",
-                                "-o", tmp, _SRC],
-                               check=True, capture_output=True, timeout=120)
-                os.replace(tmp, _SO)
-                return True
-            except (OSError, subprocess.SubprocessError):
-                continue
-        return False
+        try:
+            subprocess.run([CXX, *CXXFLAGS, "-o", tmp, _SRC],
+                           check=True, capture_output=True, timeout=120)
+            os.replace(tmp, _SO)
+            return True
+        except (OSError, subprocess.SubprocessError) as e:
+            err = getattr(e, "stderr", b"") or b""
+            _build_error = (f"{type(e).__name__}: {e} "
+                            f"{err.decode(errors='replace')[-500:]}")
+            return False
     finally:
         if os.path.exists(tmp):
             try:
@@ -99,6 +107,25 @@ def _load():
 
 def available() -> bool:
     return _load() is not None
+
+
+def rebuild() -> dict:
+    """Build the library from rs_cpu.cpp on THIS machine (replacing a
+    cached .so that may have been compiled elsewhere) and load it.
+    Returns {"ok", "compiler", "flags", "impl", "error"} — what a
+    preflight prints before it trusts the codec.  Call before anything
+    else in the process has loaded the library."""
+    global _tried
+    with _lock:
+        built = _lib is None and _build()
+        _tried = False
+    lib = _load()
+    return {"ok": lib is not None, "built": built,
+            "compiler": CXX, "flags": list(CXXFLAGS),
+            "impl": lib.gf_impl_name().decode() if lib is not None
+            else None,
+            "error": None if lib is not None
+            else (_build_error or "library did not load")}
 
 
 def gf_apply(mat: np.ndarray, data: np.ndarray) -> np.ndarray:

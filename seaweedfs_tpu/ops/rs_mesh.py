@@ -17,8 +17,10 @@ Two compiled programs cover every operation:
     (zero rows disabled), so one program serves every survivor pattern in
     the batch — jobs with different loss patterns ride one dispatch.
 
-Batches are zero-padded to a device-count multiple on the leading axis
-(NamedSharding needs even division); pad lanes are discarded on the host.
+Batches are zero-padded on the leading axis to a power-of-two multiple
+of the device count (NamedSharding needs even division, and a bounded
+set of B keeps the set of compiled programs bounded); pad lanes are
+discarded on the host.
 Output is bit-identical to CpuCoder in all modes — GF(256) has no
 rounding to disagree about, and the tests hold it to that.
 """
@@ -91,16 +93,37 @@ class MeshCoder(ErasureCoder):
         # host-side helper for rebuild-matrix derivation (pure numpy)
         from seaweedfs_tpu.ops.rs_cpu import CpuCoder
         self._host = CpuCoder(scheme)
+        # distinct (kind, padded operand shape) dispatched — each is one
+        # compiled program; the batcher's stats() reports the count
+        self.programs: set[tuple] = set()
+        # {distinct devices holding a shard of a dispatch's output:
+        # dispatches} — on an n-device mesh every dispatch should land
+        # under key n; anything under a smaller key ran on fewer chips
+        self.output_spread: dict[int, int] = {}
 
     @property
     def n_devices(self) -> int:
         return self.mesh.devices.size
 
+    def device_report(self) -> dict:
+        return mesh_mod.device_report(list(self.mesh.devices.flat))
+
+    def _fetch(self, kind: str, fn, *operands) -> np.ndarray:
+        """Dispatch, note the program's shape and where the output's
+        shards lived, and bring the result to the host."""
+        self.programs.add((kind,) + operands[0].shape)
+        out = fn(*operands)
+        spread = len({s.device for s in out.addressable_shards})
+        self.output_spread[spread] = self.output_spread.get(spread, 0) + 1
+        return np.asarray(jax.device_get(out))
+
     # ---- batch API (the batcher's entry points) ----
 
     def _pad_batch(self, words: np.ndarray) -> np.ndarray:
         b = words.shape[0]
-        pb = -(-b // self.n_devices) * self.n_devices
+        pb = self.n_devices
+        while pb < b:
+            pb *= 2
         if pb == b:
             return words
         pad = np.zeros((pb - b,) + words.shape[1:], dtype=words.dtype)
@@ -114,7 +137,7 @@ class MeshCoder(ErasureCoder):
         assert n % 4 == 0, n
         words = self._pad_batch(np.ascontiguousarray(batch).view(np.uint32))
         fn = batch_encode_fn(self.scheme, self.mesh)
-        out = np.asarray(jax.device_get(fn(words)))
+        out = self._fetch("encode", fn, words)
         return np.ascontiguousarray(out[:B]).view(np.uint8)
 
     def rebuild_batch(self, srcdata: np.ndarray,
@@ -136,7 +159,7 @@ class MeshCoder(ErasureCoder):
         words = self._pad_batch(np.ascontiguousarray(srcdata).view(np.uint32))
         coeff = self._pad_batch(coeff)
         fn = batch_apply_fn(self.mesh, m)
-        out = np.asarray(jax.device_get(fn(words, coeff)))  # (pb, m, nw)
+        out = self._fetch("apply", fn, words, coeff)  # (pb, m, nw)
         out8 = np.ascontiguousarray(out[:B]).view(np.uint8)  # (B, m, n)
         return [np.ascontiguousarray(out8[i, :np.asarray(mats[i]).shape[0]])
                 for i in range(B)]

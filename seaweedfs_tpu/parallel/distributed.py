@@ -88,22 +88,12 @@ def rebuild_fn(scheme: RSScheme, mesh: Mesh, shards_per_device: int,
             out = out ^ gathered[d]
         return out
 
-    # jax.shard_map(check_vma=) landed after 0.4.x; this container's JAX
-    # only has jax.experimental.shard_map(check_rep=). Same semantics:
-    # the value IS 'shard'-replicated after the XOR fold.
-    if hasattr(jax, "shard_map"):
-        sm = jax.shard_map(
-            kernel, mesh=mesh,
-            in_specs=(P("shard", "seq"), P()),
-            out_specs=P(None, "seq"),
-            check_vma=False)
-    else:
-        from jax.experimental.shard_map import shard_map as _shard_map
-        sm = _shard_map(
-            kernel, mesh=mesh,
-            in_specs=(P("shard", "seq"), P()),
-            out_specs=P(None, "seq"),
-            check_rep=False)
+    # the value IS 'shard'-replicated after the XOR fold
+    sm = jax.shard_map(
+        kernel, mesh=mesh,
+        in_specs=(P("shard", "seq"), P()),
+        out_specs=P(None, "seq"),
+        check_vma=False)
     return jax.jit(sm)
 
 

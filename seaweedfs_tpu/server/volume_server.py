@@ -34,6 +34,8 @@ from seaweedfs_tpu.storage.erasure_coding import decoder as ecdec
 from seaweedfs_tpu.storage.erasure_coding import encoder as ecenc
 from seaweedfs_tpu.storage.erasure_coding import layout
 from seaweedfs_tpu.storage.erasure_coding import partial as ecpart
+from seaweedfs_tpu.storage.erasure_coding.ec_volume import \
+    ec_base_file_name
 from seaweedfs_tpu.storage.file_id import parse_needle_id_cookie
 from seaweedfs_tpu.storage.needle import Needle
 from seaweedfs_tpu.storage.store import Store
@@ -288,7 +290,7 @@ class VolumeServer:
             "volumeServer", "disk_free_bytes", "statvfs free bytes",
             ("dir",))
         # mesh->CPU drains in the EC batch scheduler, labeled by the
-        # classified reason (device_put / relay_timeout / probe_error)
+        # classified reason (device_put / timeout / probe_error)
         self._m_ec_fallbacks = self.metrics.counter(
             "volumeServer", "ec_coder_fallbacks",
             "EC batcher mesh dispatch failures drained via CPU",
@@ -364,6 +366,12 @@ class VolumeServer:
                 window_s=self._ec_batch_window_s,
                 on_fallback=lambda reason: self._m_ec_fallbacks.inc(reason))
             self._coder = BatchCoder(self.ec_batcher)
+        # {"platform", "device_kind", "count"} the EC coder dispatches
+        # to; None for the host (CPU) coders
+        report = getattr(self._coder, "device_report", None)
+        self._ec_device = report() if report else None
+        if self._ec_device is not None:
+            glog.info("volume server EC coder device: %s", self._ec_device)
         self.store = Store(
             self._store_dirs, self._max_volume_counts,
             ip=reg_host, port=reg_port,
@@ -1823,6 +1831,7 @@ class VolumeServer:
         with self._lease_lock:
             extra["Leases"] = {"held": len(self._leases),
                                **self.lease_stats}
+        extra["EcDevice"] = self._ec_device
         return Response({"Version": "seaweedfs-tpu 0.1", **extra, **hb})
 
     # ---- admin ----
@@ -2249,14 +2258,19 @@ class VolumeServer:
         return strategy
 
     def _ec_base_name(self, vid: int, collection: str = "") -> str:
-        name = f"{collection}_{vid}" if collection else str(vid)
+        # callers that only know the vid (ec.rebuild, the repair queue)
+        # get the mounted volume's own stem, collection included
+        ev = self.store.find_ec_volume(vid)
+        if ev is not None:
+            return ev.base_file_name
         for loc in self.store.locations:
-            base = os.path.join(loc.directory, name)
+            base = ec_base_file_name(loc.directory, collection, vid)
             if os.path.exists(base + ".ecx") or \
                     any(os.path.exists(base + layout.shard_ext(i))
                         for i in range(layout.TOTAL_SHARDS_COUNT)):
                 return base
-        return os.path.join(self.store.locations[0].directory, name)
+        return ec_base_file_name(self.store.locations[0].directory,
+                                 collection, vid)
 
     def _ec_copy(self, req: Request) -> Response:
         """Pull shard files (+ .ecx/.ecj/.vif) from a source server
@@ -2302,9 +2316,13 @@ class VolumeServer:
 
     def _ec_mount(self, req: Request) -> Response:
         b = req.json()
-        self.store.mount_ec_shards(b.get("collection", ""), b["volume_id"],
-                                   b["shard_ids"])
-        self._push_deltas()
+        try:
+            self.store.mount_ec_shards(b.get("collection", ""),
+                                       b["volume_id"], b["shard_ids"])
+        except NotFoundError as e:
+            return Response({"error": str(e)}, status=404)
+        finally:
+            self._push_deltas()
         return Response({})
 
     def _ec_unmount(self, req: Request) -> Response:

@@ -9,8 +9,8 @@ import threading
 from typing import Optional
 
 from seaweedfs_tpu.storage.erasure_coding import layout
-from seaweedfs_tpu.storage.erasure_coding.ec_volume import (EcVolume,
-                                                            EcVolumeShard)
+from seaweedfs_tpu.storage.erasure_coding.ec_volume import (
+    EcVolume, EcVolumeShard, ec_base_file_name)
 from seaweedfs_tpu.storage.volume import Volume
 
 _DAT_RE = re.compile(r"^(?:(?P<col>.+)_)?(?P<vid>\d+)\.dat$")
@@ -73,8 +73,7 @@ class DiskLocation:
             col = m.group("col") or ""
             found.setdefault(vid, (col, []))[1].append(int(m.group("shard")))
         for vid, (col, shards) in found.items():
-            base = os.path.join(self.directory,
-                                f"{col}_{vid}" if col else str(vid))
+            base = ec_base_file_name(self.directory, col, vid)
             if not os.path.exists(base + ".ecx"):
                 continue
             for sid in shards:
@@ -101,13 +100,26 @@ class DiskLocation:
 
     # ---- ec shards ----
     def load_ec_shard(self, collection: str, vid: int, shard_id: int) -> bool:
+        """Mount one shard file; FileNotFoundError when this location
+        does not hold it (nothing is left half-mounted)."""
         with self._lock:
             ev = self.ec_volumes.get(vid)
-            if ev is None:
-                ev = EcVolume(self.directory, collection, vid)
-                self.ec_volumes[vid] = ev
+            if ev is not None:
+                # callers that only know the vid (ec.rebuild, the repair
+                # queue) mount into the volume that is already here
+                collection = ev.collection
             shard = EcVolumeShard(self.directory, collection, vid, shard_id)
-            return ev.add_shard(shard)
+            if ev is None:
+                try:
+                    ev = EcVolume(self.directory, collection, vid)
+                except BaseException:
+                    shard.close()
+                    raise
+                self.ec_volumes[vid] = ev
+            if not ev.add_shard(shard):
+                shard.close()  # already mounted
+                return False
+            return True
 
     def unload_ec_shard(self, vid: int, shard_id: int) -> bool:
         with self._lock:

@@ -127,6 +127,15 @@ class ShardBits:
         return f"ShardBits({self.shard_ids()})"
 
 
+def ec_base_file_name(directory: str, collection: str,
+                      volume_id: int) -> str:
+    """<dir>/<collection>_<vid> (or <dir>/<vid> in the default
+    collection) — the stem every file of an EC volume shares with the
+    .dat it was encoded from (reference ec_shard.go EcShardFileName)."""
+    name = f"{collection}_{volume_id}" if collection else str(volume_id)
+    return os.path.join(directory, name)
+
+
 class EcVolumeShard:
     """One local .ecNN file (reference ec_shard.go:17-49)."""
 
@@ -136,8 +145,8 @@ class EcVolumeShard:
         self.collection = collection
         self.volume_id = volume_id
         self.shard_id = shard_id
-        self.path = os.path.join(
-            directory, f"{volume_id}{layout.shard_ext(shard_id)}")
+        self.path = ec_base_file_name(directory, collection, volume_id) \
+            + layout.shard_ext(shard_id)
         self._f = open(self.path, "rb")
         self.shard_size = os.path.getsize(self.path)
         self._lock = threading.Lock()
@@ -164,7 +173,8 @@ class EcVolume:
         self.directory = directory
         self.collection = collection
         self.volume_id = volume_id
-        self.base_file_name = os.path.join(directory, str(volume_id))
+        self.base_file_name = ec_base_file_name(directory, collection,
+                                                volume_id)
         info = read_volume_info(self.base_file_name)
         self.version = int(info.get("version", version))
         # the volume's CodeSpec (RS(10,4) when the .vif predates CodeSpec

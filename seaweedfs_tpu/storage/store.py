@@ -344,16 +344,27 @@ class Store:
     # ---- EC shards ----
     def mount_ec_shards(self, collection: str, vid: int,
                         shard_ids: list[int]) -> None:
+        """Mount shard files from whichever location holds each; a shard
+        no location has a file for raises NotFoundError (after mounting
+        the ones that were found) — the caller asked for it to serve."""
+        missing = []
         for sid in shard_ids:
             for loc in self.locations:
                 try:
                     if loc.load_ec_shard(collection, vid, sid):
+                        ev = loc.find_ec_volume(vid)
                         self.new_ec_shards.append(
-                            {"id": vid, "collection": collection,
+                            {"id": vid, "collection": ev.collection,
                              "ec_index_bits": 1 << sid})
-                        break
+                    break
                 except FileNotFoundError:
                     continue
+            else:
+                missing.append(sid)
+        if missing:
+            raise NotFoundError(
+                f"ec volume {vid} (collection {collection!r}): no shard "
+                f"file for shard(s) {missing} in any location")
 
     def coder_for(self, ev: EcVolume) -> ErasureCoder:
         """The coder matching a volume's persisted CodeSpec — self.coder

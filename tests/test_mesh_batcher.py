@@ -33,7 +33,8 @@ from seaweedfs_tpu.models.coder import DEFAULT_SCHEME, make_coder
 from seaweedfs_tpu.ops.rs_cpu import CpuCoder
 from seaweedfs_tpu.ops.rs_mesh import MeshCoder
 from seaweedfs_tpu.parallel import mesh as mesh_mod
-from seaweedfs_tpu.parallel.batcher import BatchCoder, EcBatchScheduler
+from seaweedfs_tpu.parallel.batcher import (COLUMN_LADDER, BatchCoder,
+                                            EcBatchScheduler)
 from seaweedfs_tpu.qos import BACKGROUND, INTERACTIVE, class_scope
 
 CPU = CpuCoder(DEFAULT_SCHEME)
@@ -62,7 +63,7 @@ def test_classify_failure_vocabulary():
     assert mesh_mod.classify_failure("jax device_put rejected") == \
         "device_put"
     assert mesh_mod.classify_failure("DeadlineExceeded: timeout") == \
-        "relay_timeout"
+        "timeout"
     assert mesh_mod.classify_failure("boom") == "probe_error"
 
 
@@ -162,18 +163,21 @@ class _Recorder:
 
 def test_scheduler_orders_by_qos_class():
     """An interactive job submitted AFTER a background job dispatches
-    first (distinct shapes -> distinct dispatch groups, so group order
-    is observable)."""
+    first (distinct ladder rungs -> distinct dispatch groups, so group
+    order is observable)."""
     rec = _Recorder()
     sched = EcBatchScheduler(mesh_coder=rec, window_s=0.4)
     try:
         with class_scope(BACKGROUND):
-            f_bg = sched.submit_encode(_batch(1, 16, seed=4)[0])
+            f_bg = sched.submit_encode(
+                _batch(1, COLUMN_LADDER[0] + 4, seed=4)[0])
         with class_scope(INTERACTIVE):
             f_int = sched.submit_encode(_batch(1, 8, seed=5)[0])
         f_bg.result(timeout=30)
         f_int.result(timeout=30)
-        assert rec.shapes[0][2] == 8, rec.shapes  # interactive first
+        # interactive first, padded up to its ladder rung
+        assert [s[2] for s in rec.shapes] == list(COLUMN_LADDER[:2]), \
+            rec.shapes
     finally:
         sched.stop()
 
@@ -182,10 +186,10 @@ class _Boom:
     n_devices = 8
 
     def encode_batch(self, b):
-        raise RuntimeError("device_put failed: relay vanished")
+        raise RuntimeError("device_put failed: device vanished")
 
     def rebuild_batch(self, s, m):
-        raise RuntimeError("device_put failed: relay vanished")
+        raise RuntimeError("device_put failed: device vanished")
 
 
 def test_mid_run_device_loss_drains_through_cpu():

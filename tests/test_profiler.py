@@ -369,8 +369,10 @@ def test_batcher_exports_wait_and_size_histograms():
 
     from seaweedfs_tpu.parallel.batcher import EcBatchScheduler
 
-    sched = EcBatchScheduler(mesh_coder=None, window_s=0.01)
-    sched._mesh = None  # force the CPU path regardless of environment
+    sched = EcBatchScheduler(window_s=0.01)
+    # bench the mesh: every batch takes the CPU drain, whatever the
+    # environment's devices
+    sched._down_until = float("inf")
     try:
         rng = np.random.default_rng(5)
         data = rng.integers(0, 256, (10, 64), dtype=np.uint8)

@@ -1,0 +1,169 @@
+"""Compile the served path's programs for a DESCRIBED TPU v5e, no chip.
+
+The TPU compiler is installed in the CPU sandbox and compiles for a
+topology that is described, not attached (guide on-chip-measurement §2).
+These tests hand it the main path's programs at their REAL widths:
+
+  - the flat-row parity kernel (ops/rs_jax) at 1 MiB and 4 MiB rows;
+  - the batch scheduler's two mesh programs (ops/rs_mesh) at every
+    (B, n) bucket the bounded shape ladder of parallel/batcher.py can
+    produce on one device, and on a 4-device batch mesh (no collective
+    may appear: the batch axis is embarrassingly parallel);
+  - the Pallas and MXU kernels with interpret=False (a tpu_custom_call
+    must be in the lowered program).
+
+A compile that passes is not a chip run — nothing executes, no time
+means anything — but what the chip's compiler refuses, it refuses here.
+
+The topology is described inside a module-scoped fixture (never at
+import, never autouse) and everything compiles in this process: only
+one process may hold libtpu, and under xdist every worker imports this
+file.  The persistent compile cache is switched off around the compiles
+(an entry written for a described chip cannot be read back without one).
+"""
+
+import os
+
+import numpy as np
+import pytest
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+from jax.sharding import (Mesh, NamedSharding,  # noqa: E402
+                          PartitionSpec as P, SingleDeviceSharding)
+
+from seaweedfs_tpu.models.coder import DEFAULT_SCHEME  # noqa: E402
+from seaweedfs_tpu.ops import gf256, rs_jax, rs_mesh  # noqa: E402
+from seaweedfs_tpu.parallel.batcher import shape_buckets  # noqa: E402
+
+K = DEFAULT_SCHEME.data_shards
+M = DEFAULT_SCHEME.parity_shards
+MIB = 1 << 20
+COLLECTIVES = ("all-reduce", "all-gather", "all-to-all",
+               "collective-permute", "reduce-scatter")
+# every (B, n) the scheduler's ladder can ask one device for
+BUCKETS_1 = shape_buckets(max_batch=64, n_devices=1)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _batch_mesh(topo, n):
+    return Mesh(np.array(topo.devices[:n]), ("batch",))
+
+
+def _batch_args(mesh, B, n):
+    s3 = NamedSharding(mesh, P("batch", None, None))
+    words = jax.ShapeDtypeStruct((B, K, n // 4), jnp.uint32, sharding=s3)
+    coeff = jax.ShapeDtypeStruct((B, M, K), jnp.uint32, sharding=s3)
+    return words, coeff
+
+
+@pytest.mark.parametrize("row_mib", [1, 4])
+def test_flat_row_parity_compiles(one_chip, row_mib):
+    rows = [jax.ShapeDtypeStruct((row_mib * MIB // 4,), jnp.uint32,
+                                 sharding=one_chip)] * K
+    compiled = rs_jax.parity_fn(DEFAULT_SCHEME).lower(*rows).compile()
+    assert len(compiled.output_shardings) == M
+
+
+def test_ladder_is_short():
+    # the bound itself: what the compile tests below walk
+    assert len(BUCKETS_1) <= 12, BUCKETS_1
+    assert (1, MIB) in BUCKETS_1 and (1, 4 * MIB) in BUCKETS_1
+    assert all(b & (b - 1) == 0 for b, _ in BUCKETS_1)
+
+
+@pytest.mark.parametrize("B,n", BUCKETS_1)
+def test_batch_encode_compiles_every_bucket(topo, B, n):
+    mesh = _batch_mesh(topo, 1)
+    words, _ = _batch_args(mesh, B, n)
+    compiled = rs_mesh.batch_encode_fn(DEFAULT_SCHEME, mesh) \
+        .lower(words).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
+        + mem.output_size_in_bytes < 12 << 30  # fits one v5e's 16 GB
+
+
+@pytest.mark.parametrize("B,n", BUCKETS_1)
+def test_batch_apply_compiles_every_bucket(topo, B, n):
+    mesh = _batch_mesh(topo, 1)
+    compiled = rs_mesh.batch_apply_fn(mesh, M) \
+        .lower(*_batch_args(mesh, B, n)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
+        + mem.output_size_in_bytes < 12 << 30
+
+
+@pytest.mark.parametrize("kind", ["encode", "apply"])
+def test_four_device_batch_mesh_has_no_collective(topo, kind):
+    mesh = _batch_mesh(topo, 4)
+    B, n = 16, 256 << 10  # the fullest batch the ladder gives 4 devices
+    assert (B, n) in shape_buckets(max_batch=64, n_devices=4)
+    words, coeff = _batch_args(mesh, B, n)
+    if kind == "encode":
+        compiled = rs_mesh.batch_encode_fn(DEFAULT_SCHEME, mesh) \
+            .lower(words).compile()
+    else:
+        compiled = rs_mesh.batch_apply_fn(mesh, M) \
+            .lower(words, coeff).compile()
+    text = compiled.as_text()
+    assert not [c for c in COLLECTIVES if c in text]
+    # each device holds its own B/4 lanes of the output
+    (out_s,) = jax.tree_util.tree_leaves(compiled.output_shardings)
+    assert out_s.shard_shape((B, M, n // 4)) == (B // 4, M, n // 4)
+
+
+def _parity_tuple():
+    return rs_jax._mat_to_tuple(gf256.parity_matrix(K, M))
+
+
+def test_pallas_kernel_compiles_not_interpreted(one_chip, monkeypatch):
+    from seaweedfs_tpu.ops import rs_pallas
+    # the process is on the CPU backend, where interpret_mode() says
+    # "interpret"; steer it here, in the test, to what the chip gets
+    monkeypatch.setattr(rs_pallas, "interpret_mode", lambda: False)
+    rs_pallas.pallas_apply_fn.cache_clear()
+    try:
+        fn = rs_pallas.pallas_apply_fn(_parity_tuple())
+        rows = [jax.ShapeDtypeStruct((MIB // 4,), jnp.uint32,
+                                     sharding=one_chip)] * K
+        compiled = fn.lower(*rows).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+    finally:
+        rs_pallas.pallas_apply_fn.cache_clear()
+
+
+def test_mxu_kernel_compiles_not_interpreted(one_chip, monkeypatch):
+    from seaweedfs_tpu.ops import rs_mxu
+    monkeypatch.setattr(rs_mxu, "interpret_mode", lambda: False)
+    rs_mxu.mxu_apply_fn.cache_clear()
+    try:
+        fn = rs_mxu.mxu_apply_fn(_parity_tuple())
+        rows = [jax.ShapeDtypeStruct((MIB,), jnp.uint8,
+                                     sharding=one_chip)] * K
+        compiled = fn.lower(*rows).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+    finally:
+        rs_mxu.mxu_apply_fn.cache_clear()

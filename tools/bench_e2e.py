@@ -4,7 +4,10 @@ BASELINE configs 1 and 3: build a volume of needles, measure disk->shards
 encode MB/s (per CPU tier and via the TPU streaming pipeline) and rebuild
 latency for 1..4 lost shards. Results go to PERF.md.
 
-Usage: python tools/bench_e2e.py [size_gb]
+One process: the --tpu pass runs in this interpreter (no child), so it
+is the only one that touches the chip.
+
+Usage: python tools/bench_e2e.py [size_gb] [--tpu]
 """
 import os, shutil, sys, time, tempfile
 import numpy as np

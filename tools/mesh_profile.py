@@ -17,7 +17,8 @@ binds on real multi-device hardware (see measure_scaling docstring).
 
 measure_scaling() is the importable core: __graft_entry__'s multichip
 dry run and the floor test call it so every consumer measures the same
-way.
+way.  One process: the sweep runs in this interpreter, so it is the
+only one that touches the chip(s).
 """
 
 from __future__ import annotations
@@ -43,6 +44,7 @@ def measure_scaling(device_counts=None, batch: int = 16,
     from seaweedfs_tpu.ops.rs_mesh import MeshCoder
     from seaweedfs_tpu.parallel import mesh as mesh_mod
 
+    mesh_mod.ensure_compile_cache()
     avail = mesh_mod.device_count()
     if device_counts is None:
         device_counts = [n for n in (1, 2, 4, 8, 16) if n <= avail]

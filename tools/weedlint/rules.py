@@ -77,9 +77,9 @@ raw-device-discovery
     ``jax.devices()/local_devices()/device_count()`` outside
     ``parallel/mesh.py``.  Device discovery must route through
     ``mesh.devices()`` so every layer shares one cached probe (and its
-    classified ``fallback_reason``) instead of re-hanging on a flaky
-    relay per call site, and so the driver's virtual-device request is
-    honored before any backend initializes.
+    classified ``fallback_reason``), one device report and one start-up
+    gate, and so a process that must stay off the chip has one place
+    to stay out of.
 
 unbounded-body-read
     a whole-body materialization outside the streaming reader's home
