@@ -41,6 +41,10 @@ from seaweedfs_tpu.ops import gf256
 from seaweedfs_tpu.ops.rs_jax import _apply_matrix_words, _mat_to_tuple
 from seaweedfs_tpu.parallel import mesh as mesh_mod
 from seaweedfs_tpu.parallel.distributed import _gf_mul_dynamic
+from seaweedfs_tpu.utils import tracing
+
+# a dispatch's four stages on the host, in order (ec.mesh.<stage>)
+STAGES = ("pad", "launch", "fetch", "unpack")
 
 
 @functools.lru_cache(maxsize=None)
@@ -51,11 +55,14 @@ def batch_encode_fn(scheme: RSScheme, mesh: Mesh):
     mat = _mat_to_tuple(gf256.parity_matrix(scheme.data_shards,
                                             scheme.parity_shards))
 
-    def one(words):
+    # the name is the program's in a device trace (``jit_ec_encode``):
+    # benchmark/metrics/encode_device_ms_per_job.json selects by it
+    def ec_encode(words):
         return _apply_matrix_words(words, mat)
 
     s3 = mesh_mod.batch_spec(mesh)
-    return jax.jit(jax.vmap(one), in_shardings=(s3,), out_shardings=s3)
+    return jax.jit(jax.vmap(ec_encode), in_shardings=(s3,),
+                   out_shardings=s3)
 
 
 @functools.lru_cache(maxsize=None)
@@ -66,7 +73,8 @@ def batch_apply_fn(mesh: Mesh, n_out: int):
     program serves every (survivor pattern, missing set) mix in a
     batch."""
 
-    def one(words, coeff):
+    # ``jit_ec_apply`` in a device trace (apply_device_ms_per_rebuild)
+    def ec_apply(words, coeff):
         outs = []
         for i in range(n_out):
             acc = jnp.zeros_like(words[0])
@@ -76,7 +84,8 @@ def batch_apply_fn(mesh: Mesh, n_out: int):
         return jnp.stack(outs)
 
     s3 = mesh_mod.batch_spec(mesh)
-    return jax.jit(jax.vmap(one), in_shardings=(s3, s3), out_shardings=s3)
+    return jax.jit(jax.vmap(ec_apply), in_shardings=(s3, s3),
+                   out_shardings=s3)
 
 
 @register_coder("mesh")
@@ -93,9 +102,19 @@ class MeshCoder(ErasureCoder):
         # host-side helper for rebuild-matrix derivation (pure numpy)
         from seaweedfs_tpu.ops.rs_cpu import CpuCoder
         self._host = CpuCoder(scheme)
-        # distinct (kind, padded operand shape) dispatched — each is one
-        # compiled program; the batcher's stats() reports the count
+        # distinct (kind, padded operand shape) THIS coder dispatched;
+        # the batcher's stats() reports the count as programs_compiled.
+        # Not a count of compiles: the jitted functions are cached per
+        # (scheme, mesh), so a shape another MeshCoder of the process
+        # ran first (a warm-up) is counted here though nothing compiled.
+        # Real compiles: mesh_mod.CompileWatch (backend_compiles).
         self.programs: set[tuple] = set()
+        # seconds and entries per host stage of a dispatch.  Single
+        # writer under the batch scheduler (its dispatcher thread is the
+        # only caller); a coder shared by several threads can lose adds
+        self.stage_s = dict.fromkeys(STAGES, 0.0)
+        self.stage_n = dict.fromkeys(STAGES, 0)
+        self.compile_watch = mesh_mod.install_tracing()
         # {distinct devices holding a shard of a dispatch's output:
         # dispatches} — on an n-device mesh every dispatch should land
         # under key n; anything under a smaller key ran on fewer chips
@@ -108,14 +127,27 @@ class MeshCoder(ErasureCoder):
     def device_report(self) -> dict:
         return mesh_mod.device_report(list(self.mesh.devices.flat))
 
+    def _staged(self, key: str, st: tracing.stage) -> None:
+        self.stage_s[key] += st.elapsed
+        self.stage_n[key] += 1
+
     def _fetch(self, kind: str, fn, *operands) -> np.ndarray:
         """Dispatch, note the program's shape and where the output's
         shards lived, and bring the result to the host."""
         self.programs.add((kind,) + operands[0].shape)
-        out = fn(*operands)
-        spread = len({s.device for s in out.addressable_shards})
-        self.output_spread[spread] = self.output_spread.get(spread, 0) + 1
-        return np.asarray(jax.device_get(out))
+        with tracing.stage("ec.mesh.launch") as st:
+            # host -> device copies and the enqueue; returns before the
+            # device is done
+            out = fn(*operands)
+            spread = len({s.device for s in out.addressable_shards})
+            self.output_spread[spread] = \
+                self.output_spread.get(spread, 0) + 1
+        self._staged("launch", st)
+        with tracing.stage("ec.mesh.fetch") as st:
+            # waits for the device, then device -> host
+            got = np.asarray(jax.device_get(out))
+        self._staged("fetch", st)
+        return got
 
     # ---- batch API (the batcher's entry points) ----
 
@@ -135,10 +167,16 @@ class MeshCoder(ErasureCoder):
         B, k, n = batch.shape
         assert k == self.scheme.data_shards, (k, self.scheme)
         assert n % 4 == 0, n
-        words = self._pad_batch(np.ascontiguousarray(batch).view(np.uint32))
-        fn = batch_encode_fn(self.scheme, self.mesh)
+        with tracing.stage("ec.mesh.pad") as st:
+            words = self._pad_batch(
+                np.ascontiguousarray(batch).view(np.uint32))
+            fn = batch_encode_fn(self.scheme, self.mesh)
+        self._staged("pad", st)
         out = self._fetch("encode", fn, words)
-        return np.ascontiguousarray(out[:B]).view(np.uint8)
+        with tracing.stage("ec.mesh.unpack") as st:
+            parity = np.ascontiguousarray(out[:B]).view(np.uint8)
+        self._staged("unpack", st)
+        return parity
 
     def rebuild_batch(self, srcdata: np.ndarray,
                       mats: Sequence[np.ndarray]) -> list[np.ndarray]:
@@ -151,18 +189,25 @@ class MeshCoder(ErasureCoder):
         assert k == self.scheme.data_shards and n % 4 == 0
         assert len(mats) == B
         m = self.scheme.parity_shards
-        coeff = np.zeros((B, m, k), dtype=np.uint32)
-        for i, mt in enumerate(mats):
-            mt = np.asarray(mt)
-            assert mt.shape == (mt.shape[0], k) and mt.shape[0] <= m, mt.shape
-            coeff[i, :mt.shape[0]] = mt.astype(np.uint32)
-        words = self._pad_batch(np.ascontiguousarray(srcdata).view(np.uint32))
-        coeff = self._pad_batch(coeff)
-        fn = batch_apply_fn(self.mesh, m)
+        with tracing.stage("ec.mesh.pad") as st:
+            coeff = np.zeros((B, m, k), dtype=np.uint32)
+            for i, mt in enumerate(mats):
+                mt = np.asarray(mt)
+                assert mt.shape == (mt.shape[0], k) \
+                    and mt.shape[0] <= m, mt.shape
+                coeff[i, :mt.shape[0]] = mt.astype(np.uint32)
+            words = self._pad_batch(
+                np.ascontiguousarray(srcdata).view(np.uint32))
+            coeff = self._pad_batch(coeff)
+            fn = batch_apply_fn(self.mesh, m)
+        self._staged("pad", st)
         out = self._fetch("apply", fn, words, coeff)  # (pb, m, nw)
-        out8 = np.ascontiguousarray(out[:B]).view(np.uint8)  # (B, m, n)
-        return [np.ascontiguousarray(out8[i, :np.asarray(mats[i]).shape[0]])
-                for i in range(B)]
+        with tracing.stage("ec.mesh.unpack") as st:
+            out8 = np.ascontiguousarray(out[:B]).view(np.uint8)  # (B,m,n)
+            recs = [np.ascontiguousarray(
+                out8[i, :np.asarray(mats[i]).shape[0]]) for i in range(B)]
+        self._staged("unpack", st)
+        return recs
 
     # ---- scalar ErasureCoder API (batch of one) ----
 

@@ -35,6 +35,13 @@ Scheduling contract:
     MAX_DISPATCH_COLUMNS columns, so a degraded read of a new needle
     size reuses a compiled program instead of compiling its own.
 
+Where the time goes is counted always and traced when sampled: every
+stage a job passes through (``STAGES``; utils/tracing.stage) adds its
+seconds to ``stats()["stage_s"]``, the dispatcher's own time is split
+into idle / hold / dispatch (``loop_s``), and a job submitted under a
+SAMPLED request span carries that span to the dispatcher thread, which
+records the job's stages as its children.
+
 All behavioral timing routes through clockctl so the scheduler stays
 legible to the virtual-clock sim; blocking primitives (queue waits)
 stay real because the batcher never runs inside the sim kernel.
@@ -52,7 +59,7 @@ import numpy as np
 from seaweedfs_tpu.models.coder import (DEFAULT_SCHEME, ErasureCoder,
                                         RSScheme)
 from seaweedfs_tpu.qos import CLASSES, current_class
-from seaweedfs_tpu.utils import clockctl, glog, profiler
+from seaweedfs_tpu.utils import clockctl, glog, profiler, tracing
 from seaweedfs_tpu.utils.metrics import RED_BUCKETS, Histogram
 
 # coalesced-batch-size buckets: powers of two up to the default
@@ -75,6 +82,22 @@ COLUMN_LADDER = (256 << 10, 1 << 20, 4 << 20)
 # batch AND, with the ladder, the number of (B, n) programs the
 # scheduler can ever ask for (shape_buckets below lists them).
 MAX_DISPATCH_COLUMNS = 4 << 20
+
+# the stages of a job, in the order it passes through them.  Caller
+# thread: submit (contiguous copy, ladder pad, queue put) and result
+# (future wait, copy out).  Dispatcher thread: stack, then the mesh
+# coder's pad / launch / fetch / unpack (ops/rs_mesh.STAGES), then demux.
+CALLER_STAGES = ("submit", "result")
+DISPATCH_STAGES = ("stack", "pad", "launch", "fetch", "unpack", "demux")
+STAGES = CALLER_STAGES[:1] + DISPATCH_STAGES + CALLER_STAGES[1:]
+# the dispatcher thread's time, partitioned (stats()["loop_s"])
+LOOP_PARTS = ("idle", "hold", "dispatch")
+# An idle dispatcher ends its ec.batch.idle stage and begins a fresh one
+# this often: a profiler records a stage only if it was running when the
+# stage BEGAN, so a device trace started in the middle of a long idle
+# (the seconds between two seals) names it from here on, not from the
+# next job
+IDLE_REARM_S = 0.2
 
 _STOP = object()
 _CLASS_RANK = {c: i for i, c in enumerate(CLASSES)}
@@ -115,20 +138,61 @@ def _rank(cls: Optional[str]) -> int:
 
 
 class _Job:
-    __slots__ = ("kind", "data", "mat", "n", "cls", "submitted",
+    __slots__ = ("kind", "data", "mat", "n", "cls", "span", "submitted",
                  "deadline", "future")
 
     def __init__(self, kind: str, data: np.ndarray,
                  mat: Optional[np.ndarray], n: int, cls: Optional[str],
-                 submitted: float):
+                 span, submitted: float):
         self.kind = kind          # "encode" | "rebuild"
         self.data = data          # (k, bucket_columns(n)) uint8
         self.mat = mat            # rebuild only: (r, k) uint8
         self.n = n                # original column count pre-padding
         self.cls = cls
+        # the submitter's ambient span when it is sampled (else None),
+        # captured like cls: ContextVars do not reach the dispatcher
+        self.span = span
         self.submitted = submitted
         self.deadline = submitted  # + window_s, set by the scheduler
         self.future: Future = Future()
+
+
+class _CallerCells:
+    """Seconds and entries of the stages that run in the CALLERS'
+    threads.  Each thread adds into a cell of its own (one writer, no
+    lock); ``totals`` sums the cells.  Cells of threads that have ended
+    are folded into ``_retired`` when a new thread registers (the only
+    time the lock is taken), so worker churn does not grow the list."""
+
+    def __init__(self):
+        self._local = threading.local()
+        self._lock = threading.Lock()
+        self._retired = [0.0, 0] * len(CALLER_STAGES)
+        self._cells: list[tuple[threading.Thread, list]] = []
+
+    def cell(self) -> list:
+        c = getattr(self._local, "cell", None)
+        if c is None:
+            c = self._local.cell = [0.0, 0] * len(CALLER_STAGES)
+            with self._lock:
+                live = []
+                for t, old in self._cells:
+                    if t.is_alive():
+                        live.append((t, old))
+                    else:
+                        for i, v in enumerate(old):
+                            self._retired[i] += v
+                live.append((threading.current_thread(), c))
+                self._cells = live
+        return c
+
+    def totals(self) -> list:
+        with self._lock:
+            out = list(self._retired)
+            for _t, c in self._cells:
+                for i, v in enumerate(c):
+                    out[i] += v
+        return out
 
 
 class EcBatchScheduler:
@@ -165,6 +229,9 @@ class EcBatchScheduler:
         # coder dispatches to (None for an injected coder that cannot say)
         report = getattr(self._mesh, "device_report", None)
         self.device: Optional[dict] = report() if report else None
+        # the process's backend compiles (parallel/mesh.CompileWatch),
+        # where the coder is a device coder that watches them
+        self._compiles = getattr(self._mesh, "compile_watch", None)
         self._down_until = 0.0
         # counters are only written by the dispatcher thread; readers
         # (stats/metrics) tolerate a stale int
@@ -174,6 +241,24 @@ class EcBatchScheduler:
         self.cpu_batches = 0
         self.coder_fallbacks = 0
         self.max_coalesced = 0
+        # where the time goes (module docstring).  The dispatcher is the
+        # only writer of stage_s / stage_n / by_kind
+        self.stage_s = dict.fromkeys(("stack", "demux"), 0.0)
+        self.stage_n = dict.fromkeys(("stack", "demux"), 0)
+        # the dispatcher's time, as it publishes it at every turn, in
+        # one tuple so that a reader sees totals and the running part
+        # together: (idle, hold, dispatch seconds done, index of the part
+        # it is in, since when).  stats() adds the running part, so two
+        # reads differ by the wall time between them however long the
+        # dispatcher has sat in one part
+        self._loop_pub = (0.0, 0.0, 0.0, 0, clockctl.monotonic())
+        # per kind of job as submitted (an encode that carries its own
+        # matrix rides as a rebuild): bytes_in = rows x the job's own
+        # columns, bytes_padded = the same at its ladder rung (the batch
+        # pad to a power of two is not in it), bytes_out = rows returned
+        self.by_kind = {k: {"jobs": 0, "bytes_in": 0, "bytes_padded": 0,
+                            "bytes_out": 0} for k in ("encode", "rebuild")}
+        self._callers = _CallerCells()
         # RED-discipline wait histogram (submit -> dispatch, labelled by
         # QoS class) + coalescing-quality histogram; both ride stats()
         # as mergeable snapshots, same transport as the serving RED
@@ -196,19 +281,30 @@ class EcBatchScheduler:
                 mat: Optional[np.ndarray], cls: Optional[str]) -> Future:
         if self._stopped:
             raise RuntimeError("EC batch scheduler is stopped")
-        data = np.ascontiguousarray(data, dtype=np.uint8)
-        n = data.shape[1]
-        pad = bucket_columns(n) - n
-        if pad:
-            data = np.concatenate(
-                [data, np.zeros((data.shape[0], pad), dtype=np.uint8)],
-                axis=1)
-        if cls is None:
-            cls = current_class()
-        job = _Job(kind, data, mat, n, cls, clockctl.monotonic())
-        job.deadline = job.submitted + self.window_s
-        self._q.put(job)  # bounded: blocks -> backpressure
+        span = tracing.current_span()
+        if span is not None and not span.sampled:
+            span = None
+        with tracing.stage("ec.batch.submit") as st:
+            data = np.ascontiguousarray(data, dtype=np.uint8)
+            n = data.shape[1]
+            pad = bucket_columns(n) - n
+            if pad:
+                data = np.concatenate(
+                    [data, np.zeros((data.shape[0], pad), dtype=np.uint8)],
+                    axis=1)
+            if cls is None:
+                cls = current_class()
+            job = _Job(kind, data, mat, n, cls, span, clockctl.monotonic())
+            job.deadline = job.submitted + self.window_s
+            self._q.put(job)  # bounded: blocks -> backpressure
+        self.note_caller(0, st.elapsed)
         return job.future
+
+    def note_caller(self, stage: int, seconds: float) -> None:
+        """Add to a caller-thread stage (index into CALLER_STAGES)."""
+        c = self._callers.cell()
+        c[2 * stage] += seconds
+        c[2 * stage + 1] += 1
 
     def submit_encode(self, data: np.ndarray,
                       cls: Optional[str] = None,
@@ -244,12 +340,37 @@ class EcBatchScheduler:
     # ---- dispatcher ----
 
     def _loop(self) -> None:
+        # the dispatcher's time, partitioned: every instant since the
+        # thread started is in exactly one of idle (blocked on an empty
+        # queue), hold (holding >= 1 job, waiting out the window for
+        # company) and dispatch
+        done = [0.0, 0.0, 0.0]      # idle, hold, dispatch (LOOP_PARTS)
+        t = self._loop_pub[4]
+
+        def turn(part: int) -> None:
+            """The dispatcher leaves ``part`` for the next one."""
+            nonlocal t
+            now = clockctl.monotonic()
+            done[part] += now - t
+            t = now
+            self._loop_pub = (done[0], done[1], done[2],
+                              (part + 1) % 3, now)
+
         while True:
-            job = self._q.get()
+            st = tracing.stage_begin("ec.batch.idle")
+            try:
+                job = self._q.get(timeout=IDLE_REARM_S)
+            except queue.Empty:
+                job = None
+            tracing.stage_end(st)
+            if job is None:
+                continue
+            turn(0)
             if job is _STOP:
                 return
             batch = [job]
             stopping = False
+            st = tracing.stage_begin("ec.batch.hold")
             while len(batch) < self.max_batch:
                 wait = min(j.deadline for j in batch) - clockctl.monotonic()
                 try:
@@ -265,7 +386,10 @@ class EcBatchScheduler:
                     stopping = True
                     break
                 batch.append(nxt)
+            tracing.stage_end(st)
+            turn(1)
             self._dispatch(batch)
+            turn(2)
             if stopping:
                 return
 
@@ -278,8 +402,21 @@ class EcBatchScheduler:
         self.max_coalesced = max(self.max_coalesced, len(batch))
         now = clockctl.monotonic()
         for j in batch:
-            self.wait_hist.observe(max(0.0, now - j.submitted),
-                                   j.cls or "-")
+            # in a device trace: a marker on this thread's line where the
+            # job's wait ends (a TraceMe cannot be back-dated); the
+            # wait's length is the span below and wait_hist
+            with tracing.stage("ec.batch.wait"):
+                self.wait_hist.observe(max(0.0, now - j.submitted),
+                                       j.cls or "-")
+            if j.span is not None:
+                j.span.record("ec.batch.wait", j.submitted, now)
+            bk = self.by_kind[j.kind]
+            rows = j.data.shape[0]
+            bk["jobs"] += 1
+            bk["bytes_in"] += rows * j.n
+            bk["bytes_padded"] += rows * j.data.shape[1]
+            bk["bytes_out"] += j.n * (self.scheme.parity_shards
+                                      if j.mat is None else j.mat.shape[0])
         self.size_hist.observe(len(batch))
         # QoS ordering: a group containing an interactive job dispatches
         # before an all-background group
@@ -334,19 +471,55 @@ class EcBatchScheduler:
         self._run_cpu(jobs)
         self.cpu_batches += 1
 
+    def _staged(self, key: str, st: tracing.stage) -> None:
+        self.stage_s[key] += st.elapsed
+        self.stage_n[key] += 1
+
     def _run_mesh(self, jobs: list) -> None:
         kind = jobs[0].kind
-        stacked = np.stack([j.data for j in jobs])
-        if kind == "encode":
-            out = self._mesh.encode_batch(stacked)
-            for i, j in enumerate(jobs):
-                j.future.set_result(
-                    np.ascontiguousarray(out[i][:, :j.n]))
-        else:
-            recs = self._mesh.rebuild_batch(stacked,
-                                            [j.mat for j in jobs])
-            for j, rec in zip(jobs, recs):
-                j.future.set_result(np.ascontiguousarray(rec[:, :j.n]))
+        # one ec.batch.dispatch span per dispatch, under the first
+        # sampled job; its stages (and the mesh coder's) nest in it
+        lead = next((j.span for j in jobs if j.span is not None), None)
+        compiles = self._compiles
+        n_compiled = compiles.n if compiles is not None else 0
+        tok = tracing.attach(lead)
+        try:
+            with tracing.stage("ec.batch.dispatch") as disp:
+                with tracing.stage("ec.batch.stack") as st:
+                    stacked = np.stack([j.data for j in jobs])
+                self._staged("stack", st)
+                if kind == "encode":
+                    out = self._mesh.encode_batch(stacked)
+                else:
+                    out = self._mesh.rebuild_batch(stacked,
+                                                   [j.mat for j in jobs])
+                with tracing.stage("ec.batch.demux") as st:
+                    for i, j in enumerate(jobs):
+                        j.future.set_result(
+                            np.ascontiguousarray(out[i][:, :j.n]))
+                self._staged("demux", st)
+                if compiles is not None and compiles.n != n_compiled:
+                    # which step recompiled: the warm-up (or the ladder)
+                    # missed this shape and a request paid for it
+                    glog.warning(
+                        "EC batcher: %d program(s) compiled or loaded "
+                        "inside a dispatch: %s of shape %s",
+                        compiles.n - n_compiled, kind, stacked.shape)
+                if lead is not None:
+                    disp.annotate("kind", kind)
+                    disp.annotate("shape", list(stacked.shape))
+        finally:
+            tracing.detach(tok)
+        if lead is None:
+            return
+        # the other sampled jobs of the dispatch each get a child that
+        # names the one dispatch span by its id
+        t1 = disp.t0 + disp.elapsed
+        for j in jobs:
+            if j.span is not None and j.span is not lead:
+                j.span.record("ec.batch.dispatch", disp.t0, t1,
+                              {"dispatch_id": disp.span.span_id,
+                               "jobs": len(jobs)})
 
     def _run_cpu(self, jobs: list) -> None:
         for j in jobs:
@@ -384,6 +557,20 @@ class EcBatchScheduler:
 
     def stats(self) -> dict:
         programs = getattr(self._mesh, "programs", None)
+        stage_s = dict(self.stage_s)
+        stage_n = dict(self.stage_n)
+        # pad / launch / fetch / unpack are the mesh coder's own
+        stage_s.update(getattr(self._mesh, "stage_s", None) or {})
+        stage_n.update(getattr(self._mesh, "stage_n", None) or {})
+        callers = self._callers.totals()
+        for i, name in enumerate(CALLER_STAGES):
+            stage_s[name], stage_n[name] = callers[2 * i:2 * i + 2]
+        compiles = self._compiles
+        pub = self._loop_pub
+        loop_s = dict(zip(LOOP_PARTS, pub[:3]))
+        if self._thread.is_alive():
+            loop_s[LOOP_PARTS[pub[3]]] += max(
+                0.0, clockctl.monotonic() - pub[4])
         return {
             "window_s": self.window_s,
             "max_batch": self.max_batch,
@@ -391,10 +578,16 @@ class EcBatchScheduler:
             "queued": self._q.qsize(),
             "mesh_devices": self._mesh.n_devices,
             "device": self.device,
-            # distinct (kind, B, k, n) shapes dispatched = programs
-            # this process compiled (or loaded from the compile cache)
+            # distinct (kind, B, k, n) shapes THIS scheduler's coder has
+            # dispatched.  Not a count of compiles (the jitted functions
+            # are per process: a shape a warm-up ran first is counted
+            # here though nothing compiled) — that is backend_compiles
             "programs_compiled": len(programs)
             if programs is not None else None,
+            "backend_compiles": compiles.n
+            if compiles is not None else None,
+            "backend_compile_s": compiles.seconds
+            if compiles is not None else None,
             "compile_cache_dir": self.compile_cache_dir,
             # {devices an output was spread over: dispatches}
             "output_spread": dict(getattr(self._mesh, "output_spread",
@@ -409,6 +602,10 @@ class EcBatchScheduler:
             "fallback_reason": self.fallback_reason,
             "wait_hist": self.wait_hist.snapshot(),
             "size_hist": self.size_hist.snapshot(),
+            "by_kind": {k: dict(v) for k, v in self.by_kind.items()},
+            "stage_s": {k: stage_s.get(k, 0.0) for k in STAGES},
+            "stage_n": {k: stage_n.get(k, 0) for k in STAGES},
+            "loop_s": loop_s,
         }
 
 
@@ -445,18 +642,38 @@ class BatchCoder(ErasureCoder):
     def device_report(self) -> Optional[dict]:
         return self.scheduler.device
 
+    def _result(self, fut: Future,
+                out: Optional[np.ndarray] = None) -> np.ndarray:
+        """Wait for a submitted job and (with ``out``) copy its rows
+        out: the caller's last stage."""
+        with tracing.stage("ec.batch.result") as st:
+            rec = fut.result()
+            if out is not None:
+                out[:] = rec
+                rec = out
+        self.scheduler.note_caller(1, st.elapsed)
+        return rec
+
+    def _encode(self, data: np.ndarray,
+                out: Optional[np.ndarray] = None) -> np.ndarray:
+        return self._result(self.scheduler.submit_encode(
+            data, mat=self._encode_mat), out)
+
+    def _rebuild(self, src: np.ndarray, mat: np.ndarray,
+                 out: Optional[np.ndarray] = None) -> np.ndarray:
+        return self._result(self.scheduler.submit_rebuild(src, mat), out)
+
     def encode_array(self, data: np.ndarray) -> np.ndarray:
-        return self.scheduler.encode(data, mat=self._encode_mat)
+        return self._encode(data)
 
     def encode_into(self, data: np.ndarray, out: np.ndarray) -> np.ndarray:
-        out[:] = self.scheduler.encode(data, mat=self._encode_mat)
-        return out
+        return self._encode(data, out)
 
     def encode(self, shards: Sequence[bytes]) -> list[bytes]:
         k = self.scheme.data_shards
         data = np.stack([np.frombuffer(bytes(shards[i]), dtype=np.uint8)
                          for i in range(k)])
-        parity = self.scheduler.encode(data, mat=self._encode_mat)
+        parity = self._encode(data)
         return [bytes(shards[i]) for i in range(k)] + \
             [parity[i].tobytes() for i in range(self.scheme.parity_shards)]
 
@@ -467,11 +684,7 @@ class BatchCoder(ErasureCoder):
     def reconstruct_rows(self, srcdata: np.ndarray,
                          rebuild_mat: np.ndarray,
                          out: Optional[np.ndarray] = None) -> np.ndarray:
-        rec = self.scheduler.rebuild(srcdata, rebuild_mat)
-        if out is not None:
-            out[:] = rec
-            return out
-        return rec
+        return self._rebuild(srcdata, rebuild_mat, out)
 
     def _rebuild_plan(self, present: Sequence[int], missing: Sequence[int]
                       ) -> tuple[list[int], np.ndarray]:
@@ -493,7 +706,7 @@ class BatchCoder(ErasureCoder):
         src_sids, mat = self._rebuild_plan(present, missing)
         src = np.stack([np.frombuffer(bytes(shards[i]), dtype=np.uint8)
                         for i in src_sids])
-        rec = self.scheduler.rebuild(src, mat)
+        rec = self._rebuild(src, mat)
         out = [bytes(s) if s is not None else None for s in shards]
         for r, i in enumerate(missing):
             out[i] = rec[r].tobytes()
@@ -512,7 +725,7 @@ class BatchCoder(ErasureCoder):
         src_sids, mat = self._rebuild_plan(present, missing_data)
         src = np.stack([np.frombuffer(bytes(shards[i]), dtype=np.uint8)
                         for i in src_sids])
-        rec = self.scheduler.rebuild(src, mat)
+        rec = self._rebuild(src, mat)
         for r, i in enumerate(missing_data):
             out[i] = rec[r].tobytes()
         return out

@@ -16,6 +16,13 @@ buys four things the scattered call sites could not:
     compilation cache before the first ``jit`` of a process;
   - mesh constructors that agree on axis vocabulary.
 
+It is also where the program meets ``jax.profiler`` and
+``jax.monitoring``, for the same reason (utils/tracing.py and
+utils/httpd.py never import jax): :func:`install_tracing` hands
+``TraceAnnotation`` to ``tracing.set_annotator`` and starts the process's
+one :class:`CompileWatch`; :func:`device_trace` is the one exporter of a
+device trace (``POST /admin/ec/trace``).
+
 Axis vocabulary (the storage-system analogue of dp/tp/sp, SURVEY.md §5.7):
   - 'data'  : batch of independent volumes (data parallel)
   - 'shard' : the 14 RS shards of one volume (tensor/model parallel — the
@@ -28,6 +35,8 @@ Axis vocabulary (the storage-system analogue of dp/tp/sp, SURVEY.md §5.7):
 
 from __future__ import annotations
 
+import contextlib
+import glob
 import math
 import os
 import threading
@@ -36,6 +45,8 @@ from typing import Optional
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from seaweedfs_tpu.utils import tracing
 
 _probe_lock = threading.Lock()
 _probe_cache: Optional[dict] = None
@@ -142,6 +153,73 @@ def ensure_compile_cache() -> Optional[str]:
         jax.config.update("jax_compilation_cache_dir", path)
         _cache_dir = path
         return _cache_dir
+
+
+class CompileWatch:
+    """Every ``backend_compile`` of this process — a compile, or a load
+    from the persistent cache — counted and timed from the moment the
+    watch was started (``jax.monitoring`` has no per-listener
+    unregister, so there is one per process: :func:`install_tracing`).
+    A compile under an ambient request span annotates that span: a
+    request that waited for the compiler says so in /debug/traces."""
+
+    EVENT = "/jax/core/compile/backend_compile_duration"
+
+    def __init__(self):
+        self.n = 0
+        self.seconds = 0.0
+        jax.monitoring.register_event_duration_secs_listener(self._on)
+
+    def _on(self, event: str, duration: float, **_kw) -> None:
+        if event != self.EVENT:
+            return
+        # compiles are serialized by jax's own compile lock, so this is
+        # a single writer at a time
+        self.n += 1
+        self.seconds += duration
+        tracing.annotate("compiled_in_request", round(duration, 3))
+
+
+_tracing_lock = threading.Lock()
+_compile_watch: Optional[CompileWatch] = None
+
+
+def install_tracing() -> CompileWatch:
+    """Called when a device coder is built: from then on every
+    ``tracing.stage`` of the process is also a
+    ``jax.profiler.TraceAnnotation`` (an atomic load while no profile
+    runs; a host event on the profiler's clock, in the same
+    ``.xplane.pb`` as the device's, while one does), and backend
+    compiles are counted.  Idempotent; returns the process's watch."""
+    global _compile_watch
+    with _tracing_lock:
+        if _compile_watch is None:
+            tracing.set_annotator(jax.profiler.TraceAnnotation)
+            _compile_watch = CompileWatch()
+        return _compile_watch
+
+
+@contextlib.contextmanager
+def device_trace(log_dir: str):
+    """Take a device trace into ``log_dir`` for the length of the block:
+    device planes plus the host's TraceMe events at level 1 (the
+    program's stages, XLA's own spans), no Python frames.  Raises
+    RuntimeError where a profile is already running (jax allows one per
+    process).  Yields a dict that holds, after the block, the
+    ``.xplane.pb`` files written and their total size."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(log_dir, profiler_options=opts)
+    out: dict = {"dir": log_dir, "xplane": [], "xplane_bytes": 0}
+    try:
+        yield out
+    finally:
+        jax.profiler.stop_trace()
+        out["xplane"] = sorted(glob.glob(os.path.join(
+            log_dir, "plugins", "profile", "*", "*.xplane.pb")))
+        out["xplane_bytes"] = sum(os.path.getsize(p)
+                                  for p in out["xplane"])
 
 
 def classify_failure(err: Optional[str]) -> Optional[str]:

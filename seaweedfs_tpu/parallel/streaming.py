@@ -22,6 +22,11 @@ Stage plumbing invariants:
   - buffers are pooled and recycled writer -> reader, so steady-state
     allocation is zero.
 
+Each run is one ``ec.pipeline`` stage (utils/tracing.stage) with an
+``ec.pipeline.read`` / ``.encode`` / ``.write`` stage per batch in the
+thread that does it and ``ec.pipeline.commit`` at the end; the same busy
+seconds fill the caller's ``stats`` dict.
+
 The batched API at the bottom encodes many volumes concurrently by
 stacking them on a leading axis the device iterates with one program.
 """
@@ -37,7 +42,7 @@ import numpy as np
 
 from seaweedfs_tpu.models.coder import DEFAULT_SCHEME, ErasureCoder, RSScheme
 from seaweedfs_tpu.storage.erasure_coding import layout
-from seaweedfs_tpu.utils import clockctl
+from seaweedfs_tpu.utils import clockctl, tracing
 
 DEFAULT_PIPE_BATCH = 16 * 1024 * 1024
 
@@ -93,10 +98,15 @@ class _Pipeline:
                 continue
 
     def spawn(self, fn, *args) -> threading.Thread:
-        """Run fn(*args) in a daemon thread; any exception trips abort."""
+        """Run fn(*args) in a daemon thread; any exception trips abort.
+        The spawner's ambient span is re-entered there (ContextVars do
+        not cross threads), so the stage's spans hang off the caller's."""
+        span = tracing.current_span()
+
         def run():
             try:
-                fn(*args)
+                with tracing.span_scope(span):
+                    fn(*args)
             except _Aborted:
                 pass
             except BaseException as e:  # noqa: BLE001 — must reach caller
@@ -230,10 +240,10 @@ def pipelined_encode_file(base_file_name: str,
         busy = 0.0
         with open(dat_path, "rb") as f:
             for seq in range(rid, len(descs), readers):
-                t0 = clockctl.monotonic()
-                buf = data_pool.get((k, descs[seq][3]))
-                _read_rows(f, buf, descs[seq], k)
-                busy += clockctl.monotonic() - t0
+                with tracing.stage("ec.pipeline.read") as st:
+                    buf = data_pool.get((k, descs[seq][3]))
+                    _read_rows(f, buf, descs[seq], k)
+                busy += st.elapsed
                 pl.put(read_q, (seq, buf))
         _merge_stats(stats, slock, read_s=busy)
 
@@ -244,17 +254,17 @@ def pipelined_encode_file(base_file_name: str,
             if item is None:
                 break
             data, parity = item
-            t0 = clockctl.monotonic()
-            if fn is not None:
-                # materialize BEFORE recycling: on the CPU jax backend
-                # device_put may alias the host buffer, so the data array
-                # must stay untouched until the parity is out
-                parity = [np.asarray(p).view(np.uint8) for p in parity]
-            for i in range(k):
-                outs.files[i].write(data[i])
-            for r in range(m):
-                outs.files[k + r].write(parity[r])
-            busy += clockctl.monotonic() - t0
+            with tracing.stage("ec.pipeline.write") as st:
+                if fn is not None:
+                    # materialize BEFORE recycling: on the CPU jax backend
+                    # device_put may alias the host buffer, so the data
+                    # array must stay untouched until the parity is out
+                    parity = [np.asarray(p).view(np.uint8) for p in parity]
+                for i in range(k):
+                    outs.files[i].write(data[i])
+                for r in range(m):
+                    outs.files[k + r].write(parity[r])
+            busy += st.elapsed
             data_pool.put(data)
             if isinstance(parity, np.ndarray):
                 parity_pool.put(parity)
@@ -262,6 +272,7 @@ def pipelined_encode_file(base_file_name: str,
 
     outs = AtomicFileGroup([base_file_name + layout.shard_ext(i)
                             for i in range(total)])
+    whole = tracing.stage_begin("ec.pipeline")
     try:
         writer_t = pl.spawn(writer_stage, outs)
         for rid in range(readers):
@@ -274,27 +285,35 @@ def pipelined_encode_file(base_file_name: str,
                 seq, buf = pl.get(read_q)
                 stash[seq] = buf
             data = stash.pop(expected)
-            t0 = clockctl.monotonic()
-            if fn is not None:
-                words = data.view(np.uint32)
-                import jax
-                rows = [jax.device_put(words[i]) for i in range(k)]
-                parity = fn(*rows)  # async dispatch; writer synchronizes
-            else:
-                pbuf = parity_pool.get((m, data.shape[1]))
-                if hasattr(coder, "encode_into"):
-                    parity = coder.encode_into(data, pbuf)
+            # the call into the coder: under the batch scheduler it
+            # CONTAINS the job's submit / wait / dispatch / result
+            with tracing.stage("ec.pipeline.encode") as st:
+                if fn is not None:
+                    words = data.view(np.uint32)
+                    import jax
+                    rows = [jax.device_put(words[i]) for i in range(k)]
+                    parity = fn(*rows)  # async; the writer synchronizes
                 else:
-                    parity = np.asarray(coder.encode_array(data))
-            encode_busy += clockctl.monotonic() - t0
+                    pbuf = parity_pool.get((m, data.shape[1]))
+                    if hasattr(coder, "encode_into"):
+                        parity = coder.encode_into(data, pbuf)
+                    else:
+                        parity = np.asarray(coder.encode_array(data))
+            encode_busy += st.elapsed
             pl.put(write_q, (data, parity))
         pl.put(write_q, None)
         writer_t.join()
         pl.join()
+        with tracing.stage("ec.pipeline.commit") as st:
+            outs.commit()
         _merge_stats(stats, slock, encode_s=encode_busy,
+                     commit_s=st.elapsed,
                      wall_s=clockctl.monotonic() - wall0,
                      bytes_in=dat_size, batches=len(descs))
-        outs.commit()
+        if stats is not None:
+            for key in ("read_s", "encode_s", "write_s", "commit_s",
+                        "wall_s", "bytes_in", "batches"):
+                whole.annotate(key, stats.get(key))
     except _Aborted:
         # a stage failed and tripped abort while the main thread blocked;
         # surface the stage's exception, not the control-flow marker
@@ -303,6 +322,8 @@ def pipelined_encode_file(base_file_name: str,
         pl.abort.set()
         _unwind(pl, outs, reraise=False)
         raise
+    finally:
+        tracing.stage_end(whole)
 
 
 def _unwind(pl: _Pipeline, outs: "AtomicFileGroup",
@@ -362,16 +383,16 @@ def pipelined_rebuild_files(base_file_name: str,
         try:
             for off in offs:
                 n = min(batch_size, shard_size - off)
-                t0 = clockctl.monotonic()
-                buf = data_pool.get((n_src, n))
-                for r, f in enumerate(ins):
-                    f.seek(off)
-                    got = f.readinto(memoryview(buf[r]))
-                    if got < n:
-                        raise IOError(
-                            f"short read on {base_file_name}"
-                            f"{layout.shard_ext(src[r])} at {off}")
-                busy += clockctl.monotonic() - t0
+                with tracing.stage("ec.pipeline.read") as st:
+                    buf = data_pool.get((n_src, n))
+                    for r, f in enumerate(ins):
+                        f.seek(off)
+                        got = f.readinto(memoryview(buf[r]))
+                        if got < n:
+                            raise IOError(
+                                f"short read on {base_file_name}"
+                                f"{layout.shard_ext(src[r])} at {off}")
+                busy += st.elapsed
                 pl.put(read_q, buf)
             pl.put(read_q, None)
         finally:
@@ -385,15 +406,16 @@ def pipelined_rebuild_files(base_file_name: str,
             item = pl.get(write_q)
             if item is None:
                 break
-            t0 = clockctl.monotonic()
-            for r in range(len(missing)):
-                outs.files[r].write(item[r])
-            busy += clockctl.monotonic() - t0
+            with tracing.stage("ec.pipeline.write") as st:
+                for r in range(len(missing)):
+                    outs.files[r].write(item[r])
+            busy += st.elapsed
             out_pool.put(item)
         _merge_stats(stats, slock, write_s=busy)
 
     outs = AtomicFileGroup([base_file_name + layout.shard_ext(i)
                             for i in missing])
+    whole = tracing.stage_begin("ec.pipeline")
     try:
         writer_t = pl.spawn(writer_stage, outs)
         pl.spawn(reader_stage)
@@ -402,29 +424,32 @@ def pipelined_rebuild_files(base_file_name: str,
             buf = pl.get(read_q)
             if buf is None:
                 break
-            t0 = clockctl.monotonic()
-            rec = coder.reconstruct_rows(
-                buf, rmat, out_pool.get((len(missing), buf.shape[1])))
-            busy += clockctl.monotonic() - t0
+            with tracing.stage("ec.pipeline.encode") as st:
+                rec = coder.reconstruct_rows(
+                    buf, rmat, out_pool.get((len(missing), buf.shape[1])))
+            busy += st.elapsed
             pl.put(write_q, rec)
             data_pool.put(buf)
         pl.put(write_q, None)
         writer_t.join()
         pl.join()
-        _merge_stats(stats, slock, encode_s=busy,
+        with tracing.stage("ec.pipeline.commit") as st:
+            outs.commit()
+        _merge_stats(stats, slock, encode_s=busy, commit_s=st.elapsed,
                      wall_s=clockctl.monotonic() - wall0,
                      bytes_in=shard_size * n_src, batches=len(offs),
                      rebuilt_bytes=shard_size * len(missing))
         if stats is not None:
             with slock:
                 stats["sources"] = list(src)
-        outs.commit()
     except _Aborted:
         _unwind(pl, outs)
     except BaseException:
         pl.abort.set()
         _unwind(pl, outs, reraise=False)
         raise
+    finally:
+        tracing.stage_end(whole)
     return missing
 
 

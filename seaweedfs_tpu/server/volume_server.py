@@ -295,6 +295,12 @@ class VolumeServer:
             "volumeServer", "ec_coder_fallbacks",
             "EC batcher mesh dispatch failures drained via CPU",
             ("reason",))
+        # the EC batch scheduler's account of its time (stats()'s
+        # stage_s and loop_s), refreshed at scrape
+        self._m_ec_stage = self.metrics.gauge(
+            "volumeServer", "ec_batch_stage_seconds",
+            "cumulative seconds per stage of an EC job, and of the "
+            "dispatcher's idle / hold / dispatch", ("stage",))
         # hot-needle record cache + selector-core connection counters,
         # refreshed at scrape from their owners' stats() snapshots
         self._m_cache = self.metrics.gauge(
@@ -833,6 +839,7 @@ class VolumeServer:
         r("POST", "/admin/ec/rebuild_partial", self._ec_rebuild_partial)
         # batch-scheduler snapshot (coalescing + fallback counters)
         r("GET", "/admin/ec/batcher", self._admin_ec_batcher)
+        r("POST", "/admin/ec/trace", self._admin_ec_trace)
         # integrity scrub
         r("POST", "/admin/scrub", self._admin_scrub)
         r("GET", "/admin/scrub/status", self._admin_scrub_status)
@@ -858,6 +865,37 @@ class VolumeServer:
             return Response({"enabled": False})
         return Response({"enabled": True, **self.ec_batcher.stats()})
 
+    EC_TRACE_MAX_S = 30.0
+
+    def _admin_ec_trace(self, req: Request) -> Response:
+        """Take a device trace of this (live) server for ``seconds``
+        into ``dir``: the chip's operations and the served path's stages
+        (utils/tracing.stage) in one ``.xplane.pb``, on one clock.  Only
+        the process that holds the chip can trace it, so the exporter is
+        here.  Blocks for the window; 409 while a profile is running."""
+        if self.ec_batcher is None:
+            return Response({"error": "no device coder: start the "
+                             "volume server with -ecBatcher"}, status=404)
+        b = req.json()
+        try:
+            seconds = float(b["seconds"])
+            log_dir = str(b["dir"])
+        except (KeyError, TypeError, ValueError):
+            return Response({"error": 'body: {"seconds": s, "dir": d}'},
+                            status=400)
+        if not 0 < seconds <= self.EC_TRACE_MAX_S or not log_dir:
+            return Response({"error": f"seconds in (0, "
+                             f"{self.EC_TRACE_MAX_S:g}], dir not empty"},
+                            status=400)
+        from seaweedfs_tpu.parallel import mesh as mesh_mod
+        try:
+            with mesh_mod.device_trace(log_dir) as out:
+                clockctl.sleep(seconds)
+        except RuntimeError as e:
+            # jax allows one profile per process at a time
+            return Response({"error": str(e)}, status=409)
+        return Response({"seconds": seconds, **out})
+
     def _admin_health(self, req: Request) -> Response:
         return Response({"url": self.url,
                          "scrubbing": self._is_scrubbing(),
@@ -868,7 +906,7 @@ class VolumeServer:
     # overloaded (shedding /admin/qos would saw off the escape hatch)
     QOS_EXEMPT = ("/status", "/metrics", "/ui", "/debug",
                   "/admin/qos", "/admin/health", "/admin/scrub/status",
-                  "/admin/ec/batcher", "/admin/hotkeys",
+                  "/admin/ec/batcher", "/admin/ec/trace", "/admin/hotkeys",
                   "/admin/telemetry", "/admin/cache", "/admin/hints",
                   "/admin/profile")
 
@@ -992,6 +1030,12 @@ class VolumeServer:
                 self._m_cache.set(stat, value=cs[stat])
         for stat, val in self.http.conn_stats().items():
             self._m_conns.set(stat, value=val)
+        if self.ec_batcher is not None:
+            bs = self.ec_batcher.stats()
+            for stage, val in bs["stage_s"].items():
+                self._m_ec_stage.set(stage, value=val)
+            for part, val in bs["loop_s"].items():
+                self._m_ec_stage.set("loop." + part, value=val)
 
     def _handle_metrics(self, req: Request) -> Response:
         return Response(self.metrics.expose_text(),
@@ -1225,10 +1269,16 @@ class VolumeServer:
         return int(nv[1])
 
     def _handle_read(self, req: Request) -> Response:
+        with tracing.stage("volume.read"):
+            return self._handle_read_limited(req)
+
+    def _handle_read_limited(self, req: Request) -> Response:
         # byte-accounted backpressure only on the real HTTP socket path
         # (gRPC/LocalRequest dispatch never fires on_sent)
-        est = self._peek_read_size(req) \
-            if getattr(req, "handler", None) is not None else 0
+        est = 0
+        if getattr(req, "handler", None) is not None:
+            with tracing.stage("volume.read.peek"):
+                est = self._peek_read_size(req)
         if est and not self.download_limiter.try_acquire(est):
             self._m_req.inc("read_shed")
             return Response({"error": "too many requests"}, status=429,
@@ -1297,6 +1347,13 @@ class VolumeServer:
             return Response(b"", status=404, content_type="text/plain")
         except CookieMismatchError:
             return Response(b"", status=404, content_type="text/plain")
+        with tracing.stage("volume.read.respond"):
+            return self._needle_response(req, vid, key, n)
+
+    def _needle_response(self, req: Request, vid: int, key: int,
+                         n) -> Response:
+        """The buffered read's reply for a needle in hand: ledger
+        charge, headers, transforms, range and ETag handling."""
         h = getattr(req, "handler", None)
         self.ledger.charge_disk(
             len(n.data),
@@ -2204,10 +2261,16 @@ class VolumeServer:
     # ---- EC rpcs (reference volume_grpc_erasure_coding.go) ----
     def _ec_generate(self, req: Request) -> Response:
         b = req.json()
-        base = self.store.generate_ec_shards(
-            b["volume_id"], pipelined=b.get("pipelined", True),
-            code=b.get("code", ""))
-        return Response({"base": os.path.basename(base)})
+        # the pipeline's own account of the seal (read_s / encode_s /
+        # write_s / commit_s busy seconds, wall_s, bytes_in, batches);
+        # empty for the serial path
+        stats: dict = {}
+        with tracing.stage("volume.ec.generate"):
+            base = self.store.generate_ec_shards(
+                b["volume_id"], pipelined=b.get("pipelined", True),
+                stats=stats, code=b.get("code", ""))
+        return Response({"base": os.path.basename(base),
+                         "pipeline": stats})
 
     def _ec_volume_coder(self, base: str) -> ErasureCoder:
         """The coder for the volume at `base`, per its .vif CodeSpec
@@ -2416,7 +2479,8 @@ class VolumeServer:
                "shard_size": max(sizes.values()),
                "code": scheme_to_dict(scheme_from_dict(
                    read_volume_info(base).get("code"))),
-               "recover_stats": dict(self.store.ec_recover_stats)}
+               "recover_stats": dict(self.store.ec_recover_stats),
+               "read_stats": dict(self.store.ec_read_stats)}
         last = self._ec_last_strategy.get(vid)
         if last:
             out["last_repair"] = last

@@ -36,7 +36,7 @@ import threading
 from collections import OrderedDict
 from typing import Callable, Optional
 
-from seaweedfs_tpu.utils import resilience
+from seaweedfs_tpu.utils import resilience, tracing
 
 # accounting overhead per entry (key tuple, OrderedDict node, blob
 # header) — keeps thousands of tiny needles from blowing the budget
@@ -125,6 +125,7 @@ class NeedleCache:
             if ent is not None:
                 self._entries.move_to_end(key)
                 self.hits += 1
+                tracing.detail("cache", "hit")  # sampled spans only
                 return ent
             fl = self._flights.get(key)
             if fl is None:
@@ -136,6 +137,7 @@ class NeedleCache:
             else:
                 leader = False
                 self.coalesced += 1
+        tracing.detail("cache", "miss" if leader else "waited")
         if leader:
             try:
                 blob, size, version, force = loader()

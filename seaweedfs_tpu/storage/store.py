@@ -24,6 +24,7 @@ from seaweedfs_tpu.storage.needle import Needle
 from seaweedfs_tpu.storage.super_block import ReplicaPlacement, TTL
 from seaweedfs_tpu.storage.volume import (CookieMismatchError, DeletedError,
                                           NotFoundError, Volume)
+from seaweedfs_tpu.utils import tracing
 
 # remote_shard_reader(vid, shard_id, offset, size) -> bytes | None
 RemoteShardReader = Callable[[int, int, int, int], Optional[bytes]]
@@ -94,6 +95,16 @@ class Store:
         # "local" = planned group-local recovery, "global" = planned
         # full-width recovery, "generic" = unplanned collect-k fallback
         self.ec_recover_stats = {"local": 0, "global": 0, "generic": 0}
+        # what the EC read path read and waited for (shard_stat's
+        # "read_stats"; ec_recover_stats keeps its three keys): intervals
+        # served by a local pread / rebuilt, the survivor columns a
+        # rebuild read and their bytes, seconds spent in recovery.
+        # Bumped by request threads without a lock, like the tallies
+        # above: a lost add under contention is tolerated
+        self.ec_read_stats = {"intervals_local": 0,
+                              "intervals_recovered": 0,
+                              "survivor_reads": 0, "survivor_bytes": 0,
+                              "recover_s": 0.0}
 
     def load_existing_volumes(self) -> None:
         for loc in self.locations:
@@ -402,13 +413,17 @@ class Store:
             coder = self._coder_cache.setdefault(coder.scheme, coder)
         else:
             coder = self.coder
-        v.read_only = True
-        v.sync()
-        base = v.file_name()
-        ecenc.write_sorted_ecx(base)
-        ecenc.write_ec_files(base, coder, pipelined=pipelined,
-                             stats=stats)
-        write_volume_info(base, v.version, coder.scheme)
+        with tracing.stage("store.ec.generate"):
+            v.read_only = True
+            with tracing.stage("store.ec.generate.sync"):
+                v.sync()
+            base = v.file_name()
+            with tracing.stage("store.ec.generate.ecx"):
+                ecenc.write_sorted_ecx(base)
+            ecenc.write_ec_files(base, coder, pipelined=pipelined,
+                                 stats=stats)
+            with tracing.stage("store.ec.generate.vif"):
+                write_volume_info(base, v.version, coder.scheme)
         return base
 
     def unmount_ec_shards(self, vid: int, shard_ids: list[int]) -> None:
@@ -447,16 +462,20 @@ class Store:
             raise NotFoundError(f"ec volume {vid} not found")
         cache = self.needle_cache
         if cache is None:
-            intervals, offset, size = ev.locate_needle(needle_id)
+            with tracing.stage("store.ec.locate"):
+                intervals, offset, size = ev.locate_needle(needle_id)
             if t.size_is_deleted(size):
                 raise DeletedError(f"needle {needle_id:x} deleted")
             blob = b"".join(
                 self._read_one_interval(ev, iv) for iv in intervals)
             n = Needle.from_bytes(blob, size, ev.version)
         else:
-            blob, size, version = cache.get_or_load(
-                vid, needle_id,
-                lambda: self._load_ec_record(ev, needle_id))
+            # contains the loader's stages on a miss; its own time is the
+            # lookup and the wait for another reader's flight
+            with tracing.stage("store.ec.cache"):
+                blob, size, version = cache.get_or_load(
+                    vid, needle_id,
+                    lambda: self._load_ec_record(ev, needle_id))
             # admission verified the blob's CRC; hits skip the re-walk
             n = Needle.from_bytes(blob, size, version, check_crc=False)
             n.checksum = needle.payload_crc_stored(blob, size)
@@ -469,7 +488,8 @@ class Store:
         """Cache loader: the needle's full record blob via the interval
         ladder. Flags whether any interval was degraded-reconstructed,
         so the cache force-admits records that cost a decode."""
-        intervals, _offset, size = ev.locate_needle(needle_id)
+        with tracing.stage("store.ec.locate"):
+            intervals, _offset, size = ev.locate_needle(needle_id)
         if t.size_is_deleted(size):
             raise DeletedError(f"needle {needle_id:x} deleted")
         meter = {"recovered": 0}
@@ -478,7 +498,8 @@ class Store:
         # the one CRC walk this blob ever pays: admission-time, over
         # memoryview windows — hits re-parse with check_crc=False and
         # range reads serve memoryview slices of the verified bytes
-        needle.verify_record_crc(blob, size, ev.version)
+        with tracing.stage("store.ec.crc"):
+            needle.verify_record_crc(blob, size, ev.version)
         return blob, size, ev.version, meter["recovered"] > 0
 
     def _read_record_range(self, ev: EcVolume, rec_offset: int,
@@ -617,8 +638,10 @@ class Store:
 
     def _read_one_interval(self, ev: EcVolume, iv: layout.Interval,
                            meter: Optional[dict] = None) -> bytes:
-        data, shard_id = ev.read_interval(iv)
+        with tracing.stage("store.ec.read_interval"):
+            data, shard_id = ev.read_interval(iv)
         if data is not None:
+            self.ec_read_stats["intervals_local"] += 1
             return data
         # remote shard
         if self.remote_shard_reader is not None:
@@ -630,7 +653,13 @@ class Store:
         # degraded: fetch the same range of >= k other shards and reconstruct
         if meter is not None:
             meter["recovered"] = meter.get("recovered", 0) + 1
-        return self._recover_one_interval(ev, iv, shard_id)
+        with tracing.stage("store.ec.recover") as st:
+            st.annotate("bytes", iv.size)
+            got = self._recover_one_interval(ev, iv, shard_id)
+        rs = self.ec_read_stats
+        rs["intervals_recovered"] += 1
+        rs["recover_s"] += st.elapsed
+        return got
 
 
     RECOVER_POOL_WORKERS = 32  # > 2x total shards: room for concurrent
@@ -673,25 +702,27 @@ class Store:
                 return got
         bufs: dict[int, bytes] = {}
         remote_sids: list[int] = []
-        for sid in range(total):
-            if sid == wanted_shard:
-                continue
-            local = ev.shards.get(sid)
-            if local is not None:
-                bufs[sid] = local.read_at(shard_off, iv.size)
-                # a plan-capable coder may find an arbitrary k-subset
-                # rank-deficient, so keep every local column for it
-                if len(bufs) >= k and not plan_capable:
-                    break
-            elif self.remote_shard_reader is not None:
-                remote_sids.append(sid)
-        # same reasoning remotely: the fallback is rare (a planned
-        # source was unreachable), so over-collect for plan coders
-        need = k if not plan_capable \
-            else min(total - 1, len(bufs) + len(remote_sids))
-        if len(bufs) < need and remote_sids:
-            self._fetch_remote_shards(ev, iv, shard_off, bufs,
-                                      remote_sids, need)
+        with tracing.stage("store.ec.survivors"):
+            for sid in range(total):
+                if sid == wanted_shard:
+                    continue
+                local = ev.shards.get(sid)
+                if local is not None:
+                    bufs[sid] = local.read_at(shard_off, iv.size)
+                    # a plan-capable coder may find an arbitrary k-subset
+                    # rank-deficient, so keep every local column for it
+                    if len(bufs) >= k and not plan_capable:
+                        break
+                elif self.remote_shard_reader is not None:
+                    remote_sids.append(sid)
+            # same reasoning remotely: the fallback is rare (a planned
+            # source was unreachable), so over-collect for plan coders
+            need = k if not plan_capable \
+                else min(total - 1, len(bufs) + len(remote_sids))
+            if len(bufs) < need and remote_sids:
+                self._fetch_remote_shards(ev, iv, shard_off, bufs,
+                                          remote_sids, need)
+        self._count_survivors(bufs, iv.size)
         if len(bufs) < k:
             raise NotFoundError(
                 f"ec volume {ev.volume_id}: only {len(bufs)} shards "
@@ -707,6 +738,11 @@ class Store:
                 f"but pattern unrecoverable: {e}")
         self.ec_recover_stats["generic"] += 1
         return full[wanted_shard]
+
+    def _count_survivors(self, bufs: dict, size: int) -> None:
+        rs = self.ec_read_stats
+        rs["survivor_reads"] += len(bufs)
+        rs["survivor_bytes"] += len(bufs) * size
 
     def _recover_via_plan(self, ev: EcVolume, iv: layout.Interval,
                           shard_off: int, coder: ErasureCoder,
@@ -726,17 +762,19 @@ class Store:
             return None
         bufs: dict[int, bytes] = {}
         remote: list[int] = []
-        for sid in src:
-            local = ev.shards.get(sid)
-            if local is not None:
-                bufs[sid] = local.read_at(shard_off, iv.size)
-            elif self.remote_shard_reader is not None:
-                remote.append(sid)
-            else:
-                return None
-        if remote:
-            self._fetch_remote_shards(ev, iv, shard_off, bufs, remote,
-                                      len(src))
+        with tracing.stage("store.ec.survivors"):
+            for sid in src:
+                local = ev.shards.get(sid)
+                if local is not None:
+                    bufs[sid] = local.read_at(shard_off, iv.size)
+                elif self.remote_shard_reader is not None:
+                    remote.append(sid)
+                else:
+                    return None
+            if remote:
+                self._fetch_remote_shards(ev, iv, shard_off, bufs, remote,
+                                          len(src))
+        self._count_survivors(bufs, iv.size)
         if len(bufs) != len(src):
             return None
         rows = np.empty((len(src), iv.size), dtype=np.uint8)

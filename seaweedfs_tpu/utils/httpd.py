@@ -485,6 +485,14 @@ def _fd_writable(sock, timeout: Optional[float]) -> bool:
 _BUSY_BODY = b'{"error": "server busy"}'
 
 
+def _finish_span(span, status: int, t_cpu: float) -> None:
+    """Close a request's server span, with the CPU its thread burned
+    since ``t_cpu`` (the clock read the ledger's row shares)."""
+    if span is not tracing.NOOP:
+        span.cpu_ms = (clockctl.thread_time() - t_cpu) * 1e3
+    span.finish(status=status)
+
+
 class _ConnHandler(BaseHTTPRequestHandler):
     """Per-connection handler object; lives as long as the connection
     (parked or active) and is re-entered by worker threads one request
@@ -520,6 +528,10 @@ class _ConnHandler(BaseHTTPRequestHandler):
                 pass
         self.rfile = _BufferedReader(sock)
         self.wfile = sock.makefile("wb", self.wbufsize)
+        # when the selector core handed this connection to the worker
+        # pool (0.0 once the first request of the slice has taken it):
+        # the server span's queue_ms
+        self.ready_at = 0.0
 
     def log_message(self, *args):
         pass  # request lines are emitted via glog at -v=2
@@ -736,6 +748,12 @@ class _ConnHandler(BaseHTTPRequestHandler):
         span = (tracer.server_span(f"{self.command} {path}",
                                    self.headers)
                 if tracer is not None else tracing.NOOP)
+        ready_at = self.ready_at
+        if ready_at and span is not tracing.NOOP:
+            # selector saw the socket readable -> a worker got here
+            # (pool queue, request line and headers off the wire)
+            span.queue_ms = (clockctl.monotonic() - ready_at) * 1e3
+        self.ready_at = 0.0
         tok = tracing.attach(span)
         try:
             self._dispatch_inner(path, length, span)
@@ -753,11 +771,14 @@ class _ConnHandler(BaseHTTPRequestHandler):
         ptok = profiler.tag(eff_cls, fam,
                             span.trace_id if span.sampled else None)
         ledger = server.ledger
-        t_cpu = clockctl.thread_time() if ledger is not None else 0.0
+        # one per-thread CPU clock read serves the ledger row and the
+        # server span's cpu_ms
+        t_cpu = clockctl.thread_time() \
+            if ledger is not None or span is not tracing.NOOP else 0.0
         status, bytes_in, bytes_out = 500, 0, 0
         try:
             status, bytes_in, bytes_out = self._dispatch_gated(
-                path, length, span, fam, eff_cls)
+                path, length, span, fam, eff_cls, t_cpu)
         finally:
             profiler.untag(ptok)
             if ledger is not None:
@@ -772,7 +793,8 @@ class _ConnHandler(BaseHTTPRequestHandler):
                     cpu_s=clockctl.thread_time() - t_cpu,
                     bytes_in=bytes_in, bytes_out=bytes_out)
 
-    def _dispatch_gated(self, path, length, span, fam, eff_cls):
+    def _dispatch_gated(self, path, length, span, fam, eff_cls,
+                        t_cpu=0.0):
         server = self.srv
         # RED edge observation brackets EVERYTHING — admission
         # sheds, gate rejects, 404s, handler 500s — so the
@@ -798,7 +820,7 @@ class _ConnHandler(BaseHTTPRequestHandler):
             if isinstance(verdict, Response):
                 self._reject(verdict, length)
                 red_observe(verdict.status)
-                span.finish(status=verdict.status)
+                _finish_span(span, verdict.status, t_cpu)
                 return verdict.status, 0, 0
             release = verdict
         on_sent = None
@@ -853,7 +875,13 @@ class _ConnHandler(BaseHTTPRequestHandler):
                 resp.headers.setdefault("Connection", "close")
                 self.close_connection = True
             out_status = resp.status
+            t_send = clockctl.monotonic()
             self._send(resp)
+            if span is not tracing.NOOP:
+                # header formatting + the write into wfile; a body that
+                # fits wfile's buffer reaches the socket at the flush
+                # after _dispatch, outside the span
+                span.send_ms = (clockctl.monotonic() - t_send) * 1e3
             glog.vlog(2, "%s %s %d %dB %.1fms",
                       self.command, self.path, resp.status,
                       len(resp.body),
@@ -867,7 +895,7 @@ class _ConnHandler(BaseHTTPRequestHandler):
             if release is not None:
                 release()
             red_observe(out_status)
-            span.finish(status=out_status)
+            _finish_span(span, out_status, t_cpu)
         return (out_status,
                 stream.consumed if stream is not None else 0,
                 len(resp.body) if resp is not None else 0)
@@ -1218,6 +1246,7 @@ class _SelectorCore:
             self._submit(h)
 
     def _submit(self, h) -> None:
+        h.ready_at = clockctl.monotonic()
         if self._pool.submit(lambda: self._service(h)):
             return
         # worker queue saturated: canned 503 + close, never blocking

@@ -25,23 +25,32 @@ span whose methods are empty.
 Header format: ``X-Weed-Trace: <trace_id>:<span_id>:<flags>`` with
 trace_id 16 hex chars, span_id 8 hex chars, flags bit 0 = sampled.
 
+Stages (``stage(name)``): the one primitive the layers below the handler
+use to name what a request is doing.  A stage (1) enters the installed
+*annotator* — ``jax.profiler.TraceAnnotation``, injected by
+parallel/mesh.py, so that while a device trace is being taken the stage
+lands in the same ``.xplane.pb`` on the profiler's clock — and (2)
+records a child span, but only under a SAMPLED ambient span: an
+unsampled or absent span allocates no ``Span``, no dict and takes no
+lock.  It times itself (``elapsed``) so the layer that owns a counter
+adds the same two clock reads to it; the primitive keeps no counters.
+
 Stdlib-only on purpose: httpd, resilience and the QoS governor all
 import this module, so it must sit below them in the import DAG
-(it only imports glog, which imports nothing).
+(it only imports glog and clockctl, which import nothing of ours).
 """
 
 from __future__ import annotations
 
 import collections
 import contextlib
-import os
 import random
 import threading
 import time
 from contextvars import ContextVar
 from typing import Optional
 
-from seaweedfs_tpu.utils import glog
+from seaweedfs_tpu.utils import clockctl, glog
 
 from seaweedfs_tpu.utils import headers
 TRACE_HEADER = headers.TRACE
@@ -55,7 +64,11 @@ _HEX = set("0123456789abcdef")
 
 
 def _new_id(nbytes: int) -> str:
-    return os.urandom(nbytes).hex()
+    # ids have to be unique, not secret: the process's Mersenne Twister
+    # (seeded from the OS at import) and no system call — os.urandom
+    # drops the interpreter lock, and a thread that drops it among busy
+    # threads waits its turn to get it back, once per span
+    return "%0*x" % (2 * nbytes, random.getrandbits(8 * nbytes))
 
 
 class _NoopSpan:
@@ -92,9 +105,14 @@ _current: ContextVar[Optional["Span"]] = ContextVar("weed_span",
 
 
 class Span:
+    # queue_ms / cpu_ms / send_ms: the HTTP edge's three floats of a
+    # server span (utils/httpd.py) — slots, not annotations, so every
+    # request carries them at no allocation and the slow-span tail keep
+    # shows them
     __slots__ = ("tracer", "trace_id", "span_id", "parent_id", "name",
                  "kind", "start", "duration_ms", "status", "error",
-                 "sampled", "annotations")
+                 "sampled", "annotations", "_t0", "queue_ms", "cpu_ms",
+                 "send_ms")
 
     def __init__(self, tracer: "Tracer", trace_id: str, span_id: str,
                  parent_id: str, name: str, kind: str, sampled: bool):
@@ -105,11 +123,15 @@ class Span:
         self.name = name
         self.kind = kind
         self.sampled = sampled
+        # `start` is wall time (spans of one trace are stitched across
+        # nodes by it); the duration comes from the monotonic clock
         self.start = time.time()
+        self._t0 = clockctl.monotonic()
         self.duration_ms = 0.0
         self.status = 0
         self.error = ""
         self.annotations: Optional[dict] = None  # lazy — most spans bare
+        self.queue_ms = self.cpu_ms = self.send_ms = 0.0
 
     def annotate(self, key, value) -> None:
         if self.annotations is None:
@@ -120,8 +142,21 @@ class Span:
         return Span(self.tracer, self.trace_id, _new_id(4), self.span_id,
                     name, kind, self.sampled)
 
+    def record(self, name: str, t0: float, t1: float,
+               annotations: Optional[dict] = None) -> None:
+        """A finished child whose interval [t0, t1] (``clockctl.monotonic``)
+        was timed elsewhere — by the thread that did the work, which
+        holds this span only because it was captured at a hand-off.
+        Callers check ``sampled`` first."""
+        ch = self.child(name, "internal")
+        ch.start = self.start + (t0 - self._t0)
+        ch.duration_ms = (t1 - t0) * 1000.0
+        ch.status = 200
+        ch.annotations = annotations
+        self.tracer._record(ch)
+
     def finish(self, status: int = 200, error: str = "") -> None:
-        self.duration_ms = (time.time() - self.start) * 1000.0
+        self.duration_ms = (clockctl.monotonic() - self._t0) * 1000.0
         self.status = status
         self.error = error
         self.tracer._record(self)
@@ -142,6 +177,10 @@ class Span:
             "status": self.status,
             "sampled": self.sampled,
         }
+        if self.kind == "server":
+            d["queue_ms"] = round(self.queue_ms, 3)
+            d["cpu_ms"] = round(self.cpu_ms, 3)
+            d["send_ms"] = round(self.send_ms, 3)
         if self.error:
             d["error"] = self.error
         if self.annotations:
@@ -309,6 +348,85 @@ def annotate(key, value) -> None:
     s = _current.get()
     if s is not None:
         s.annotate(key, value)
+
+
+def detail(key, value) -> None:
+    """Attach key=value to the ambient span only when it is SAMPLED:
+    for sizes, batch widths and outcomes on paths every request takes,
+    where an unsampled span must not grow an annotations dict."""
+    s = _current.get()
+    if s is not None and s.sampled:
+        s.annotate(key, value)
+
+
+# ---- stages ----
+
+# annotator(name) -> context manager entered for the length of a stage.
+# parallel/mesh.py (the one module that owns JAX) installs
+# jax.profiler.TraceAnnotation when a device coder is built; this module
+# and httpd stay stdlib-only and never import jax.
+_annotator = None
+
+
+def set_annotator(fn) -> None:
+    global _annotator
+    _annotator = fn
+
+
+class stage:
+    """``with tracing.stage("ec.batch.stack") as st:`` — see the module
+    docstring.  ``st.annotate`` reaches the child span when there is one
+    (sampled requests only); ``st.t0`` is when it began
+    (``clockctl.monotonic``) and ``st.elapsed`` its length in seconds once it
+    has exited.  Names are static strings."""
+
+    __slots__ = ("name", "span", "elapsed", "_ann", "_tok", "t0")
+
+    def __init__(self, name: str):
+        self.name = name
+        self.span = None
+        self.elapsed = 0.0
+
+    def __enter__(self) -> "stage":
+        ann = _annotator
+        if ann is not None:
+            ann = ann(self.name)
+            ann.__enter__()
+        self._ann = ann
+        parent = _current.get()
+        if parent is not None and parent.sampled:
+            self.span = parent.child(self.name, "internal")
+            self._tok = _current.set(self.span)
+        self.t0 = clockctl.monotonic()
+        return self
+
+    def __exit__(self, et, ev, tb) -> bool:
+        self.elapsed = clockctl.monotonic() - self.t0
+        span = self.span
+        if span is not None:
+            _current.reset(self._tok)
+            if et is None:
+                span.finish()
+            else:
+                span.finish(status=500, error=f"{et.__name__}: {ev}")
+        if self._ann is not None:
+            self._ann.__exit__(et, ev, tb)
+        return False
+
+    def annotate(self, key, value) -> None:
+        if self.span is not None:
+            self.span.annotate(key, value)
+
+
+def stage_begin(name: str) -> stage:
+    """``stage`` for the few sites whose stage does not nest in the
+    code's blocks (the dispatcher loop's idle and hold); end it with
+    ``stage_end`` on every path."""
+    return stage(name).__enter__()
+
+
+def stage_end(st: stage) -> None:
+    st.__exit__(None, None, None)
 
 
 def current_trace_id() -> str:

@@ -180,3 +180,121 @@ def test_any_needle_size_is_bit_identical_on_a_ladder_shape(sched, n):
     assert widths <= set(COLUMN_LADDER[:2]), sched._mesh.programs
     # seven needle sizes, at most (encode, apply) x two rungs compiled
     assert st["programs_compiled"] <= 4
+
+
+# ------------------------------- names, compiles, the trace exporter
+
+def test_the_two_programs_lower_under_their_own_names():
+    """A device trace names a module after the jitted function: the
+    benchmark's per-program metrics select ``jit_ec_encode`` and
+    ``jit_ec_apply`` by prefix."""
+    import jax
+    import jax.numpy as jnp
+
+    from seaweedfs_tpu.ops import rs_mesh
+    mesh = mesh_mod.batch_mesh(1)
+    words = jax.ShapeDtypeStruct((1, K, 1024), jnp.uint32)
+    coeff = jax.ShapeDtypeStruct((1, 4, K), jnp.uint32)
+    enc = rs_mesh.batch_encode_fn(DEFAULT_SCHEME, mesh).lower(words)
+    app = rs_mesh.batch_apply_fn(mesh, 4).lower(words, coeff)
+    assert "module @jit_ec_encode" in enc.as_text()
+    assert "module @jit_ec_apply" in app.as_text()
+
+
+def test_backend_compiles_counts_compiles_not_first_uses():
+    from seaweedfs_tpu.ops.rs_mesh import MeshCoder
+    # a mesh of its own (3 of the virtual devices): no other test of the
+    # process can have compiled its programs
+    sched = EcBatchScheduler(mesh_coder=MeshCoder(n_devices=3),
+                             window_s=0.001)
+    data = np.zeros((K, 4096), dtype=np.uint8)
+    try:
+        c0 = sched.stats()["backend_compiles"]
+        assert c0 is not None
+        sched.encode(data)
+        c1 = sched.stats()
+        sched.encode(data)
+        c2 = sched.stats()
+    finally:
+        sched.stop()
+    assert c1["backend_compiles"] == c0 + 1
+    assert c2["backend_compiles"] == c1["backend_compiles"]
+    assert c1["backend_compile_s"] > 0
+    assert c2["programs_compiled"] == c1["programs_compiled"] == 1
+    # a second coder over the same mesh: a first use, nothing compiled
+    again = EcBatchScheduler(mesh_coder=MeshCoder(n_devices=3),
+                             window_s=0.001)
+    try:
+        again.encode(data)
+        c3 = again.stats()
+    finally:
+        again.stop()
+    assert c3["programs_compiled"] == 1
+    assert c3["backend_compiles"] == c2["backend_compiles"]
+
+
+def test_admin_ec_trace_exports_device_and_host_stages(tmp_path):
+    import threading
+
+    import jax
+
+    from seaweedfs_tpu.server.master import MasterServer
+    from seaweedfs_tpu.server.volume_server import VolumeServer
+    from seaweedfs_tpu.utils.httpd import http_call, http_json
+    master = MasterServer()
+    master.start()
+    vs = VolumeServer([str(tmp_path / "v")], master.url, ec_batcher=True,
+                      scrub_interval_s=0)
+    os.makedirs(tmp_path / "h")
+    host = VolumeServer([str(tmp_path / "h")], master.url,
+                        scrub_interval_s=0)
+    vs.start()
+    host.start()
+    stop = threading.Event()
+
+    def work():
+        data = np.ones((K, 4096), dtype=np.uint8)
+        while not stop.is_set():
+            vs.store.coder.encode_array(data)   # the BatchCoder facade
+    vs.ec_batcher.encode(np.ones((K, 4096), dtype=np.uint8))  # compile
+    worker = threading.Thread(target=work)
+    worker.start()
+    url = f"http://{vs.url}/admin/ec/trace"
+    try:
+        # long enough for whole dispatches to begin AND end inside it
+        # with the suite's other workers competing for the cores
+        out = http_json("POST", url, {"seconds": 3.0,
+                                      "dir": str(tmp_path / "trace")})
+        for bad in ({"seconds": 31, "dir": "x"}, {"seconds": 1},
+                    {"seconds": 0, "dir": "x"}):
+            assert http_call("POST", url, json_body=bad)[0] == 400
+        # one profile per process: a second one while the first runs
+        first = threading.Thread(target=http_json, args=(
+            "POST", url, {"seconds": 1.0, "dir": str(tmp_path / "t2")}))
+        first.start()
+        import time
+        time.sleep(0.3)
+        assert http_call("POST", url, json_body={
+            "seconds": 0.1, "dir": str(tmp_path / "t3")})[0] == 409
+        first.join(30)
+        assert not first.is_alive()
+        # without a device coder there is nothing to trace
+        assert http_call("POST", f"http://{host.url}/admin/ec/trace",
+                         json_body={"seconds": 0.1, "dir": "x"})[0] == 404
+    finally:
+        stop.set()
+        worker.join(30)
+        host.stop()
+        vs.stop()
+        master.stop()
+    assert out["dir"] == str(tmp_path / "trace") and out["seconds"] == 3.0
+    assert len(out["xplane"]) == 1 and out["xplane"][0].endswith(
+        ".xplane.pb")
+    assert out["xplane_bytes"] == os.path.getsize(out["xplane"][0]) > 0
+    data = jax.profiler.ProfileData.from_file(out["xplane"][0])
+    names = {e.name for plane in data.planes
+             if not plane.name.startswith("/device:")
+             for line in plane.lines for e in line.events}
+    assert {"ec.batch.dispatch", "ec.batch.hold", "ec.batch.stack",
+            "ec.mesh.launch", "ec.mesh.fetch", "ec.batch.submit",
+            "ec.batch.result"} <= names

@@ -263,3 +263,77 @@ def test_numpy_fallback_methods_agree():
         if rs_cpu._native() is not None:
             assert np.array_equal(
                 out, rs_cpu._gf_apply(mat, data, use_native=True)), n
+
+
+# ---- the pipeline's account of a seal, in /admin/ec/generate's reply ----
+
+def test_generate_reply_carries_the_pipelines_stats(tmp_path, monkeypatch):
+    """``pipeline``: busy seconds per stage, the commit, the bytes read;
+    ``base`` as before.  At the default sample rate's "off" side (rate
+    0) no stage of the seal or of a read allocates a child span."""
+    import time
+
+    from seaweedfs_tpu.client import operation
+    from seaweedfs_tpu.client.wdclient import MasterClient
+    from seaweedfs_tpu.server.master import MasterServer
+    from seaweedfs_tpu.server.volume_server import VolumeServer
+    from seaweedfs_tpu.utils import tracing
+    from seaweedfs_tpu.utils.httpd import http_call, http_json
+
+    children = []
+    real_child = tracing.Span.child
+    monkeypatch.setattr(tracing.Span, "child", lambda self, *a, **kw: (
+        children.append(a), real_child(self, *a, **kw))[1])
+    master = MasterServer(volume_size_limit_mb=64, tracing_enabled=False)
+    master.start()
+    vs = VolumeServer([str(tmp_path / "v")], master.url,
+                      scrub_interval_s=0, trace_sample=0.0)
+    try:
+        vs.start()
+        mc = MasterClient(master.url, cache_ttl=0.0)
+        deadline = time.time() + 10
+        while True:
+            try:
+                up = operation.upload_data(mc, os.urandom(300_000))
+                break
+            except Exception:  # noqa: BLE001 — the node is still joining
+                if time.time() > deadline:
+                    raise
+                time.sleep(0.05)
+        vid = int(up.fid.split(",")[0])
+        del children[:]
+        reply = http_json("POST", f"http://{vs.url}/admin/ec/generate",
+                          {"volume_id": vid})
+        dat = os.path.join(str(tmp_path / "v"), reply["base"] + ".dat")
+        p = reply["pipeline"]
+        assert reply["base"] == str(vid)
+        assert p["bytes_in"] == os.path.getsize(dat)
+        assert p["read_s"] + p["encode_s"] + p["write_s"] > 0
+        assert p["batches"] >= 1 and p["commit_s"] > 0
+        assert p["wall_s"] >= p["commit_s"]
+        for i in range(TOTAL):
+            assert os.path.exists(os.path.join(
+                str(tmp_path / "v"), reply["base"] + layout.shard_ext(i)))
+        # the serial path has no pipeline to account for
+        serial = http_json("POST", f"http://{vs.url}/admin/ec/generate",
+                           {"volume_id": vid, "pipelined": False})
+        assert serial == {"base": str(vid), "pipeline": {}}
+        http_json("POST", f"http://{vs.url}/admin/ec/mount",
+                  {"volume_id": vid,
+                   "shard_ids": list(range(TOTAL))})
+        http_json("POST", f"http://{vs.url}/admin/delete_volume",
+                  {"volume_id": vid})
+        status, body, _ = http_call("GET", f"http://{vs.url}/{up.fid}")
+        assert status == 200 and len(body) == 300_000
+        stat = http_json(
+            "GET", f"http://{vs.url}/admin/ec/shard_stat?volumeId={vid}")
+        assert set(stat["recover_stats"]) == {"local", "global", "generic"}
+        assert stat["read_stats"]["intervals_local"] >= 1
+        assert stat["read_stats"]["intervals_recovered"] == 0
+        # (http_call's client spans for outbound RPCs are the tracer's
+        # own and older than the stages)
+        assert [c for c in children if c[0].startswith(
+            ("ec.", "store.", "volume."))] == []
+    finally:
+        vs.stop()
+        master.stop()

@@ -443,6 +443,189 @@ def test_repair_queue_aged_task_escapes_partial_wave():
     assert done.wait(5)
 
 
+# ------------------------------------- stages, counters (PR 25)
+
+def _tree(tr):
+    spans = tr.snapshot(limit=0)["spans"]
+    by_id = {s["span_id"]: s for s in spans}
+    return spans, {s["span_id"]: by_id[s["parent_id"]]["name"]
+                   for s in spans if s["parent_id"] in by_id}
+
+
+def test_sampled_jobs_yield_the_stage_tree():
+    from seaweedfs_tpu.utils import tracing
+    sched = EcBatchScheduler(window_s=0.002)
+    coder = BatchCoder(sched)
+    tr = tracing.Tracer(node="t", sample_rate=1.0)
+    data = _batch(1, 5000, seed=3)[0]
+    try:
+        for name, call in (
+                ("seal", lambda: coder.encode_into(
+                    data, np.empty((M, 5000), dtype=np.uint8))),
+                ("read", lambda: coder.reconstruct_rows(
+                    data, CPU.rebuild_matrix(list(range(1, TOTAL)), [0])))):
+            root = tr.root_span(name, sampled=True)
+            with tracing.span_scope(root):
+                with tracing.stage("caller") as caller:
+                    out = call()
+            root.finish()
+            assert out.shape[1] == 5000
+    finally:
+        sched.stop()
+    spans, parent = _tree(tr)
+    for req, kind in (("seal", "encode"), ("read", "rebuild")):
+        root = next(s for s in spans if s["name"] == req)
+        mine = [s for s in spans if s["trace_id"] == root["trace_id"]]
+        names = sorted(s["name"] for s in mine)
+        assert names == sorted(
+            [req, "caller", "ec.batch.submit", "ec.batch.wait",
+             "ec.batch.dispatch", "ec.batch.result", "ec.batch.stack",
+             "ec.mesh.pad", "ec.mesh.launch", "ec.mesh.fetch",
+             "ec.mesh.unpack", "ec.batch.demux"])
+        for s in mine:
+            under = parent.get(s["span_id"])
+            if s["name"] in ("ec.batch.submit", "ec.batch.result",
+                             "ec.batch.wait", "ec.batch.dispatch"):
+                # caller-thread stages and the dispatcher's two hang off
+                # the span that was ambient at submit
+                assert under == "caller", (s["name"], under)
+            elif s["name"].startswith("ec."):
+                assert under == "ec.batch.dispatch", (s["name"], under)
+        disp = next(s for s in mine if s["name"] == "ec.batch.dispatch")
+        assert disp["annotations"]["kind"] == kind
+        assert disp["annotations"]["shape"] == [1, K, COLUMN_LADDER[0]]
+        wait = next(s for s in mine if s["name"] == "ec.batch.wait")
+        result = next(s for s in mine if s["name"] == "ec.batch.result")
+        inner = sum(s["duration_ms"] for s in mine
+                    if parent.get(s["span_id"]) == "ec.batch.dispatch")
+        assert inner <= disp["duration_ms"] + 0.01
+        # the caller waits out the job's wait and its dispatch
+        assert result["duration_ms"] + 1.0 >= \
+            wait["duration_ms"] + disp["duration_ms"] - 1.0
+        assert wait["duration_ms"] >= 1.0    # the 2 ms window, alone
+
+
+def test_two_sampled_jobs_in_one_dispatch_share_it_by_id():
+    from seaweedfs_tpu.utils import tracing
+    sched = EcBatchScheduler(window_s=0.3)
+    tr = tracing.Tracer(node="t", sample_rate=1.0)
+    roots = [tr.root_span(f"r{i}", sampled=True) for i in range(2)]
+    try:
+        futs = []
+        for root in roots:
+            with tracing.span_scope(root):
+                futs.append(sched.submit_encode(_batch(1, 4096)[0]))
+        for f in futs:
+            f.result(timeout=60)
+    finally:
+        sched.stop()
+    for root in roots:
+        root.finish()
+    spans, parent = _tree(tr)
+    disp = [s for s in spans if s["name"] == "ec.batch.dispatch"]
+    assert sorted(parent[s["span_id"]] for s in disp) == ["r0", "r1"]
+    lead = next(s for s in disp if "shape" in s["annotations"])
+    other = next(s for s in disp if s is not lead)
+    assert lead["annotations"]["shape"][0] == 2
+    assert other["annotations"] == {"dispatch_id": lead["span_id"],
+                                    "jobs": 2}
+    assert other["duration_ms"] == pytest.approx(lead["duration_ms"],
+                                                 abs=0.5)
+    # the stages hang off the one dispatch span, once
+    assert sum(1 for s in spans if s["name"] == "ec.mesh.fetch") == 1
+
+
+def test_loop_and_stage_counters_account_for_the_wall():
+    sched = EcBatchScheduler(window_s=0.01)
+    coder = BatchCoder(sched)
+    n = 100_000
+    data = _batch(1, n, seed=5)[0]
+    mat = CPU.rebuild_matrix(list(range(2, TOTAL)), [0, 1])
+    try:
+        coder.encode_array(data)                 # compile outside
+        coder.reconstruct_rows(data, mat)
+        a, t0 = sched.stats(), time.monotonic()
+        for _ in range(6):
+            coder.encode_array(data)
+            time.sleep(0.03)
+        for _ in range(4):
+            coder.reconstruct_rows(data, mat)
+        time.sleep(0.25)      # a long idle, still running at the read
+        b, t1 = sched.stats(), time.monotonic()
+    finally:
+        sched.stop()
+    d = {k: b["loop_s"][k] - a["loop_s"][k] for k in b["loop_s"]}
+    assert set(d) == {"idle", "hold", "dispatch"}
+    assert sum(d.values()) == pytest.approx(t1 - t0, rel=0.02, abs=0.005)
+    assert d["hold"] == pytest.approx(10 * 0.01, rel=0.5)  # lone jobs
+    assert d["idle"] >= 6 * 0.03 + 0.25 - 0.1
+    stage = {k: b["stage_s"][k] - a["stage_s"][k] for k in b["stage_s"]}
+    count = {k: b["stage_n"][k] - a["stage_n"][k] for k in b["stage_n"]}
+    assert list(stage) == ["submit", "stack", "pad", "launch", "fetch",
+                           "unpack", "demux", "result"]
+    assert set(count.values()) == {10}
+    six = sum(stage[k] for k in ("stack", "pad", "launch", "fetch",
+                                 "unpack", "demux"))
+    assert 0.6 * d["dispatch"] <= six <= d["dispatch"]
+    # the caller waits for hold + dispatch
+    assert stage["result"] >= d["dispatch"]
+    by = {k: {f: b["by_kind"][k][f] - a["by_kind"][k][f]
+              for f in b["by_kind"][k]} for k in b["by_kind"]}
+    rung = COLUMN_LADDER[0]
+    assert by["encode"] == {"jobs": 6, "bytes_in": 6 * K * n,
+                            "bytes_padded": 6 * K * rung,
+                            "bytes_out": 6 * M * n}
+    assert by["rebuild"] == {"jobs": 4, "bytes_in": 4 * K * n,
+                             "bytes_padded": 4 * K * rung,
+                             "bytes_out": 4 * 2 * n}
+    # the keys the benchmark and the smoke read are as they were
+    for key in ("jobs_total", "batches_total", "mesh_batches",
+                "cpu_batches", "coder_fallbacks", "programs_compiled",
+                "wait_hist", "size_hist"):
+        assert key in b
+    assert b["jobs_total"] - a["jobs_total"] == 10
+    assert sum(sum(c) for _l, c, _s, _e in b["wait_hist"]["series"]) \
+        == b["jobs_total"]
+
+
+def test_unsampled_jobs_allocate_no_span_and_take_no_tracer_lock(
+        monkeypatch):
+    from seaweedfs_tpu.utils import tracing
+    made = []
+    real_child = tracing.Span.child
+    monkeypatch.setattr(tracing.Span, "child", lambda self, *a, **kw: (
+        made.append(a), real_child(self, *a, **kw))[1])
+
+    class Lock:
+        taken = 0
+
+        def __enter__(self):
+            Lock.taken += 1
+
+        def __exit__(self, *a):
+            pass
+    tr = tracing.Tracer(node="t", sample_rate=0.0)
+    tr._lock = Lock()
+    sched = EcBatchScheduler(window_s=0.001)
+    coder = BatchCoder(sched)
+    data = _batch(1, 4096)[0]
+    root = tr.root_span("req")
+    assert root.sampled is False
+    try:
+        with tracing.span_scope(root):
+            coder.encode_array(data)
+            coder.reconstruct_rows(
+                data, CPU.rebuild_matrix(list(range(1, TOTAL)), [0]))
+    finally:
+        sched.stop()
+    # (before the root is finished: a request slower than slow_ms is
+    # kept by the tail keep, which does take the lock)
+    assert made == [] and Lock.taken == 0
+    assert root.annotations is None
+    root.finish()
+    assert sched.stats()["stage_n"]["result"] == 2   # counted all the same
+
+
 # ------------------------------------------ volume-server seam (e2e)
 
 def test_volume_server_ec_batcher_end_to_end(tmp_path):

@@ -66,7 +66,7 @@ def test_recorder_tail_keep_policy():
     err = tr.server_span("GET /err", {})
     err.finish(status=503)  # 5xx -> always kept
     slow = tr.server_span("GET /slow", {})
-    slow.start -= 1.0  # fake a 1s request
+    slow._t0 -= 1.0  # fake a 1s request (durations are monotonic)
     slow.finish(status=200)  # past slow_ms -> always kept
     snap = tr.snapshot()
     assert [s["name"] for s in snap["spans"]] == ["GET /err", "GET /slow"]
@@ -522,3 +522,221 @@ def test_put_overhead_at_one_percent_sampling(tmp_path):
     on = run(True)
     assert on <= off * 1.5, \
         f"tracing overhead too high: {off:.3f}s off vs {on:.3f}s on"
+
+
+# ---- stages (PR 25): annotator always, spans only when sampled ----
+
+class _CountingLock:
+    def __init__(self):
+        self.taken = 0
+        self._lock = threading.Lock()
+
+    def __enter__(self):
+        self.taken += 1
+        return self._lock.__enter__()
+
+    def __exit__(self, *a):
+        return self._lock.__exit__(*a)
+
+
+class _Annotation:
+    log: list = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        self.log.append(("enter", self.name))
+
+    def __exit__(self, *a):
+        self.log.append(("exit", self.name))
+
+
+@pytest.fixture
+def annotator():
+    before = tracing._annotator
+    _Annotation.log = []
+    tracing.set_annotator(_Annotation)
+    yield _Annotation.log
+    tracing.set_annotator(before)
+
+
+@pytest.fixture
+def spans_made(monkeypatch):
+    """How many Spans were constructed, by whatever path."""
+    made = []
+
+    class Counted(tracing.Span):
+        __slots__ = ()
+
+        def __init__(self, *a, **kw):
+            made.append(a[4])
+            super().__init__(*a, **kw)
+    monkeypatch.setattr(tracing, "Span", Counted)
+    return made
+
+
+@pytest.mark.parametrize("ambient", ["none", "unsampled"])
+def test_stage_without_a_sampled_span_only_calls_the_annotator(
+        annotator, spans_made, ambient):
+    tr = tracing.Tracer(node="n", sample_rate=0.0)
+    tr._lock = _CountingLock()
+    root = tr.root_span("req") if ambient == "unsampled" else None
+    del spans_made[:]
+    with tracing.span_scope(root):
+        with tracing.stage("outer") as st:
+            st.annotate("bytes", 4096)     # goes nowhere, allocates nothing
+            tracing.detail("k", "v")
+            with tracing.stage("inner"):
+                pass
+        held = tracing.stage_begin("loose")
+        tracing.stage_end(held)
+        assert tracing.current_span() is root
+    assert spans_made == []
+    assert st.span is None and st.elapsed >= 0.0
+    assert root is None or root.annotations is None
+    assert tr._lock.taken == 0 and tr._started == 0
+    assert annotator == [("enter", "outer"), ("enter", "inner"),
+                         ("exit", "inner"), ("exit", "outer"),
+                         ("enter", "loose"), ("exit", "loose")]
+
+
+def test_stage_children_link_up_and_self_times_add_up(annotator):
+    tr = tracing.Tracer(node="n", sample_rate=1.0)
+    root = tr.root_span("req", sampled=True)
+    with tracing.span_scope(root):
+        time.sleep(0.002)
+        with tracing.stage("a") as a:
+            a.annotate("bytes", 7)
+            time.sleep(0.003)
+            with tracing.stage("a.1"):
+                time.sleep(0.004)
+            with tracing.stage("a.2"):
+                time.sleep(0.002)
+        with pytest.raises(ValueError):
+            with tracing.stage("b"):
+                time.sleep(0.001)
+                raise ValueError("boom")
+    root.finish()
+    spans = {s["name"]: s for s in tr.snapshot()["spans"]}
+    assert spans["a"]["parent_id"] == spans["b"]["parent_id"] \
+        == root.span_id
+    assert spans["a.1"]["parent_id"] == spans["a.2"]["parent_id"] \
+        == spans["a"]["span_id"]
+    assert {s["trace_id"] for s in spans.values()} == {root.trace_id}
+    assert spans["a"]["annotations"] == {"bytes": 7}
+    assert spans["b"]["status"] == 500 and "boom" in spans["b"]["error"]
+    kids: dict = {}
+    for s in spans.values():
+        kids.setdefault(s["parent_id"], []).append(s)
+    self_ms = {n: s["duration_ms"] - sum(
+        c["duration_ms"] for c in kids.get(s["span_id"], []))
+        for n, s in spans.items()}
+    assert all(v >= 0 for v in self_ms.values()), self_ms
+    assert sum(self_ms.values()) == pytest.approx(
+        spans["req"]["duration_ms"], abs=0.01)
+    assert self_ms["a.1"] >= 3.5 and self_ms["a"] >= 2.5
+    assert a.elapsed * 1e3 == pytest.approx(spans["a"]["duration_ms"],
+                                            abs=0.5)
+    # the annotator saw every stage, sampled or not
+    assert [n for ev, n in annotator if ev == "enter"] == [
+        "a", "a.1", "a.2", "b"]
+
+
+def test_span_captured_at_a_handoff_parents_another_threads_spans():
+    tr = tracing.Tracer(node="n", sample_rate=1.0)
+    root = tr.root_span("req", sampled=True)
+    with tracing.span_scope(root):
+        with tracing.stage("submit-side") as st:
+            captured = tracing.current_span()
+            t_submit = time.monotonic()
+    assert captured is st.span
+
+    def worker():
+        assert tracing.current_span() is None   # ContextVars stay behind
+        now = time.monotonic()
+        captured.record("waited", t_submit, now, {"jobs": 2})
+        tok = tracing.attach(captured)
+        try:
+            with tracing.stage("worked"):
+                time.sleep(0.002)
+        finally:
+            tracing.detach(tok)
+        assert tracing.current_span() is None
+    th = threading.Thread(target=worker)
+    th.start()
+    th.join(5)
+    assert not th.is_alive()
+    spans = {s["name"]: s for s in tr.snapshot()["spans"]}
+    for name in ("waited", "worked"):
+        assert spans[name]["parent_id"] == captured.span_id
+        assert spans[name]["trace_id"] == root.trace_id
+    assert spans["waited"]["annotations"] == {"jobs": 2}
+    assert spans["waited"]["duration_ms"] >= 0
+    # back-dated onto the parent's wall clock
+    assert spans["waited"]["start"] == pytest.approx(
+        captured.start + (t_submit - captured._t0), abs=1e-6)
+    assert spans["worked"]["duration_ms"] >= 1.5
+
+
+def test_durations_are_monotonic_and_start_is_wall_time():
+    tr = tracing.Tracer(node="n", sample_rate=1.0)
+    sp = tr.root_span("op", sampled=True)
+    assert abs(sp.start - time.time()) < 1.0
+    sp.start -= 3600.0          # a wall clock stepped under the span
+    sp.finish()
+    assert tr.snapshot()["spans"][0]["duration_ms"] < 1000.0
+
+
+def test_edge_modules_import_without_jax():
+    """tracing, httpd and the master sit below jax in the import DAG:
+    the benchmark's parent and chip_smoke.py's parent import them and
+    may never hold the chip."""
+    import subprocess
+    import sys
+    code = ("import sys\n"
+            "import seaweedfs_tpu.utils.tracing\n"
+            "import seaweedfs_tpu.utils.httpd\n"
+            "import seaweedfs_tpu.server.master\n"
+            "assert 'jax' not in sys.modules, 'jax was imported'\n"
+            "from seaweedfs_tpu.utils import tracing\n"
+            "assert tracing._annotator is None\n")
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=120)
+    assert r.returncode == 0, r.stderr[-2000:]
+
+
+def test_server_span_carries_queue_cpu_and_send_ms():
+    from seaweedfs_tpu.utils.httpd import HttpServer, Response
+    srv = HttpServer()
+    srv.tracer = tracing.Tracer(node="edge", sample_rate=0.0, slow_ms=0.0)
+
+    def burn(req):
+        t0 = time.thread_time()
+        while time.thread_time() - t0 < 0.003:
+            pass
+        return Response(b"x" * 1000, content_type="text/plain")
+    srv.add("GET", "/burn", burn)
+    srv.start()
+    try:
+        for _ in range(2):   # the second rides the kept-alive connection
+            status, _body, _ = http_call(
+                "GET", f"http://{srv.host}:{srv.port}/burn")
+            assert status == 200
+        deadline = time.time() + 5
+        while len(srv.tracer.snapshot()["spans"]) < 2 \
+                and time.time() < deadline:
+            time.sleep(0.01)
+        spans = srv.tracer.snapshot()["spans"]
+    finally:
+        srv.stop()
+    assert len(spans) == 2
+    for s in spans:
+        # unsampled, kept by the slow-span tail keep: the floats are there
+        assert s["sampled"] is False and s["kind"] == "server"
+        assert s["cpu_ms"] >= 2.5
+        assert s["cpu_ms"] <= s["duration_ms"] + 1.0
+        assert 0.0 <= s["send_ms"] <= s["duration_ms"]
+        assert s["queue_ms"] >= 0.0
+        assert "annotations" not in s
+    assert spans[0]["queue_ms"] > 0.0   # accepted -> a worker took it
