@@ -35,8 +35,6 @@ def _add_common_volume_args(p):
                         "device-sized mesh batches (overrides -coder; "
                         "CPU fallback on device loss; stats at "
                         "/admin/ec/batcher)")
-    p.add_argument("-ecBatchWindowMs", type=float, default=5.0,
-                   help="batcher coalescing window in ms (with -ecBatcher)")
     p.add_argument("-index", default="memory", choices=["memory", "ldb"],
                    help="needle map kind (reference -index flag)")
     p.add_argument("-tcp", action="store_true",
@@ -104,7 +102,6 @@ def cmd_volume(args):
                       rack=args.rack, data_center=args.dataCenter,
                       coder=None if args.ecBatcher else make_coder(args.coder),
                       ec_batcher=args.ecBatcher,
-                      ec_batch_window_s=args.ecBatchWindowMs / 1000.0,
                       max_volume_counts=[args.max] * len(dirs),
                       disk_types=[t.strip() for t in args.disk.split(",")
                                   if t.strip()] if args.disk.strip()
@@ -138,7 +135,6 @@ def cmd_server(args):
     vs = VolumeServer(dirs, ms.url, host=args.ip, port=args.port,
                       coder=None if args.ecBatcher else make_coder(args.coder),
                       ec_batcher=args.ecBatcher,
-                      ec_batch_window_s=args.ecBatchWindowMs / 1000.0,
                       max_volume_counts=[args.max] * len(dirs),
                       disk_types=[t.strip() for t in args.disk.split(",")
                                   if t.strip()] if args.disk.strip()

@@ -6,16 +6,20 @@ independent callers: concurrent ``ec.encode`` pipelines on different
 volumes, the repair queue's rebuild jobs, degraded reads.  This module
 is the funnel between them and the mesh:
 
-  submit (any thread) -> bounded queue -> dispatcher thread coalesces a
-  deadline-bounded batch -> one MeshCoder dispatch -> per-job futures.
+  submit (any thread) -> bounded queue -> dispatcher thread takes what
+  is queued -> one MeshCoder dispatch -> per-job futures.
 
 Scheduling contract:
   - the submission queue is BOUNDED (overload becomes backpressure on
     the submitting pipeline, not memory growth);
-  - every job carries a coalescing deadline (submit time + window); the
-    dispatcher never holds a job past the EARLIEST deadline in its
-    batch, so a lone job costs at most one window of latency and a
-    burst fills a device-sized batch;
+  - the dispatcher never WAITS for company: it takes the first job,
+    drains what is already queued beside it (up to max_batch) and
+    dispatches.  Coalescing happens behind a busy dispatch: jobs that
+    arrive while one runs queue up and ride the next one together, so a
+    burst fills a device-sized batch and a lone job on an idle
+    scheduler goes straight to the device — not held, and not copied
+    either (a dispatch of one job hands the coder a view of the job's
+    own buffer; ``lone_dispatches`` counts them);
   - jobs are ordered by QoS class (interactive > write > background —
     the ambient class is captured at submit, same as every other
     fan-out edge) before dispatch, so a background rebuild flood cannot
@@ -38,9 +42,9 @@ Scheduling contract:
 Where the time goes is counted always and traced when sampled: every
 stage a job passes through (``STAGES``; utils/tracing.stage) adds its
 seconds to ``stats()["stage_s"]``, the dispatcher's own time is split
-into idle / hold / dispatch (``loop_s``), and a job submitted under a
-SAMPLED request span carries that span to the dispatcher thread, which
-records the job's stages as its children.
+into idle / hold (the drain) / dispatch (``loop_s``), and a job
+submitted under a SAMPLED request span carries that span to the
+dispatcher thread, which records the job's stages as its children.
 
 All behavioral timing routes through clockctl so the scheduler stays
 legible to the virtual-clock sim; blocking primitives (queue waits)
@@ -139,7 +143,7 @@ def _rank(cls: Optional[str]) -> int:
 
 class _Job:
     __slots__ = ("kind", "data", "mat", "n", "cls", "span", "submitted",
-                 "deadline", "future")
+                 "future")
 
     def __init__(self, kind: str, data: np.ndarray,
                  mat: Optional[np.ndarray], n: int, cls: Optional[str],
@@ -153,7 +157,6 @@ class _Job:
         # captured like cls: ContextVars do not reach the dispatcher
         self.span = span
         self.submitted = submitted
-        self.deadline = submitted  # + window_s, set by the scheduler
         self.future: Future = Future()
 
 
@@ -201,11 +204,10 @@ class EcBatchScheduler:
 
     def __init__(self, scheme: RSScheme = DEFAULT_SCHEME, *,
                  mesh_coder=None, cpu_coder: Optional[ErasureCoder] = None,
-                 window_s: float = 0.005, max_batch: int = 64,
-                 queue_depth: int = 256, cooldown_s: float = 30.0,
+                 max_batch: int = 64, queue_depth: int = 256,
+                 cooldown_s: float = 30.0,
                  on_fallback: Optional[Callable[[str], None]] = None):
         self.scheme = scheme
-        self.window_s = window_s
         self.max_batch = max_batch
         self.cooldown_s = cooldown_s
         self._on_fallback = on_fallback
@@ -238,6 +240,8 @@ class EcBatchScheduler:
         self.jobs_total = 0
         self.batches_total = 0
         self.mesh_batches = 0
+        # mesh dispatches of ONE job: not held, not copied (_run_mesh)
+        self.lone_dispatches = 0
         self.cpu_batches = 0
         self.coder_fallbacks = 0
         self.max_coalesced = 0
@@ -295,7 +299,6 @@ class EcBatchScheduler:
             if cls is None:
                 cls = current_class()
             job = _Job(kind, data, mat, n, cls, span, clockctl.monotonic())
-            job.deadline = job.submitted + self.window_s
             self._q.put(job)  # bounded: blocks -> backpressure
         self.note_caller(0, st.elapsed)
         return job.future
@@ -342,8 +345,8 @@ class EcBatchScheduler:
     def _loop(self) -> None:
         # the dispatcher's time, partitioned: every instant since the
         # thread started is in exactly one of idle (blocked on an empty
-        # queue), hold (holding >= 1 job, waiting out the window for
-        # company) and dispatch
+        # queue), hold (holding >= 1 job, draining what queued up
+        # beside it) and dispatch
         done = [0.0, 0.0, 0.0]      # idle, hold, dispatch (LOOP_PARTS)
         t = self._loop_pub[4]
 
@@ -371,15 +374,12 @@ class EcBatchScheduler:
             batch = [job]
             stopping = False
             st = tracing.stage_begin("ec.batch.hold")
+            # nothing is WAITED for: what queued up behind the last
+            # dispatch rides this one, and a job with nobody beside it
+            # leaves at once
             while len(batch) < self.max_batch:
-                wait = min(j.deadline for j in batch) - clockctl.monotonic()
                 try:
-                    # past the earliest deadline nothing is WAITED for,
-                    # but jobs that queued up behind a busy dispatch
-                    # still ride this batch — or a dispatch slower than
-                    # the window would never coalesce anything
-                    nxt = self._q.get(timeout=wait) if wait > 0 \
-                        else self._q.get_nowait()
+                    nxt = self._q.get_nowait()
                 except queue.Empty:
                     break
                 if nxt is _STOP:
@@ -420,7 +420,7 @@ class EcBatchScheduler:
         self.size_hist.observe(len(batch))
         # QoS ordering: a group containing an interactive job dispatches
         # before an all-background group
-        batch.sort(key=lambda j: (_rank(j.cls), j.deadline))
+        batch.sort(key=lambda j: (_rank(j.cls), j.submitted))
         groups: dict[tuple, list] = {}
         for j in batch:
             groups.setdefault((j.kind,) + j.data.shape, []).append(j)
@@ -453,6 +453,8 @@ class EcBatchScheduler:
             try:
                 self._run_mesh(jobs)
                 self.mesh_batches += 1
+                if len(jobs) == 1:
+                    self.lone_dispatches += 1
                 return
             except Exception as e:  # noqa: BLE001 — the fallback ladder
                 from seaweedfs_tpu.parallel import mesh as mesh_mod
@@ -486,7 +488,10 @@ class EcBatchScheduler:
         try:
             with tracing.stage("ec.batch.dispatch") as disp:
                 with tracing.stage("ec.batch.stack") as st:
-                    stacked = np.stack([j.data for j in jobs])
+                    # one job is its own batch: a view of the buffer
+                    # submit made contiguous and padded, not a copy
+                    stacked = jobs[0].data[None] if len(jobs) == 1 \
+                        else np.stack([j.data for j in jobs])
                 self._staged("stack", st)
                 if kind == "encode":
                     out = self._mesh.encode_batch(stacked)
@@ -572,7 +577,6 @@ class EcBatchScheduler:
             loop_s[LOOP_PARTS[pub[3]]] += max(
                 0.0, clockctl.monotonic() - pub[4])
         return {
-            "window_s": self.window_s,
             "max_batch": self.max_batch,
             "queue_depth": self._q.maxsize,
             "queued": self._q.qsize(),
@@ -596,6 +600,7 @@ class EcBatchScheduler:
             "jobs_total": self.jobs_total,
             "batches_total": self.batches_total,
             "mesh_batches": self.mesh_batches,
+            "lone_dispatches": self.lone_dispatches,
             "cpu_batches": self.cpu_batches,
             "coder_fallbacks": self.coder_fallbacks,
             "max_coalesced": self.max_coalesced,

@@ -101,7 +101,6 @@ class VolumeServer:
                  tracing_enabled: bool = True,
                  trace_sample: float = 0.01,
                  ec_batcher: bool = False,
-                 ec_batch_window_s: float = 0.005,
                  needle_cache_mb: int = 64,
                  hinted_handoff: bool = True,
                  zero_copy: bool = True,
@@ -146,10 +145,11 @@ class VolumeServer:
 
         ec_batcher routes this node's EC encode/rebuild work through a
         cross-volume batch scheduler (parallel/batcher.py): concurrent
-        volumes' block-groups coalesce for ec_batch_window_s into one
-        device-mesh dispatch, with a CPU drain when devices fail
-        mid-run. Off (the default) keeps the per-volume coder path.
-        Ignored when an explicit `coder` is passed.
+        volumes' block-groups that queue up behind a running dispatch
+        coalesce into the next device-mesh dispatch (a lone job is not
+        held), with a CPU drain when devices fail mid-run. Off (the
+        default) keeps the per-volume coder path. Ignored when an
+        explicit `coder` is passed.
 
         needle_cache_mb byte-budgets the hot-needle record cache
         (storage/needle_cache.py) fronting the healthy and degraded-EC
@@ -197,7 +197,6 @@ class VolumeServer:
         self._dc = data_center
         self._coder = coder
         self._ec_batcher_enabled = ec_batcher and coder is None
-        self._ec_batch_window_s = ec_batch_window_s
         self.ec_batcher = None  # EcBatchScheduler when enabled
         self._needle_map_kind = needle_map_kind
         self._tcp_port = tcp_port
@@ -369,7 +368,6 @@ class VolumeServer:
             from seaweedfs_tpu.parallel.batcher import (BatchCoder,
                                                         EcBatchScheduler)
             self.ec_batcher = EcBatchScheduler(
-                window_s=self._ec_batch_window_s,
                 on_fallback=lambda reason: self._m_ec_fallbacks.inc(reason))
             self._coder = BatchCoder(self.ec_batcher)
         # {"platform", "device_kind", "count"} the EC coder dispatches

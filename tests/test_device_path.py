@@ -153,7 +153,7 @@ def test_bucket_columns_ladder():
 
 @pytest.fixture(scope="module")
 def sched():
-    s = EcBatchScheduler(window_s=0.001)
+    s = EcBatchScheduler()
     yield s
     s.stop()
 
@@ -205,8 +205,7 @@ def test_backend_compiles_counts_compiles_not_first_uses():
     from seaweedfs_tpu.ops.rs_mesh import MeshCoder
     # a mesh of its own (3 of the virtual devices): no other test of the
     # process can have compiled its programs
-    sched = EcBatchScheduler(mesh_coder=MeshCoder(n_devices=3),
-                             window_s=0.001)
+    sched = EcBatchScheduler(mesh_coder=MeshCoder(n_devices=3))
     data = np.zeros((K, 4096), dtype=np.uint8)
     try:
         c0 = sched.stats()["backend_compiles"]
@@ -222,8 +221,7 @@ def test_backend_compiles_counts_compiles_not_first_uses():
     assert c1["backend_compile_s"] > 0
     assert c2["programs_compiled"] == c1["programs_compiled"] == 1
     # a second coder over the same mesh: a first use, nothing compiled
-    again = EcBatchScheduler(mesh_coder=MeshCoder(n_devices=3),
-                             window_s=0.001)
+    again = EcBatchScheduler(mesh_coder=MeshCoder(n_devices=3))
     try:
         again.encode(data)
         c3 = again.stats()

@@ -369,7 +369,7 @@ def test_batcher_exports_wait_and_size_histograms():
 
     from seaweedfs_tpu.parallel.batcher import EcBatchScheduler
 
-    sched = EcBatchScheduler(window_s=0.01)
+    sched = EcBatchScheduler()
     # bench the mesh: every batch takes the CPU drain, whatever the
     # environment's devices
     sched._down_until = float("inf")
