@@ -25,6 +25,26 @@ def norm_disk(disk: str) -> str:
     return "" if disk in ("", "hdd") else disk
 
 
+def ec_geometry_of(entry: dict) -> Optional[tuple[int, int]]:
+    """(data_shards, total_shards) of a heartbeat's / topology dump's EC
+    entry, None where it states none (a volume of the default RS(10,4),
+    or a holder that predates the keys)."""
+    k, total = entry.get("data_shards"), entry.get("total_shards")
+    if k and total and 0 < k < total:
+        return int(k), int(total)
+    return None
+
+
+def ec_geometry_keys(geometry: Optional[tuple[int, int]]) -> dict:
+    if geometry is None:
+        return {}
+    return {"data_shards": geometry[0], "total_shards": geometry[1]}
+
+
+DEFAULT_EC_GEOMETRY = (ec_layout.DATA_SHARDS_COUNT,
+                       ec_layout.TOTAL_SHARDS_COUNT)
+
+
 class DataNode:
     def __init__(self, ip: str, port: int, public_url: str = "",
                  max_volume_count: int = 8):
@@ -82,7 +102,10 @@ class DataNode:
             used += self.ec_shard_count() / ec_layout.TOTAL_SHARDS_COUNT
         return self.disk_slots.get(d, 0) - used
 
-    def to_info(self) -> dict:
+    def to_info(self, ec_geometry: Optional[dict] = None) -> dict:
+        """``ec_geometry``: the topology's vid -> (data, total) shard
+        counts, handed on beside each EC entry's bits."""
+        ec_geometry = ec_geometry or {}
         return {
             "id": self.id, "ip": self.ip, "port": self.port,
             "public_url": self.public_url,
@@ -91,7 +114,8 @@ class DataNode:
             "disk_slots": dict(self.disk_slots),
             "volumes": list(self.volumes.values()),
             "ec_shards": [
-                {"id": vid, "ec_index_bits": bits}
+                {"id": vid, "ec_index_bits": bits,
+                 **ec_geometry_keys(ec_geometry.get(vid))}
                 for vid, bits in self.ec_shards.items()],
             "rack": self.rack.id if self.rack else "",
             "data_center": self.rack.data_center.id
@@ -208,7 +232,10 @@ class Topology:
                  pulse_seconds: float = 5.0):
         self.data_centers: dict[str, DataCenter] = {}
         self.layouts: dict[tuple[str, str, str], VolumeLayout] = {}
+        # vid -> holders per shard id; as many lists as the volume's
+        # code has shards (ec_geometry; 14 where no holder said)
         self.ec_shard_map: dict[int, list[list[DataNode]]] = {}
+        self.ec_geometry: dict[int, tuple[int, int]] = {}
         self.volume_size_limit = volume_size_limit
         self.pulse_seconds = pulse_seconds
         self.max_volume_id = 0
@@ -307,6 +334,8 @@ class Topology:
             # EC shards: full sync
             new_ec = {e["id"]: e["ec_index_bits"]
                       for e in hb.get("ec_shards", [])}
+            geometry = {e["id"]: ec_geometry_of(e)
+                        for e in hb.get("ec_shards", [])}
             for vid in list(node.ec_shards):
                 if vid not in new_ec:
                     self._unregister_ec_shards(vid, node, node.ec_shards[vid])
@@ -314,7 +343,8 @@ class Topology:
             for vid, bits in new_ec.items():
                 old = node.ec_shards.get(vid, 0)
                 node.ec_shards[vid] = bits
-                self._register_ec_shards(vid, node, bits, old)
+                self._register_ec_shards(vid, node, bits, old,
+                                         geometry[vid])
                 self.max_volume_id = max(self.max_volume_id, vid)
             self._notify(
                 node,
@@ -354,7 +384,8 @@ class Topology:
                 vid, bits = e["id"], e["ec_index_bits"]
                 old = node.ec_shards.get(vid, 0)
                 node.ec_shards[vid] = old | bits
-                self._register_ec_shards(vid, node, bits, 0)
+                self._register_ec_shards(vid, node, bits, 0,
+                                         ec_geometry_of(e))
                 new_ec_vids.add(vid)
             for e in deltas.get("deleted_ec_shards", []):
                 vid, bits = e["id"], e["ec_index_bits"]
@@ -404,10 +435,17 @@ class Topology:
 
     # ---- EC registry ----
     def _register_ec_shards(self, vid: int, node: DataNode, bits: int,
-                            old_bits: int = 0) -> None:
-        shards = self.ec_shard_map.setdefault(
-            vid, [[] for _ in range(ec_layout.TOTAL_SHARDS_COUNT)])
-        for sid in range(ec_layout.TOTAL_SHARDS_COUNT):
+                            old_bits: int = 0,
+                            geometry: Optional[tuple[int, int]] = None
+                            ) -> None:
+        """``geometry``: the volume's (data, total) shard counts as the
+        holder's heartbeat stated them, None where it stated none."""
+        if geometry is not None:
+            self.ec_geometry[vid] = geometry
+        total = max(self.ec_volume_geometry(vid)[1], bits.bit_length())
+        shards = self.ec_shard_map.setdefault(vid, [])
+        shards.extend([] for _ in range(total - len(shards)))
+        for sid in range(bits.bit_length()):
             if bits & (1 << sid) and node not in shards[sid]:
                 shards[sid].append(node)
 
@@ -416,11 +454,17 @@ class Topology:
         shards = self.ec_shard_map.get(vid)
         if not shards:
             return
-        for sid in range(ec_layout.TOTAL_SHARDS_COUNT):
+        for sid in range(min(bits.bit_length(), len(shards))):
             if bits & (1 << sid) and node in shards[sid]:
                 shards[sid].remove(node)
         if all(not s for s in shards):
             self.ec_shard_map.pop(vid, None)
+            self.ec_geometry.pop(vid, None)
+
+    def ec_volume_geometry(self, vid: int) -> tuple[int, int]:
+        """(data_shards, total_shards) of an EC volume: what a holder's
+        heartbeat stated, RS(10,4) where none did."""
+        return self.ec_geometry.get(vid, DEFAULT_EC_GEOMETRY)
 
     # ---- lookup ----
     def lookup(self, collection: str, vid: int) -> list[DataNode]:
@@ -498,7 +542,8 @@ class Topology:
                     "id": dc.id,
                     "racks": [{
                         "id": r.id,
-                        "nodes": [n.to_info() for n in r.nodes.values()],
+                        "nodes": [n.to_info(self.ec_geometry)
+                                  for n in r.nodes.values()],
                     } for r in dc.racks.values()],
                 } for dc in self.data_centers.values()],
             }

@@ -140,6 +140,51 @@ def scheme_from_dict(d: Optional[dict]) -> RSScheme:
                     int(d.get("parity_shards", 4)))
 
 
+# a served volume's shard ids are bits of a 32-bit mask in heartbeats
+# (ShardBits, master.proto ec_index_bits) and two digits of a file name
+MAX_VOLUME_SHARDS = 32
+
+
+class CodeSpecError(ValueError):
+    """A request's ``code`` names no scheme a volume can carry (the
+    HTTP edge answers 400)."""
+
+
+def code_spec_name(scheme: RSScheme) -> str:
+    """The spec string of a scheme: ``rs-6-3``, ``lrc-10-2-2``.  What
+    ``parse_code_spec`` reads back, and the key of per-geometry counters
+    (``by_spec`` in the batch scheduler's stats)."""
+    if isinstance(scheme, LrcScheme):
+        return (f"lrc-{scheme.data_shards}-{scheme.local_groups}-"
+                f"{scheme.global_parities}")
+    return f"rs-{scheme.data_shards}-{scheme.parity_shards}"
+
+
+def parse_code_spec(spec: str, default: RSScheme = DEFAULT_SCHEME
+                    ) -> RSScheme:
+    """The one parser from a request's ``code`` to a scheme: ``""`` /
+    ``rs`` -> ``default`` (the server's own), ``rs-<k>-<m>`` ->
+    RSScheme(k, m), ``lrc`` -> LRC(10,2,2).  Anything else — a coder
+    registry name, a geometry no volume can carry — raises
+    CodeSpecError."""
+    spec = (spec or "").strip().lower()
+    if spec in ("", "rs"):
+        return default
+    if spec == "lrc":
+        return LrcScheme()
+    parts = spec.split("-")
+    if len(parts) == 3 and parts[0] == "rs" \
+            and parts[1].isdigit() and parts[2].isdigit():
+        k, m = int(parts[1]), int(parts[2])
+        if k > 0 and m > 0 and k + m <= MAX_VOLUME_SHARDS:
+            return RSScheme(k, m)
+        raise CodeSpecError(
+            f"code {spec!r}: a volume carries 1..{MAX_VOLUME_SHARDS} "
+            "shards, at least one of them data and one parity")
+    raise CodeSpecError(
+        f"unknown code {spec!r}: expected '', 'rs', 'rs-<k>-<m>' or 'lrc'")
+
+
 def coder_name_for_scheme(scheme: RSScheme, fallback: str = "cpu-mt") -> str:
     """The registry name that matches a scheme's code family; `fallback`
     names the RS coder to use (its -mt suffix carries over to LRC)."""

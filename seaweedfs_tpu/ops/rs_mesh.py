@@ -9,13 +9,18 @@ mesh (parallel/mesh.batch_mesh): device d computes lanes
 [d*B/n .. (d+1)*B/n) with no collectives, so throughput scales with
 device count for batches that fill the mesh.
 
-Two compiled programs cover every operation:
+Two compiled programs PER GEOMETRY cover every operation, and carry the
+geometry in their names (``jit_ec_encode_rs_6_3``, ``jit_ec_apply_rs_10_4``
+in a device trace):
 
-  - encode: the static RS(10,4) parity matrix unrolls at trace time into
+  - encode: the scheme's static parity matrix unrolls at trace time into
     the same Horner/XOR graph as rs_jax (bit-identical by construction);
   - rebuild: the coefficient matrix arrives as a TRACED (B, m, k) operand
     (zero rows disabled), so one program serves every survivor pattern in
     the batch — jobs with different loss patterns ride one dispatch.
+
+A MeshCoder is of ONE scheme; the batch scheduler keeps one per geometry
+it has seen, all on the same device mesh.
 
 Batches are zero-padded on the leading axis to a power-of-two multiple
 of the device count (NamedSharding needs even division, and a bounded
@@ -36,7 +41,8 @@ import numpy as np
 from jax.sharding import Mesh
 
 from seaweedfs_tpu.models.coder import (DEFAULT_SCHEME, ErasureCoder,
-                                        RSScheme, register_coder)
+                                        RSScheme, code_spec_name,
+                                        register_coder)
 from seaweedfs_tpu.ops import gf256
 from seaweedfs_tpu.ops.rs_jax import _apply_matrix_words, _mat_to_tuple
 from seaweedfs_tpu.parallel import mesh as mesh_mod
@@ -47,33 +53,41 @@ from seaweedfs_tpu.utils import tracing
 STAGES = ("pad", "launch", "fetch", "unpack")
 
 
+def _named(fn, stem: str, scheme: RSScheme):
+    """Give a program its name in a device trace: ``jit_<stem>_rs_<k>_<m>``.
+    The benchmark's metrics select by the prefixes ``jit_ec_encode`` /
+    ``jit_ec_apply``; the geometry after them tells two codes' programs
+    apart (the name is part of the compile-cache key too)."""
+    fn.__name__ = fn.__qualname__ = \
+        f"{stem}_rs_{scheme.data_shards}_{scheme.parity_shards}"
+    return fn
+
+
 @functools.lru_cache(maxsize=None)
 def batch_encode_fn(scheme: RSScheme, mesh: Mesh):
     """jit over the mesh: (B, k, nw) uint32 sharded P('batch', None, None)
-    -> (B, m, nw) parity with matching sharding.  Static parity matrix,
-    no collectives."""
+    -> (B, m, nw) parity with matching sharding.  The scheme's static
+    parity matrix, no collectives."""
     mat = _mat_to_tuple(gf256.parity_matrix(scheme.data_shards,
                                             scheme.parity_shards))
 
-    # the name is the program's in a device trace (``jit_ec_encode``):
-    # benchmark/metrics/encode_device_ms_per_job.json selects by it
     def ec_encode(words):
         return _apply_matrix_words(words, mat)
 
     s3 = mesh_mod.batch_spec(mesh)
-    return jax.jit(jax.vmap(ec_encode), in_shardings=(s3,),
-                   out_shardings=s3)
+    return jax.jit(jax.vmap(_named(ec_encode, "ec_encode", scheme)),
+                   in_shardings=(s3,), out_shardings=s3)
 
 
 @functools.lru_cache(maxsize=None)
-def batch_apply_fn(mesh: Mesh, n_out: int):
+def batch_apply_fn(scheme: RSScheme, mesh: Mesh):
     """jit over the mesh: per-lane GF matrix application with TRACED
-    coefficients — (B, k, nw) words x (B, n_out, k) coeff -> (B, n_out,
-    nw).  Zero coefficient rows yield zero output rows, so one compiled
-    program serves every (survivor pattern, missing set) mix in a
-    batch."""
+    coefficients — (B, k, nw) words x (B, m, k) coeff -> (B, m, nw), m the
+    scheme's parity count (the most rows a rebuild can ask for).  Zero
+    coefficient rows yield zero output rows, so one compiled program
+    serves every (survivor pattern, missing set) mix in a batch."""
+    n_out = scheme.parity_shards
 
-    # ``jit_ec_apply`` in a device trace (apply_device_ms_per_rebuild)
     def ec_apply(words, coeff):
         outs = []
         for i in range(n_out):
@@ -84,8 +98,8 @@ def batch_apply_fn(mesh: Mesh, n_out: int):
         return jnp.stack(outs)
 
     s3 = mesh_mod.batch_spec(mesh)
-    return jax.jit(jax.vmap(ec_apply), in_shardings=(s3, s3),
-                   out_shardings=s3)
+    return jax.jit(jax.vmap(_named(ec_apply, "ec_apply", scheme)),
+                   in_shardings=(s3, s3), out_shardings=s3)
 
 
 @register_coder("mesh")
@@ -98,12 +112,14 @@ class MeshCoder(ErasureCoder):
     def __init__(self, scheme: RSScheme = DEFAULT_SCHEME,
                  n_devices: int | None = None, mesh: Optional[Mesh] = None):
         super().__init__(scheme)
+        self.spec = code_spec_name(scheme)  # the stages' ``spec`` attribute
         self.mesh = mesh if mesh is not None else mesh_mod.batch_mesh(n_devices)
         # host-side helper for rebuild-matrix derivation (pure numpy)
         from seaweedfs_tpu.ops.rs_cpu import CpuCoder
         self._host = CpuCoder(scheme)
-        # distinct (kind, padded operand shape) THIS coder dispatched;
-        # the batcher's stats() reports the count as programs_compiled.
+        # distinct (kind, padded operand shape) THIS coder dispatched, all
+        # of its one scheme; the batcher's stats() reports the count over
+        # its coders as programs_compiled, and per scheme under by_spec.
         # Not a count of compiles: the jitted functions are cached per
         # (scheme, mesh), so a shape another MeshCoder of the process
         # ran first (a warm-up) is counted here though nothing compiled.
@@ -136,6 +152,7 @@ class MeshCoder(ErasureCoder):
         shards lived, and bring the result to the host."""
         self.programs.add((kind,) + operands[0].shape)
         with tracing.stage("ec.mesh.launch") as st:
+            st.annotate("spec", self.spec)
             # host -> device copies and the enqueue; returns before the
             # device is done
             out = fn(*operands)
@@ -144,6 +161,7 @@ class MeshCoder(ErasureCoder):
                 self.output_spread.get(spread, 0) + 1
         self._staged("launch", st)
         with tracing.stage("ec.mesh.fetch") as st:
+            st.annotate("spec", self.spec)
             # waits for the device, then device -> host
             got = np.asarray(jax.device_get(out))
         self._staged("fetch", st)
@@ -168,12 +186,14 @@ class MeshCoder(ErasureCoder):
         assert k == self.scheme.data_shards, (k, self.scheme)
         assert n % 4 == 0, n
         with tracing.stage("ec.mesh.pad") as st:
+            st.annotate("spec", self.spec)
             words = self._pad_batch(
                 np.ascontiguousarray(batch).view(np.uint32))
             fn = batch_encode_fn(self.scheme, self.mesh)
         self._staged("pad", st)
         out = self._fetch("encode", fn, words)
         with tracing.stage("ec.mesh.unpack") as st:
+            st.annotate("spec", self.spec)
             parity = np.ascontiguousarray(out[:B]).view(np.uint8)
         self._staged("unpack", st)
         return parity
@@ -190,6 +210,7 @@ class MeshCoder(ErasureCoder):
         assert len(mats) == B
         m = self.scheme.parity_shards
         with tracing.stage("ec.mesh.pad") as st:
+            st.annotate("spec", self.spec)
             coeff = np.zeros((B, m, k), dtype=np.uint32)
             for i, mt in enumerate(mats):
                 mt = np.asarray(mt)
@@ -199,10 +220,11 @@ class MeshCoder(ErasureCoder):
             words = self._pad_batch(
                 np.ascontiguousarray(srcdata).view(np.uint32))
             coeff = self._pad_batch(coeff)
-            fn = batch_apply_fn(self.mesh, m)
+            fn = batch_apply_fn(self.scheme, self.mesh)
         self._staged("pad", st)
         out = self._fetch("apply", fn, words, coeff)  # (pb, m, nw)
         with tracing.stage("ec.mesh.unpack") as st:
+            st.annotate("spec", self.spec)
             out8 = np.ascontiguousarray(out[:B]).view(np.uint8)  # (B,m,n)
             recs = [np.ascontiguousarray(
                 out8[i, :np.asarray(mats[i]).shape[0]]) for i in range(B)]

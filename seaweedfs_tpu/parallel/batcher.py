@@ -37,7 +37,12 @@ Scheduling contract:
     short fixed ladder (COLUMN_LADDER), a batch pads to a power of two
     (MeshCoder._pad_batch) and one dispatch carries at most
     MAX_DISPATCH_COLUMNS columns, so a degraded read of a new needle
-    size reuses a compiled program instead of compiling its own.
+    size reuses a compiled program instead of compiling its own;
+  - a job carries its code GEOMETRY (the RS (k, m) whose programs run
+    it: a volume's own scheme, read from its .vif by the store).  Jobs
+    of one geometry coalesce, jobs of two never share a dispatch, and
+    the scheduler keeps one mesh coder (on the one device mesh) and one
+    host fallback per geometry it has seen; ``by_spec`` counts each.
 
 Where the time goes is counted always and traced when sampled: every
 stage a job passes through (``STAGES``; utils/tracing.stage) adds its
@@ -61,7 +66,7 @@ from typing import Callable, Optional, Sequence
 import numpy as np
 
 from seaweedfs_tpu.models.coder import (DEFAULT_SCHEME, ErasureCoder,
-                                        RSScheme)
+                                        RSScheme, code_spec_name)
 from seaweedfs_tpu.qos import CLASSES, current_class
 from seaweedfs_tpu.utils import clockctl, glog, profiler, tracing
 from seaweedfs_tpu.utils.metrics import RED_BUCKETS, Histogram
@@ -142,12 +147,13 @@ def _rank(cls: Optional[str]) -> int:
 
 
 class _Job:
-    __slots__ = ("kind", "data", "mat", "n", "cls", "span", "submitted",
-                 "future")
+    __slots__ = ("scheme", "kind", "data", "mat", "n", "cls", "span",
+                 "submitted", "future")
 
-    def __init__(self, kind: str, data: np.ndarray,
+    def __init__(self, scheme: RSScheme, kind: str, data: np.ndarray,
                  mat: Optional[np.ndarray], n: int, cls: Optional[str],
                  span, submitted: float):
+        self.scheme = scheme      # the geometry whose programs run it
         self.kind = kind          # "encode" | "rebuild"
         self.data = data          # (k, bucket_columns(n)) uint8
         self.mat = mat            # rebuild only: (r, k) uint8
@@ -158,6 +164,24 @@ class _Job:
         self.span = span
         self.submitted = submitted
         self.future: Future = Future()
+
+
+class _Geometry:
+    """What the scheduler keeps per code geometry it has seen: the mesh
+    coder and the host fallback of that scheme (each built when first
+    needed, by the dispatcher thread) and its counters (``by_spec``)."""
+
+    __slots__ = ("scheme", "spec", "mesh", "cpu", "counters")
+
+    def __init__(self, scheme: RSScheme, mesh=None,
+                 cpu: Optional[ErasureCoder] = None):
+        self.scheme = scheme
+        self.spec = code_spec_name(scheme)
+        self.mesh = mesh
+        self.cpu = cpu
+        self.counters = dict.fromkeys(
+            ("jobs", "mesh_dispatches", "cpu_dispatches", "bytes_in",
+             "bytes_out"), 0)
 
 
 class _CallerCells:
@@ -200,7 +224,9 @@ class _CallerCells:
 
 class EcBatchScheduler:
     """The funnel.  Construct one per process (the volume server owns
-    one); hand pipelines a BatchCoder facade over it."""
+    one); hand pipelines a BatchCoder facade over it, one per code
+    geometry.  ``scheme`` is the geometry of a job submitted without
+    one."""
 
     def __init__(self, scheme: RSScheme = DEFAULT_SCHEME, *,
                  mesh_coder=None, cpu_coder: Optional[ErasureCoder] = None,
@@ -212,10 +238,6 @@ class EcBatchScheduler:
         self.cooldown_s = cooldown_s
         self._on_fallback = on_fallback
         self._q: "queue.Queue" = queue.Queue(maxsize=queue_depth)
-        if cpu_coder is None:
-            from seaweedfs_tpu.ops.rs_cpu import CpuCoderMT
-            cpu_coder = CpuCoderMT(scheme)
-        self._cpu = cpu_coder
         self.fallback_reason: Optional[str] = None
         self._mesh = mesh_coder
         self.compile_cache_dir: Optional[str] = None
@@ -234,6 +256,11 @@ class EcBatchScheduler:
         # the process's backend compiles (parallel/mesh.CompileWatch),
         # where the coder is a device coder that watches them
         self._compiles = getattr(self._mesh, "compile_watch", None)
+        # per geometry: mesh coder, host fallback, counters.  Further
+        # geometries are added by the dispatcher thread when their first
+        # job arrives; stats() only reads
+        self._geometries: dict[RSScheme, _Geometry] = {
+            scheme: _Geometry(scheme, self._mesh, cpu_coder)}
         self._down_until = 0.0
         # counters are only written by the dispatcher thread; readers
         # (stats/metrics) tolerate a stale int
@@ -282,13 +309,18 @@ class EcBatchScheduler:
     # ---- submission (any thread) ----
 
     def _submit(self, kind: str, data: np.ndarray,
-                mat: Optional[np.ndarray], cls: Optional[str]) -> Future:
+                mat: Optional[np.ndarray], cls: Optional[str],
+                scheme: Optional[RSScheme]) -> Future:
         if self._stopped:
             raise RuntimeError("EC batch scheduler is stopped")
+        if scheme is None:
+            scheme = self.scheme
         span = tracing.current_span()
         if span is not None and not span.sampled:
             span = None
         with tracing.stage("ec.batch.submit") as st:
+            if st.span is not None:
+                st.annotate("spec", code_spec_name(scheme))
             data = np.ascontiguousarray(data, dtype=np.uint8)
             n = data.shape[1]
             pad = bucket_columns(n) - n
@@ -298,7 +330,8 @@ class EcBatchScheduler:
                     axis=1)
             if cls is None:
                 cls = current_class()
-            job = _Job(kind, data, mat, n, cls, span, clockctl.monotonic())
+            job = _Job(scheme, kind, data, mat, n, cls, span,
+                       clockctl.monotonic())
             self._q.put(job)  # bounded: blocks -> backpressure
         self.note_caller(0, st.elapsed)
         return job.future
@@ -311,34 +344,42 @@ class EcBatchScheduler:
 
     def submit_encode(self, data: np.ndarray,
                       cls: Optional[str] = None,
-                      mat: Optional[np.ndarray] = None) -> Future:
-        """(k, n) uint8 -> Future of (m, n) uint8 parity.  RS parity by
-        default; pass ``mat`` — an (m, k) GF(256) parity matrix, e.g. an
-        LrcCoder's — to encode under another code family.  Matrix-
-        carrying encodes ride the same per-job-matrix path as rebuilds
-        (parity IS mat @ data over GF(256)), so one drain can mix RS and
-        LRC volumes and every future demuxes exactly its own rows."""
+                      mat: Optional[np.ndarray] = None,
+                      scheme: Optional[RSScheme] = None) -> Future:
+        """(k, n) uint8 -> Future of (m, n) uint8 parity: the RS parity
+        of ``scheme`` (the scheduler's own when None), by that geometry's
+        static-matrix program.  Pass ``mat`` — an (m, k) GF(256) parity
+        matrix, e.g. an LrcCoder's — to encode under another code family
+        of the same (k, m): matrix-carrying encodes ride the per-job-
+        matrix path of rebuilds (parity IS mat @ data over GF(256)), so
+        one dispatch can mix RS rebuilds and LRC encodes and every
+        future demuxes exactly its own rows."""
         if mat is not None:
             return self._submit("rebuild", data,
                                 np.ascontiguousarray(mat, dtype=np.uint8),
-                                cls)
-        return self._submit("encode", data, None, cls)
+                                cls, scheme)
+        return self._submit("encode", data, None, cls, scheme)
 
     def submit_rebuild(self, srcdata: np.ndarray, rebuild_mat: np.ndarray,
-                       cls: Optional[str] = None) -> Future:
+                       cls: Optional[str] = None,
+                       scheme: Optional[RSScheme] = None) -> Future:
         """(k, n) rows of the first k present shards + (r, k) rebuild
         matrix -> Future of (r, n) recovered rows."""
         return self._submit("rebuild", srcdata,
                             np.ascontiguousarray(rebuild_mat,
-                                                 dtype=np.uint8), cls)
+                                                 dtype=np.uint8), cls,
+                            scheme)
 
     def encode(self, data: np.ndarray, cls: Optional[str] = None,
-               mat: Optional[np.ndarray] = None) -> np.ndarray:
-        return self.submit_encode(data, cls, mat).result()
+               mat: Optional[np.ndarray] = None,
+               scheme: Optional[RSScheme] = None) -> np.ndarray:
+        return self.submit_encode(data, cls, mat, scheme).result()
 
     def rebuild(self, srcdata: np.ndarray, rebuild_mat: np.ndarray,
-                cls: Optional[str] = None) -> np.ndarray:
-        return self.submit_rebuild(srcdata, rebuild_mat, cls).result()
+                cls: Optional[str] = None,
+                scheme: Optional[RSScheme] = None) -> np.ndarray:
+        return self.submit_rebuild(srcdata, rebuild_mat, cls,
+                                   scheme).result()
 
     # ---- dispatcher ----
 
@@ -411,19 +452,25 @@ class EcBatchScheduler:
             if j.span is not None:
                 j.span.record("ec.batch.wait", j.submitted, now)
             bk = self.by_kind[j.kind]
+            bs = self._geometry(j.scheme).counters
             rows = j.data.shape[0]
+            rows_out = j.scheme.parity_shards if j.mat is None \
+                else j.mat.shape[0]
             bk["jobs"] += 1
+            bs["jobs"] += 1
             bk["bytes_in"] += rows * j.n
+            bs["bytes_in"] += rows * j.n
             bk["bytes_padded"] += rows * j.data.shape[1]
-            bk["bytes_out"] += j.n * (self.scheme.parity_shards
-                                      if j.mat is None else j.mat.shape[0])
+            bk["bytes_out"] += rows_out * j.n
+            bs["bytes_out"] += rows_out * j.n
         self.size_hist.observe(len(batch))
         # QoS ordering: a group containing an interactive job dispatches
         # before an all-background group
         batch.sort(key=lambda j: (_rank(j.cls), j.submitted))
         groups: dict[tuple, list] = {}
         for j in batch:
-            groups.setdefault((j.kind,) + j.data.shape, []).append(j)
+            groups.setdefault((j.scheme, j.kind) + j.data.shape,
+                              []).append(j)
         # profiler attribution: the dispatcher thread does the batch's
         # work, so samples land under the batch's best (first) class
         with profiler.scope(cls=batch[0].cls or "background",
@@ -436,23 +483,37 @@ class EcBatchScheduler:
                 for i in range(0, len(jobs), step):
                     self._run_group(jobs[i:i + step])
 
+    def _geometry(self, scheme: RSScheme) -> _Geometry:
+        g = self._geometries.get(scheme)
+        if g is None:
+            g = self._geometries[scheme] = _Geometry(scheme)
+        return g
+
     def _mesh_compatible(self, jobs: list) -> bool:
-        # the mesh kernel is traced for (k, <=m)-shaped work; an LRC
-        # group-local rebuild reads fewer than k sources, and that is a
-        # routing decision, not a mesh failure — send it to the CPU
+        # a geometry's programs are traced for (k, <=m)-shaped work; an
+        # LRC group-local rebuild reads fewer than k sources, and that
+        # is a routing decision, not a mesh failure — send it to the CPU
         # coder without benching the mesh
-        j = jobs[0]  # groups share data.shape by construction
-        if j.data.shape[0] != self.scheme.data_shards:
+        j = jobs[0]  # groups share scheme and data.shape by construction
+        if j.data.shape[0] != j.scheme.data_shards:
             return False
         return all(jj.mat is None
-                   or jj.mat.shape[0] <= self.scheme.parity_shards
+                   or jj.mat.shape[0] <= j.scheme.parity_shards
                    for jj in jobs)
 
     def _run_group(self, jobs: list) -> None:
+        g = self._geometry(jobs[0].scheme)
         if self._mesh_healthy() and self._mesh_compatible(jobs):
             try:
-                self._run_mesh(jobs)
+                if g.mesh is None:
+                    # a further geometry's first job: one more MeshCoder,
+                    # on the same device mesh
+                    from seaweedfs_tpu.ops.rs_mesh import MeshCoder
+                    g.mesh = MeshCoder(g.scheme, mesh=getattr(
+                        self._mesh, "mesh", None))
+                self._run_mesh(jobs, g)
                 self.mesh_batches += 1
+                g.counters["mesh_dispatches"] += 1
                 if len(jobs) == 1:
                     self.lone_dispatches += 1
                 return
@@ -472,13 +533,15 @@ class EcBatchScheduler:
                         pass
         self._run_cpu(jobs)
         self.cpu_batches += 1
+        g.counters["cpu_dispatches"] += 1
 
     def _staged(self, key: str, st: tracing.stage) -> None:
         self.stage_s[key] += st.elapsed
         self.stage_n[key] += 1
 
-    def _run_mesh(self, jobs: list) -> None:
+    def _run_mesh(self, jobs: list, g: _Geometry) -> None:
         kind = jobs[0].kind
+        mesh, spec = g.mesh, g.spec
         # one ec.batch.dispatch span per dispatch, under the first
         # sampled job; its stages (and the mesh coder's) nest in it
         lead = next((j.span for j in jobs if j.span is not None), None)
@@ -494,10 +557,10 @@ class EcBatchScheduler:
                         else np.stack([j.data for j in jobs])
                 self._staged("stack", st)
                 if kind == "encode":
-                    out = self._mesh.encode_batch(stacked)
+                    out = mesh.encode_batch(stacked)
                 else:
-                    out = self._mesh.rebuild_batch(stacked,
-                                                   [j.mat for j in jobs])
+                    out = mesh.rebuild_batch(stacked,
+                                             [j.mat for j in jobs])
                 with tracing.stage("ec.batch.demux") as st:
                     for i, j in enumerate(jobs):
                         j.future.set_result(
@@ -508,9 +571,11 @@ class EcBatchScheduler:
                     # missed this shape and a request paid for it
                     glog.warning(
                         "EC batcher: %d program(s) compiled or loaded "
-                        "inside a dispatch: %s of shape %s",
-                        compiles.n - n_compiled, kind, stacked.shape)
+                        "inside a dispatch: %s %s of shape %s",
+                        compiles.n - n_compiled, spec, kind,
+                        stacked.shape)
                 if lead is not None:
+                    disp.annotate("spec", spec)
                     disp.annotate("kind", kind)
                     disp.annotate("shape", list(stacked.shape))
         finally:
@@ -524,16 +589,20 @@ class EcBatchScheduler:
             if j.span is not None and j.span is not lead:
                 j.span.record("ec.batch.dispatch", disp.t0, t1,
                               {"dispatch_id": disp.span.span_id,
-                               "jobs": len(jobs)})
+                               "jobs": len(jobs), "spec": spec})
 
     def _run_cpu(self, jobs: list) -> None:
         for j in jobs:
             try:
+                g = self._geometry(j.scheme)
+                if g.cpu is None:
+                    from seaweedfs_tpu.ops.rs_cpu import CpuCoderMT
+                    g.cpu = CpuCoderMT(g.scheme)
+                cpu = g.cpu
                 if j.kind == "encode":
-                    out = np.asarray(self._cpu.encode_array(j.data))
+                    out = np.asarray(cpu.encode_array(j.data))
                 else:
-                    out = np.asarray(
-                        self._cpu.reconstruct_rows(j.data, j.mat))
+                    out = np.asarray(cpu.reconstruct_rows(j.data, j.mat))
                 j.future.set_result(np.ascontiguousarray(out[:, :j.n]))
             except BaseException as e:  # noqa: BLE001 — per-job demux
                 j.future.set_exception(e)
@@ -561,12 +630,29 @@ class EcBatchScheduler:
             self.cpu_batches += 1
 
     def stats(self) -> dict:
-        programs = getattr(self._mesh, "programs", None)
         stage_s = dict(self.stage_s)
         stage_n = dict(self.stage_n)
-        # pad / launch / fetch / unpack are the mesh coder's own
-        stage_s.update(getattr(self._mesh, "stage_s", None) or {})
-        stage_n.update(getattr(self._mesh, "stage_n", None) or {})
+        programs = None
+        output_spread: dict = {}
+        # per geometry that has had a job, keyed by its spec ("rs-6-3"):
+        # jobs add up to jobs_total, the dispatches to mesh_batches and
+        # cpu_batches, programs to programs_compiled
+        by_spec = {}
+        for g in list(self._geometries.values()):
+            # pad / launch / fetch / unpack are the mesh coders' own
+            for k, v in (getattr(g.mesh, "stage_s", None) or {}).items():
+                stage_s[k] = stage_s.get(k, 0.0) + v
+            for k, v in (getattr(g.mesh, "stage_n", None) or {}).items():
+                stage_n[k] = stage_n.get(k, 0) + v
+            for k, v in (getattr(g.mesh, "output_spread", None)
+                         or {}).items():
+                output_spread[k] = output_spread.get(k, 0) + v
+            mine = getattr(g.mesh, "programs", None)
+            if mine is not None:
+                programs = (programs or 0) + len(mine)
+            if g.counters["jobs"]:
+                by_spec[g.spec] = {**g.counters,
+                                   "programs": len(mine or ())}
         callers = self._callers.totals()
         for i, name in enumerate(CALLER_STAGES):
             stage_s[name], stage_n[name] = callers[2 * i:2 * i + 2]
@@ -582,20 +668,19 @@ class EcBatchScheduler:
             "queued": self._q.qsize(),
             "mesh_devices": self._mesh.n_devices,
             "device": self.device,
-            # distinct (kind, B, k, n) shapes THIS scheduler's coder has
-            # dispatched.  Not a count of compiles (the jitted functions
-            # are per process: a shape a warm-up ran first is counted
-            # here though nothing compiled) — that is backend_compiles
-            "programs_compiled": len(programs)
-            if programs is not None else None,
+            # distinct (geometry, kind, B, k, n) THIS scheduler's coders
+            # have dispatched.  Not a count of compiles (the jitted
+            # functions are per process: a shape a warm-up ran first is
+            # counted here though nothing compiled) — that is
+            # backend_compiles
+            "programs_compiled": programs,
             "backend_compiles": compiles.n
             if compiles is not None else None,
             "backend_compile_s": compiles.seconds
             if compiles is not None else None,
             "compile_cache_dir": self.compile_cache_dir,
             # {devices an output was spread over: dispatches}
-            "output_spread": dict(getattr(self._mesh, "output_spread",
-                                          None) or {}),
+            "output_spread": output_spread,
             "mesh_healthy": self._mesh_healthy(),
             "jobs_total": self.jobs_total,
             "batches_total": self.batches_total,
@@ -608,6 +693,7 @@ class EcBatchScheduler:
             "wait_hist": self.wait_hist.snapshot(),
             "size_hist": self.size_hist.snapshot(),
             "by_kind": {k: dict(v) for k, v in self.by_kind.items()},
+            "by_spec": by_spec,
             "stage_s": {k: stage_s.get(k, 0.0) for k in STAGES},
             "stage_n": {k: stage_n.get(k, 0) for k in STAGES},
             "loop_s": loop_s,
@@ -615,17 +701,21 @@ class EcBatchScheduler:
 
 
 class BatchCoder(ErasureCoder):
-    """ErasureCoder facade over an EcBatchScheduler — a drop-in for the
-    Store/pipeline coder seam.  Each pipeline keeps calling
-    encode_into/reconstruct_rows per block-group exactly as before; the
-    facade turns those calls into scheduler submissions, so N concurrent
-    volume pipelines coalesce into device-sized mesh batches without
-    knowing about each other.
+    """ErasureCoder facade of ONE scheme over an EcBatchScheduler — a
+    drop-in for the Store/pipeline coder seam.  Each pipeline keeps
+    calling encode_into/reconstruct_rows per block-group exactly as
+    before; the facade turns those calls into scheduler submissions, so N
+    concurrent volume pipelines coalesce into device-sized mesh batches
+    without knowing about each other.
 
-    Pass a ``scheme`` from a different code family (LrcScheme) to get a
-    facade for that family sharing the SAME scheduler: its encodes and
-    rebuilds carry their own GF matrices, so RS and LRC volumes coalesce
-    into one drain and each future demuxes bit-identical per-job rows."""
+    A scheduler serves as many facades as there are schemes among the
+    store's volumes (``for_scheme``).  A plain RS scheme of any (k, m)
+    submits under its own geometry: encodes run that geometry's static-
+    matrix program, rebuilds its apply program.  A scheme of another
+    code FAMILY (LrcScheme) derives its matrices on its family's host
+    coder and its jobs carry them: they ride the apply program of the RS
+    geometry with the same (k, m), beside that geometry's rebuilds, and
+    each future demuxes bit-identical per-job rows."""
 
     def __init__(self, scheduler: EcBatchScheduler,
                  scheme: Optional[RSScheme] = None):
@@ -633,16 +723,24 @@ class BatchCoder(ErasureCoder):
             scheme = scheduler.scheme
         super().__init__(scheme)
         self.scheduler = scheduler
-        if scheme == scheduler.scheme:
+        if type(scheme) is RSScheme:
             from seaweedfs_tpu.ops.rs_cpu import CpuCoder
             self._host = CpuCoder(scheme)  # matrix derivation only
-            self._encode_mat = None  # scheduler's native RS parity path
+            self._encode_mat = None  # the geometry's static parity program
+            self._geometry = scheme
         else:
             from seaweedfs_tpu.models.coder import (coder_name_for_scheme,
                                                     make_coder)
             self._host = make_coder(coder_name_for_scheme(scheme, "cpu"),
                                     scheme)
             self._encode_mat = np.ascontiguousarray(self._host._parity)
+            self._geometry = RSScheme(scheme.data_shards,
+                                      scheme.parity_shards)
+
+    def for_scheme(self, scheme: RSScheme) -> "BatchCoder":
+        """A facade of another scheme over the SAME scheduler (the store
+        asks for one per volume geometry it meets)."""
+        return BatchCoder(self.scheduler, scheme)
 
     def device_report(self) -> Optional[dict]:
         return self.scheduler.device
@@ -662,11 +760,12 @@ class BatchCoder(ErasureCoder):
     def _encode(self, data: np.ndarray,
                 out: Optional[np.ndarray] = None) -> np.ndarray:
         return self._result(self.scheduler.submit_encode(
-            data, mat=self._encode_mat), out)
+            data, mat=self._encode_mat, scheme=self._geometry), out)
 
     def _rebuild(self, src: np.ndarray, mat: np.ndarray,
                  out: Optional[np.ndarray] = None) -> np.ndarray:
-        return self._result(self.scheduler.submit_rebuild(src, mat), out)
+        return self._result(self.scheduler.submit_rebuild(
+            src, mat, scheme=self._geometry), out)
 
     def encode_array(self, data: np.ndarray) -> np.ndarray:
         return self._encode(data)

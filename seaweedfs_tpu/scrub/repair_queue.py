@@ -21,7 +21,6 @@ from __future__ import annotations
 import threading
 
 from seaweedfs_tpu.qos import BACKGROUND, class_scope
-from seaweedfs_tpu.storage.erasure_coding import layout
 from seaweedfs_tpu.utils import clockctl, glog, profiler, tracing
 from seaweedfs_tpu.utils.httpd import http_json
 from seaweedfs_tpu.utils.limiter import TokenBucket
@@ -261,11 +260,11 @@ class RepairQueue:
     def _scan(self) -> None:
         topo = self.master.topo
         with topo.lock:
+            # owners has one list per shard of the volume's own code
             degraded = {
                 vid: sum(1 for nodes in owners if not nodes)
                 for vid, owners in topo.ec_shard_map.items()
-                if 0 < sum(1 for nodes in owners if nodes)
-                < layout.TOTAL_SHARDS_COUNT}
+                if 0 < sum(1 for nodes in owners if nodes) < len(owners)}
         now = clockctl.now()
         for vid in list(self._degraded_since):
             if vid not in degraded:
@@ -412,16 +411,16 @@ class RepairQueue:
         shard_owners = {sid: [n for n in nodes]
                         for sid, nodes in enumerate(owners)}
         present = {sid for sid, nodes in shard_owners.items() if nodes}
-        missing = sorted(set(range(layout.TOTAL_SHARDS_COUNT)) - present)
+        missing = sorted(set(shard_owners) - present)
         if not missing:
             return 0  # healed while queued (e.g. by an operator)
-        if len(present) < layout.DATA_SHARDS_COUNT \
-                and not self.partial_repair:
+        data_shards = topo.ec_volume_geometry(vid)[0]
+        if len(present) < data_shards and not self.partial_repair:
             # the partial path may still repair an LRC group loss from
             # fewer than k survivors; legacy copy+rebuild cannot
             raise RuntimeError(
                 f"vol {vid}: only {len(present)} shards survive, "
-                f"need {layout.DATA_SHARDS_COUNT}")
+                f"need {data_shards}")
 
         # 3. rebuilder = node already holding the most shards (fewest
         # copies to stage); collection comes from any present shard
@@ -455,10 +454,10 @@ class RepairQueue:
 
         # 4b. legacy choreography: stage every needed shard, then
         # rebuild locally
-        if len(present) < layout.DATA_SHARDS_COUNT:
+        if len(present) < data_shards:
             raise RuntimeError(
                 f"vol {vid}: only {len(present)} shards survive, "
-                f"need {layout.DATA_SHARDS_COUNT}")
+                f"need {data_shards}")
         moved = 0
         for sid in need:
             src = self._pick_source(shard_owners[sid])

@@ -24,8 +24,8 @@ from typing import Optional
 
 import numpy as np
 
-from seaweedfs_tpu.models.coder import (ErasureCoder, scheme_from_dict,
-                                        scheme_to_dict)
+from seaweedfs_tpu.models.coder import (CodeSpecError, ErasureCoder,
+                                        scheme_from_dict, scheme_to_dict)
 from seaweedfs_tpu.ops.rs_cpu import gf_partial_product
 from seaweedfs_tpu.qos import (BACKGROUND, WRITE, QosGovernor, class_scope,
                                classify, current_class, from_headers)
@@ -300,6 +300,11 @@ class VolumeServer:
             "volumeServer", "ec_batch_stage_seconds",
             "cumulative seconds per stage of an EC job, and of the "
             "dispatcher's idle / hold / dispatch", ("stage",))
+        # ... and of its work per code geometry (stats()'s by_spec)
+        self._m_ec_spec = self.metrics.gauge(
+            "volumeServer", "ec_batch_spec",
+            "EC batch scheduler counters per code geometry",
+            ("spec", "stat"))
         # hot-needle record cache + selector-core connection counters,
         # refreshed at scrape from their owners' stats() snapshots
         self._m_cache = self.metrics.gauge(
@@ -1034,6 +1039,9 @@ class VolumeServer:
                 self._m_ec_stage.set(stage, value=val)
             for part, val in bs["loop_s"].items():
                 self._m_ec_stage.set("loop." + part, value=val)
+            for spec, counters in bs["by_spec"].items():
+                for stat, val in counters.items():
+                    self._m_ec_spec.set(spec, stat, value=val)
 
     def _handle_metrics(self, req: Request) -> Response:
         return Response(self.metrics.expose_text(),
@@ -2264,9 +2272,13 @@ class VolumeServer:
         # empty for the serial path
         stats: dict = {}
         with tracing.stage("volume.ec.generate"):
-            base = self.store.generate_ec_shards(
-                b["volume_id"], pipelined=b.get("pipelined", True),
-                stats=stats, code=b.get("code", ""))
+            try:
+                base = self.store.generate_ec_shards(
+                    b["volume_id"], pipelined=b.get("pipelined", True),
+                    stats=stats, code=b.get("code", ""))
+            except CodeSpecError as e:
+                # the caller's mistake, not the server's
+                return Response({"error": str(e)}, status=400)
         return Response({"base": os.path.basename(base),
                          "pipeline": stats})
 

@@ -694,11 +694,11 @@ class ShellContext:
 
     def _ec_encode_one(self, topo: dict, vid: int, delete_source: bool,
                        pipelined: bool = True, code: str = "") -> dict:
-        scheme = None
-        if code.startswith("lrc"):
-            from seaweedfs_tpu.models.coder import LrcScheme
-            scheme = LrcScheme()
-        plan = ec_plan.plan_ec_encode(topo, vid, scheme=scheme)
+        # the one parser the volume server reads the same string with:
+        # an unknown spec fails here, before anything is marked readonly
+        from seaweedfs_tpu.models.coder import parse_code_spec
+        plan = ec_plan.plan_ec_encode(topo, vid,
+                                      scheme=parse_code_spec(code))
         source = plan["source"]
         collection = ""
         for dc in topo.get("data_centers", []):
@@ -817,10 +817,8 @@ class ShellContext:
                     rack_of[n["id"]] = \
                         f"{dc.get('id', '')}/{rack.get('id', '')}"
                     for e in n.get("ec_shards", []):
-                        bits = e["ec_index_bits"]
-                        for sid in range(layout.TOTAL_SHARDS_COUNT):
-                            if bits & (1 << sid):
-                                owners[e["id"]][sid].append(n["id"])
+                        for sid in ec_plan.shard_ids_of(e):
+                            owners[e["id"]][sid].append(n["id"])
         try:
             repair = self.ec_repair_status()
         except Exception:

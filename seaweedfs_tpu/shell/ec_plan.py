@@ -15,7 +15,16 @@ import dataclasses
 from collections import defaultdict
 from typing import Optional
 
+from seaweedfs_tpu.cluster.topology import (DEFAULT_EC_GEOMETRY,
+                                            ec_geometry_of)
 from seaweedfs_tpu.storage.erasure_coding import layout
+from seaweedfs_tpu.storage.erasure_coding.ec_volume import ShardBits
+
+
+def shard_ids_of(entry: dict) -> list[int]:
+    """The shard ids a topology dump's EC entry holds, whatever the
+    volume's shard count."""
+    return ShardBits(entry["ec_index_bits"]).shard_ids()
 
 
 @dataclasses.dataclass
@@ -62,10 +71,7 @@ def collect_ec_nodes(topology: dict) -> list[EcNode]:
                     rack=n.get("rack", rack.get("id", "")),
                     data_center=n.get("data_center", dc.get("id", "")))
                 for e in n.get("ec_shards", []):
-                    bits = e["ec_index_bits"]
-                    node.shards[e["id"]] = {
-                        sid for sid in range(layout.TOTAL_SHARDS_COUNT)
-                        if bits & (1 << sid)}
+                    node.shards[e["id"]] = set(shard_ids_of(e))
                 out.append(node)
     out.sort(key=lambda n: -n.free_ec_slots)
     return out
@@ -183,7 +189,8 @@ def plan_ec_encode(topology: dict, vid: int,
                    source_node: Optional[str] = None,
                    scheme=None) -> dict:
     """Plan: where the volume lives, and where each generated shard
-    goes. An LRC `scheme` asks for rack-aligned local groups first
+    goes: one target per shard of `scheme` (RS(10,4) when None). An LRC
+    `scheme` asks for rack-aligned local groups first
     (grouped_ec_distribution), falling back to the balanced round-robin
     when the topology cannot align."""
     replicas = []
@@ -202,7 +209,9 @@ def plan_ec_encode(topology: dict, vid: int,
         targets = grouped_ec_distribution(nodes, scheme)
     rack_aligned = targets is not None
     if targets is None:
-        targets = balanced_ec_distribution(nodes)
+        targets = balanced_ec_distribution(
+            nodes, scheme.total_shards if scheme is not None
+            else layout.TOTAL_SHARDS_COUNT)
     moves = [ShardMove(vid, sid, source, target)
              for sid, target in enumerate(targets)]
     return {"vid": vid, "source": source, "replicas": replicas,
@@ -214,27 +223,30 @@ def plan_ec_rebuild(topology: dict) -> list[dict]:
     rebuilder (most free slots) (reference command_ec_rebuild.go)."""
     shard_owners: dict[int, dict[int, list[str]]] = defaultdict(
         lambda: defaultdict(list))
+    geometry: dict[int, tuple[int, int]] = {}
     for dc in topology.get("data_centers", []):
         for rack in dc.get("racks", []):
             for n in rack.get("nodes", []):
                 for e in n.get("ec_shards", []):
-                    bits = e["ec_index_bits"]
-                    for sid in range(layout.TOTAL_SHARDS_COUNT):
-                        if bits & (1 << sid):
-                            shard_owners[e["id"]][sid].append(n["id"])
+                    # the volume's CodeSpec as its holder's heartbeat
+                    # stated it, RS(10,4) where it stated none
+                    geometry[e["id"]] = ec_geometry_of(e) \
+                        or DEFAULT_EC_GEOMETRY
+                    for sid in shard_ids_of(e):
+                        shard_owners[e["id"]][sid].append(n["id"])
     nodes = collect_ec_nodes(topology)
     plans = []
     for vid, owners in sorted(shard_owners.items()):
         present = sorted(owners)
-        if len(present) >= layout.TOTAL_SHARDS_COUNT:
+        data_shards, total = geometry[vid]
+        if len(present) >= total:
             continue
-        if len(present) < layout.DATA_SHARDS_COUNT:
+        if len(present) < data_shards:
             plans.append({"vid": vid, "error":
                           f"unrepairable: only {len(present)} shards"})
             continue
         rebuilder = max(nodes, key=lambda n: n.free_ec_slots)
-        missing = [sid for sid in range(layout.TOTAL_SHARDS_COUNT)
-                   if sid not in owners]
+        missing = [sid for sid in range(total) if sid not in owners]
         copies = [ShardMove(vid, sid, owners[sid][0], rebuilder.node_id)
                   for sid in present
                   if rebuilder.node_id not in owners[sid]]
@@ -306,11 +318,9 @@ def plan_ec_decode(topology: dict, vid: int) -> dict:
                 for e in n.get("ec_shards", []):
                     if e["id"] != vid:
                         continue
-                    bits = e["ec_index_bits"]
-                    for sid in range(layout.TOTAL_SHARDS_COUNT):
-                        if bits & (1 << sid):
-                            owners[sid].append(n["id"])
-                            node_shards[n["id"]].add(sid)
+                    for sid in shard_ids_of(e):
+                        owners[sid].append(n["id"])
+                        node_shards[n["id"]].add(sid)
     if not owners:
         raise LookupError(f"ec volume {vid} not found")
     collector = max(node_shards, key=lambda k: len(node_shards[k]))

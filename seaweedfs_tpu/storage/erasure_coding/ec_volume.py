@@ -102,17 +102,17 @@ class ShardBits:
         return bool(self.bits & (1 << shard_id))
 
     def shard_ids(self) -> list[int]:
-        return [i for i in range(layout.TOTAL_SHARDS_COUNT)
+        # every set bit, whatever the volume's shard count
+        return [i for i in range(self.bits.bit_length())
                 if self.has_shard_id(i)]
 
     def shard_id_count(self) -> int:
         return bin(self.bits).count("1")
 
-    def minus_parity_shards(self) -> "ShardBits":
-        b = self
-        for i in range(layout.DATA_SHARDS_COUNT, layout.TOTAL_SHARDS_COUNT):
-            b = b.remove_shard_id(i)
-        return b
+    def minus_parity_shards(
+            self, data_shards: int = layout.DATA_SHARDS_COUNT
+    ) -> "ShardBits":
+        return ShardBits(self.bits & ((1 << data_shards) - 1))
 
     def plus(self, other: "ShardBits") -> "ShardBits":
         return ShardBits(self.bits | other.bits)
@@ -270,7 +270,8 @@ class EcVolume:
         """Read one interval from a LOCAL shard. Returns (data, shard_id);
         data is None when the shard is not local (caller goes remote /
         degraded, reference store_ec.go:188-218)."""
-        shard_id, off = interval.to_shard_id_and_offset(large_block, small_block)
+        shard_id, off = interval.to_shard_id_and_offset(
+            large_block, small_block, self.data_shards)
         shard = self.shards.get(shard_id)
         if shard is None:
             return None, shard_id

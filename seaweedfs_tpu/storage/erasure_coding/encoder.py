@@ -1,4 +1,6 @@
-"""EC encode/rebuild pipelines: .dat -> .ec00...ec13, .idx -> .ecx.
+"""EC encode/rebuild pipelines: .dat -> one .ecNN per shard of the
+coder's scheme (.ec00-.ec13 under RS(10,4), .ec00-.ec08 under RS(6,3)),
+.idx -> .ecx.
 
 Functional equivalent of reference weed/storage/erasure_coding/ec_encoder.go,
 re-designed for a TPU backend: instead of fixed 256KB CPU batches
@@ -21,8 +23,8 @@ from seaweedfs_tpu.storage.erasure_coding import layout
 from seaweedfs_tpu.storage.needle_map import MemDb
 
 # Batch of bytes PER SHARD pushed through the coder in one step. 4MB/shard
-# = 40MB of input on RS(10,4); big enough to amortize dispatch, small
-# enough to double-buffer in HBM alongside outputs.
+# = 40MB of input on RS(10,4), 24MB on RS(6,3); big enough to amortize
+# dispatch, small enough to double-buffer in HBM alongside outputs.
 DEFAULT_BATCH_SIZE = 4 * 1024 * 1024
 
 
@@ -82,8 +84,9 @@ def write_ec_files(base_file_name: str, coder: Optional[ErasureCoder] = None,
                    pipelined: bool = False,
                    readers: int = 1,
                    stats: Optional[dict] = None) -> None:
-    """Encode <base>.dat into <base>.ec00 .. .ec13 (WriteEcFiles
-    equivalent, reference ec_encoder.go:56-59,194-231).
+    """Encode <base>.dat into <base>.ec00 .. .ec<total-1>, as many files
+    as `coder.scheme` has shards (WriteEcFiles equivalent, reference
+    ec_encoder.go:56-59,194-231).
 
     pipelined=True runs the staged reader/coder/writer pipeline from
     parallel/streaming.py (overlapped I/O + compute, same bits on disk —

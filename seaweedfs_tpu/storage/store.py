@@ -14,7 +14,8 @@ import threading
 from typing import Callable, Optional
 
 from seaweedfs_tpu.models.coder import (DEFAULT_SCHEME, ErasureCoder,
-                                        coder_name_for_scheme, make_coder)
+                                        RSScheme, coder_name_for_scheme,
+                                        make_coder, parse_code_spec)
 from seaweedfs_tpu.storage import types as t
 from seaweedfs_tpu.storage.disk_location import DiskLocation
 from seaweedfs_tpu.storage.erasure_coding import layout
@@ -366,7 +367,9 @@ class Store:
                         ev = loc.find_ec_volume(vid)
                         self.new_ec_shards.append(
                             {"id": vid, "collection": ev.collection,
-                             "ec_index_bits": 1 << sid})
+                             "ec_index_bits": 1 << sid,
+                             "data_shards": ev.data_shards,
+                             "total_shards": ev.total_shards})
                     break
                 except FileNotFoundError:
                     continue
@@ -389,30 +392,37 @@ class Store:
             return self.coder
         c = self._coder_cache.get(scheme)
         if c is None:
-            c = make_coder(coder_name_for_scheme(scheme), scheme)
+            sibling = getattr(self.coder, "for_scheme", None)
+            if sibling is not None and type(scheme) is RSScheme:
+                # the store's coder is the batch scheduler's facade:
+                # every RS geometry submits to the one device queue
+                c = sibling(scheme)
+            else:
+                # another code family (LRC), or a store on a host coder
+                c = make_coder(coder_name_for_scheme(scheme), scheme)
             self._coder_cache[scheme] = c
         return c
 
     def generate_ec_shards(self, vid: int, pipelined: bool = True,
                            stats: Optional[dict] = None,
                            code: str = "") -> str:
-        """VolumeEcShardsGenerate equivalent: write .ec00-.ec13 + .ecx +
-        .vif next to the volume's files (reference
+        """VolumeEcShardsGenerate equivalent: write one .ecNN file per
+        shard of the code (.ec00-.ec13 for RS(10,4), .ec00-.ec08 for
+        RS(6,3)) + .ecx + .vif next to the volume's files (reference
         server/volume_grpc_erasure_coding.go:38-81). Returns the base file
         name. The volume must exist locally; it is marked readonly first.
-        `code` picks the family ('' / 'rs' -> the store coder, 'lrc' ->
-        LRC(10,2,2)); the chosen CodeSpec is persisted in the .vif."""
+        `code` is a code spec (models/coder.parse_code_spec: '' / 'rs' ->
+        the store coder's scheme, 'rs-<k>-<m>', 'lrc' -> LRC(10,2,2); an
+        unknown one raises CodeSpecError); the chosen CodeSpec is persisted
+        in the .vif, and everything later reads it from there."""
         from seaweedfs_tpu.storage.erasure_coding import encoder as ecenc
         from seaweedfs_tpu.storage.erasure_coding.ec_volume import \
             write_volume_info
+        scheme = parse_code_spec(code, self.coder.scheme)
         v = self.find_volume(vid)
         if v is None:
             raise NotFoundError(f"volume {vid} not found")
-        if code and code != "rs":
-            coder = make_coder(code)
-            coder = self._coder_cache.setdefault(coder.scheme, coder)
-        else:
-            coder = self.coder
+        coder = self.coder_for_scheme(scheme)
         with tracing.stage("store.ec.generate"):
             v.read_only = True
             with tracing.stage("store.ec.generate.sync"):
@@ -514,7 +524,7 @@ class Store:
         intervals = layout.locate_data(
             layout.LARGE_BLOCK_SIZE, layout.SMALL_BLOCK_SIZE,
             ev.data_shards * ev.shard_size(),
-            rec_offset + rel_off, length)
+            rec_offset + rel_off, length, data_shards=ev.data_shards)
         return b"".join(
             self._read_one_interval(ev, iv) for iv in intervals)
 
@@ -615,10 +625,11 @@ class Store:
         intervals = layout.locate_data(
             layout.LARGE_BLOCK_SIZE, layout.SMALL_BLOCK_SIZE,
             ev.data_shards * ev.shard_size(),
-            rec_offset + rel_off, length)
+            rec_offset + rel_off, length, data_shards=ev.data_shards)
         locs = None
         for iv in intervals:
-            sid = iv.to_shard_id_and_offset()[0]
+            sid = iv.to_shard_id_and_offset(
+                data_shards=ev.data_shards)[0]
             if sid in ev.shards:
                 continue
             if self.remote_shard_reader is None:
@@ -645,7 +656,8 @@ class Store:
             return data
         # remote shard
         if self.remote_shard_reader is not None:
-            shard_off = iv.to_shard_id_and_offset()[1]
+            shard_off = iv.to_shard_id_and_offset(
+                data_shards=ev.data_shards)[1]
             data = self.remote_shard_reader(ev.volume_id, shard_id, shard_off,
                                             iv.size)
             if data is not None and len(data) == iv.size:
@@ -693,7 +705,8 @@ class Store:
         coder = self.coder_for(ev)
         k = coder.scheme.data_shards
         total = coder.scheme.total_shards
-        shard_off = iv.to_shard_id_and_offset()[1]
+        shard_off = iv.to_shard_id_and_offset(
+            data_shards=ev.data_shards)[1]
         plan_capable = hasattr(coder, "plan_rebuild")
         if plan_capable:
             got = self._recover_via_plan(ev, iv, shard_off, coder,
@@ -938,6 +951,9 @@ class Store:
                     "id": ev.volume_id,
                     "collection": ev.collection,
                     "ec_index_bits": ev.shard_bits().bits,
+                    # the volume's CodeSpec, for the master's planners
+                    "data_shards": ev.data_shards,
+                    "total_shards": ev.total_shards,
                 })
         disk_slots: dict[str, int] = {}
         for loc in self.locations:

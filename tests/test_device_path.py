@@ -196,7 +196,7 @@ def test_the_two_programs_lower_under_their_own_names():
     words = jax.ShapeDtypeStruct((1, K, 1024), jnp.uint32)
     coeff = jax.ShapeDtypeStruct((1, 4, K), jnp.uint32)
     enc = rs_mesh.batch_encode_fn(DEFAULT_SCHEME, mesh).lower(words)
-    app = rs_mesh.batch_apply_fn(mesh, 4).lower(words, coeff)
+    app = rs_mesh.batch_apply_fn(DEFAULT_SCHEME, mesh).lower(words, coeff)
     assert "module @jit_ec_encode" in enc.as_text()
     assert "module @jit_ec_apply" in app.as_text()
 

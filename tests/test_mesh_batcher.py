@@ -663,7 +663,8 @@ def test_two_sampled_jobs_in_one_dispatch_share_it_by_id():
     other = next(s for s in disp if s is not lead)
     assert lead["annotations"]["shape"][0] == 2
     assert other["annotations"] == {"dispatch_id": lead["span_id"],
-                                    "jobs": 2}
+                                    "jobs": 2, "spec": "rs-10-4"}
+    assert lead["annotations"]["spec"] == "rs-10-4"
     assert other["duration_ms"] == pytest.approx(lead["duration_ms"],
                                                  abs=0.5)
     # the stages hang off the one dispatch span, once

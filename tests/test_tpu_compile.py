@@ -34,7 +34,7 @@ import jax.numpy as jnp  # noqa: E402
 from jax.sharding import (Mesh, NamedSharding,  # noqa: E402
                           PartitionSpec as P, SingleDeviceSharding)
 
-from seaweedfs_tpu.models.coder import DEFAULT_SCHEME  # noqa: E402
+from seaweedfs_tpu.models.coder import DEFAULT_SCHEME, RSScheme  # noqa: E402
 from seaweedfs_tpu.ops import gf256, rs_jax, rs_mesh  # noqa: E402
 from seaweedfs_tpu.parallel.batcher import shape_buckets  # noqa: E402
 
@@ -109,7 +109,7 @@ def test_batch_encode_compiles_every_bucket(topo, B, n):
 @pytest.mark.parametrize("B,n", BUCKETS_1)
 def test_batch_apply_compiles_every_bucket(topo, B, n):
     mesh = _batch_mesh(topo, 1)
-    compiled = rs_mesh.batch_apply_fn(mesh, M) \
+    compiled = rs_mesh.batch_apply_fn(DEFAULT_SCHEME, mesh) \
         .lower(*_batch_args(mesh, B, n)).compile()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
@@ -126,13 +126,39 @@ def test_four_device_batch_mesh_has_no_collective(topo, kind):
         compiled = rs_mesh.batch_encode_fn(DEFAULT_SCHEME, mesh) \
             .lower(words).compile()
     else:
-        compiled = rs_mesh.batch_apply_fn(mesh, M) \
+        compiled = rs_mesh.batch_apply_fn(DEFAULT_SCHEME, mesh) \
             .lower(words, coeff).compile()
     text = compiled.as_text()
     assert not [c for c in COLLECTIVES if c in text]
     # each device holds its own B/4 lanes of the output
     (out_s,) = jax.tree_util.tree_leaves(compiled.output_shardings)
     assert out_s.shard_shape((B, M, n // 4)) == (B // 4, M, n // 4)
+
+
+@pytest.mark.parametrize("kind", ["encode", "apply"])
+@pytest.mark.parametrize("n", [256 << 10, MIB])
+def test_rs6_3_programs_compile_under_their_own_names(topo, kind, n):
+    """A second geometry's two programs at the rungs its volumes use (a
+    degraded read's 256 KiB, a seal's 1 MiB row), B = 1: each compiles
+    for the chip and carries its geometry in its name, beside RS(10,4)'s."""
+    scheme = RSScheme(6, 3)
+    mesh = _batch_mesh(topo, 1)
+    s3 = NamedSharding(mesh, P("batch", None, None))
+    words = jax.ShapeDtypeStruct((1, 6, n // 4), jnp.uint32, sharding=s3)
+    coeff = jax.ShapeDtypeStruct((1, 3, 6), jnp.uint32, sharding=s3)
+    if kind == "encode":
+        lowered = rs_mesh.batch_encode_fn(scheme, mesh).lower(words)
+        ours = rs_mesh.batch_encode_fn(DEFAULT_SCHEME, mesh)
+    else:
+        lowered = rs_mesh.batch_apply_fn(scheme, mesh).lower(words, coeff)
+        ours = rs_mesh.batch_apply_fn(DEFAULT_SCHEME, mesh)
+    assert f"jit_ec_{kind}_rs_6_3" in lowered.as_text()[:200]
+    assert f"jit_ec_{kind}_rs_10_4" in ours.lower(
+        *_batch_args(mesh, 1, n)[:1 if kind == "encode" else 2]
+    ).as_text()[:200]
+    (out_s,) = jax.tree_util.tree_leaves(
+        lowered.compile().output_shardings)
+    assert out_s.shard_shape((1, 3, n // 4)) == (1, 3, n // 4)
 
 
 def _parity_tuple():
