@@ -257,6 +257,12 @@ class MeshCoder(ErasureCoder):
                        missing: Sequence[int]) -> np.ndarray:
         return self._host.rebuild_matrix(present, missing)
 
+    def job_rows(self, n: int) -> np.ndarray:
+        """A zeroed (k, n rounded up to the uint32 lanes) operand for
+        reconstruct_rows: a caller fills rows[:, :n] in place."""
+        return np.zeros((self.scheme.data_shards, n + (-n) % 4),
+                        dtype=np.uint8)
+
     def reconstruct_rows(self, srcdata: np.ndarray,
                          rebuild_mat: np.ndarray,
                          out: Optional[np.ndarray] = None) -> np.ndarray:

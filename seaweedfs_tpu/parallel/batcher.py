@@ -785,6 +785,16 @@ class BatchCoder(ErasureCoder):
                        missing: Sequence[int]) -> np.ndarray:
         return self._host.rebuild_matrix(present, missing)
 
+    def job_rows(self, n: int) -> np.ndarray:
+        """A zeroed (k, rung) operand for a job of ``n`` columns.  A
+        caller that fills rows[:, :n] in place (a degraded read's
+        survivors) hands submit a buffer that is contiguous and on the
+        ladder already: nothing is stacked, padded or copied on the way
+        to the device.  The rows come back rung-wide; columns past ``n``
+        are zero in, zero out."""
+        return np.zeros((self.scheme.data_shards, bucket_columns(n)),
+                        dtype=np.uint8)
+
     def reconstruct_rows(self, srcdata: np.ndarray,
                          rebuild_mat: np.ndarray,
                          out: Optional[np.ndarray] = None) -> np.ndarray:

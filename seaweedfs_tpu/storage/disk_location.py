@@ -29,6 +29,9 @@ class DiskLocation:
         self.disk_type = disk_type
         self.volumes: dict[int, Volume] = {}
         self.ec_volumes: dict[int, EcVolume] = {}
+        # handed to every EcVolume mounted here for its lookup counters;
+        # a Store points it at its ec_read_stats
+        self.ec_read_stats: Optional[dict] = None
         self._lock = threading.RLock()
 
     # ---- scanning ----
@@ -111,7 +114,8 @@ class DiskLocation:
             shard = EcVolumeShard(self.directory, collection, vid, shard_id)
             if ev is None:
                 try:
-                    ev = EcVolume(self.directory, collection, vid)
+                    ev = EcVolume(self.directory, collection, vid,
+                                  stats=self.ec_read_stats)
                 except BaseException:
                     shard.close()
                     raise

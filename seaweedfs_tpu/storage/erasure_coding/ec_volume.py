@@ -9,7 +9,11 @@ from __future__ import annotations
 import json
 import os
 import threading
+from array import array
+from bisect import bisect_left
 from typing import Callable, Iterator, Optional
+
+import numpy as np
 
 from seaweedfs_tpu.models.coder import scheme_from_dict, scheme_to_dict
 from seaweedfs_tpu.storage import types as t
@@ -68,6 +72,46 @@ def search_needle_from_sorted_index(
         else:
             hi = mid
     raise NotFoundError(f"needle {needle_id:x} not in ecx")
+
+
+_ECX_ENTRY = np.dtype([("key", ">u8"), ("offset", ">u4"), ("size", ">i4")])
+
+
+def load_sorted_index(raw: bytes) -> tuple[array, array, array]:
+    """The entries of a sorted 16-byte-entry index as native-endian
+    (keys, offset units, sizes) arrays, built once: `bisect_left` over
+    `keys` is the whole search, and a tombstone is one store into
+    `sizes`. 16 B a needle, as in the file."""
+    entries = np.frombuffer(raw, dtype=_ECX_ENTRY,
+                            count=len(raw) // _ECX_ENTRY.itemsize)
+    columns = []
+    for code, field in (("Q", "key"), ("I", "offset"), ("i", "size")):
+        col = array(code)
+        native = _ECX_ENTRY[field].newbyteorder("=")
+        if col.itemsize != native.itemsize:
+            raise RuntimeError(f"array({code!r}) holds {col.itemsize} bytes "
+                               f"an item here, the index {native.itemsize}")
+        col.frombytes(entries[field].astype(native).tobytes())
+        columns.append(col)
+    return columns[0], columns[1], columns[2]
+
+
+def _position(keys: array, needle_id: int) -> int:
+    """Where needle_id sits in the sorted keys, or -1."""
+    i = bisect_left(keys, needle_id)
+    return i if i < len(keys) and keys[i] == needle_id else -1
+
+
+def _pread_full(fd: int, length: int, offset: int) -> bytes:
+    """`length` bytes at `offset`, short only at the end of the file
+    (one pread as a rule; a read that comes back short is continued)."""
+    data = os.pread(fd, length, offset)
+    while 0 < len(data) < length:
+        more = os.pread(fd, length - len(data), offset + len(data))
+        if not more:
+            break
+        data += more
+    return data
 
 
 def iterate_ecj_file(base_file_name: str) -> Iterator[int]:
@@ -147,14 +191,27 @@ class EcVolumeShard:
         self.shard_id = shard_id
         self.path = ec_base_file_name(directory, collection, volume_id) \
             + layout.shard_ext(shard_id)
-        self._f = open(self.path, "rb")
+        # positional reads only: no file position, so no lock to share it
+        self._f = open(self.path, "rb", buffering=0)
         self.shard_size = os.path.getsize(self.path)
-        self._lock = threading.Lock()
 
     def read_at(self, offset: int, length: int) -> bytes:
-        with self._lock:
-            self._f.seek(offset)
-            return self._f.read(length)
+        """One pread; short only at the end of the file."""
+        return _pread_full(self._f.fileno(), length, offset)
+
+    def read_into(self, offset: int, buf) -> int:
+        """Fill a writable buffer (a row of a job's operand) from
+        `offset` by preadv, no bytes object between; returns the bytes
+        read, short only at the end of the file."""
+        fd = self._f.fileno()
+        view = memoryview(buf)
+        n = os.preadv(fd, [view], offset)
+        while 0 < n < len(view):
+            got = os.preadv(fd, [view[n:]], offset + n)
+            if not got:
+                break
+            n += got
+        return n
 
     def close(self):
         self._f.close()
@@ -169,10 +226,14 @@ class EcVolume:
     (reference ec_volume.go:25-76)."""
 
     def __init__(self, directory: str, collection: str, volume_id: int,
-                 version: int = 3):
+                 version: int = 3, stats: Optional[dict] = None):
         self.directory = directory
         self.collection = collection
         self.volume_id = volume_id
+        # where lookups are counted: the mounting store's ec_read_stats
+        # (storage/store.py), a dict of its own otherwise
+        self.stats = stats if stats is not None \
+            else {"ecx_lookups": 0, "ecx_file_searches": 0}
         self.base_file_name = ec_base_file_name(directory, collection,
                                                 volume_id)
         info = read_volume_info(self.base_file_name)
@@ -184,9 +245,22 @@ class EcVolume:
         self._ecx_lock = threading.Lock()
         self._ecj_lock = threading.Lock()
         ecx = self.base_file_name + ".ecx"
-        self.ecx_file = open(ecx, "r+b") if os.path.exists(ecx) else None
+        # unbuffered: the handle is the tombstones' way to the disk
+        # (delete_needle), and the fallback's when the index did not load
+        self.ecx_file = open(ecx, "r+b", buffering=0) \
+            if os.path.exists(ecx) else None
         self.ecx_file_size = os.path.getsize(ecx) if self.ecx_file else 0
         self.ecx_created_at = os.path.getmtime(ecx) if self.ecx_file else 0
+        # the index in memory from mount to unmount (upstream searches
+        # the file): lookups bisect these without a lock or a system
+        # call; delete_needle, under _ecx_lock, is their one writer
+        self._ecx_index: Optional[tuple[array, array, array]] = None
+        if self.ecx_file is not None:
+            try:
+                self._ecx_index = load_sorted_index(_pread_full(
+                    self.ecx_file.fileno(), self.ecx_file_size, 0))
+            except (OSError, MemoryError):
+                pass  # lookups search the file, under the lock
         # shard-location cache for remote reads (volume server fills this)
         self.shard_locations: dict[int, list[str]] = {}
         self.shard_locations_refreshed_at = 0.0
@@ -223,8 +297,20 @@ class EcVolume:
     def find_needle_from_ecx(self, needle_id: int) -> tuple[int, int]:
         """(offset_bytes, size); raises NotFoundError; tombstones surface as
         deleted size (reference ec_volume.go:205-250)."""
+        index = self._ecx_index
+        if index is None:
+            return self._find_needle_in_ecx_file(needle_id)
+        self.stats["ecx_lookups"] += 1
+        keys, offsets, sizes = index
+        i = _position(keys, needle_id)
+        if i < 0:
+            raise NotFoundError(f"needle {needle_id:x} not in ecx")
+        return t.offset_to_actual(offsets[i]), sizes[i]
+
+    def _find_needle_in_ecx_file(self, needle_id: int) -> tuple[int, int]:
         if self.ecx_file is None:
             raise NotFoundError("no ecx file")
+        self.stats["ecx_file_searches"] += 1
         with self._ecx_lock:
             off_units, size = search_needle_from_sorted_index(
                 self.ecx_file, self.ecx_file_size, needle_id)
@@ -248,15 +334,26 @@ class EcVolume:
         return intervals, offset, size
 
     def delete_needle(self, needle_id: int) -> None:
-        """Tombstone in .ecx + journal append to .ecj
-        (reference ec_volume_delete.go:27-49)."""
+        """Tombstone in .ecx (the file, then the index in memory: every
+        lookup that starts after this returns sees it) + journal append
+        to .ecj (reference ec_volume_delete.go:27-49)."""
         if self.ecx_file is None:
             raise NotFoundError("no ecx file")
         try:
             with self._ecx_lock:
-                search_needle_from_sorted_index(
-                    self.ecx_file, self.ecx_file_size, needle_id,
-                    mark_needle_deleted)
+                index = self._ecx_index
+                if index is None:
+                    search_needle_from_sorted_index(
+                        self.ecx_file, self.ecx_file_size, needle_id,
+                        mark_needle_deleted)
+                else:
+                    keys, _, sizes = index
+                    i = _position(keys, needle_id)
+                    if i < 0:
+                        return
+                    mark_needle_deleted(self.ecx_file,
+                                        i * t.NEEDLE_MAP_ENTRY_SIZE)
+                    sizes[i] = t.TOMBSTONE_FILE_SIZE
         except NotFoundError:
             return
         with self._ecj_lock:
@@ -278,6 +375,7 @@ class EcVolume:
         return shard.read_at(off, interval.size), shard_id
 
     def close(self):
+        self._ecx_index = None
         if self.ecx_file:
             self.ecx_file.close()
             self.ecx_file = None

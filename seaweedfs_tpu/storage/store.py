@@ -13,6 +13,8 @@ import os
 import threading
 from typing import Callable, Optional
 
+import numpy as np
+
 from seaweedfs_tpu.models.coder import (DEFAULT_SCHEME, ErasureCoder,
                                         RSScheme, coder_name_for_scheme,
                                         make_coder, parse_code_spec)
@@ -99,13 +101,24 @@ class Store:
         # what the EC read path read and waited for (shard_stat's
         # "read_stats"; ec_recover_stats keeps its three keys): intervals
         # served by a local pread / rebuilt, the survivor columns a
-        # rebuild read and their bytes, seconds spent in recovery.
+        # rebuild read and their bytes, seconds spent in recovery; the
+        # needle lookups answered from the index in memory and those
+        # that searched the .ecx file (counted by the mounted EcVolumes);
+        # the rebuilt intervals whose survivors were read straight into
+        # the rows of the job's operand (_gather_survivors).
         # Bumped by request threads without a lock, like the tallies
         # above: a lost add under contention is tolerated
         self.ec_read_stats = {"intervals_local": 0,
                               "intervals_recovered": 0,
                               "survivor_reads": 0, "survivor_bytes": 0,
-                              "recover_s": 0.0}
+                              "recover_s": 0.0,
+                              "ecx_lookups": 0, "ecx_file_searches": 0,
+                              "survivor_gathers": 0}
+        for loc in self.locations:
+            loc.ec_read_stats = self.ec_read_stats
+        # the one coefficient row a degraded read of plain RS asks for,
+        # per (scheme, the k shards read, the shard wanted)
+        self._rebuild_rows: dict[tuple, np.ndarray] = {}
 
     def load_existing_volumes(self) -> None:
         for loc in self.locations:
@@ -713,6 +726,10 @@ class Store:
                                          wanted_shard)
             if got is not None:
                 return got
+        elif hasattr(coder, "rebuild_matrix") \
+                and hasattr(coder, "reconstruct_rows"):
+            return self._recover_one_row(ev, iv, shard_off, coder,
+                                         wanted_shard)
         bufs: dict[int, bytes] = {}
         remote_sids: list[int] = []
         with tracing.stage("store.ec.survivors"):
@@ -735,7 +752,7 @@ class Store:
             if len(bufs) < need and remote_sids:
                 self._fetch_remote_shards(ev, iv, shard_off, bufs,
                                           remote_sids, need)
-        self._count_survivors(bufs, iv.size)
+        self._count_survivors(len(bufs), iv.size)
         if len(bufs) < k:
             raise NotFoundError(
                 f"ec volume {ev.volume_id}: only {len(bufs)} shards "
@@ -752,10 +769,90 @@ class Store:
         self.ec_recover_stats["generic"] += 1
         return full[wanted_shard]
 
-    def _count_survivors(self, bufs: dict, size: int) -> None:
+    def _count_survivors(self, n: int, size: int) -> None:
         rs = self.ec_read_stats
-        rs["survivor_reads"] += len(bufs)
-        rs["survivor_bytes"] += len(bufs) * size
+        rs["survivor_reads"] += n
+        rs["survivor_bytes"] += n * size
+
+    def _survivor_sources(self, ev: EcVolume, sids,
+                          want: Optional[int] = None
+                          ) -> tuple[dict, list[int]]:
+        """Of `sids` in order: ({sid: mounted shard}, stopping at `want`
+        of them, [sids that only a peer can serve])."""
+        local: dict = {}
+        remote: list[int] = []
+        for sid in sids:
+            shard = ev.shards.get(sid)
+            if shard is None:
+                remote.append(sid)
+                continue
+            local[sid] = shard
+            if len(local) == want:
+                break
+        return local, remote
+
+    def _gather_survivors(self, ev: EcVolume, sids: list[int],
+                          local: dict, fetched: dict, shard_off: int,
+                          size: int, rows: np.ndarray) -> None:
+        """Fill rows[r, :size] with shard sids[r]'s range: a mounted
+        shard (`local`) by one preadv straight into the row, a fetched
+        one by a copy. Columns past `size` are the caller's (zero, in a
+        job buffer)."""
+        for r, sid in enumerate(sids):
+            got = fetched.get(sid)
+            if got is not None:
+                rows[r, :size] = np.frombuffer(got, dtype=np.uint8)
+            elif local[sid].read_into(shard_off, rows[r, :size]) != size:
+                raise NotFoundError(
+                    f"ec volume {ev.volume_id}: shard {sid} ends inside "
+                    f"[{shard_off}, {shard_off + size})")
+        self._count_survivors(len(sids), size)
+        self.ec_read_stats["survivor_gathers"] += 1
+
+    def _recover_one_row(self, ev: EcVolume, iv: layout.Interval,
+                         shard_off: int, coder: ErasureCoder,
+                         wanted_shard: int) -> bytes:
+        """Plain RS: the first k reachable shards (mounted ones first,
+        peers first-k-wins for the rest) gathered once into the job's
+        operand, and ONE row asked of the coder: the wanted shard's."""
+        k = coder.scheme.data_shards
+        size = iv.size
+        local, remote_sids = self._survivor_sources(
+            ev, (sid for sid in range(coder.scheme.total_shards)
+                 if sid != wanted_shard), want=k)
+        fetched: dict[int, bytes] = {}
+        with tracing.stage("store.ec.survivors"):
+            if len(local) < k and remote_sids \
+                    and self.remote_shard_reader is not None:
+                self._fetch_remote_shards(ev, iv, shard_off, fetched,
+                                          remote_sids, k - len(local))
+            present = sorted([*local, *fetched])[:k]
+            if len(present) < k:
+                self._count_survivors(len(fetched), size)
+                raise NotFoundError(
+                    f"ec volume {ev.volume_id}: only {len(present)} "
+                    f"shards reachable, need {k}")
+            # a scheduler's facade hands out a buffer already on its
+            # ladder's rung (zero past `size`): submit copies nothing
+            job_rows = getattr(coder, "job_rows", None)
+            rows = job_rows(size) if job_rows is not None \
+                else np.empty((k, size), dtype=np.uint8)
+            self._gather_survivors(ev, present, local, fetched, shard_off,
+                                   size, rows)
+        key = (coder.scheme, tuple(present), wanted_shard)
+        mat = self._rebuild_rows.get(key)
+        try:
+            if mat is None:
+                mat = self._rebuild_rows[key] = np.ascontiguousarray(
+                    coder.rebuild_matrix(present, [wanted_shard]),
+                    dtype=np.uint8)
+            rec = coder.reconstruct_rows(rows, mat)
+        except ValueError as e:
+            raise NotFoundError(
+                f"ec volume {ev.volume_id}: {k} shards reachable but "
+                f"pattern unrecoverable: {e}")
+        self.ec_recover_stats["generic"] += 1
+        return rec[0, :size].tobytes()
 
     def _recover_via_plan(self, ev: EcVolume, iv: layout.Interval,
                           shard_off: int, coder: ErasureCoder,
@@ -763,7 +860,6 @@ class Store:
         """Try the coder's cheapest-source repair plan. Returns the
         recovered range, or None when a planned source is unreachable
         (the caller then falls back to the generic collect-k ladder)."""
-        import numpy as np
         total = coder.scheme.total_shards
         try:
             src, mat = coder.plan_rebuild(
@@ -773,26 +869,20 @@ class Store:
             return None
         if src is None:
             return None
-        bufs: dict[int, bytes] = {}
-        remote: list[int] = []
-        with tracing.stage("store.ec.survivors"):
-            for sid in src:
-                local = ev.shards.get(sid)
-                if local is not None:
-                    bufs[sid] = local.read_at(shard_off, iv.size)
-                elif self.remote_shard_reader is not None:
-                    remote.append(sid)
-                else:
-                    return None
-            if remote:
-                self._fetch_remote_shards(ev, iv, shard_off, bufs, remote,
-                                          len(src))
-        self._count_survivors(bufs, iv.size)
-        if len(bufs) != len(src):
+        local, remote = self._survivor_sources(ev, src)
+        if remote and self.remote_shard_reader is None:
             return None
-        rows = np.empty((len(src), iv.size), dtype=np.uint8)
-        for r, sid in enumerate(src):
-            rows[r] = np.frombuffer(bufs[sid], dtype=np.uint8)
+        fetched: dict[int, bytes] = {}
+        with tracing.stage("store.ec.survivors"):
+            if remote:
+                self._fetch_remote_shards(ev, iv, shard_off, fetched,
+                                          remote, len(remote))
+                if len(fetched) != len(remote):
+                    self._count_survivors(len(fetched), iv.size)
+                    return None
+            rows = np.empty((len(src), iv.size), dtype=np.uint8)
+            self._gather_survivors(ev, src, local, fetched, shard_off,
+                                   iv.size, rows)
         strat = "local" if len(src) < coder.scheme.data_shards \
             else "global"
         self.ec_recover_stats[strat] += 1

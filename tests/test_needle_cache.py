@@ -191,13 +191,14 @@ def test_degraded_bit_identity_and_warm_hits(tmp_path):
     store, payloads = _degraded_ec_store(tmp_path)
     store.needle_cache = NeedleCache(capacity_bytes=8 << 20)
     reconstructs = {"n": 0}
-    real = store.coder.reconstruct
+    # a degraded read of plain RS asks the coder for one row
+    real = store.coder.reconstruct_rows
 
-    def counting(shards):
+    def counting(rows, mat):
         reconstructs["n"] += 1
-        return real(shards)
+        return real(rows, mat)
 
-    store.coder.reconstruct = counting
+    store.coder.reconstruct_rows = counting
     for nid, data in payloads.items():
         assert store.read_ec_shard_needle(1, nid).data == data
     cold = reconstructs["n"]
@@ -215,14 +216,14 @@ def test_single_flight_32_concurrent_cold_readers(tmp_path):
     store.needle_cache = NeedleCache(capacity_bytes=8 << 20)
     nid, data = 2, payloads[2]
     decodes = {"n": 0}
-    real = store.coder.reconstruct
+    real = store.coder.reconstruct_rows
 
-    def slow_decode(shards):
+    def slow_decode(rows, mat):
         decodes["n"] += 1
         time.sleep(0.2)  # hold the flight open so waiters pile up
-        return real(shards)
+        return real(rows, mat)
 
-    store.coder.reconstruct = slow_decode
+    store.coder.reconstruct_rows = slow_decode
     start = threading.Barrier(32)
     results, errors = [], []
 
@@ -252,13 +253,14 @@ def test_ec_range_read_caches_reconstruction(tmp_path):
     store, payloads = _degraded_ec_store(tmp_path, n_files=6)
     store.needle_cache = NeedleCache(capacity_bytes=8 << 20)
     reconstructs = {"n": 0}
-    real = store.coder.reconstruct
+    # a degraded read of plain RS asks the coder for one row
+    real = store.coder.reconstruct_rows
 
-    def counting(shards):
+    def counting(rows, mat):
         reconstructs["n"] += 1
-        return real(shards)
+        return real(rows, mat)
 
-    store.coder.reconstruct = counting
+    store.coder.reconstruct_rows = counting
     # find a needle whose range read actually needs recovery
     # (remote_shard_reader is None, so any missing-local interval does)
     for nid, data in payloads.items():
