@@ -16,8 +16,9 @@ its coder dispatches to), load, encode (`ec.encode` through the shell,
 shard files against a CpuCoder reference and the scalar GF tables),
 serve (healthy reads), degrade (three shards gone, reads reconstructed),
 restore (repair queue or `ec.rebuild`, whichever gets there), batcher
-(nothing ran on the CPU behind the curtain), kernels (jax / pallas / mxu
-coders run, bit-identical, compiled not interpreted).
+(nothing ran on the CPU behind the curtain), kernels (the two device
+coders, jax and mesh, run in a child of their own, bit-identical with
+CpuCoder, on the device the server reported).
 
 On success the last line of stdout is exactly
     {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
@@ -58,31 +59,17 @@ KERNELS_CHILD = r"""
 import json, sys
 import numpy as np
 seed, row_bytes = int(sys.argv[1]), int(sys.argv[2])
-from seaweedfs_tpu.models.coder import make_coder
-from seaweedfs_tpu.ops import rs_jax
+from seaweedfs_tpu.models.coder import DEVICE_CODERS, make_coder
 from seaweedfs_tpu.ops.rs_cpu import CpuCoder
 from seaweedfs_tpu.parallel import mesh as mesh_mod
 rng = np.random.default_rng(seed)
 data = rng.integers(0, 256, (10, row_bytes), dtype=np.uint8)
 want = CpuCoder().encode_array(data)
-out = {"device": None, "interpret": None, "identical": {},
-       "tpu_custom_call": {}}
-coders = {}
-for name in ("jax", "pallas", "mxu"):
-    coders[name] = make_coder(name)
-    got = np.asarray(coders[name].encode_array(data))
+out = {"device": None, "identical": {}}
+for name in DEVICE_CODERS:
+    got = np.asarray(make_coder(name).encode_array(data))
     out["identical"][name] = bool(np.array_equal(got, want))
 out["device"] = mesh_mod.device_report()
-out["interpret"] = rs_jax.interpret_mode()
-words = data.view(np.uint32)
-lowered = {
-    "pallas": coders["pallas"]._pallas_parity.lower(
-        *[words[i] for i in range(10)]),
-    "mxu": coders["mxu"]._mxu_parity.lower(
-        *[data[i] for i in range(10)]),
-}
-for name, low in lowered.items():
-    out["tpu_custom_call"][name] = "tpu_custom_call" in low.as_text()
 print("KERNELS " + json.dumps(out), flush=True)
 """
 
@@ -726,12 +713,9 @@ class Smoke:
         log(f"[kernels] 10 x {row_bytes} B rows: " + json.dumps(out))
         if not all(out["identical"].values()):
             raise RuntimeError(f"not bit-identical with CpuCoder: {out}")
-        if on_tpu:
-            if out["device"]["platform"] != "tpu" or out["interpret"] or \
-                    not all(out["tpu_custom_call"].values()):
-                raise RuntimeError(
-                    "the Pallas kernels were not compiled for the TPU "
-                    f"(interpret={out['interpret']}): {out}")
+        if on_tpu and out["device"]["platform"] != "tpu":
+            raise RuntimeError(
+                f"the device coders did not run on the TPU: {out}")
 
     # ---- the run ----
     def run(self) -> int:

@@ -12,6 +12,7 @@ import json
 import sys
 import time
 
+from seaweedfs_tpu.models.coder import DEVICE_CODERS
 from seaweedfs_tpu.utils import clockctl
 
 
@@ -27,8 +28,8 @@ def _add_common_volume_args(p):
     p.add_argument("-rack", default="")
     p.add_argument("-dataCenter", default="")
     p.add_argument("-coder", default="cpu",
-                   choices=["cpu", "jax", "pallas", "mesh"],
-                   help="erasure coder backend (jax/pallas = TPU, "
+                   choices=["cpu", *DEVICE_CODERS],
+                   help="erasure coder backend (jax = the device, "
                         "mesh = multi-device batch)")
     p.add_argument("-ecBatcher", action="store_true",
                    help="coalesce concurrent EC encode/rebuild jobs into "

@@ -4,7 +4,10 @@ This is the interface BASELINE.json asks for: the reference hard-wires
 klauspost/reedsolomon (`reedsolomon.New(10, 4)` at
 reference weed/storage/erasure_coding/ec_encoder.go:199); we instead route
 every encode/reconstruct through an `ErasureCoder` so the CPU path stays the
-default and the TPU (JAX/Pallas) path is selected by configuration.
+default and the device path is selected by configuration. Which coder
+serves a volume of ANOTHER scheme than the one a coder was built for is
+the coder's own answer (`ErasureCoder.for_scheme`): the store, the batch
+scheduler's facade and the repair queue ask, none of them chooses.
 
 Semantics mirror the reference codec's contract:
   - encode(shards): shards is a list of `total` equal-length byte buffers;
@@ -19,6 +22,7 @@ Semantics mirror the reference codec's contract:
 from __future__ import annotations
 
 import abc
+import importlib
 from typing import Optional, Sequence
 
 
@@ -185,20 +189,31 @@ def parse_code_spec(spec: str, default: RSScheme = DEFAULT_SCHEME
         f"unknown code {spec!r}: expected '', 'rs', 'rs-<k>-<m>' or 'lrc'")
 
 
-def coder_name_for_scheme(scheme: RSScheme, fallback: str = "cpu-mt") -> str:
-    """The registry name that matches a scheme's code family; `fallback`
-    names the RS coder to use (its -mt suffix carries over to LRC)."""
-    if isinstance(scheme, LrcScheme):
-        return "lrc-mt" if fallback.endswith("-mt") else "lrc"
-    return fallback
+def host_coder(scheme: RSScheme, threaded: bool) -> "ErasureCoder":
+    """The host coder of a scheme's code family (``cpu`` / ``lrc``), or
+    its multi-threaded sibling (``cpu-mt`` / ``lrc-mt``)."""
+    family = "lrc" if isinstance(scheme, LrcScheme) else "cpu"
+    return make_coder(family + ("-mt" if threaded else ""), scheme)
 
 
 class ErasureCoder(abc.ABC):
-    """Codec over byte buffers. Implementations: CpuCoder (numpy / native C++),
-    JaxCoder (jnp, runs on TPU), PallasCoder (hand-tiled TPU kernel)."""
+    """Codec over byte buffers, of ONE scheme. Implementations: CpuCoder /
+    LrcCoder (numpy / native C++, on the host), JaxCoder and MeshCoder (on
+    the device), BatchCoder (the batch scheduler's facade)."""
 
     def __init__(self, scheme: RSScheme = DEFAULT_SCHEME):
         self.scheme = scheme
+
+    def for_scheme(self, scheme: RSScheme) -> "ErasureCoder":
+        """The coder that serves a volume of `scheme` beside this one:
+        itself for its own scheme, else the multi-threaded host coder of
+        the scheme's family. Overridden where a coder knows better: a
+        host coder keeps its own threading (CpuCoder), the batch
+        scheduler's facade keeps every plain RS geometry on its device
+        queue (BatchCoder)."""
+        if scheme == self.scheme:
+            return self
+        return host_coder(scheme, threaded=True)
 
     @abc.abstractmethod
     def encode(self, shards: Sequence[bytearray | bytes | memoryview]) -> list[bytes]:
@@ -227,6 +242,12 @@ class ErasureCoder(abc.ABC):
         return np.stack([np.frombuffer(full[k + i], dtype=np.uint8)
                          for i in range(self.scheme.parity_shards)])
 
+    def encode_into(self, data, out) -> "np.ndarray":
+        """encode_array into a caller's (m, n) uint8 buffer (the EC
+        pipeline recycles its parity buffers); returns `out`."""
+        out[:] = self.encode_array(data)
+        return out
+
     def reconstruct_arrays(self, present: dict, n: int) -> list:
         """present: {shard_id: (n,) uint8 array}. Returns all `total` shards
         as uint8 arrays (missing ones reconstructed)."""
@@ -247,6 +268,12 @@ class ErasureCoder(abc.ABC):
 
 _REGISTRY: dict[str, type] = {}
 
+# every coder name there is, and the ops/ module that registers it when
+# imported; a DEVICE coder dispatches to what JAX finds
+_CODER_MODULES = {"cpu": "rs_cpu", "cpu-mt": "rs_cpu", "lrc": "lrc",
+                 "lrc-mt": "lrc", "jax": "rs_jax", "mesh": "rs_mesh"}
+DEVICE_CODERS = ("jax", "mesh")
+
 
 def register_coder(name: str):
     def deco(cls):
@@ -256,30 +283,22 @@ def register_coder(name: str):
 
 
 def make_coder(name: str = "cpu", scheme: RSScheme = DEFAULT_SCHEME) -> ErasureCoder:
-    """Factory: 'cpu' (default, like the reference), 'jax', 'pallas',
-    'mxu' (measurement kernel — see ops/rs_mxu.py), 'mesh' (batched
-    multi-device dispatch — see ops/rs_mesh.py), 'lrc' (locally
-    repairable code — see ops/lrc.py)."""
-    # import for registration side effects
-    from seaweedfs_tpu.ops import rs_cpu  # noqa: F401
-    if name in ("lrc", "lrc-mt"):
-        from seaweedfs_tpu.ops import lrc  # noqa: F401
-        if not isinstance(scheme, LrcScheme):
-            scheme = LrcScheme()
-    if name in ("jax", "tpu", "pallas", "mxu", "mesh"):
-        # a device coder: refuse (with the reason) where JAX found only
-        # the CPU and the CPU was not asked for by name, and place the
-        # compile cache before the first jit
+    """Factory over the six registered names: 'cpu' (default, like the
+    reference) and 'cpu-mt' (ops/rs_cpu.py), 'jax' (ops/rs_jax.py), 'mesh'
+    (batched multi-device dispatch, ops/rs_mesh.py), 'lrc' and 'lrc-mt'
+    (locally repairable code, ops/lrc.py)."""
+    if name not in _CODER_MODULES:
+        raise KeyError(
+            f"unknown coder {name!r}; known: {sorted(_CODER_MODULES)}")
+    if _CODER_MODULES[name] == "lrc" and not isinstance(scheme, LrcScheme):
+        scheme = LrcScheme()
+    if name in DEVICE_CODERS:
+        # refuse (with the reason) where JAX found only the CPU and the
+        # CPU was not asked for by name, and place the compile cache
+        # before the first jit
         from seaweedfs_tpu.parallel import mesh as mesh_mod
         mesh_mod.ensure_compile_cache()
         mesh_mod.require_accelerator(f"coder {name!r}")
-        from seaweedfs_tpu.ops import rs_jax  # noqa: F401
-    if name == "pallas":
-        from seaweedfs_tpu.ops import rs_pallas  # noqa: F401
-    if name == "mxu":
-        from seaweedfs_tpu.ops import rs_mxu  # noqa: F401
-    if name == "mesh":
-        from seaweedfs_tpu.ops import rs_mesh  # noqa: F401
-    if name not in _REGISTRY:
-        raise KeyError(f"unknown coder {name!r}; known: {sorted(_REGISTRY)}")
+    # imported for its registration side effect
+    importlib.import_module("seaweedfs_tpu.ops." + _CODER_MODULES[name])
     return _REGISTRY[name](scheme)

@@ -11,8 +11,8 @@ this construction exactly is what makes our .ec shards bit-identical to the
 reference's.
 
 Everything here is plain numpy — it is the ground-truth/reference path. The
-TPU path (ops/rs_jax.py, ops/rs_pallas.py) is validated bit-for-bit against
-this module.
+device path (ops/rs_jax.py's kernels, ops/rs_mesh.py over them) is validated
+bit-for-bit against this module.
 """
 
 from __future__ import annotations

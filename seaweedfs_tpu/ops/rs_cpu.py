@@ -26,7 +26,8 @@ from typing import Optional, Sequence
 import numpy as np
 
 from seaweedfs_tpu.models.coder import (DEFAULT_SCHEME, ErasureCoder,
-                                        RSScheme, register_coder)
+                                        RSScheme, host_coder,
+                                        register_coder)
 from seaweedfs_tpu.ops import gf256
 
 # column-shard boundaries stay multiples of the widest vector stride (the
@@ -208,6 +209,13 @@ class CpuCoder(ErasureCoder):
         self.workers = auto_workers() if workers == "auto" else max(1, workers)
         self._parity = np.asarray(
             gf256.parity_matrix(scheme.data_shards, scheme.parity_shards))
+
+    def for_scheme(self, scheme: RSScheme) -> ErasureCoder:
+        """A host coder answers with its family's host coder, threaded
+        as itself: cpu -> lrc, cpu-mt -> lrc-mt."""
+        if scheme == self.scheme:
+            return self
+        return host_coder(scheme, threaded=self.workers > 1)
 
     def _apply(self, mat: np.ndarray, data: np.ndarray,
                out: Optional[np.ndarray] = None) -> np.ndarray:

@@ -5,7 +5,7 @@ weed/storage/erasure_coding/ec_encoder.go:199) with an XLA program that runs
 on TPU.
 
 Formulation: GF(256) multiplication is linear over GF(2), so the parity
-transform factors into bitplanes. The production kernel uses the Horner
+transform factors into bitplanes. The flat-row kernel uses the Horner
 form over output bits: for each parity row i, first XOR-combine the input
 shards selected by bit b of the matrix constants (S_ib), then fold the 8
 planes with one doubling chain per OUTPUT row:
@@ -18,13 +18,20 @@ everything unrolls into an elementwise XOR/shift graph that XLA fuses into
 one HBM-bound pass — no gather, no table lookup, no data-dependent control
 flow.
 
-Layout matters more than anything else here: shards are passed as SEPARATE
-flat device arrays, not one stacked (k, n) array. A stacked uint32 (10, n)
-operand forces an 8-sublane-padded 2D tiling and measured 4x slower than
-flat rows on v5e (54 vs 193 GB/s of input with parity materialized to
-HBM). A Pallas-tiled variant lives in ops/rs_pallas.py (measured slower
-than this XLA-fused path — see PERF.md); this module is both the
-production kernel and the semantics ground truth.
+Two operand layouts are in the tree. JaxCoder (this module) passes the
+shards as SEPARATE flat device arrays (`parity_fn`, `_apply_matrix_rows`);
+MeshCoder (ops/rs_mesh.py), the coder every benchmark cell serves with,
+stacks a batch into one (B, k, nw) operand (`_apply_matrix_words`) so the
+leading axis can be sharded over the batch mesh. What the ledger says is
+about the stacked operand only: `kernel_roofline.seal` 3.40% for RS(10,4)
+and 10.02% for RS(6,3) on a v5e (PERF_LEDGER.jsonl, PR 28). Flat rows
+against the stacked operand on the chip is an open head-to-head (ROADMAP
+S7); until it is measured both layouts stay.
+
+This module is the home of the kernel primitives: `_xtime` and the two
+static-matrix forms for encode, and `_gf_mul_dynamic`, the traced-
+coefficient step of the rebuild program (`jit_ec_apply_*`). ops/gf256.py
+is the semantics ground truth they are held to.
 """
 
 from __future__ import annotations
@@ -50,6 +57,20 @@ def _xtime(v: jnp.ndarray) -> jnp.ndarray:
     hi = v & _HIGH1
     lo = (v & _LOW7) << 1
     return lo ^ ((hi >> 7) * _RED)
+
+
+def _gf_mul_dynamic(c: jnp.ndarray, words: jnp.ndarray) -> jnp.ndarray:
+    """c * words over GF(256) where c is a TRACED uint32 scalar holding a
+    byte value (same constant applied to all 4 packed lanes)."""
+    acc = jnp.zeros_like(words)
+    d = words
+    for b in range(8):
+        bit = (c >> b) & 1
+        mask = (jnp.uint32(0) - bit.astype(jnp.uint32))  # 0 or 0xffffffff
+        acc = acc ^ (d & mask)
+        if b < 7:
+            d = _xtime(d)
+    return acc
 
 
 def _apply_matrix_words(words: jnp.ndarray, mat: tuple[tuple[int, ...], ...]) -> jnp.ndarray:
@@ -81,8 +102,8 @@ def _apply_matrix_rows(rows: Sequence[jnp.ndarray],
                        mat: tuple[tuple[int, ...], ...]) -> list[jnp.ndarray]:
     """Horner-form transform over separate flat uint32 row arrays.
 
-    Bit-identical to _apply_matrix_words (tested); this is the production
-    formulation — see the module docstring for why.
+    Bit-identical to _apply_matrix_words (tested); JaxCoder's
+    formulation (module docstring: the two layouts).
     """
     m, k = len(mat), len(mat[0])
     assert len(rows) == k
@@ -117,50 +138,11 @@ def _mat_to_tuple(mat: np.ndarray) -> tuple[tuple[int, ...], ...]:
     return tuple(tuple(int(x) for x in row) for row in np.asarray(mat))
 
 
-def interpret_mode() -> bool:
-    """Whether Pallas kernels run the interpreter (shared by rs_pallas /
-    rs_mxu): yes on the CPU backend, where the test mesh validates
-    bit-identity; no on the TPU, where they compile.  Any other backend
-    is an error — a Mosaic-TPU kernel has no third place to run."""
-    backend = jax.default_backend()
-    if backend == "cpu":
-        return True
-    if backend == "tpu":
-        return False
-    raise RuntimeError(
-        f"Pallas TPU kernels cannot run on the {backend!r} backend")
-
-
-def pad_rows_to_multiple(rows: np.ndarray, tile: int
-                         ) -> tuple[np.ndarray, int]:
-    """Zero-pad the last axis of a (k, n) array up to a multiple of
-    `tile`; returns (padded, original_n)."""
-    n = rows.shape[1]
-    pad = (-n) % tile
-    if pad:
-        rows = np.concatenate(
-            [rows, np.zeros((rows.shape[0], pad), dtype=rows.dtype)],
-            axis=1)
-    return rows, n
-
-
 def parity_fn(scheme: RSScheme = DEFAULT_SCHEME):
-    """The jitted parity kernel: k flat uint32 rows -> tuple of m rows.
-    Flat separate rows are the fast device layout (module docstring)."""
+    """The jitted parity kernel: k flat uint32 rows -> tuple of m rows
+    (the flat layout of the module docstring)."""
     pm = gf256.parity_matrix(scheme.data_shards, scheme.parity_shards)
     return _encode_fn(_mat_to_tuple(pm))
-
-
-@functools.lru_cache(maxsize=None)
-def parity_words_fn(scheme: RSScheme = DEFAULT_SCHEME):
-    """2D variant for vmap/mesh composition: (k, nw) uint32 -> (m, nw)."""
-    pm = _mat_to_tuple(
-        gf256.parity_matrix(scheme.data_shards, scheme.parity_shards))
-
-    @jax.jit
-    def f(words):
-        return _apply_matrix_words(words, pm)
-    return f
 
 
 def decode_fn(scheme: RSScheme, present: tuple[int, ...]):
@@ -205,8 +187,8 @@ class JaxCoder(ErasureCoder):
 
     def _run_rows(self, fn, words: np.ndarray) -> np.ndarray:
         """Apply a row-based jitted kernel to a (k, nw) uint32 host matrix,
-        feeding each row as its own flat device array (see module
-        docstring for why), and restack on the host."""
+        feeding each row as its own flat device array (module
+        docstring), and restack on the host."""
         outs = fn(*[words[i] for i in range(words.shape[0])])
         return np.stack([np.asarray(jax.device_get(o)) for o in outs])
 
@@ -261,7 +243,3 @@ class JaxCoder(ErasureCoder):
         for i in range(k):
             out[i] = rows[i]
         return out
-
-
-# `pallas` name resolves here too until ops/rs_pallas.py specializes it.
-register_coder("tpu")(JaxCoder)

@@ -44,9 +44,9 @@ from seaweedfs_tpu.models.coder import (DEFAULT_SCHEME, ErasureCoder,
                                         RSScheme, code_spec_name,
                                         register_coder)
 from seaweedfs_tpu.ops import gf256
-from seaweedfs_tpu.ops.rs_jax import _apply_matrix_words, _mat_to_tuple
+from seaweedfs_tpu.ops.rs_jax import (_apply_matrix_words, _gf_mul_dynamic,
+                                      _mat_to_tuple)
 from seaweedfs_tpu.parallel import mesh as mesh_mod
-from seaweedfs_tpu.parallel.distributed import _gf_mul_dynamic
 from seaweedfs_tpu.utils import tracing
 
 # a dispatch's four stages on the host, in order (ec.mesh.<stage>)
@@ -237,10 +237,6 @@ class MeshCoder(ErasureCoder):
         assert data.shape[1] % 4 == 0
         return self.encode_batch(
             np.ascontiguousarray(data, dtype=np.uint8)[None])[0]
-
-    def encode_into(self, data: np.ndarray, out: np.ndarray) -> np.ndarray:
-        out[:] = self.encode_array(data)
-        return out
 
     def encode(self, shards: Sequence[bytes]) -> list[bytes]:
         k = self.scheme.data_shards

@@ -723,24 +723,26 @@ class BatchCoder(ErasureCoder):
             scheme = scheduler.scheme
         super().__init__(scheme)
         self.scheduler = scheduler
-        if type(scheme) is RSScheme:
-            from seaweedfs_tpu.ops.rs_cpu import CpuCoder
-            self._host = CpuCoder(scheme)  # matrix derivation only
-            self._encode_mat = None  # the geometry's static parity program
-            self._geometry = scheme
-        else:
-            from seaweedfs_tpu.models.coder import (coder_name_for_scheme,
-                                                    make_coder)
-            self._host = make_coder(coder_name_for_scheme(scheme, "cpu"),
-                                    scheme)
-            self._encode_mat = np.ascontiguousarray(self._host._parity)
-            self._geometry = RSScheme(scheme.data_shards,
-                                      scheme.parity_shards)
+        from seaweedfs_tpu.ops.rs_cpu import CpuCoder
+        # the RS geometry whose queue and programs the jobs ride, and the
+        # scheme's own host coder, for matrix derivation only
+        self._geometry = RSScheme(scheme.data_shards, scheme.parity_shards)
+        self._host = CpuCoder(self._geometry).for_scheme(scheme)
+        # None: the geometry's static parity program encodes; another
+        # family's jobs carry their parity matrix to the apply program
+        self._encode_mat = None if scheme == self._geometry \
+            else np.ascontiguousarray(self._host._parity)
 
-    def for_scheme(self, scheme: RSScheme) -> "BatchCoder":
-        """A facade of another scheme over the SAME scheduler (the store
-        asks for one per volume geometry it meets)."""
-        return BatchCoder(self.scheduler, scheme)
+    def for_scheme(self, scheme: RSScheme) -> ErasureCoder:
+        """A facade over the SAME scheduler for a plain RS scheme (every
+        RS geometry submits to the one device queue); for another code
+        family (LRC) the family's host coder: its seals stay off the
+        device."""
+        if scheme == self.scheme:
+            return self
+        if type(scheme) is RSScheme:
+            return BatchCoder(self.scheduler, scheme)
+        return super().for_scheme(scheme)
 
     def device_report(self) -> Optional[dict]:
         return self.scheduler.device

@@ -14,7 +14,9 @@ buys four things the scattered call sites could not:
     require) which hardware the EC math ran on;
   - one :func:`ensure_compile_cache` that places JAX's persistent
     compilation cache before the first ``jit`` of a process;
-  - mesh constructors that agree on axis vocabulary.
+  - the one mesh: :func:`batch_mesh`, 1-D over the 'batch' axis — the
+    cross-volume job axis the MeshCoder / batch scheduler shard over
+    (one block-group of work per lane, no collectives).
 
 It is also where the program meets ``jax.profiler`` and
 ``jax.monitoring``, for the same reason (utils/tracing.py and
@@ -22,22 +24,12 @@ utils/httpd.py never import jax): :func:`install_tracing` hands
 ``TraceAnnotation`` to ``tracing.set_annotator`` and starts the process's
 one :class:`CompileWatch`; :func:`device_trace` is the one exporter of a
 device trace (``POST /admin/ec/trace``).
-
-Axis vocabulary (the storage-system analogue of dp/tp/sp, SURVEY.md §5.7):
-  - 'data'  : batch of independent volumes (data parallel)
-  - 'shard' : the 14 RS shards of one volume (tensor/model parallel — the
-              dimension collectives run over during degraded rebuild)
-  - 'seq'   : position along the stripe (sequence parallel — EC columns are
-              independent, so this axis never needs a collective on encode)
-  - 'batch' : the 1-D cross-volume job axis the MeshCoder/batch scheduler
-              shard over (one block-group of work per lane)
 """
 
 from __future__ import annotations
 
 import contextlib
 import glob
-import math
 import os
 import threading
 from typing import Optional
@@ -94,7 +86,7 @@ def cpu_requested() -> bool:
 
 def require_accelerator(what: str) -> dict:
     """Start-up gate for anything that was asked to run EC math on the
-    device (``-ecBatcher``, ``-coder jax|pallas|mesh``): returns the
+    device (``-ecBatcher``, ``-coder jax|mesh``): returns the
     :func:`device_report` when the backend is an accelerator, or the
     CPU asked for by name; raises RuntimeError with the reason
     otherwise, so the server fails at start-up instead of serving from
@@ -274,50 +266,14 @@ def probe(force: bool = False) -> dict:
     return dict(out)
 
 
-def make_mesh(n_devices: int | None = None,
-              axis_names: tuple[str, ...] = ("data", "shard", "seq"),
-              shape: tuple[int, ...] | None = None) -> Mesh:
-    devs = devices(n_devices)
-    n = len(devs)
-    if shape is None:
-        shape = _default_shape(n, len(axis_names))
-    assert math.prod(shape) == n, (shape, n)
-    arr = np.array(devs).reshape(shape)
-    return Mesh(arr, axis_names)
-
-
 def batch_mesh(n_devices: int | None = None) -> Mesh:
     """1-D mesh over the cross-volume 'batch' axis — the MeshCoder /
     batch-scheduler topology: independent block-groups of work, one
     slice per device, no collectives."""
-    return make_mesh(n_devices, axis_names=("batch",))
+    return Mesh(np.array(devices(n_devices)), ("batch",))
 
 
 def batch_spec(mesh: Mesh, rank: int = 3) -> NamedSharding:
     """NamedSharding splitting the leading (batch) axis of a rank-N
     operand across a batch_mesh."""
     return NamedSharding(mesh, P("batch", *([None] * (rank - 1))))
-
-
-def _default_shape(n: int, naxes: int) -> tuple[int, ...]:
-    """Factor n into naxes dims, biasing size toward the trailing ('seq')
-    axis, then 'shard', keeping 'data' smallest."""
-    dims = [1] * naxes
-    i = naxes - 1
-    while n > 1:
-        # peel smallest prime factor
-        f = 2
-        while n % f:
-            f += 1
-        dims[i] *= f
-        n //= f
-        i = (i - 1) if i > 0 else naxes - 1
-    return tuple(dims)
-
-
-def replicated(mesh: Mesh) -> NamedSharding:
-    return NamedSharding(mesh, P())
-
-
-def spec(mesh: Mesh, *axes) -> NamedSharding:
-    return NamedSharding(mesh, P(*axes))

@@ -1,13 +1,13 @@
 """Staged EC pipelines: overlapped read -> code -> write for whole volumes.
 
-BASELINE.json configs 2 and 4: a 30GB volume cannot sit in a v5e's 16GB
-HBM, so ec.encode streams column-aligned batches disk -> host -> HBM with
-reader threads prefetching batch N+1 while the coder works on batch N and
-a writer thread drains batch N-1 to the shard files. The same three-stage
-shape serves the CPU coder (whose native kernel releases the GIL, so the
-reader/writer threads genuinely overlap the GF compute) and the JAX coder
-(whose async dispatch overlaps host->device transfer with device compute;
-the writer's np.asarray() is the synchronization point).
+A 30GB volume is walked in column-aligned batches: reader threads
+prefetch batch N+1 from the .dat while the caller's thread has the coder
+on batch N and a writer thread drains batch N-1 to the shard files. The
+pipeline knows files and the ``ErasureCoder`` seam, nothing of devices:
+the same three stages serve a host coder (whose native kernel releases
+the GIL, so the reader/writer threads genuinely overlap the GF compute)
+and the batch scheduler's facade (``BatchCoder``: the call into the coder
+is a job's submit / wait / dispatch / result on the device queue).
 
 Stage plumbing invariants:
   - every inter-stage queue is BOUNDED (maxsize=prefetch): a slow writer
@@ -26,9 +26,6 @@ Each run is one ``ec.pipeline`` stage (utils/tracing.stage) with an
 ``ec.pipeline.read`` / ``.encode`` / ``.write`` stage per batch in the
 thread that does it and ``ec.pipeline.commit`` at the end; the same busy
 seconds fill the caller's ``stats`` dict.
-
-The batched API at the bottom encodes many volumes concurrently by
-stacking them on a leading axis the device iterates with one program.
 """
 
 from __future__ import annotations
@@ -40,7 +37,7 @@ from typing import Optional, Sequence
 
 import numpy as np
 
-from seaweedfs_tpu.models.coder import DEFAULT_SCHEME, ErasureCoder, RSScheme
+from seaweedfs_tpu.models.coder import ErasureCoder
 from seaweedfs_tpu.storage.erasure_coding import layout
 from seaweedfs_tpu.utils import clockctl, tracing
 
@@ -196,24 +193,21 @@ def _read_rows(f, buf: np.ndarray, desc, k: int) -> None:
 
 
 def pipelined_encode_file(base_file_name: str,
-                          scheme: RSScheme = DEFAULT_SCHEME,
+                          coder: ErasureCoder,
                           large_block: int = layout.LARGE_BLOCK_SIZE,
                           small_block: int = layout.SMALL_BLOCK_SIZE,
                           batch_size: int = DEFAULT_PIPE_BATCH,
                           prefetch: int = 2,
-                          coder: Optional[ErasureCoder] = None,
                           readers: int = 1,
                           stats: Optional[dict] = None) -> None:
     """write_ec_files as a staged pipeline; identical on-disk output.
 
-    coder=None keeps the original behaviour (the JAX parity kernel);
-    passing an ErasureCoder (typically CpuCoder / CpuCoderMT) runs its
-    encode on the main thread between the reader and writer stages.
-    `stats`, when a dict, receives per-stage busy seconds (read_s /
-    encode_s / write_s), wall_s, bytes_in and batches — the numbers
-    tools/ec_profile.py prints."""
-    if coder is not None:
-        scheme = coder.scheme
+    The coder's encode runs on the calling thread between the reader
+    and writer stages. `stats`, when a dict, receives per-stage busy
+    seconds (read_s / encode_s / write_s / commit_s), wall_s, bytes_in
+    and batches — the ``pipeline`` object of ``/admin/ec/generate``'s
+    reply."""
+    scheme = coder.scheme
     k = scheme.data_shards
     total = scheme.total_shards
     m = total - k
@@ -222,11 +216,6 @@ def pipelined_encode_file(base_file_name: str,
     descs = list(layout.iter_encode_batches(dat_size, large_block,
                                             small_block, batch_size, k))
     readers = max(1, min(readers, len(descs) or 1))
-
-    fn = None
-    if coder is None:
-        from seaweedfs_tpu.ops.rs_jax import parity_fn
-        fn = parity_fn(scheme)  # fn(*rows) -> tuple of parity rows
 
     pl = _Pipeline()
     read_q: "queue.Queue" = queue.Queue(maxsize=prefetch)
@@ -255,19 +244,13 @@ def pipelined_encode_file(base_file_name: str,
                 break
             data, parity = item
             with tracing.stage("ec.pipeline.write") as st:
-                if fn is not None:
-                    # materialize BEFORE recycling: on the CPU jax backend
-                    # device_put may alias the host buffer, so the data
-                    # array must stay untouched until the parity is out
-                    parity = [np.asarray(p).view(np.uint8) for p in parity]
                 for i in range(k):
                     outs.files[i].write(data[i])
                 for r in range(m):
                     outs.files[k + r].write(parity[r])
             busy += st.elapsed
             data_pool.put(data)
-            if isinstance(parity, np.ndarray):
-                parity_pool.put(parity)
+            parity_pool.put(parity)
         _merge_stats(stats, slock, write_s=busy)
 
     outs = AtomicFileGroup([base_file_name + layout.shard_ext(i)
@@ -288,17 +271,8 @@ def pipelined_encode_file(base_file_name: str,
             # the call into the coder: under the batch scheduler it
             # CONTAINS the job's submit / wait / dispatch / result
             with tracing.stage("ec.pipeline.encode") as st:
-                if fn is not None:
-                    words = data.view(np.uint32)
-                    import jax
-                    rows = [jax.device_put(words[i]) for i in range(k)]
-                    parity = fn(*rows)  # async; the writer synchronizes
-                else:
-                    pbuf = parity_pool.get((m, data.shape[1]))
-                    if hasattr(coder, "encode_into"):
-                        parity = coder.encode_into(data, pbuf)
-                    else:
-                        parity = np.asarray(coder.encode_array(data))
+                parity = coder.encode_into(
+                    data, parity_pool.get((m, data.shape[1])))
             encode_busy += st.elapsed
             pl.put(write_q, (data, parity))
         pl.put(write_q, None)
@@ -451,25 +425,3 @@ def pipelined_rebuild_files(base_file_name: str,
     finally:
         tracing.stage_end(whole)
     return missing
-
-
-def batch_encode_volumes(data_batch: np.ndarray,
-                         scheme: RSScheme = DEFAULT_SCHEME,
-                         mesh=None) -> np.ndarray:
-    """Encode B volumes' column batches at once: (B, k, n) uint8 ->
-    (B, m, n) parity. With a mesh, shards over ('data', 'seq'); without,
-    vmaps on one chip (config 4: saturate HBM with 64 concurrent
-    volumes)."""
-    import jax
-
-    from seaweedfs_tpu.ops.rs_jax import parity_words_fn
-
-    B, k, n = data_batch.shape
-    assert k == scheme.data_shards and n % 4 == 0
-    if mesh is not None:
-        from seaweedfs_tpu.parallel.distributed import distributed_encode
-        return distributed_encode(scheme, mesh, data_batch)
-    words = np.ascontiguousarray(data_batch).view(np.uint32)
-    fn = jax.jit(jax.vmap(parity_words_fn(scheme)))
-    out = np.asarray(jax.device_get(fn(words)))
-    return out.view(np.uint8)

@@ -509,12 +509,11 @@ class RepairQueue:
         fanning the reduction chain across k holders. Returns
         (source_sids | None, strategy); None = use every survivor."""
         try:
-            from seaweedfs_tpu.models.coder import (coder_name_for_scheme,
-                                                    make_coder,
+            from seaweedfs_tpu.models.coder import (make_coder,
                                                     scheme_from_dict)
             spec = self._shard_stat(vid, collection, rebuilder_url)
             scheme = scheme_from_dict(spec.get("code"))
-            coder = make_coder(coder_name_for_scheme(scheme), scheme)
+            coder = make_coder("cpu").for_scheme(scheme)
             if not hasattr(coder, "plan_rebuild"):
                 return None, "global"
             src, _mat = coder.plan_rebuild(sorted(present), sorted(missing))

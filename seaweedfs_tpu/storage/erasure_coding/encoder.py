@@ -98,8 +98,8 @@ def write_ec_files(base_file_name: str, coder: Optional[ErasureCoder] = None,
     if pipelined:
         from seaweedfs_tpu.parallel import streaming
         streaming.pipelined_encode_file(
-            base_file_name, coder.scheme, large_block, small_block,
-            batch_size, coder=coder, readers=readers, stats=stats)
+            base_file_name, coder, large_block, small_block,
+            batch_size, readers=readers, stats=stats)
         return
     from seaweedfs_tpu.parallel.streaming import AtomicFileGroup
     k = coder.scheme.data_shards

@@ -16,7 +16,6 @@ from typing import Callable, Optional
 import numpy as np
 
 from seaweedfs_tpu.models.coder import (DEFAULT_SCHEME, ErasureCoder,
-                                        RSScheme, coder_name_for_scheme,
                                         make_coder, parse_code_spec)
 from seaweedfs_tpu.storage import types as t
 from seaweedfs_tpu.storage.disk_location import DiskLocation
@@ -401,19 +400,14 @@ class Store:
         return self.coder_for_scheme(getattr(ev, "scheme", None))
 
     def coder_for_scheme(self, scheme) -> ErasureCoder:
-        if scheme is None or scheme == self.coder.scheme:
+        """One coder per scheme among the store's volumes: the store's
+        own coder says which (ErasureCoder.for_scheme), the cache keeps
+        it the same object from then on."""
+        if scheme is None:
             return self.coder
         c = self._coder_cache.get(scheme)
         if c is None:
-            sibling = getattr(self.coder, "for_scheme", None)
-            if sibling is not None and type(scheme) is RSScheme:
-                # the store's coder is the batch scheduler's facade:
-                # every RS geometry submits to the one device queue
-                c = sibling(scheme)
-            else:
-                # another code family (LRC), or a store on a host coder
-                c = make_coder(coder_name_for_scheme(scheme), scheme)
-            self._coder_cache[scheme] = c
+            c = self._coder_cache[scheme] = self.coder.for_scheme(scheme)
         return c
 
     def generate_ec_shards(self, vid: int, pipelined: bool = True,

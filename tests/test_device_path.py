@@ -29,7 +29,7 @@ def test_device_coder_refuses_an_unasked_for_cpu(monkeypatch):
     monkeypatch.setattr(mesh_mod, "cpu_requested", lambda: False)
     with pytest.raises(RuntimeError, match="only the CPU backend"):
         EcBatchScheduler()
-    for name in ("jax", "pallas", "mesh"):
+    for name in ("jax", "mesh"):
         with pytest.raises(RuntimeError, match="JAX_PLATFORMS=cpu"):
             make_coder(name)
     make_coder("cpu")  # a host coder is nobody's business
@@ -78,17 +78,6 @@ def test_volume_server_status_carries_the_coder_device(tmp_path):
         host.stop()
         vs.stop()
         master.stop()
-
-
-def test_interpret_mode_is_decided_by_name(monkeypatch):
-    from seaweedfs_tpu.ops import rs_jax
-    for backend, want in (("cpu", True), ("tpu", False)):
-        monkeypatch.setattr(rs_jax.jax, "default_backend",
-                            lambda b=backend: b)
-        assert rs_jax.interpret_mode() is want
-    monkeypatch.setattr(rs_jax.jax, "default_backend", lambda: "gpu")
-    with pytest.raises(RuntimeError, match="gpu"):
-        rs_jax.interpret_mode()
 
 
 # ------------------------------------------------- compile-cache placement

@@ -72,31 +72,45 @@ def test_mesh_coder_registered():
     assert isinstance(make_coder("mesh"), MeshCoder)
 
 
-def test_encode_batch_bit_identical_odd_batch():
-    """B=5 on 8 devices exercises the zero-pad lanes."""
-    mc = MeshCoder(DEFAULT_SCHEME)
-    data = _batch(5, 4096)
+MIB = 1 << 20
+
+
+@pytest.mark.parametrize("n_devices,B,n", [
+    (nd, B, MIB) for nd in (1, 2, 4, 8) for B in (1, 3)] + [(8, 5, 4096)])
+def test_encode_batch_bit_identical_odd_batch(n_devices, B, n):
+    """A batch smaller than, or not a multiple of, the device count is
+    zero-padded up to it (B=1 and 3 on 2, 4, 8 devices; B=3 on one is
+    padded to the next power of two): the pad lanes never leak."""
+    mc = MeshCoder(DEFAULT_SCHEME, n_devices=n_devices)
+    data = _batch(B, n)
     out = mc.encode_batch(data)
-    assert out.shape == (5, M, 4096)
-    for i in range(5):
+    assert out.shape == (B, M, n)
+    for i in range(B):
         assert np.array_equal(out[i], CPU.encode_array(data[i]))
+    (spread,) = mc.output_spread       # one dispatch, on every device
+    assert spread == n_devices
 
 
-def test_rebuild_batch_heterogeneous_loss_one_dispatch():
-    """Jobs with DIFFERENT survivor patterns (data-only, parity-only,
-    mixed, single-shard) ride one traced-coefficient dispatch."""
-    mc = MeshCoder(DEFAULT_SCHEME)
-    losses = [(0, 3, 7, 9), (10, 11, 12, 13), (0, 5, 11, 13), (2,), (12,)]
-    data = _batch(len(losses), 2048, seed=1)
+@pytest.mark.parametrize("n_devices", [1, 8])
+@pytest.mark.parametrize("drop", [(0, 3, 7, 9), (10, 11, 12, 13),
+                                  (0, 5, 11, 13), (9,)])
+def test_rebuild_batch_heterogeneous_loss_one_dispatch(drop, n_devices):
+    """Jobs with DIFFERENT survivor patterns (the case's own — data-only,
+    parity-only, mixed, single-shard — beside a lost data shard and a
+    lost parity shard) ride one traced-coefficient dispatch, at 1 MiB a
+    shard."""
+    mc = MeshCoder(DEFAULT_SCHEME, n_devices=n_devices)
+    losses = [drop, (2,), (12,)]
+    data = _batch(len(losses), MIB, seed=1)
     srcs, mats, want = [], [], []
-    for i, drop in enumerate(losses):
-        shards = CPU.encode([data[i, j].tobytes() for j in range(K)])
-        full = [np.frombuffer(s, dtype=np.uint8) for s in shards]
-        present = [j for j in range(TOTAL) if j not in drop]
-        srcs.append(np.stack([full[j] for j in sorted(present)[:K]]))
-        mats.append(CPU.rebuild_matrix(present, list(drop)))
-        want.append(np.stack([full[j] for j in drop]))
+    for i, lost in enumerate(losses):
+        full = np.concatenate([data[i], CPU.encode_array(data[i])])
+        present = [j for j in range(TOTAL) if j not in lost]
+        srcs.append(full[present[:K]])
+        mats.append(CPU.rebuild_matrix(present, list(lost)))
+        want.append(full[list(lost)])
     recs = mc.rebuild_batch(np.stack(srcs), mats)
+    assert sum(mc.output_spread.values()) == 1
     for rec, expect in zip(recs, want):
         assert np.array_equal(rec, expect)
 

@@ -116,8 +116,9 @@ def test_gb_volume_ec_lifecycle(tmp_path):
     assert dat_size >= SIZE
 
     # encode (streaming pipeline — the production path) + sorted index
+    from seaweedfs_tpu.models.coder import make_coder
     from seaweedfs_tpu.parallel import streaming
-    streaming.pipelined_encode_file(base)
+    streaming.pipelined_encode_file(base, make_coder("cpu"))
     encoder.write_sorted_ecx(base)
     shard_size = os.path.getsize(base + layout.shard_ext(0))
     # multi-row small-block layout actually exercised
@@ -130,7 +131,6 @@ def test_gb_volume_ec_lifecycle(tmp_path):
     # streaming device path). Spot-check the first 64MB of each shard row
     # to keep runtime sane.
     import numpy as _np
-    from seaweedfs_tpu.models.coder import make_coder
     cpu = make_coder("cpu")
     span = min(64 << 20, layout.SMALL_BLOCK_SIZE)
     with open(base + ".dat", "rb") as f:

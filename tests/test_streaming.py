@@ -1,20 +1,20 @@
-"""Streaming/batched EC pipeline correctness vs the reference layout."""
-
-import os
+"""Streaming EC pipeline correctness vs the reference layout."""
 
 import numpy as np
 import pytest
 
-from seaweedfs_tpu.models.coder import RSScheme, make_coder
-from seaweedfs_tpu.parallel.streaming import (batch_encode_volumes,
-                                              pipelined_encode_file)
+from seaweedfs_tpu.models.coder import make_coder
+from seaweedfs_tpu.parallel.streaming import pipelined_encode_file
 from seaweedfs_tpu.storage.erasure_coding import encoder as ecenc
 from seaweedfs_tpu.storage.erasure_coding import layout
 
 LB, SB = 640, 160
 
 
-def test_pipelined_encode_matches_reference_layout(tmp_path):
+@pytest.mark.parametrize("coder_name", ["cpu", "jax", "mesh"])
+def test_pipelined_encode_matches_reference_layout(tmp_path, coder_name):
+    """The pipeline against the serial walk, with a host coder and with
+    each device coder in the coder seat."""
     rng = np.random.default_rng(0)
     dat = rng.integers(0, 256, 2 * LB * 10 + 3 * SB * 10 + 77,
                        dtype=np.uint8).tobytes()
@@ -24,20 +24,11 @@ def test_pipelined_encode_matches_reference_layout(tmp_path):
 
     ecenc.write_ec_files(str(tmp_path / "a"), make_coder("cpu"), LB, SB,
                          batch_size=SB)
-    pipelined_encode_file(str(tmp_path / "b"), RSScheme(10, 4), LB, SB,
-                          batch_size=SB)
+    pipelined_encode_file(str(tmp_path / "b"), make_coder(coder_name),
+                          LB, SB, batch_size=SB)
     for i in range(14):
         with open(tmp_path / ("a" + layout.shard_ext(i)), "rb") as f:
             want = f.read()
         with open(tmp_path / ("b" + layout.shard_ext(i)), "rb") as f:
             got = f.read()
         assert got == want, f"shard {i} differs"
-
-
-def test_batch_encode_volumes_matches_cpu():
-    rng = np.random.default_rng(1)
-    batch = rng.integers(0, 256, (6, 10, 2048), dtype=np.uint8)
-    parity = batch_encode_volumes(batch)
-    cpu = make_coder("cpu")
-    for b in range(6):
-        assert np.array_equal(parity[b], cpu.encode_array(batch[b]))

@@ -8,9 +8,7 @@ These tests hand it the main path's programs at their REAL widths:
   - the batch scheduler's two mesh programs (ops/rs_mesh) at every
     (B, n) bucket the bounded shape ladder of parallel/batcher.py can
     produce on one device, and on a 4-device batch mesh (no collective
-    may appear: the batch axis is embarrassingly parallel);
-  - the Pallas and MXU kernels with interpret=False (a tpu_custom_call
-    must be in the lowered program).
+    may appear: the batch axis is embarrassingly parallel).
 
 A compile that passes is not a chip run — nothing executes, no time
 means anything — but what the chip's compiler refuses, it refuses here.
@@ -35,7 +33,7 @@ from jax.sharding import (Mesh, NamedSharding,  # noqa: E402
                           PartitionSpec as P, SingleDeviceSharding)
 
 from seaweedfs_tpu.models.coder import DEFAULT_SCHEME, RSScheme  # noqa: E402
-from seaweedfs_tpu.ops import gf256, rs_jax, rs_mesh  # noqa: E402
+from seaweedfs_tpu.ops import rs_jax, rs_mesh  # noqa: E402
 from seaweedfs_tpu.parallel.batcher import shape_buckets  # noqa: E402
 
 K = DEFAULT_SCHEME.data_shards
@@ -159,37 +157,3 @@ def test_rs6_3_programs_compile_under_their_own_names(topo, kind, n):
     (out_s,) = jax.tree_util.tree_leaves(
         lowered.compile().output_shardings)
     assert out_s.shard_shape((1, 3, n // 4)) == (1, 3, n // 4)
-
-
-def _parity_tuple():
-    return rs_jax._mat_to_tuple(gf256.parity_matrix(K, M))
-
-
-def test_pallas_kernel_compiles_not_interpreted(one_chip, monkeypatch):
-    from seaweedfs_tpu.ops import rs_pallas
-    # the process is on the CPU backend, where interpret_mode() says
-    # "interpret"; steer it here, in the test, to what the chip gets
-    monkeypatch.setattr(rs_pallas, "interpret_mode", lambda: False)
-    rs_pallas.pallas_apply_fn.cache_clear()
-    try:
-        fn = rs_pallas.pallas_apply_fn(_parity_tuple())
-        rows = [jax.ShapeDtypeStruct((MIB // 4,), jnp.uint32,
-                                     sharding=one_chip)] * K
-        compiled = fn.lower(*rows).compile()
-        assert "tpu_custom_call" in compiled.as_text()
-    finally:
-        rs_pallas.pallas_apply_fn.cache_clear()
-
-
-def test_mxu_kernel_compiles_not_interpreted(one_chip, monkeypatch):
-    from seaweedfs_tpu.ops import rs_mxu
-    monkeypatch.setattr(rs_mxu, "interpret_mode", lambda: False)
-    rs_mxu.mxu_apply_fn.cache_clear()
-    try:
-        fn = rs_mxu.mxu_apply_fn(_parity_tuple())
-        rows = [jax.ShapeDtypeStruct((MIB,), jnp.uint8,
-                                     sharding=one_chip)] * K
-        compiled = fn.lower(*rows).compile()
-        assert "tpu_custom_call" in compiled.as_text()
-    finally:
-        rs_mxu.mxu_apply_fn.cache_clear()
