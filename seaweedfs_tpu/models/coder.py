@@ -196,6 +196,24 @@ def host_coder(scheme: RSScheme, threaded: bool) -> "ErasureCoder":
     return make_coder(family + ("-mt" if threaded else ""), scheme)
 
 
+class Encoded:
+    """What ``ErasureCoder.encode_begin`` hands back: ``result()`` is the
+    caller's ``out``, filled; ``done()`` says whether it is filled
+    already.  This one is: the result of a coder that does its work in
+    the begin."""
+
+    __slots__ = ("_out",)
+
+    def __init__(self, out):
+        self._out = out
+
+    def done(self) -> bool:
+        return True
+
+    def result(self):
+        return self._out
+
+
 class ErasureCoder(abc.ABC):
     """Codec over byte buffers, of ONE scheme. Implementations: CpuCoder /
     LrcCoder (numpy / native C++, on the host), JaxCoder and MeshCoder (on
@@ -247,6 +265,15 @@ class ErasureCoder(abc.ABC):
         pipeline recycles its parity buffers); returns `out`."""
         out[:] = self.encode_array(data)
         return out
+
+    def encode_begin(self, data, out) -> Encoded:
+        """encode_into in two steps: begin a batch, ask for ``out``
+        filled later (``.result()``), and meanwhile begin the next.  A
+        coder that works on the caller's thread has done it all when
+        this returns; one that hands the batch to another thread (the
+        batch scheduler's facade) returns while it is in flight, and the
+        EC pipeline keeps a second batch in the coder behind it."""
+        return Encoded(self.encode_into(data, out))
 
     def reconstruct_arrays(self, present: dict, n: int) -> list:
         """present: {shard_id: (n,) uint8 array}. Returns all `total` shards

@@ -148,7 +148,7 @@ def _rank(cls: Optional[str]) -> int:
 
 class _Job:
     __slots__ = ("scheme", "kind", "data", "mat", "n", "cls", "span",
-                 "submitted", "future")
+                 "submitted", "future", "taken")
 
     def __init__(self, scheme: RSScheme, kind: str, data: np.ndarray,
                  mat: Optional[np.ndarray], n: int, cls: Optional[str],
@@ -164,6 +164,10 @@ class _Job:
         self.span = span
         self.submitted = submitted
         self.future: Future = Future()
+        # set once the job has LEFT the queue (the dispatcher holds it,
+        # or stop() drained it): nothing submitted from then on can
+        # share its dispatch (BatchCoder.encode_begin)
+        self.taken = threading.Event()
 
 
 class _Geometry:
@@ -310,7 +314,7 @@ class EcBatchScheduler:
 
     def _submit(self, kind: str, data: np.ndarray,
                 mat: Optional[np.ndarray], cls: Optional[str],
-                scheme: Optional[RSScheme]) -> Future:
+                scheme: Optional[RSScheme]) -> _Job:
         if self._stopped:
             raise RuntimeError("EC batch scheduler is stopped")
         if scheme is None:
@@ -334,7 +338,7 @@ class EcBatchScheduler:
                        clockctl.monotonic())
             self._q.put(job)  # bounded: blocks -> backpressure
         self.note_caller(0, st.elapsed)
-        return job.future
+        return job
 
     def note_caller(self, stage: int, seconds: float) -> None:
         """Add to a caller-thread stage (index into CALLER_STAGES)."""
@@ -354,6 +358,11 @@ class EcBatchScheduler:
         matrix path of rebuilds (parity IS mat @ data over GF(256)), so
         one dispatch can mix RS rebuilds and LRC encodes and every
         future demuxes exactly its own rows."""
+        return self._encode_job(data, cls, mat, scheme).future
+
+    def _encode_job(self, data: np.ndarray, cls: Optional[str],
+                    mat: Optional[np.ndarray],
+                    scheme: Optional[RSScheme]) -> _Job:
         if mat is not None:
             return self._submit("rebuild", data,
                                 np.ascontiguousarray(mat, dtype=np.uint8),
@@ -368,7 +377,7 @@ class EcBatchScheduler:
         return self._submit("rebuild", srcdata,
                             np.ascontiguousarray(rebuild_mat,
                                                  dtype=np.uint8), cls,
-                            scheme)
+                            scheme).future
 
     def encode(self, data: np.ndarray, cls: Optional[str] = None,
                mat: Optional[np.ndarray] = None,
@@ -443,6 +452,7 @@ class EcBatchScheduler:
         self.max_coalesced = max(self.max_coalesced, len(batch))
         now = clockctl.monotonic()
         for j in batch:
+            j.taken.set()
             # in a device trace: a marker on this thread's line where the
             # job's wait ends (a TraceMe cannot be back-dated); the
             # wait's length is the span below and wait_hist
@@ -624,6 +634,7 @@ class EcBatchScheduler:
             except queue.Empty:
                 break
             if j is not _STOP:
+                j.taken.set()
                 leftovers.append(j)
         if leftovers:
             self._run_cpu(leftovers)
@@ -700,13 +711,31 @@ class EcBatchScheduler:
         }
 
 
+class _QueuedEncode:
+    """An encode on the device queue (``BatchCoder.encode_begin``): the
+    wait, the copy out and the caller's ``result`` stage happen when the
+    result is asked for."""
+
+    __slots__ = ("_coder", "_fut", "_out")
+
+    def __init__(self, coder: "BatchCoder", fut: Future, out: np.ndarray):
+        self._coder, self._fut, self._out = coder, fut, out
+
+    def done(self) -> bool:
+        return self._fut.done()
+
+    def result(self) -> np.ndarray:
+        return self._coder._result(self._fut, self._out)
+
+
 class BatchCoder(ErasureCoder):
     """ErasureCoder facade of ONE scheme over an EcBatchScheduler — a
     drop-in for the Store/pipeline coder seam.  Each pipeline keeps
-    calling encode_into/reconstruct_rows per block-group exactly as
-    before; the facade turns those calls into scheduler submissions, so N
+    calling encode_begin/reconstruct_rows per block-group as for any
+    coder; the facade turns those calls into scheduler submissions, so N
     concurrent volume pipelines coalesce into device-sized mesh batches
-    without knowing about each other.
+    without knowing about each other, and a pipeline's begun batch waits
+    on the queue while the dispatcher has the one before it.
 
     A scheduler serves as many facades as there are schemes among the
     store's volumes (``for_scheme``).  A plain RS scheme of any (k, m)
@@ -732,6 +761,9 @@ class BatchCoder(ErasureCoder):
         # family's jobs carry their parity matrix to the apply program
         self._encode_mat = None if scheme == self._geometry \
             else np.ascontiguousarray(self._host._parity)
+        # per calling thread: ``taken`` of the job its last encode_begin
+        # submitted (the event alone: the job holds its buffers)
+        self._begun = threading.local()
 
     def for_scheme(self, scheme: RSScheme) -> ErasureCoder:
         """A facade over the SAME scheduler for a plain RS scheme (every
@@ -773,7 +805,24 @@ class BatchCoder(ErasureCoder):
         return self._encode(data)
 
     def encode_into(self, data: np.ndarray, out: np.ndarray) -> np.ndarray:
-        return self._encode(data, out)
+        return self.encode_begin(data, out).result()
+
+    def encode_begin(self, data: np.ndarray,
+                     out: np.ndarray) -> _QueuedEncode:
+        """Submit now, wait and copy out when asked: the caller may begin
+        its next batch while the dispatcher has this one.  Not before
+        this caller's previous job has LEFT the queue, though: a lone
+        pipeline keeps two jobs in the coder and never two in the queue,
+        so its jobs cannot share a dispatch (a B = 2 program that nothing
+        warmed: ~2 s of compile inside a seal) whatever the threads'
+        timing.  In step the wait is over long before it is asked for."""
+        last = getattr(self._begun, "taken", None)
+        if last is not None:
+            last.wait()
+        job = self.scheduler._encode_job(data, None, self._encode_mat,
+                                         self._geometry)
+        self._begun.taken = job.taken
+        return _QueuedEncode(self, job.future, out)
 
     def encode(self, shards: Sequence[bytes]) -> list[bytes]:
         k = self.scheme.data_shards

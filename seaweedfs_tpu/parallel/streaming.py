@@ -1,21 +1,38 @@
 """Staged EC pipelines: overlapped read -> code -> write for whole volumes.
 
 A 30GB volume is walked in column-aligned batches: reader threads
-prefetch batch N+1 from the .dat while the caller's thread has the coder
-on batch N and a writer thread drains batch N-1 to the shard files. The
-pipeline knows files and the ``ErasureCoder`` seam, nothing of devices:
-the same three stages serve a host coder (whose native kernel releases
-the GIL, so the reader/writer threads genuinely overlap the GF compute)
-and the batch scheduler's facade (``BatchCoder``: the call into the coder
-is a job's submit / wait / dispatch / result on the device queue).
+prefetch from the .dat, the caller's thread takes each batch through the
+coder and two writer threads drain the coded ones to the shard files
+(one the data shards' rows, one the parity shards': a single thread
+copying a whole batch into the page cache took as long as the device
+took to code it). The
+caller uses the coder's two-step form (``ErasureCoder.encode_begin``):
+it BEGINS batch N+1 as soon as the reader has it and only then collects
+batch N and hands it to the writer, in order. A coder that hands its
+batches to another thread therefore always has the next one waiting
+when it is done with one (never more than two begun and not collected;
+``overlapped`` in the stats counts the batches begun while the one
+before was not finished); a coder that works on the caller's thread has
+finished when its begin returns, so nothing is ever ahead and the calls
+come in the order they always did. The pipeline knows files and the
+``ErasureCoder`` seam, nothing of devices: the same stages serve a host
+coder (whose native kernel releases the GIL, so the reader/writer
+threads genuinely overlap the GF compute) and the batch scheduler's
+facade (``BatchCoder``: the begin is a job's submit, the collect its
+wait / dispatch / result on the device queue, and the dispatcher finds
+job N+1 queued the moment it has demuxed job N).
 
 Stage plumbing invariants:
   - every inter-stage queue is BOUNDED (maxsize=prefetch): a slow writer
     backpressures the coder, a slow coder backpressures the readers, so
-    peak memory is O(prefetch * batch) regardless of volume size;
+    peak memory is O(prefetch * batch) regardless of volume size (the
+    batch ahead in the coder is one more data and one more parity
+    buffer);
   - a failing stage records its exception in the _Pipeline and trips the
     shared abort event; every blocking put/get polls that event, so all
-    threads unwind promptly and the first error is re-raised to the caller;
+    threads unwind promptly and the first error is re-raised to the caller
+    (a batch that fails in the coder likewise; the batch begun behind it
+    is abandoned where it is, not waited for);
   - shard outputs go to `.tmp` names and are renamed into place only after
     every stage has finished cleanly — an interrupted pipeline never
     leaves a truncated file under a final shard name;
@@ -23,9 +40,11 @@ Stage plumbing invariants:
     allocation is zero.
 
 Each run is one ``ec.pipeline`` stage (utils/tracing.stage) with an
-``ec.pipeline.read`` / ``.encode`` / ``.write`` stage per batch in the
-thread that does it and ``ec.pipeline.commit`` at the end; the same busy
-seconds fill the caller's ``stats`` dict.
+``ec.pipeline.read`` / ``.write`` stage per batch in the thread that does
+it, an ``ec.pipeline.encode`` stage per turn of the caller in the coder
+(begin N+1, collect N: one a batch, and one more for the last collect
+when batches were ahead) and ``ec.pipeline.commit`` at the end; the same
+busy seconds fill the caller's ``stats`` dict.
 """
 
 from __future__ import annotations
@@ -202,11 +221,14 @@ def pipelined_encode_file(base_file_name: str,
                           stats: Optional[dict] = None) -> None:
     """write_ec_files as a staged pipeline; identical on-disk output.
 
-    The coder's encode runs on the calling thread between the reader
-    and writer stages. `stats`, when a dict, receives per-stage busy
-    seconds (read_s / encode_s / write_s / commit_s), wall_s, bytes_in
-    and batches — the ``pipeline`` object of ``/admin/ec/generate``'s
-    reply."""
+    The calling thread stands between the reader and writer stages and
+    keeps up to two batches in the coder (module docstring). `stats`,
+    when a dict, receives per-stage busy seconds (read_s / encode_s: the
+    caller's time in the coder, begins and collects / write_s: the two
+    writers' together / commit_s), wall_s, bytes_in, batches and
+    overlapped (batches begun while the one before was still in the
+    coder: batches - 1 under the batch scheduler, 0 under a host coder)
+    — the ``pipeline`` object of ``/admin/ec/generate``'s reply."""
     scheme = coder.scheme
     k = scheme.data_shards
     total = scheme.total_shards
@@ -219,7 +241,8 @@ def pipelined_encode_file(base_file_name: str,
 
     pl = _Pipeline()
     read_q: "queue.Queue" = queue.Queue(maxsize=prefetch)
-    write_q: "queue.Queue" = queue.Queue(maxsize=prefetch)
+    data_q: "queue.Queue" = queue.Queue(maxsize=prefetch)
+    parity_q: "queue.Queue" = queue.Queue(maxsize=prefetch)
     data_pool = _BufferPool()
     parity_pool = _BufferPool()
     slock = threading.Lock()
@@ -236,57 +259,90 @@ def pipelined_encode_file(base_file_name: str,
                 pl.put(read_q, (seq, buf))
         _merge_stats(stats, slock, read_s=busy)
 
-    def writer_stage(outs: AtomicFileGroup):
+    def writer_stage(q: "queue.Queue", files: list, pool: _BufferPool):
+        """One of the two writers: a batch's rows, one to a file, in the
+        order they are handed over; the buffer then goes back to its
+        pool."""
         busy = 0.0
         while True:
-            item = pl.get(write_q)
-            if item is None:
+            rows = pl.get(q)
+            if rows is None:
                 break
-            data, parity = item
             with tracing.stage("ec.pipeline.write") as st:
-                for i in range(k):
-                    outs.files[i].write(data[i])
-                for r in range(m):
-                    outs.files[k + r].write(parity[r])
+                for f, row in zip(files, rows):
+                    f.write(row)
             busy += st.elapsed
-            data_pool.put(data)
-            parity_pool.put(parity)
+            pool.put(rows)
         _merge_stats(stats, slock, write_s=busy)
+
+    def hand_over(rows, parity):
+        pl.put(data_q, rows)
+        pl.put(parity_q, parity)
 
     outs = AtomicFileGroup([base_file_name + layout.shard_ext(i)
                             for i in range(total)])
     whole = tracing.stage_begin("ec.pipeline")
     try:
-        writer_t = pl.spawn(writer_stage, outs)
+        # the data shards' files and the parity shards' have a writer
+        # each: one thread copying 14 MiB a batch into the page cache is
+        # as slow as the dispatch it runs beside
+        pl.spawn(writer_stage, data_q, outs.files[:k], data_pool)
+        pl.spawn(writer_stage, parity_q, outs.files[k:], parity_pool)
         for rid in range(readers):
             pl.spawn(reader_stage, rid)
 
         encode_busy = 0.0
+        overlapped = 0
         stash: dict[int, np.ndarray] = {}
+        # the batch that is in the coder and not collected yet: (its
+        # data, what encode_begin returned).  One here at most, and one
+        # more while the next is begun: never more than two
+        ahead = None
         for expected in range(len(descs)):
             while expected not in stash:
                 seq, buf = pl.get(read_q)
                 stash[seq] = buf
             data = stash.pop(expected)
-            # the call into the coder: under the batch scheduler it
-            # CONTAINS the job's submit / wait / dispatch / result
+            ready = []
+            # the caller's turn in the coder: batch N+1 is begun, only
+            # then batch N collected.  Under the batch scheduler the
+            # begin CONTAINS the job's submit and the collect its wait /
+            # dispatch / result: the dispatcher finds N+1 queued when it
+            # is done with N.  A host coder does all of it in the begin
+            # and leaves nothing ahead
             with tracing.stage("ec.pipeline.encode") as st:
-                parity = coder.encode_into(
+                if ahead is not None and not ahead[1].done():
+                    overlapped += 1
+                began = coder.encode_begin(
                     data, parity_pool.get((m, data.shape[1])))
+                if ahead is not None:
+                    ready.append((ahead[0], _collect(pl, ahead[1])))
+                    ahead = None
+                if began.done():
+                    ready.append((data, _collect(pl, began)))
+                else:
+                    ahead = (data, began)
             encode_busy += st.elapsed
-            pl.put(write_q, (data, parity))
-        pl.put(write_q, None)
-        writer_t.join()
+            for rows, parity in ready:
+                hand_over(rows, parity)
+        if ahead is not None:
+            # the last batch, with none to begin behind it
+            with tracing.stage("ec.pipeline.encode") as st:
+                parity = _collect(pl, ahead[1])
+            encode_busy += st.elapsed
+            hand_over(ahead[0], parity)
+        hand_over(None, None)
         pl.join()
         with tracing.stage("ec.pipeline.commit") as st:
             outs.commit()
         _merge_stats(stats, slock, encode_s=encode_busy,
                      commit_s=st.elapsed,
                      wall_s=clockctl.monotonic() - wall0,
-                     bytes_in=dat_size, batches=len(descs))
+                     bytes_in=dat_size, batches=len(descs),
+                     overlapped=overlapped)
         if stats is not None:
             for key in ("read_s", "encode_s", "write_s", "commit_s",
-                        "wall_s", "bytes_in", "batches"):
+                        "wall_s", "bytes_in", "batches", "overlapped"):
                 whole.annotate(key, stats.get(key))
     except _Aborted:
         # a stage failed and tripped abort while the main thread blocked;
@@ -298,6 +354,17 @@ def pipelined_encode_file(base_file_name: str,
         raise
     finally:
         tracing.stage_end(whole)
+
+
+def _collect(pl: _Pipeline, began) -> np.ndarray:
+    """The parity of a begun batch.  Its failure is the pipeline's: kept
+    as the first error if none came before, and the unwinding waits for
+    no batch that is still in the coder."""
+    try:
+        return began.result()
+    except Exception as e:  # noqa: BLE001 — re-raised as PipelineError
+        pl.fail(e)
+        raise _Aborted() from e
 
 
 def _unwind(pl: _Pipeline, outs: "AtomicFileGroup",

@@ -2268,8 +2268,8 @@ class VolumeServer:
     def _ec_generate(self, req: Request) -> Response:
         b = req.json()
         # the pipeline's own account of the seal (read_s / encode_s /
-        # write_s / commit_s busy seconds, wall_s, bytes_in, batches);
-        # empty for the serial path
+        # write_s / commit_s busy seconds, wall_s, bytes_in, batches,
+        # overlapped); empty for the serial path
         stats: dict = {}
         with tracing.stage("volume.ec.generate"):
             try:

@@ -15,11 +15,14 @@ kilobytes; layout.py keeps the same strict-> split at any scale.
 
 import glob
 import os
+import threading
 
 import numpy as np
 import pytest
 
-from seaweedfs_tpu.models.coder import make_coder
+from seaweedfs_tpu.models.coder import (DEFAULT_SCHEME, Encoded,
+                                        ErasureCoder, RSScheme, make_coder)
+from seaweedfs_tpu.ops import rs_cpu
 from seaweedfs_tpu.parallel import streaming
 from seaweedfs_tpu.storage.erasure_coding import decoder as ecdec
 from seaweedfs_tpu.storage.erasure_coding import encoder as ecenc
@@ -94,14 +97,20 @@ def test_pipelined_odd_batch_snaps_to_block(tmp_path):
 
 # ---- crash-safety: no truncated shard ever visible ----
 
-class _BoomCoder:
+class _BoomCoder(ErasureCoder):
     """Wraps a real coder; fails on the Nth encode call."""
 
     def __init__(self, blow_at: int):
         self._inner = make_coder("cpu")
-        self.scheme = self._inner.scheme
+        super().__init__(self._inner.scheme)
         self.calls = 0
         self.blow_at = blow_at
+
+    def encode(self, shards):
+        return self._inner.encode(shards)
+
+    def reconstruct(self, shards):
+        return self._inner.reconstruct(shards)
 
     def encode_into(self, data, out):
         self.calls += 1
@@ -171,6 +180,263 @@ def test_rebuild_crash_on_truncated_survivor(tmp_path):
                                pipelined=True)
     assert not os.path.exists(base + layout.shard_ext(12))
     assert not glob.glob(str(tmp_path) + "/*.tmp")
+
+
+# ---- the window of two: batch N+1 is begun before batch N is collected ----
+
+class _Begun:
+    """What _OffThreadCoder.encode_begin returns: filled, or failed, by
+    the coder's own thread, when the coder's rule says so."""
+
+    def __init__(self, coder, seq, data, out):
+        self.coder, self.seq, self.data, self.out = coder, seq, data, out
+        self.finished = threading.Event()
+        self.error = None
+
+    def done(self):
+        return self.finished.is_set()
+
+    def result(self):
+        assert self.finished.wait(60)
+        with self.coder.cv:
+            self.coder.collected.append(self.seq)
+        if self.error is not None:
+            raise self.error
+        return self.out
+
+
+class _OffThreadCoder(ErasureCoder):
+    """Begun batches are finished by ANOTHER thread, and batch N only on
+    request: once batch N+1 has been begun, or N is the last of
+    ``batches`` (so the pipeline's window is full at every step it can
+    be).  ``fail_at``: that batch fails instead; every batch after it is
+    never finished."""
+
+    def __init__(self, scheme, batches, fail_at=None):
+        super().__init__(scheme)
+        self._inner = rs_cpu.CpuCoder(scheme)
+        self.batches, self.fail_at = batches, fail_at
+        self.cv = threading.Condition()
+        self.begun: list[_Begun] = []
+        self.collected: list[int] = []
+        self.most_uncollected = 0
+        self.closed = False
+        self._thread = threading.Thread(target=self._finisher, daemon=True)
+        self._thread.start()
+
+    def encode(self, shards):
+        return self._inner.encode(shards)
+
+    def reconstruct(self, shards):
+        return self._inner.reconstruct(shards)
+
+    def encode_begin(self, data, out):
+        with self.cv:
+            b = _Begun(self, len(self.begun), data, out)
+            self.begun.append(b)
+            self.most_uncollected = max(
+                self.most_uncollected,
+                len(self.begun) - len(self.collected))
+            self.cv.notify_all()
+        return b
+
+    def _finisher(self):
+        for seq in range(self.batches):
+            want = min(self.batches, seq + 2)
+            with self.cv:
+                self.cv.wait_for(
+                    lambda: len(self.begun) >= want or self.closed)
+                if self.closed:
+                    return
+                b = self.begun[seq]
+            if seq == self.fail_at:
+                b.error = ValueError(f"batch {seq} lost")
+                b.finished.set()
+                return
+            self._inner.encode_into(b.data, b.out)
+            b.finished.set()
+
+    def close(self):
+        with self.cv:
+            self.closed = True
+            self.cv.notify_all()
+        self._thread.join(10)
+
+
+def _tail_size(k: int, batches: int) -> int:
+    """A .dat of ``batches`` small rows (a batch each at batch_size=SB),
+    the last of them short."""
+    return (batches - 1) * SB * k + 37
+
+
+@pytest.mark.parametrize("batches", [1, 2, 3, 7])
+@pytest.mark.parametrize("scheme", [DEFAULT_SCHEME, RSScheme(6, 3)],
+                         ids=["rs10-4", "rs6-3"])
+def test_window_of_two_matches_the_serial_walk(tmp_path, scheme, batches):
+    """A coder that finishes batch N on another thread, and only once
+    N+1 is begun: byte-identical shards, written in order; never more
+    than two batches begun and not collected; every batch but the first
+    begun while the one before it was still in the coder."""
+    k, total = scheme.data_shards, scheme.total_shards
+    sbase, pbase = str(tmp_path / "s"), str(tmp_path / "p")
+    for b in (sbase, pbase):
+        _make_dat(b, _tail_size(k, batches), seed=batches)
+    ecenc.write_ec_files(sbase, rs_cpu.CpuCoder(scheme), LB, SB,
+                         batch_size=SB)
+    coder = _OffThreadCoder(scheme, batches)
+    stats: dict = {}
+    try:
+        streaming.pipelined_encode_file(pbase, coder, LB, SB,
+                                        batch_size=SB, stats=stats)
+    finally:
+        coder.close()
+    for i in range(total):
+        ext = layout.shard_ext(i)
+        assert open(pbase + ext, "rb").read() == \
+            open(sbase + ext, "rb").read(), ext
+    assert not os.path.exists(pbase + layout.shard_ext(total))
+    assert coder.collected == list(range(batches))
+    assert coder.most_uncollected == min(2, batches)
+    assert stats["batches"] == batches
+    assert stats["overlapped"] == batches - 1
+    assert stats["encode_s"] > 0
+
+
+class _SpyCoder(rs_cpu.CpuCoder):
+    """The host coder, recording what encode_into is handed."""
+
+    def __init__(self):
+        super().__init__()
+        self.calls = []
+
+    def encode_into(self, data, out):
+        self.calls.append((data.tobytes(), out.shape, out.dtype))
+        return super().encode_into(data, out)
+
+
+def test_host_coder_leaves_nothing_in_flight(tmp_path):
+    """A coder that works on the caller's thread: one encode_into a
+    batch, in the plan's order, on the (k, step) rows and an (m, step)
+    buffer as before the window existed, and ``overlapped`` 0."""
+    base = str(tmp_path / "v")
+    size = LB * K + 2 * SB * K + 11
+    dat = _make_dat(base, size, seed=4)
+    coder = _SpyCoder()
+    stats: dict = {}
+    streaming.pipelined_encode_file(base, coder, LB, SB, batch_size=SB,
+                                    stats=stats)
+    want = []
+    padded = dat + bytes(LB * K + 3 * SB * K - size)
+    for row_off, block, b, step in layout.iter_encode_batches(
+            size, LB, SB, SB, K):
+        rows = b"".join(padded[row_off + i * block + b:
+                               row_off + i * block + b + step]
+                        for i in range(K))
+        want.append((rows, (TOTAL - K, step), np.uint8))
+    assert coder.calls == want
+    assert stats["batches"] == len(want) and stats["overlapped"] == 0
+    # the seam's two-step form on a host coder: all done in the begin
+    rows = np.frombuffer(want[0][0], dtype=np.uint8).reshape(K, -1)
+    out = np.empty((TOTAL - K, rows.shape[1]), dtype=np.uint8)
+    began = coder.encode_begin(rows, out)
+    assert isinstance(began, Encoded) and began.done()
+    assert began.result() is out
+    assert np.array_equal(out, make_coder("cpu").encode_array(rows))
+    sbase = str(tmp_path / "s")
+    _make_dat(sbase, size, seed=4)
+    ecenc.write_ec_files(sbase, make_coder("cpu"), LB, SB, batch_size=SB)
+    assert _shards(base) == _shards(sbase)
+
+
+@pytest.mark.parametrize("fail_at,batches", [(0, 2), (1, 4), (2, 4)])
+def test_failed_batch_is_raised_with_the_next_still_in_the_coder(
+        tmp_path, fail_at, batches):
+    """Batch N fails while N+1 is in the coder (and is never finished):
+    N's error is the PipelineError's cause, no shard has a final name,
+    no .tmp is left, and the call is back without N+1."""
+    base = str(tmp_path / "v")
+    _make_dat(base, _tail_size(K, batches), seed=8)
+    coder = _OffThreadCoder(DEFAULT_SCHEME, batches, fail_at=fail_at)
+    try:
+        with pytest.raises(streaming.PipelineError) as ei:
+            streaming.pipelined_encode_file(base, coder, LB, SB,
+                                            batch_size=SB)
+        assert isinstance(ei.value.__cause__, ValueError)
+        assert str(ei.value.__cause__) == f"batch {fail_at} lost"
+        assert len(coder.begun) == fail_at + 2
+        assert not coder.begun[fail_at + 1].done()
+        assert coder.collected == list(range(fail_at + 1))
+    finally:
+        coder.close()
+    assert _leftovers(tmp_path) == []
+
+
+class _SpiedFile:
+    """A shard's .tmp file that notes which thread writes it, and can be
+    made to fail."""
+
+    def __init__(self, f, log, fail):
+        self._f, self._log, self._fail = f, log, fail
+
+    def write(self, row):
+        self._log.append(threading.get_ident())
+        if self._fail and len(self._log) >= 2:
+            raise IOError("no space left")
+        return self._f.write(row)
+
+    def close(self):
+        self._f.close()
+
+
+def _spy_on_shard_files(monkeypatch, failing=()):
+    logs: dict[int, list[int]] = {}
+    real_init = streaming.AtomicFileGroup.__init__
+
+    def init(self, paths):
+        real_init(self, paths)
+        self.files = [
+            _SpiedFile(f, logs.setdefault(i, []), i in failing)
+            for i, f in enumerate(self.files)]
+
+    monkeypatch.setattr(streaming.AtomicFileGroup, "__init__", init)
+    return logs
+
+
+def test_data_rows_and_parity_rows_have_a_writer_each(
+        tmp_path, monkeypatch):
+    """Two writer threads: every data shard's file is written by one of
+    them, every parity shard's by the other, a write a batch each."""
+    logs = _spy_on_shard_files(monkeypatch)
+    sbase, pbase = str(tmp_path / "s"), str(tmp_path / "p")
+    for b in (sbase, pbase):
+        _make_dat(b, _tail_size(K, 5), seed=6)
+    stats: dict = {}
+    streaming.pipelined_encode_file(pbase, make_coder("cpu"), LB, SB,
+                                    batch_size=SB, stats=stats)
+    assert [len(logs[i]) for i in range(TOTAL)] == [5] * TOTAL
+    data_threads = {t for i in range(K) for t in logs[i]}
+    parity_threads = {t for i in range(K, TOTAL) for t in logs[i]}
+    assert len(data_threads) == len(parity_threads) == 1
+    assert data_threads != parity_threads
+    assert threading.get_ident() not in data_threads | parity_threads
+    assert stats["write_s"] > 0
+    monkeypatch.undo()
+    ecenc.write_ec_files(sbase, make_coder("cpu"), LB, SB, batch_size=SB)
+    assert _shards(pbase) == _shards(sbase)
+
+
+@pytest.mark.parametrize("failing", [3, K + 1],
+                         ids=["data-writer", "parity-writer"])
+def test_either_writer_crash_raises_pipeline_error(tmp_path, monkeypatch,
+                                                   failing):
+    _spy_on_shard_files(monkeypatch, failing=(failing,))
+    base = str(tmp_path / "v")
+    _make_dat(base, _tail_size(K, 6), seed=7)
+    with pytest.raises(streaming.PipelineError) as ei:
+        streaming.pipelined_encode_file(base, make_coder("cpu"), LB, SB,
+                                        batch_size=SB)
+    assert isinstance(ei.value.__cause__, IOError)
+    assert _leftovers(tmp_path) == []
 
 
 # ---- pipelined rebuild / decode identity ----
@@ -310,6 +576,9 @@ def test_generate_reply_carries_the_pipelines_stats(tmp_path, monkeypatch):
         assert p["bytes_in"] == os.path.getsize(dat)
         assert p["read_s"] + p["encode_s"] + p["write_s"] > 0
         assert p["batches"] >= 1 and p["commit_s"] > 0
+        # the default coder works on the caller's thread: no batch is
+        # begun while another is in the coder
+        assert p["overlapped"] == 0
         assert p["wall_s"] >= p["commit_s"]
         for i in range(TOTAL):
             assert os.path.exists(os.path.join(

@@ -241,6 +241,74 @@ def test_lone_job_on_an_idle_scheduler_is_not_held():
     assert "window_s" not in st
 
 
+class _HeldUntilNextIsQueued(_Recorder):
+    """Every dispatch but the last is held until one more job has been
+    SUBMITTED than have been dispatched: the dispatcher is as slow as it
+    takes for the caller's next job to be queued behind the running one."""
+
+    def __init__(self, sched_q, jobs: int):
+        super().__init__()
+        self.jobs = jobs
+        self.submitted = 0
+        self.cv = threading.Condition()
+        real_put = sched_q.put
+
+        def put(job, *a, **kw):
+            real_put(job, *a, **kw)
+            with self.cv:
+                self.submitted += 1
+                self.cv.notify_all()
+        sched_q.put = put
+
+    def encode_batch(self, b):
+        with self.cv:
+            want = min(self.jobs, len(self.seen) + 2)
+            assert self.cv.wait_for(lambda: self.submitted >= want, 60)
+        return super().encode_batch(b)
+
+
+@pytest.mark.parametrize("batches", [2, 3, 9])
+def test_lone_pipeline_keeps_two_jobs_in_the_queue_and_dispatches_one(
+        tmp_path, batches):
+    """A lone seal's window of two: job N+1 is queued while N is
+    dispatched, and still every dispatch carries ONE job, as a view
+    (``traffic/single.json`` warms only the B = 1 program)."""
+    from seaweedfs_tpu.parallel import streaming
+    from seaweedfs_tpu.storage.erasure_coding import encoder as ecenc
+    from seaweedfs_tpu.storage.erasure_coding import layout
+    lb, sb = 640, 160
+    sbase, pbase = str(tmp_path / "s"), str(tmp_path / "p")
+    dat = np.random.default_rng(batches).integers(
+        0, 256, batches * sb * K - 7, dtype=np.uint8).tobytes()
+    for base in (sbase, pbase):
+        with open(base + ".dat", "wb") as f:
+            f.write(dat)
+    ecenc.write_ec_files(sbase, CPU, lb, sb, batch_size=sb)
+    sched = EcBatchScheduler(mesh_coder=_Recorder())
+    held = _HeldUntilNextIsQueued(sched._q, batches)
+    sched._mesh = sched._geometries[DEFAULT_SCHEME].mesh = held
+    stats: dict = {}
+    try:
+        streaming.pipelined_encode_file(pbase, BatchCoder(sched), lb, sb,
+                                        batch_size=sb, stats=stats)
+        # a dispatch is counted after its futures are set
+        while sched.stats()["mesh_batches"] < batches:
+            time.sleep(0.001)
+        st = sched.stats()
+    finally:
+        sched.stop()
+    assert stats["batches"] == batches
+    assert stats["overlapped"] == batches - 1
+    assert st["max_coalesced"] == 1
+    assert st["lone_dispatches"] == st["mesh_batches"] == batches
+    assert st["cpu_batches"] == 0 and st["coder_fallbacks"] == 0
+    assert len(held.seen) == batches
+    for i in range(TOTAL):
+        ext = layout.shard_ext(i)
+        assert open(pbase + ext, "rb").read() == \
+            open(sbase + ext, "rb").read()
+
+
 @pytest.mark.parametrize("kind", ["encode", "rebuild"])
 def test_lone_job_reaches_the_coder_uncopied(kind):
     """At B = 1 the coder's operand is a view of the job's own buffer
@@ -694,6 +762,10 @@ def test_loop_and_stage_counters_account_for_the_wall():
     try:
         coder.encode_array(data)                 # compile outside
         coder.reconstruct_rows(data, mat)
+        # a dispatch is counted after its futures are set: wait for the
+        # dispatcher to have counted the two above
+        while sched.stats()["lone_dispatches"] < 2:
+            time.sleep(0.001)
         a, t0 = sched.stats(), time.monotonic()
         for _ in range(6):
             coder.encode_array(data)
