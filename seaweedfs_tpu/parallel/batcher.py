@@ -42,7 +42,10 @@ Scheduling contract:
     it: a volume's own scheme, read from its .vif by the store).  Jobs
     of one geometry coalesce, jobs of two never share a dispatch, and
     the scheduler keeps one mesh coder (on the one device mesh) and one
-    host fallback per geometry it has seen; ``by_spec`` counts each.
+    host fallback per geometry it has seen; ``by_spec`` counts each,
+    ``by_rung`` each job width (which rung of the ladder the traffic
+    rides, and how full its dispatches get) and ``cap_splits`` the groups
+    that MAX_DISPATCH_COLUMNS cut into more than one dispatch.
 
 Where the time goes is counted always and traced when sampled: every
 stage a job passes through (``STAGES``; utils/tracing.stage) adds its
@@ -276,6 +279,11 @@ class EcBatchScheduler:
         self.cpu_batches = 0
         self.coder_fallbacks = 0
         self.max_coalesced = 0
+        # per job width (a ladder rung, or a multiple of the top one):
+        # {columns: [jobs, mesh dispatches, most jobs in one of them]},
+        # and the groups cut by MAX_DISPATCH_COLUMNS
+        self._by_rung: dict[int, list[int]] = {}
+        self.cap_splits = 0
         # where the time goes (module docstring).  The dispatcher is the
         # only writer of stage_s / stage_n / by_kind
         self.stage_s = dict.fromkeys(("stack", "demux"), 0.0)
@@ -473,6 +481,7 @@ class EcBatchScheduler:
             bk["bytes_padded"] += rows * j.data.shape[1]
             bk["bytes_out"] += rows_out * j.n
             bs["bytes_out"] += rows_out * j.n
+            self._rung(j.data.shape[1])[0] += 1
         self.size_hist.observe(len(batch))
         # QoS ordering: a group containing an interactive job dispatches
         # before an all-background group
@@ -490,8 +499,13 @@ class EcBatchScheduler:
                 # or a job per device
                 step = max(self._mesh.n_devices, MAX_DISPATCH_COLUMNS
                            // max(1, jobs[0].data.shape[1]))
+                if len(jobs) > step:
+                    self.cap_splits += 1
                 for i in range(0, len(jobs), step):
                     self._run_group(jobs[i:i + step])
+
+    def _rung(self, columns: int) -> list[int]:
+        return self._by_rung.setdefault(columns, [0, 0, 0])
 
     def _geometry(self, scheme: RSScheme) -> _Geometry:
         g = self._geometries.get(scheme)
@@ -524,6 +538,9 @@ class EcBatchScheduler:
                 self._run_mesh(jobs, g)
                 self.mesh_batches += 1
                 g.counters["mesh_dispatches"] += 1
+                rung = self._rung(jobs[0].data.shape[1])
+                rung[1] += 1
+                rung[2] = max(rung[2], len(jobs))
                 if len(jobs) == 1:
                     self.lone_dispatches += 1
                 return
@@ -705,6 +722,12 @@ class EcBatchScheduler:
             "size_hist": self.size_hist.snapshot(),
             "by_kind": {k: dict(v) for k, v in self.by_kind.items()},
             "by_spec": by_spec,
+            # keyed by the jobs' columns: the jobs add up to jobs_total,
+            # the dispatches to mesh_batches
+            "by_rung": {str(n): dict(zip(
+                ("jobs", "mesh_dispatches", "max_coalesced"), r))
+                for n, r in sorted(self._by_rung.items())},
+            "cap_splits": self.cap_splits,
             "stage_s": {k: stage_s.get(k, 0.0) for k in STAGES},
             "stage_n": {k: stage_n.get(k, 0) for k in STAGES},
             "loop_s": loop_s,

@@ -305,6 +305,15 @@ class VolumeServer:
             "volumeServer", "ec_batch_spec",
             "EC batch scheduler counters per code geometry",
             ("spec", "stat"))
+        # ... per job width (by_rung) and the groups its column cap cut
+        self._m_ec_rung = self.metrics.gauge(
+            "volumeServer", "ec_batch_rung",
+            "EC batch scheduler counters per job width in columns",
+            ("rung", "stat"))
+        self._m_ec_cap_splits = self.metrics.gauge(
+            "volumeServer", "ec_batch_cap_splits",
+            "groups of EC jobs cut into several dispatches by the "
+            "column cap")
         # hot-needle record cache + selector-core connection counters,
         # refreshed at scrape from their owners' stats() snapshots
         self._m_cache = self.metrics.gauge(
@@ -1042,6 +1051,10 @@ class VolumeServer:
             for spec, counters in bs["by_spec"].items():
                 for stat, val in counters.items():
                     self._m_ec_spec.set(spec, stat, value=val)
+            for rung, counters in bs["by_rung"].items():
+                for stat, val in counters.items():
+                    self._m_ec_rung.set(rung, stat, value=val)
+            self._m_ec_cap_splits.set(value=bs["cap_splits"])
 
     def _handle_metrics(self, req: Request) -> Response:
         return Response(self.metrics.expose_text(),

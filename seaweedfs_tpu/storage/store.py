@@ -104,7 +104,12 @@ class Store:
         # needle lookups answered from the index in memory and those
         # that searched the .ecx file (counted by the mounted EcVolumes);
         # the rebuilt intervals whose survivors were read straight into
-        # the rows of the job's operand (_gather_survivors).
+        # the rows of the job's operand (_gather_survivors); the whole
+        # records the needle cache's loader read (_load_ec_record), their
+        # bytes and the intervals they were joined from, and the bytes
+        # of all rebuilt intervals (so record_intervals / records_loaded
+        # is how many blocks a needle spans, recovered_bytes /
+        # intervals_recovered how wide a rebuild job is).
         # Bumped by request threads without a lock, like the tallies
         # above: a lost add under contention is tolerated
         self.ec_read_stats = {"intervals_local": 0,
@@ -112,7 +117,10 @@ class Store:
                               "survivor_reads": 0, "survivor_bytes": 0,
                               "recover_s": 0.0,
                               "ecx_lookups": 0, "ecx_file_searches": 0,
-                              "survivor_gathers": 0}
+                              "survivor_gathers": 0,
+                              "records_loaded": 0, "record_bytes": 0,
+                              "record_intervals": 0,
+                              "recovered_bytes": 0}
         for loc in self.locations:
             loc.ec_read_stats = self.ec_read_stats
         # the one coefficient row a degraded read of plain RS asks for,
@@ -509,9 +517,17 @@ class Store:
             intervals, _offset, size = ev.locate_needle(needle_id)
         if t.size_is_deleted(size):
             raise DeletedError(f"needle {needle_id:x} deleted")
-        meter = {"recovered": 0}
+        meter = {"recovered": 0}    # bytes of its intervals rebuilt
         blob = b"".join(
             self._read_one_interval(ev, iv, meter) for iv in intervals)
+        rs = self.ec_read_stats
+        rs["records_loaded"] += 1
+        rs["record_bytes"] += len(blob)
+        rs["record_intervals"] += len(intervals)
+        # on the loader's stage (store.ec.cache), sampled spans only
+        tracing.detail("intervals", len(intervals))
+        tracing.detail("bytes", len(blob))
+        tracing.detail("recovered", meter["recovered"])
         # the one CRC walk this blob ever pays: admission-time, over
         # memoryview windows — hits re-parse with check_crc=False and
         # range reads serve memoryview slices of the verified bytes
@@ -671,12 +687,13 @@ class Store:
                 return data
         # degraded: fetch the same range of >= k other shards and reconstruct
         if meter is not None:
-            meter["recovered"] = meter.get("recovered", 0) + 1
+            meter["recovered"] = meter.get("recovered", 0) + iv.size
         with tracing.stage("store.ec.recover") as st:
             st.annotate("bytes", iv.size)
             got = self._recover_one_interval(ev, iv, shard_id)
         rs = self.ec_read_stats
         rs["intervals_recovered"] += 1
+        rs["recovered_bytes"] += iv.size
         rs["recover_s"] += st.elapsed
         return got
 

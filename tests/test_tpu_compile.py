@@ -157,3 +157,36 @@ def test_rs6_3_programs_compile_under_their_own_names(topo, kind, n):
     (out_s,) = jax.tree_util.tree_leaves(
         lowered.compile().output_shardings)
     assert out_s.shard_shape((1, 3, n // 4)) == (1, 3, n // 4)
+
+
+def _chunks_warm_shapes():
+    import json
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "traffic",
+        "degraded1.chunks.json")
+    with open(path) as f:
+        return [tuple(s) for s in json.load(f)["warm"]["apply"]]
+
+
+@pytest.mark.parametrize("B", [1, 2, 4])
+def test_chunk_read_apply_program_compiles_at_one_mib(topo, B):
+    """The apply program a rebuilt chunk read dispatches (cell
+    ``reads.degraded1.chunks``: every lost interval a whole 1 MiB block,
+    at most 4 jobs a dispatch): (B, 10, 1 MiB) in, (B, 4, 1 MiB) out
+    under its own name, well inside the chip's memory; and the cell's
+    traffic file warms exactly these."""
+    assert (B, MIB) in _chunks_warm_shapes() and (B, MIB) in BUCKETS_1
+    assert all(n == MIB and b in (1, 2, 4) for b, n in _chunks_warm_shapes())
+    mesh = _batch_mesh(topo, 1)
+    lowered = rs_mesh.batch_apply_fn(DEFAULT_SCHEME, mesh) \
+        .lower(*_batch_args(mesh, B, MIB))
+    assert "jit_ec_apply_rs_10_4" in lowered.as_text()[:200]
+    compiled = lowered.compile()
+    (out_s,) = jax.tree_util.tree_leaves(compiled.output_shardings)
+    assert out_s.shard_shape((B, M, MIB // 4)) == (B, M, MIB // 4)
+    mem = compiled.memory_analysis()
+    # operand and result of the dispatch, and little beside them
+    assert mem.argument_size_in_bytes >= B * K * MIB
+    assert mem.output_size_in_bytes >= B * M * MIB
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
+        + mem.output_size_in_bytes < 1 << 30
