@@ -78,6 +78,12 @@ def read_data(mc: MasterClient, fid: str,
     next-ranked replica — the serial walk failed over only after a
     full timeout, paying the slowest server's tail on every read.
     delete_file below stays serial: deletes are not safe to race.
+    With ONE usable holder (replication 000, an EC volume on one
+    server) and no ambient deadline there is nobody to hedge to and
+    nothing to return early for, and hedged() makes the GET on this
+    thread (`mc.peer_health.hedge_stats()` counts it `direct`); a
+    caller inside a deadline_scope keeps the pool, which alone can
+    give up on a holder that has stopped answering.
 
     Two divergence-era behaviors ride the fetch:
     - cache-aware routing: a replica whose response carries the

@@ -73,6 +73,11 @@ def _bind(lib) -> None:
     lib.gf_apply_strided.restype = None
     lib.crc32c.argtypes = [ctypes.c_uint32, ctypes.c_void_p, ctypes.c_int64]
     lib.crc32c.restype = ctypes.c_uint32
+    lib.pread_rows.argtypes = [
+        ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64,
+        ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64, ctypes.c_void_p,
+    ]
+    lib.pread_rows.restype = None
     lib.gf_force_impl.argtypes = [ctypes.c_int]
     lib.gf_force_impl.restype = ctypes.c_int
     lib.gf_impl_name.restype = ctypes.c_char_p
@@ -199,3 +204,33 @@ def crc32c(data: bytes | bytearray | memoryview | np.ndarray,
     if buf.size == 0:
         return crc & 0xFFFFFFFF
     return int(lib.crc32c(crc, buf.ctypes.data, buf.size))
+
+
+def pread_rows(fds: list[int], offset: int, rows: np.ndarray,
+               size: int) -> list[int]:
+    """rows[r, :size] = `size` bytes from `offset` of file descriptor
+    fds[r] (negative: the row is left as it is), every row in ONE
+    foreign call: the interpreter lock is given away once for all the
+    reads, not once a file. Returns the bytes read per row, short only
+    at the end of a file; a failed read raises OSError."""
+    lib = _load()
+    if lib is None:
+        raise RuntimeError("native library not available")
+    if rows.dtype != np.uint8 or rows.ndim != 2 \
+            or not rows.flags.writeable or rows.strides[1] != 1 \
+            or rows.strides[0] < rows.shape[1]:
+        raise ValueError("rows must be a writable 2-D uint8 array with "
+                         "contiguous rows")
+    n = len(fds)
+    if n != rows.shape[0] or not 0 <= size <= rows.shape[1] \
+            or offset < 0:
+        raise ValueError(f"{n} files at {offset} for {size} bytes do "
+                         f"not fit rows of shape {rows.shape}")
+    c_fds = (ctypes.c_int32 * n)(*fds)
+    got = (ctypes.c_int64 * n)()
+    lib.pread_rows(c_fds, n, offset, rows.ctypes.data, rows.strides[0],
+                   size, got)
+    for r, g in enumerate(got):
+        if g < 0:
+            raise OSError(-g, os.strerror(-g), f"fd {fds[r]}")
+    return list(got)

@@ -20,7 +20,8 @@ from seaweedfs_tpu.models.coder import (DEFAULT_SCHEME, ErasureCoder,
 from seaweedfs_tpu.storage import types as t
 from seaweedfs_tpu.storage.disk_location import DiskLocation
 from seaweedfs_tpu.storage.erasure_coding import layout
-from seaweedfs_tpu.storage.erasure_coding.ec_volume import EcVolume
+from seaweedfs_tpu.storage.erasure_coding.ec_volume import (
+    EcVolume, read_shards_into)
 from seaweedfs_tpu.storage import needle
 from seaweedfs_tpu.storage.needle import Needle
 from seaweedfs_tpu.storage.super_block import ReplicaPlacement, TTL
@@ -805,15 +806,18 @@ class Store:
     def _gather_survivors(self, ev: EcVolume, sids: list[int],
                           local: dict, fetched: dict, shard_off: int,
                           size: int, rows: np.ndarray) -> None:
-        """Fill rows[r, :size] with shard sids[r]'s range: a mounted
-        shard (`local`) by one preadv straight into the row, a fetched
-        one by a copy. Columns past `size` are the caller's (zero, in a
-        job buffer)."""
+        """Fill rows[r, :size] with shard sids[r]'s range: the mounted
+        shards (`local`) read straight into their rows, all in one go
+        (read_shards_into), a fetched one by a copy. Columns past `size`
+        are the caller's (zero, in a job buffer)."""
         for r, sid in enumerate(sids):
             got = fetched.get(sid)
             if got is not None:
                 rows[r, :size] = np.frombuffer(got, dtype=np.uint8)
-            elif local[sid].read_into(shard_off, rows[r, :size]) != size:
+        mounted = [None if sid in fetched else local[sid] for sid in sids]
+        for sid, shard, n in zip(sids, mounted, read_shards_into(
+                mounted, shard_off, rows, size)):
+            if shard is not None and n != size:
                 raise NotFoundError(
                     f"ec volume {ev.volume_id}: shard {sid} ends inside "
                     f"[{shard_off}, {shard_off + size})")

@@ -61,6 +61,9 @@ CLOSED = "closed"
 OPEN = "open"
 HALF_OPEN = "half-open"
 
+# what PeerHealth.count_hedge() counts (resilience_hedges_total{outcome})
+HEDGE_OUTCOMES = ("direct", "pooled", "probe", "fired")
+
 # Process-wide breaker-open listeners: fn(peer_key) runs when any
 # PeerHealth-tracked breaker transitions closed/half-open -> open.
 # httpd's connection pool registers here to evict the dead peer's idle
@@ -391,6 +394,10 @@ class PeerHealth:
         self.hedge_max_s = hedge_max_s
         self._peers: dict[str, CircuitBreaker] = {}
         self._lock = threading.Lock()
+        # plain integers beside resilience_hedges_total: a client's
+        # PeerHealth has no registry, and direct / (direct + pooled)
+        # is how often hedged() skipped the pool
+        self._hedge_counts = dict.fromkeys(HEDGE_OUTCOMES, 0)
         if metrics is not None:
             self._c_outcomes = metrics.counter(
                 "resilience", "peer_calls_total",
@@ -440,8 +447,18 @@ class PeerHealth:
             self._c_outcomes.inc("ok" if ok else "error")
 
     def count_hedge(self, outcome: str) -> None:
+        with self._lock:
+            self._hedge_counts[outcome] += 1
         if self._c_hedges is not None:
             self._c_hedges.inc(outcome)
+
+    def hedge_stats(self) -> dict:
+        """How hedged() calls went, by outcome: `direct` (one usable
+        candidate, no deadline: run on the caller's thread), `pooled`
+        (handed to the hedge pool), `probe` and `fired` (backup legs of
+        pooled calls)."""
+        with self._lock:
+            return dict(self._hedge_counts)
 
     def rank(self, urls: Iterable[str],
              pressure: Optional[dict] = None) -> list[str]:
@@ -500,6 +517,22 @@ def _get_hedge_pool():
     return _hedge_pool
 
 
+def _run_leg(fn: Callable[[str], object], c: str,
+             health: Optional[PeerHealth]):
+    """One dial of one candidate: an exception is a None result, and
+    the outcome (with its latency on success) is recorded."""
+    t0 = _now()
+    try:
+        out = fn(c)
+    except Exception:
+        out = None
+    lat = _now() - t0
+    if health is not None:
+        health.record(c, out is not None, lat if out is not None
+                      else None)
+    return out
+
+
 def hedged(fn: Callable[[str], object], candidates: Sequence[str],
            health: Optional[PeerHealth] = None,
            delay: Optional[float] = None,
@@ -513,9 +546,17 @@ def hedged(fn: Callable[[str], object], candidates: Sequence[str],
     forced (an open circuit must not make a sole holder unreachable).
     A candidate due a half-open probe is fired immediately alongside
     the primary, so real traffic doubles as the probe. Returns the
-    winning result or None."""
-    from concurrent.futures import FIRST_COMPLETED, wait
+    winning result or None.
 
+    DIRECT: when the screening leaves exactly one candidate and no
+    deadline is given or ambient, fn runs on the CALLING thread. With
+    nobody to hedge to, the pool would only add a Future, a queue
+    hand-over and two wake-ups, each of which gives the interpreter
+    lock away among all the readers of the process (a client reading a
+    volume of replication 000 always has one holder). Recording, the
+    forced dial of a sole OPEN holder and the span annotations are the
+    same. Any deadline keeps the pool: only a second thread can return
+    at the deadline while fn is still blocked in its peer's socket."""
     if not candidates:
         return None
     order = list(candidates)
@@ -528,29 +569,31 @@ def hedged(fn: Callable[[str], object], candidates: Sequence[str],
                   if health.breaker(c).state != OPEN
                   or health.breaker(c).probe_ripe()]
         order = usable if usable else [order[0]]
+    dl = deadline or current_deadline()
+    ctx_sp = tracing.current_span()
+    if dl is None and len(order) == 1:
+        if health is not None:
+            health.count_hedge("direct")
+        out = _run_leg(fn, order[0], health)
+        if out is not None and ctx_sp is not None:
+            ctx_sp.annotate("hedge.winner", order[0])
+            ctx_sp.annotate("hedge.legs_fired", 1)
+        return out
+    from concurrent.futures import FIRST_COMPLETED, wait
+    if health is not None:
+        health.count_hedge("pooled")
     if delay is None:
         delay = (health.hedge_delay(order[0])
                  if health is not None else 0.05)
-    dl = deadline or current_deadline()
     pool = _get_hedge_pool()
     ctx_dl = dl  # propagate into workers
-    # ContextVars don't cross the pool: capture the ambient span here
-    # and re-enter it in each worker, so every leg's http_call becomes
-    # a child span of the request that hedged
-    ctx_sp = tracing.current_span()
 
     def run_one(c: str):
-        t0 = _now()
-        try:
-            with deadline_scope(ctx_dl), tracing.span_scope(ctx_sp):
-                out = fn(c)
-        except Exception:
-            out = None
-        lat = _now() - t0
-        if health is not None:
-            health.record(c, out is not None, lat if out is not None
-                          else None)
-        return out
+        # ContextVars don't cross the pool: re-enter the span that was
+        # ambient at the call in each worker, so every leg's http_call
+        # becomes a child span of the request that hedged
+        with deadline_scope(ctx_dl), tracing.span_scope(ctx_sp):
+            return _run_leg(fn, c, health)
 
     pending = {pool.submit(run_one, order[0]): order[0]}
     nxt = 1

@@ -13,8 +13,9 @@ from seaweedfs_tpu.models.coder import (DEFAULT_SCHEME, LrcScheme, RSScheme,
                                         make_coder)
 from seaweedfs_tpu.storage import types as t
 from seaweedfs_tpu.storage.erasure_coding import layout
+from seaweedfs_tpu.native import rs_native
 from seaweedfs_tpu.storage.erasure_coding.ec_volume import (
-    EcVolume, NotFoundError, iterate_ecj_file,
+    EcVolume, NotFoundError, iterate_ecj_file, read_shards_into,
     search_needle_from_sorted_index)
 from seaweedfs_tpu.storage.needle import Needle
 from seaweedfs_tpu.storage.store import Store
@@ -369,3 +370,107 @@ def test_the_job_reaches_the_scheduler_on_a_rung_with_one_row(
         store.close()
     finally:
         sched.stop()
+
+
+
+# ------------------------------------- the survivors gathered in one call
+
+def _without_the_library(monkeypatch):
+    monkeypatch.setattr(rs_native, "available", lambda: False)
+
+
+@pytest.mark.parametrize("library", ["native", "absent"])
+def test_read_shards_into_fills_every_row_short_only_at_the_end(
+        sealed, monkeypatch, library):
+    """One foreign call for all the shards, or a preadv a shard where
+    the library is absent: the same rows, the same counts."""
+    if library == "absent":
+        _without_the_library(monkeypatch)
+    elif not rs_native.available():
+        pytest.skip("no native library on this machine")
+    code, store, _records = sealed
+    ev = store.find_ec_volume(5)
+    sids = sorted(ev.shards)[:4]
+    shards = [ev.shards[s] for s in sids]
+    shards[1] = None                       # a row that is the caller's
+    size, end = 3000, shards[0].shard_size
+    for offset, want in [(0, size), (12345, size), (end - 1000, 1000),
+                         (end, 0)]:
+        rows = np.full((4, 4096), 7, dtype=np.uint8)
+        got = read_shards_into(shards, offset, rows, size)
+        assert got == [want, 0, want, want], (code, offset)
+        for r, shard in enumerate(shards):
+            if shard is None:
+                assert (rows[r] == 7).all()
+                continue
+            assert rows[r, :want].tobytes() == shard.read_at(offset, size)
+            assert (rows[r, want:] == 7).all()
+
+
+def test_pread_rows_refuses_what_does_not_fit_and_reports_a_failed_read(
+        tmp_path):
+    if not rs_native.available():
+        pytest.skip("no native library on this machine")
+    path = tmp_path / "f"
+    path.write_bytes(b"x" * 100)
+    fd = os.open(path, os.O_RDONLY)
+    try:
+        rows = np.zeros((2, 64), dtype=np.uint8)
+        assert rs_native.pread_rows([fd, -1], 90, rows, 64) == [10, 0]
+        for bad in [dict(fds=[fd]), dict(size=65), dict(offset=-1),
+                    dict(rows=rows.astype(np.uint16)),
+                    dict(rows=rows[:, ::2]), dict(rows=rows[0])]:
+            kw = {**dict(fds=[fd, -1], offset=0, rows=rows, size=8), **bad}
+            with pytest.raises(ValueError):
+                rs_native.pread_rows(kw["fds"], kw["offset"], kw["rows"],
+                                     kw["size"])
+        frozen = rows.copy()
+        frozen.flags.writeable = False
+        with pytest.raises(ValueError):
+            rs_native.pread_rows([fd, -1], 0, frozen, 8)
+    finally:
+        os.close(fd)
+    with pytest.raises(OSError):
+        rs_native.pread_rows([fd, -1], 0, rows, 8)   # a closed descriptor
+
+
+@pytest.mark.parametrize("library", ["native", "absent"])
+def test_a_rebuilt_interval_gathers_its_survivors_in_one_call(
+        sealed, monkeypatch, library):
+    """The mounted survivors of a rebuilt interval (k for plain RS, the
+    plan's group for LRC) are read by ONE call of the library (the
+    interpreter lock given away once, not once a shard), and without
+    the library the read is the same bytes."""
+    code, store, records = sealed
+    scheme = CODES[code]
+    calls = []
+    if library == "absent":
+        _without_the_library(monkeypatch)
+    elif not rs_native.available():
+        pytest.skip("no native library on this machine")
+    else:
+        real = rs_native.pread_rows
+
+        def counted(fds, offset, rows, size):
+            calls.append(sum(1 for fd in fds if fd >= 0))
+            return real(fds, offset, rows, size)
+        monkeypatch.setattr(rs_native, "pread_rows", counted)
+    vid, lost = 5, 2
+    ev = store.find_ec_volume(vid)
+    nid = next(n for n, (kd, _) in records.items() if kd == "64KiB"
+               and lost in {s for s, _ in _shards_of(ev, n)})
+    store.unmount_ec_shards(vid, [lost])
+    try:
+        before = store.ec_read_stats["intervals_recovered"]
+        assert store.read_ec_shard_needle(vid, nid, cookie=nid).data \
+            == records[nid][1]
+        rebuilt = store.ec_read_stats["intervals_recovered"] - before
+    finally:
+        store.mount_ec_shards("", vid, [lost])
+    assert rebuilt >= 1
+    if library == "native":
+        assert len(calls) == rebuilt
+        if type(scheme) is RSScheme:
+            assert calls == [scheme.data_shards] * rebuilt
+        else:
+            assert all(1 < n < scheme.data_shards for n in calls)

@@ -3,6 +3,7 @@ breakers, hedged degraded reads — units plus chaos e2e over a live
 in-process cluster with tools/netchaos.py fault-injecting proxies."""
 
 import socket
+import sys
 import threading
 import time
 import types
@@ -15,10 +16,11 @@ from seaweedfs_tpu.client.wdclient import MasterClient
 from seaweedfs_tpu.server.master import MasterServer
 from seaweedfs_tpu.server.volume_server import VolumeServer
 from seaweedfs_tpu.shell.commands import ShellContext
-from seaweedfs_tpu.utils import resilience
+from seaweedfs_tpu.utils import resilience, tracing
 from seaweedfs_tpu.utils.httpd import HttpServer, Response, http_call, \
     http_json
 from seaweedfs_tpu.utils.limiter import TokenBucket
+from seaweedfs_tpu.utils.metrics import Registry
 from seaweedfs_tpu.utils.resilience import (CLOSED, DEADLINE_HEADER, OPEN,
                                             CircuitBreaker, Deadline,
                                             DeadlineExceeded, PeerHealth,
@@ -265,6 +267,219 @@ def test_hedged_respects_deadline():
                  deadline=Deadline.after(0.2))
     assert out is None
     assert time.perf_counter() - t0 < 1.0
+
+
+# ---------------- hedged(): direct or pooled ----------------
+
+@pytest.fixture
+def pool_submits(monkeypatch):
+    """What hedged() hands to the hedge pool, counted."""
+    pool = resilience._get_hedge_pool()
+    submits = []
+    real = pool.submit
+
+    def submit(fn, *a, **kw):
+        submits.append(fn)
+        return real(fn, *a, **kw)
+    monkeypatch.setattr(pool, "submit", submit)
+    return submits
+
+
+def _open_unripe(ph: PeerHealth, url: str) -> None:
+    for _ in range(ph.failure_threshold):
+        ph.record(url, False)
+    assert ph.snapshot()[url]["state"] == OPEN
+    assert not ph.breaker(url).probe_ripe()
+
+
+@pytest.mark.parametrize("case,direct", [
+    ("one_candidate", True),
+    ("one_usable_of_two", True),       # the other is OPEN and not ripe
+    ("sole_holder_open", True),        # forced, and forced directly
+    ("one_candidate_deadline_argument", False),
+    ("one_candidate_deadline_ambient", False),
+    ("two_candidates", False),
+])
+def test_hedged_direct_only_with_one_candidate_and_no_deadline(
+        case, direct, pool_submits):
+    """A read with nobody to hedge to and no deadline to return at
+    stays on the caller's thread; a second candidate or any deadline
+    needs the pool's second thread and keeps it."""
+    ph = PeerHealth(failure_threshold=2, open_for=60.0)
+    seen = []
+
+    def fn(c):
+        seen.append((c, threading.get_ident(),
+                     threading.current_thread().name))
+        return c.encode()
+
+    candidates, kw, scope = ["a"], {}, deadline_scope(None)
+    if case == "one_usable_of_two":
+        _open_unripe(ph, "b")
+        candidates = ["a", "b"]
+    elif case == "sole_holder_open":
+        _open_unripe(ph, "a")
+    elif case == "one_candidate_deadline_argument":
+        kw["deadline"] = Deadline.after(5.0)
+    elif case == "one_candidate_deadline_ambient":
+        scope = deadline_scope(Deadline.after(5.0))
+    elif case == "two_candidates":
+        candidates = ["a", "b"]
+    with scope:
+        assert hedged(fn, candidates, health=ph, **kw) == b"a"
+    assert [c for c, _, _ in seen] == ["a"]
+    _, ident, name = seen[0]
+    if direct:
+        assert ident == threading.get_ident()
+        assert pool_submits == []
+        assert ph.hedge_stats() == {"direct": 1, "pooled": 0,
+                                    "probe": 0, "fired": 0}
+    else:
+        assert ident != threading.get_ident()
+        assert name.startswith("hedge")
+        assert len(pool_submits) == 1
+        assert ph.hedge_stats()["direct"] == 0
+        assert ph.hedge_stats()["pooled"] == 1
+    assert ph.snapshot()["a"]["success_total"] == 1
+
+
+@pytest.mark.parametrize("path", ["direct", "pooled"])
+def test_hedged_sole_holder_recorded_alike_on_both_paths(path,
+                                                         pool_submits):
+    """What PeerHealth learns from a one-holder read is the same on the
+    caller's thread and on the pool: a success with its latency, a
+    failure (a None or an exception) without one; failure_threshold of
+    them open the breaker, and the sole holder is still dialed."""
+    ph = PeerHealth(failure_threshold=3, open_for=60.0)
+    kw = {} if path == "direct" else {"deadline": Deadline.after(30.0)}
+    calls = []
+
+    def fn(c):
+        calls.append(threading.get_ident())
+        if mode == "raise":
+            raise ConnectionError("down")
+        if mode == "none":
+            return None
+        time.sleep(0.02)
+        return b"ok"
+
+    mode = "ok"
+    assert hedged(fn, ["only"], health=ph, **kw) == b"ok"
+    snap = ph.snapshot()["only"]
+    assert snap["success_total"] == 1 and snap["failure_total"] == 0
+    assert snap["ewma_ms"] >= 20.0       # the latency was recorded
+    for i, mode in enumerate(["raise", "none", "raise"], start=1):
+        assert hedged(fn, ["only"], health=ph, **kw) is None
+        snap = ph.snapshot()["only"]
+        assert snap["failure_total"] == i
+        assert snap["consecutive_failures"] == i
+    assert snap["state"] == OPEN and snap["opened_total"] == 1
+    # OPEN and not ripe: still dialed, and on the same path
+    mode = "ok"
+    assert hedged(fn, ["only"], health=ph, **kw) == b"ok"
+    assert ph.snapshot()["only"]["state"] == CLOSED
+    assert len(calls) == 5
+    on_caller = [t == threading.get_ident() for t in calls]
+    assert on_caller == [path == "direct"] * 5
+    assert len(pool_submits) == (0 if path == "direct" else 5)
+    assert ph.hedge_stats()[path] == 5
+
+
+@pytest.mark.parametrize("path", ["direct", "pooled"])
+def test_hedged_ambient_span_reaches_fn_and_is_annotated(path):
+    span = tracing.Tracer(node="t").root_span("read", sampled=True)
+    seen = []
+    candidates = ["a"] if path == "direct" else ["a", "b"]
+    with tracing.span_scope(span):
+        out = hedged(lambda c: seen.append(tracing.current_span()) or c,
+                     candidates, health=PeerHealth())
+    assert out == "a"
+    assert seen == [span]
+    attrs = span.to_dict()["annotations"]
+    assert attrs["hedge.winner"] == "a"
+    assert attrs["hedge.legs_fired"] == 1
+
+
+def test_hedged_without_health_goes_direct_too():
+    me = threading.get_ident()
+    assert hedged(lambda c: threading.get_ident(), ["a"]) == me
+    assert hedged(lambda c: None, ["a"]) is None
+
+
+def test_hedge_stats_count_direct_and_pooled_and_are_exported():
+    reg = Registry()
+    ph = PeerHealth(metrics=reg)
+    for _ in range(7):
+        assert hedged(lambda c: c, ["only"], health=ph) == "only"
+    assert ph.hedge_stats() == {"direct": 7, "pooled": 0,
+                                "probe": 0, "fired": 0}
+    assert hedged(lambda c: c, ["a", "b"], health=ph, delay=0.5) == "a"
+    assert ph.hedge_stats() == {"direct": 7, "pooled": 1,
+                                "probe": 0, "fired": 0}
+    # a slow primary fires its backup: still one pooled call
+    assert hedged(lambda c: time.sleep(0.2 if c == "a" else 0) or c,
+                  ["a", "b"], health=ph, delay=0.01) == "b"
+    assert ph.hedge_stats() == {"direct": 7, "pooled": 2,
+                                "probe": 0, "fired": 1}
+    text = reg.expose_text()
+    assert 'resilience_hedges_total{outcome="direct"} 7' in text
+    assert 'resilience_hedges_total{outcome="pooled"} 2' in text
+    # a client's PeerHealth has no registry: the integers still count
+    bare = PeerHealth()
+    hedged(lambda c: c, ["only"], health=bare)
+    assert bare.hedge_stats()["direct"] == 1
+
+
+def test_hedge_stats_lose_no_count_under_concurrent_readers():
+    """Sixteen readers share one client's PeerHealth: every direct call
+    is counted once (a lost update would read low)."""
+    ph = PeerHealth()
+    readers, each = 16, 400
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        def reader():
+            for _ in range(each):
+                hedged(lambda c: c, ["only"], health=ph)
+        threads = [threading.Thread(target=reader) for _ in range(readers)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60.0)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(old)
+    assert ph.hedge_stats()["direct"] == readers * each
+    assert ph.snapshot()["only"]["success_total"] == readers * each
+
+
+def test_read_data_of_one_holder_is_direct(tmp_path):
+    """operation.read_data against a live cluster with one holder (the
+    default replication 000): the GET is made on the reader's thread
+    and the client's PeerHealth counts it direct."""
+    master = MasterServer(volume_size_limit_mb=64)
+    master.start()
+    vs = VolumeServer([str(tmp_path / "v")], master.url)
+    vs.start()
+    mc = MasterClient(master.url)
+    try:
+        data = b"one holder, no deadline" * 100
+        fid = operation.upload_data(mc, data).fid
+        assert current_deadline() is None
+        assert operation.read_data(mc, fid) == data
+        assert operation.read_data(mc, fid, byte_range=(4, 9)) == data[4:10]
+        assert mc.peer_health.hedge_stats() == {
+            "direct": 2, "pooled": 0, "probe": 0, "fired": 0}
+        snap = mc.peer_health.snapshot()
+        assert [s["success_total"] for s in snap.values()] == [2]
+        # a caller with a budget keeps the pool, which can enforce it
+        with deadline_scope(Deadline.after(10.0)):
+            assert operation.read_data(mc, fid) == data
+        assert mc.peer_health.hedge_stats()["pooled"] == 1
+    finally:
+        mc.stop()
+        vs.stop()
+        master.stop()
 
 
 # ---------------- TokenBucket.peek ----------------
