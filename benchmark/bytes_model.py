@@ -19,7 +19,11 @@ from benchmark.reference import encode_rows
 def seal_min_bytes(dat_size: int, k: int, m: int, large_block: int,
                    small_block: int) -> int:
     """Bytes read plus bytes written by the parity computation of one
-    seal of a ``.dat`` of ``dat_size`` bytes."""
+    seal of a ``.dat`` of ``dat_size`` bytes.  The same formula for every
+    code family: k data columns in, m = ``parity_shards`` columns out
+    (under LRC the local and the global parities together), whatever the
+    rows' coefficients; a local parity row reads only its group, but the
+    request still has every data byte read once."""
     total = 0
     for row_off, block in encode_rows(dat_size, k, large_block,
                                       small_block):

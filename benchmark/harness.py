@@ -105,7 +105,11 @@ class Cluster:
 
     def start(self, warm_shapes: dict, volume_size_limit_mb: int,
               max_volumes: int, env: dict) -> dict:
-        """Start both servers; returns the wrapper's warm-up report."""
+        """Start both servers; returns the wrapper's warm-up report.
+        ``warm_shapes`` becomes ``warm.json``: ``encode`` and ``apply``,
+        lists of ``[B, columns]``, and ``code``, the spec string of the
+        scheme to warm them on (absent or ``""``: the server's
+        default)."""
         ports, socks = [], []
         for _ in range(2):
             s = socket.socket()

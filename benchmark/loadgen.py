@@ -2,11 +2,14 @@
 configuration asks for, and drives a window of closed-loop workers.
 
 Everything that tells one cell from another is data: a configuration
-file (``configs/<name>.json``: code geometry, whether the volumes are
-sealed, which shards are lost, extra keys of the seal request) and a
-traffic file (``traffic/<name>.json``: the operation, how many volumes and
-objects, how many workers, which shapes to warm).  A new cell is new
-files.
+file (``configs/<name>.json``: the code its files are held to, ``code``,
+with its ``family``; whether the volumes are sealed; which shards are
+lost; ``generate_body``, the extra keys of the window's seal requests, of
+which ``code`` is the code the program is asked for, in the window and in
+``prepare()``'s ``ec.encode`` alike) and a traffic file
+(``traffic/<name>.json``: the operation, how many volumes and objects,
+how many workers, which shapes to warm).  A new cell is new files: a
+sealed read of another geometry or of another code family among them.
 
 All inputs come from ``--seed``.  Every seed gives the same SET of object
 sizes and the same number of objects per volume; the bytes, the cookies
@@ -90,6 +93,15 @@ def fill(cluster: Cluster, traffic: dict, seed: int) -> Corpus:
     return corpus
 
 
+def asked_code(config: dict) -> str:
+    """The code the program is ASKED for: ``generate_body.code``, the
+    spec string of a seal request (``""``: the server's default).  What
+    the files are HELD to is the configuration's ``code`` block; the two
+    are two keys on purpose, and the controls live on their
+    disagreement."""
+    return config.get("generate_body", {}).get("code", "")
+
+
 def shard_paths(cluster: Cluster, vid: int, total: int) -> list[str]:
     return [os.path.join(cluster.voldir, f"{vid}.ec{s:02d}")
             for s in range(total)]
@@ -98,8 +110,11 @@ def shard_paths(cluster: Cluster, vid: int, total: int) -> list[str]:
 def prepare(cluster: Cluster, config: dict, corpus: Corpus) -> None:
     """Bring the volumes into the state the configuration names:
     ``volumes`` (left as they are, to be sealed over and over) or
-    ``sealed`` (``ec.encode`` through the shell, then the configuration's
-    lost shards unmounted and their files deleted, caches cleared)."""
+    ``sealed`` (``ec.encode`` through the shell under the asked code,
+    ``asked_code``; then the configuration's lost shards unmounted and
+    their files deleted, caches cleared).  How many shard files there are
+    comes from the STATED code, ``code.data_shards + code.parity_shards``:
+    9 or 16 as well as 14."""
     if config["state"] != "sealed":
         return
     from seaweedfs_tpu.shell.commands import ShellContext
@@ -108,10 +123,10 @@ def prepare(cluster: Cluster, config: dict, corpus: Corpus) -> None:
     sh.lock()
     t0 = time.monotonic()
     for vid in corpus.vids:
-        sh.ec_encode(vid=vid)
+        sh.ec_encode(vid=vid, code=asked_code(config))
     sh.unlock()
-    log(f"[prepare] ec.encode of volumes {corpus.vids}: "
-        f"{time.monotonic() - t0:.2f}s")
+    log(f"[prepare] ec.encode -code {asked_code(config)!r} of volumes "
+        f"{corpus.vids}: {time.monotonic() - t0:.2f}s")
     lost = list(config.get("lost_shards", []))
     for vid in corpus.vids:
         if lost:
@@ -190,21 +205,34 @@ SAMPLE_BYTES = 64 << 10
 def seal_workers(cluster: Cluster, config: dict, traffic: dict,
                  corpus: Corpus, seed: int, stream: int) -> list:
     """One worker per volume: ``POST /admin/ec/generate`` again and
-    again.  After each reply, outside the call's timed span and before
-    the next call starts: the 14 shard files, ``.ecx`` and ``.vif`` are
-    looked at (a stat each): every one in place, of the size the ``.dat``
-    gives, and a file other than the one the previous call left (the
+    again, each call a seal of a volume that has no shards: BEFORE a
+    call, outside its timed span (in the sealer's pause, where the
+    traffic has a period), whatever shard files the call before or the
+    warm-up left are deleted through the server (``POST
+    /admin/ec/delete_shards``, which takes ``.ecx`` and ``.vif`` with
+    the last shard) and seen to be gone.  What a call would otherwise pay
+    is the machine's disk REPLACING the ~0.75 GiB the last call left
+    (45-49% of a call on the chip machine's 9p disk, and most of the
+    cells' run-to-run spread: PERF.md section 6, PR 34), which is the
+    price of re-sealing ONE volume (the configurations'
+    ``distinct_volumes`` reduction) and not of a seal.  After each reply,
+    also outside the timed span: the shard files, ``.ecx`` and ``.vif``
+    are looked at (a stat each): every one in place, of the size the
+    ``.dat`` gives, and a file other than the one the previous call left
+    (gone before the call; where the delete left it, by its inode: the
     program writes ``.tmp`` names and renames); and ``SAMPLE_SPANS``
     spans of ``SAMPLE_BYTES`` at offsets drawn from the seed are read
-    from all 14 files and kept as one sha256 each, for the reference to
+    from all the files and kept as one sha256 each, for the reference to
     be held against once the window has closed (every call of the window
-    is compared so, not only the last).  With ``period_seconds`` in the
-    traffic file a worker starts its i-th call ``i * period`` after the
-    window opened, or when the one before is done if that is later: a
-    sealer that is given work at a rate, so that a run writes a few GiB
-    and not all the disk takes in the time.  A record is (start, end,
-    .dat bytes, error or None, stale, [(volume, offset, length, digest)],
-    seconds the call started late)."""
+    is compared so, not only the last, whose files stay).  With
+    ``period_seconds`` in the traffic file a worker starts its i-th call
+    ``i * period`` after the window opened, or when the one before is
+    done and cleared if that is later: a sealer that is given work at a
+    rate, so that a run writes a few GiB and not all the disk takes in
+    the time.  A record is (start, end, .dat bytes, error or None, stale,
+    [(volume, offset, length, digest)], seconds the call started late,
+    seconds the delete before it took, the reply's ``pipeline``: the
+    program's own account of the call, logged and read by no metric)."""
     from benchmark import reference
     code = config["code"]
     k, total = code["data_shards"], code["data_shards"] + code["parity_shards"]
@@ -255,22 +283,42 @@ def seal_workers(cluster: Cluster, config: dict, traffic: dict,
                     stale = True
             return stale
 
+        def clear() -> float:
+            """The volume as a first seal finds it: no shard files.
+            Returns the seconds that took."""
+            if not any(os.path.exists(p) for p in shards):
+                return 0.0
+            t = time.monotonic()
+            try:
+                cluster.http("POST", cluster.volume
+                             + "/admin/ec/delete_shards",
+                             {"volume_id": vid,
+                              "shard_ids": list(range(total))})
+            except Exception as e:  # noqa: BLE001 — the next look tells
+                log(f"[seal] volume {vid}: delete_shards failed: {e}")
+            for p in shards:
+                if not os.path.exists(p):
+                    last_inodes.pop(p, None)
+            return time.monotonic() - t
+
         def loop(window: Window, rec: list) -> None:
             due = window.t0
-            while due < window.t_end:
+            # a call that was cleared for is made: the files the window
+            # leaves are a call's, whole
+            while due < window.t_end and time.monotonic() < window.t_end:
+                cleared_s = clear()
                 _sleep_until(due)
                 start = time.monotonic()
-                if start >= window.t_end:
-                    return
                 late, due = start - due, due + period
                 err = None
                 stale = False
                 sampled = []
                 dat_size = os.path.getsize(base + ".dat")
+                reply = None
                 try:
-                    cluster.http("POST", cluster.volume
-                                 + "/admin/ec/generate",
-                                 {"volume_id": vid, **body_extra})
+                    reply = cluster.http("POST", cluster.volume
+                                         + "/admin/ec/generate",
+                                         {"volume_id": vid, **body_extra})
                 except Exception as e:  # noqa: BLE001 — counted as failed
                     err = f"{type(e).__name__}: {e}"
                 end = time.monotonic()
@@ -279,7 +327,7 @@ def seal_workers(cluster: Cluster, config: dict, traffic: dict,
                     if not stale:
                         sampled = sample(dat_size)
                 rec.append((start, end, dat_size, err, stale, sampled,
-                            late))
+                            late, cleared_s, (reply or {}).get("pipeline")))
             # a sealer with no turn left stays to the close: the window,
             # and a trace of it, is as long as was asked for
             _sleep_until(window.t_end)

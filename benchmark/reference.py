@@ -1,13 +1,23 @@
-"""Plain reference for RS(k, m) erasure coding over GF(2^8).
+"""Plain reference for erasure coding over GF(2^8), by code family.
 
 Independent of ``seaweedfs_tpu``: imports nothing of the program and
-takes nothing the program has made.  Everything is worked out from the
-published construction the upstream project uses (klauspost/reedsolomon
-as vendored by SeaweedFS): the field GF(2^8) with the polynomial
-x^8+x^4+x^3+x^2+1 (0x11d) and generator 2; a (k+m) x k Vandermonde
-matrix ``V[r][c] = r^c`` multiplied by the inverse of its top k x k
-square, so that the top is the identity (data shards are the data) and
-the bottom m rows are the parity matrix.
+takes nothing the program has made.  A code is the ``code`` block of a
+configuration file, and ``code_parity_matrix`` is the one way from a
+block to its matrix:
+
+- ``family`` ``"rs"`` (``data_shards`` k, ``parity_shards`` m): the
+  published construction the upstream project uses (klauspost/reedsolomon
+  as vendored by SeaweedFS): the field GF(2^8) with the polynomial
+  x^8+x^4+x^3+x^2+1 (0x11d) and generator 2; a (k+m) x k Vandermonde
+  matrix ``V[r][c] = r^c`` multiplied by the inverse of its top k x k
+  square, so that the top is the identity (data shards are the data) and
+  the bottom m rows are the parity matrix.
+- ``family`` ``"lrc"`` (``data_shards`` k, ``local_groups`` l,
+  ``global_parities`` g, ``parity_shards`` = l + g): a basic pyramid code
+  over that same matrix (``lrc_parity_matrix``).
+
+Each family is a function of its own here; a further family is a further
+function and a line in ``code_parity_matrix``.
 
 The shard layout is upstream's two-tier block interleave
 (weed/storage/erasure_coding/ec_encoder.go): while MORE than
@@ -110,10 +120,63 @@ def parity_matrix(k: int, m: int) -> list[list[int]]:
     return generator_matrix(k, m)[k:]
 
 
+def lrc_parity_matrix(k: int, l: int, g: int) -> list[list[int]]:
+    """The l + g parity rows of LRC(k, l, g), a basic pyramid code
+    (Huang, Chen, Li, "Pyramid Codes: Flexible Schemes to Trade Space for
+    Access Efficiency in Reliable Data Storage Systems", 2007; the
+    topology of k data, l local and g global fragments is that of Huang
+    et al., "Erasure Coding in Windows Azure Storage", USENIX ATC 2012).
+    Take the g + 1 parity rows of RS(k, g + 1); split row 0 into l rows,
+    the i-th keeping row 0's coefficients on the i-th group of k / l
+    columns and zero elsewhere (a group's local parity: any one of its
+    k / l + 1 members is a combination of the others); rows 1..g stay
+    as the global parities.  Shard order: data, locals, globals.
+
+    The COEFFICIENTS are this store's choice: the pyramid construction
+    says which MDS code to start from only up to its being MDS, the store
+    starts from upstream's Vandermonde-derived RS matrix, and Azure's own
+    coefficients are not published as a matrix.  A configuration that
+    cites Azure's LRC lists that under ``assumed``."""
+    if l <= 0 or k % l:
+        raise ValueError(f"LRC: {l} local groups do not divide {k} "
+                         "data shards evenly")
+    base = parity_matrix(k, g + 1)
+    size = k // l
+    locals_ = [[c if i * size <= j < (i + 1) * size else 0
+                for j, c in enumerate(base[0])] for i in range(l)]
+    return locals_ + base[1:]
+
+
+def rs_code(k: int, m: int, large_block: int = 1 << 30,
+            small_block: int = MIB) -> dict:
+    """The ``code`` block of plain RS(k, m), for a caller that has the
+    numbers and no configuration file."""
+    return {"family": "rs", "data_shards": k, "parity_shards": m,
+            "large_block_bytes": large_block,
+            "small_block_bytes": small_block}
+
+
+def code_parity_matrix(code: dict) -> list[list[int]]:
+    """From a configuration's ``code`` block to its parity matrix
+    (``parity_shards`` rows of ``data_shards`` coefficients): the one
+    place that reads ``family``."""
+    family = code.get("family", "rs")
+    k, m = code["data_shards"], code["parity_shards"]
+    if family == "rs":
+        return parity_matrix(k, m)
+    if family == "lrc":
+        l, g = code["local_groups"], code["global_parities"]
+        if m != l + g:
+            raise ValueError(f"LRC({k},{l},{g}) has {l + g} parity "
+                             f"shards; the code block states {m}")
+        return lrc_parity_matrix(k, l, g)
+    raise ValueError(f"unknown code family {family!r}")
+
+
 def recover_matrix(k: int, m: int, present: list[int],
                    missing: list[int]) -> list[list[int]]:
-    """Rows that give each ``missing`` shard from the first k of the
-    ``present`` ones."""
+    """RS(k, m): rows that give each ``missing`` shard from the first k
+    of the ``present`` ones."""
     gen = generator_matrix(k, m)
     src = sorted(present)[:k]
     inv = mat_inv([gen[s] for s in src])
@@ -176,6 +239,12 @@ def shard_file_size(dat_size: int, k: int, large_block: int,
                                          small_block))
 
 
+def _layout_of(code: dict) -> tuple[int, int, int]:
+    """What ``encode_rows`` needs of a ``code`` block."""
+    return (code["data_shards"], code["large_block_bytes"],
+            code["small_block_bytes"])
+
+
 def _read_into(f, offset: int, out: np.ndarray) -> None:
     out[:] = 0
     f.seek(offset)
@@ -184,16 +253,17 @@ def _read_into(f, offset: int, out: np.ndarray) -> None:
         out[:len(buf)] = np.frombuffer(buf, dtype=np.uint8)
 
 
-def differing_shard_files(dat_path: str, shard_paths: list[str], k: int,
-                          m: int, large_block: int, small_block: int,
-                          step: int = MIB,
-                          part: tuple[int, int] = (0, 1)) -> list[int]:
+def differing_files(dat_path: str, shard_paths: list[str], code: dict,
+                    step: int = MIB,
+                    part: tuple[int, int] = (0, 1)) -> list[int]:
     """Ids of the shard files that do not hold, byte for byte, what the
-    reference gives for ``dat_path``: a wrong size, a missing file, a
-    data shard that is not the .dat's blocks, a parity shard that is not
-    the code of them.  ``part=(i, n)`` looks at every n-th step from
-    the i-th on, so that n callers cover the file between them."""
-    pm = parity_matrix(k, m)
+    reference gives for ``dat_path`` under the ``code`` block: a wrong
+    size, a missing file, a data shard that is not the .dat's blocks, a
+    parity shard that is not the code of them.  ``part=(i, n)`` looks at
+    every n-th step from the i-th on, so that n callers cover the file
+    between them."""
+    pm = code_parity_matrix(code)
+    k, large_block, small_block = _layout_of(code)
     dat_size = os.path.getsize(dat_path)
     want_size = shard_file_size(dat_size, k, large_block, small_block)
     bad: set[int] = set()
@@ -237,14 +307,25 @@ def differing_shard_files(dat_path: str, shard_paths: list[str], k: int,
     return sorted(bad)
 
 
-def expected_spans(dat_path: str, spans: list[tuple[int, int]], k: int,
-                   m: int, large_block: int,
-                   small_block: int) -> list[bytes]:
+def differing_shard_files(dat_path: str, shard_paths: list[str], k: int,
+                          m: int, large_block: int, small_block: int,
+                          step: int = MIB,
+                          part: tuple[int, int] = (0, 1)) -> list[int]:
+    """``differing_files`` under plain RS(k, m), by its numbers."""
+    return differing_files(dat_path, shard_paths,
+                           rs_code(k, m, large_block, small_block), step,
+                           part)
+
+
+def expected_spans(dat_path: str, spans: list[tuple[int, int]],
+                   code: dict) -> list[bytes]:
     """For each (offset, length) of a SHARD file, neither crossing a
-    block's end: the k+m spans the reference gives there, one after
-    another (data blocks from the ``.dat``, zero-filled past its end, then
-    their parity).  What a seal's sampled look is compared with."""
-    pm = parity_matrix(k, m)
+    block's end: the k+m spans the reference gives there under the
+    ``code`` block, one after another (data blocks from the ``.dat``,
+    zero-filled past its end, then their parity).  What a seal's sampled
+    look is compared with."""
+    pm = code_parity_matrix(code)
+    k, large_block, small_block = _layout_of(code)
     dat_size = os.path.getsize(dat_path)
     rows = encode_rows(dat_size, k, large_block, small_block)
     out = []
@@ -267,17 +348,17 @@ def expected_spans(dat_path: str, spans: list[tuple[int, int]], k: int,
     return out
 
 
-def differing_shard_files_many(jobs: list[tuple[str, list[str]]], k: int,
-                               m: int, large_block: int, small_block: int,
+def differing_shard_files_many(jobs: list[tuple[str, list[str]]],
+                               code: dict,
                                threads: int = 4) -> list[list[int]]:
-    """``differing_shard_files`` for several volumes, each file's steps
-    dealt out to ``threads`` workers (numpy's look-ups and file reads
-    release the interpreter lock)."""
-    for c in {c for row in parity_matrix(k, m) for c in row if c > 1}:
+    """``differing_files`` for several volumes of one ``code``, each
+    file's steps dealt out to ``threads`` workers (numpy's look-ups and
+    file reads release the interpreter lock)."""
+    for c in {c for row in code_parity_matrix(code) for c in row if c > 1}:
         _pair_table(c)  # built once, before the threads share them
     with ThreadPoolExecutor(max_workers=threads) as pool:
-        futs = [[pool.submit(differing_shard_files, dat, shards, k, m,
-                             large_block, small_block, MIB, (i, threads))
+        futs = [[pool.submit(differing_files, dat, shards, code, MIB,
+                             (i, threads))
                  for i in range(threads)]
                 for dat, shards in jobs]
         return [sorted({sid for f in parts for sid in f.result()})

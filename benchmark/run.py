@@ -137,6 +137,10 @@ def snapshot(cluster: Cluster, vids: list[int], sealed: bool) -> dict:
             "GET", cluster.volume + f"/admin/ec/shard_stat?volumeId={vids[0]}"
         )["recover_stats"]
         out["recover.intervals"] = sum(rs.values())
+        # by the strategy the store took (``local``: from a lost shard's
+        # own group under LRC; ``generic``: from k survivors): logged
+        # beside the window, read by no metric
+        out.update({f"recover.by.{k}": v for k, v in rs.items()})
     return out
 
 
@@ -162,7 +166,9 @@ def seal_end_to_end(records: list[tuple], workers: int) -> dict:
     bytes of the calls that succeeded over the time all calls took,
     times the sealers.  With sealers that call back to back that is the
     bytes over the window; with a ``period_seconds`` the time a sealer
-    waits for its next turn is the generator's and is left out."""
+    waits for its next turn is the generator's and is left out, as is
+    its delete of the last call's shards before each call
+    (``loadgen.seal_workers``)."""
     took = sum(end - start for start, end, *_ in records)
     done = sum(size for _s, _e, size, err, *_ in records if err is None)
     return {"seal_mbps": done / 1e6 * workers / took}
@@ -190,20 +196,21 @@ def read_end_to_end(records: list[tuple], t0: float, t_end: float) -> dict:
 def compare_seal(cluster: Cluster, config: dict, corpus, records) -> dict:
     """Every shard file left on disk by the window's last call on each
     volume, byte for byte against the plain reference's encoding of that
-    volume's ``.dat``; and of EVERY call of the window its look at its
-    files and the spans it sampled from all 14 of them (offsets from the
-    seed), against the reference's bytes for the same spans."""
+    volume's ``.dat`` under the configuration's STATED code (its ``code``
+    block, family and all; what the seal was asked for is
+    ``generate_body``'s affair); and of EVERY call of the window its look
+    at its files and the spans it sampled from all of them (offsets from
+    the seed), against the reference's bytes for the same spans."""
     code = config["code"]
-    k, m = code["data_shards"], code["parity_shards"]
+    total = code["data_shards"] + code["parity_shards"]
     jobs = [(os.path.join(cluster.voldir, f"{vid}.dat"),
-             loadgen.shard_paths(cluster, vid, k + m))
+             loadgen.shard_paths(cluster, vid, total))
             for vid in corpus.vids]
     t0 = time.monotonic()
     bad = reference.differing_shard_files_many(
-        jobs, k, m, code["large_block_bytes"], code["small_block_bytes"],
-        threads=min(8, os.cpu_count() or 1))
+        jobs, code, threads=min(8, os.cpu_count() or 1))
     log(f"[check] reference encoding of {len(jobs)} volume(s) compared "
-        f"with {len(jobs) * (k + m)} shard files in "
+        f"with {len(jobs) * total} shard files in "
         f"{time.monotonic() - t0:.2f}s; differing: "
         f"{ {v: b for v, b in zip(corpus.vids, bad) if b} }")
     t0 = time.monotonic()
@@ -211,8 +218,7 @@ def compare_seal(cluster: Cluster, config: dict, corpus, records) -> dict:
     for vid, (dat, _shards) in zip(corpus.vids, jobs):
         spans = [sp for r in records for sp in r[5] if sp[0] == vid]
         want = reference.expected_spans(
-            dat, [(off, n) for _v, off, n, _d in spans], k, m,
-            code["large_block_bytes"], code["small_block_bytes"])
+            dat, [(off, n) for _v, off, n, _d in spans], code)
         sampled += len(spans)
         wrong += sum(1 for sp, w in zip(spans, want)
                      if hashlib.sha256(w).hexdigest() != sp[3])
@@ -301,7 +307,7 @@ def log_window(window, records: list[tuple], counters: dict,
     slow = sorted(records, key=lambda r: r[0] - r[1])[:12]
     log("[window] the longest operations (start after t0, seconds): "
         + ", ".join(f"{r[0] - window.t0:.2f}+{r[1] - r[0]:.3f}"
-                    for r in sorted(slow)))
+                    for r in sorted(slow, key=lambda r: r[0])))
     lat = sorted((r[1] - r[0]) * 1e3 for r in records if r[3] is None)
     if lat:
         log("[window] operation ms: " + ", ".join(
@@ -309,10 +315,19 @@ def log_window(window, records: list[tuple], counters: dict,
             for q in (10, 50, 75, 90, 95, 98, 99, 99.5, 99.9))
             + f", max={lat[-1]:.2f}, n={len(lat)}")
     if op == "seal":
+        calls = sorted(records, key=lambda r: r[0])
         log("[window] MB/s of each call, in order: " + " ".join(
-            f"{r[2] / 1e6 / (r[1] - r[0]):.0f}" for r in sorted(records))
-            + f"; the latest start {max(r[6] for r in records) * 1e3:.1f} "
-            "ms after it was due")
+            f"{r[2] / 1e6 / (r[1] - r[0]):.0f}" for r in calls)
+            + f"; the latest start {max(r[6] for r in calls) * 1e3:.1f} "
+            "ms after it was due; clearing the shards before each took "
+            + " ".join(f"{r[7] * 1e3:.0f}" for r in calls) + " ms")
+        # the program's own account of each call (B1: no metric yet)
+        log("[window] pipeline of each call, ms (wall read encode write "
+            "commit; batches/overlapped): " + "; ".join(
+                " ".join(f"{r[8].get(k + '_s', 0) * 1e3:.0f}" for k in
+                         ("wall", "read", "encode", "write", "commit"))
+                + f" {r[8].get('batches')}/{r[8].get('overlapped')}"
+                for r in calls if r[8]))
 
 
 def reduce_trace(cluster: Cluster, trace_dir: str, traced_s: float,
@@ -333,7 +348,8 @@ def reduce_trace(cluster: Cluster, trace_dir: str, traced_s: float,
     log(f"[trace] {extracted['xplane_bytes']} B of xplane; planes "
         f"{reduced['planes']}; busy {reduced['busy_s']:.4f}s of "
         f"{traced_s:.2f}s; {reduced['module_calls']:.0f} program runs, "
-        f"{reduced['modules_s']:.4f}s")
+        f"{reduced['modules_s']:.4f}s: " + json.dumps(
+            {n: round(t, 6) for n, t in sorted(reduced['modules'].items())}))
     return reduced
 
 
@@ -378,12 +394,15 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool,
     try:
         try:
             warm = cluster.start(
-                traffic["warm"], config["volume_size_limit_mb"],
+                {**traffic["warm"], "code": loadgen.asked_code(config)},
+                config["volume_size_limit_mb"],
                 config["servers"]["max_volumes"],
                 config["servers"].get("env", {}))
-            log(f"[servers] ready; warm-up {warm['warm_s']:.2f}s: "
-                f"{warm['programs']}; compile cache "
-                f"{warm['compile_cache_dir']}")
+            log(f"[servers] ready; warm-up {warm['warm_s']:.2f}s of "
+                f"{warm['spec']}: {warm['programs']}; compile cache "
+                f"{warm['compile_cache_dir']}"
+                + (f"; UNWARMED: {warm['unwarmed']}"
+                   if "unwarmed" in warm else ""))
             dev = check_device(cluster, cell, peaks, require_platform)
 
             corpus = loadgen.fill(cluster, traffic, seed)

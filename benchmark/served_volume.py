@@ -9,9 +9,14 @@ the CLI volume server; nothing in the program calls ``jax.profiler``.
 So this wrapper, before it hands over to the CLI,
 
 1. compiles (or loads from the persistent cache) exactly the shapes
-   ``<control-dir>/warm.json`` lists, by calling the mesh coder's two
-   batch entry points on zeros.  Their jitted functions are cached per
-   (scheme, mesh), so the server's scheduler finds them compiled;
+   ``<control-dir>/warm.json`` lists (``encode`` and ``apply``, each a
+   list of ``[B, columns]``), by calling the two batch entry points of
+   the mesh coder of the scheme that ``warm.json``'s ``code`` names (the
+   spec string the configuration asks the program for; ``""`` or absent:
+   the server's default).  Their jitted functions are cached per (scheme,
+   mesh), so the server's scheduler finds them compiled.  ``warm.done``
+   reports the programs, the ``spec`` they were of and, where the program
+   has no device coder of that scheme, ``unwarmed`` with the reason;
 2. starts a control thread that watches ``<control-dir>`` for command
    files and answers each with ``<command>.done``:
    ``trace.start`` (body: the log directory), ``trace.stop``, ``stats`` (peak bytes in use per device, and
@@ -61,34 +66,69 @@ class CompileCount:
             self.seconds += duration
 
 
+def served_coder(scheme, mesh_coder):
+    """The coder the server hands a volume of ``scheme`` under
+    ``-ecBatcher``, by the program's own seam: the facade the server
+    builds (``BatchCoder`` over an ``EcBatchScheduler``) asked
+    ``for_scheme``.  The wrapper does not know the rule; it asks."""
+    from seaweedfs_tpu.parallel.batcher import BatchCoder, EcBatchScheduler
+    scheduler = EcBatchScheduler(mesh_coder=mesh_coder)
+    try:
+        return BatchCoder(scheduler).for_scheme(scheme)
+    finally:
+        scheduler.stop()
+
+
 def warm(control_dir: str) -> None:
-    """Compile the cell's own shapes and no others."""
+    """Compile the cell's own shapes and no others, under the cell's own
+    code: ``warm.json``'s ``code`` is the spec string the configuration
+    asks the program for (``""``: the server's default), read by the
+    program's one parser, and the device coder warmed is of THAT scheme:
+    its static-matrix encode program, its apply program with its own k
+    and a rebuild matrix of its own, on zeros.
+
+    Where the server would serve the scheme from a host coder
+    (``served_coder`` reports no device: LRC on this tree) nothing is
+    warmed and the report says so under ``unwarmed``; the run goes on,
+    for the cell to fail on its counts (``mesh_dispatches_in_window``,
+    ``cpu_batches``) and not here."""
     import numpy as np
+    from seaweedfs_tpu.models.coder import code_spec_name, parse_code_spec
     from seaweedfs_tpu.ops.rs_mesh import MeshCoder
     from seaweedfs_tpu.parallel import mesh as mesh_mod
     with open(os.path.join(control_dir, "warm.json")) as f:
         shapes = json.load(f)
     t0 = time.monotonic()
     cache_dir = mesh_mod.ensure_compile_cache()
-    coder = MeshCoder()
-    k = coder.scheme.data_shards
+    scheme = parse_code_spec(shapes.get("code", ""))
+    default = MeshCoder()
+    coder = default if scheme == default.scheme else MeshCoder(scheme)
+    k = scheme.data_shards
     took = []
+    report = {"spec": code_spec_name(scheme), "compile_cache_dir": cache_dir,
+              "device": coder.device_report()}
+    served = served_coder(scheme, default)
+    device_of = getattr(served, "device_report", None)
+    if device_of is None or device_of() is None:
+        report["unwarmed"] = (f"the server serves {scheme!r} from "
+                              f"{type(served).__name__}, a host coder: the "
+                              "program has no device coder of this scheme")
+        shapes = {}
     for b, n in shapes.get("encode", []):
         t = time.monotonic()
         coder.encode_batch(np.zeros((b, k, n), dtype=np.uint8))
         took.append(["encode", b, n, round(time.monotonic() - t, 3)])
     if shapes.get("apply"):
-        present = list(range(1, coder.scheme.total_shards))
+        present = list(range(1, scheme.total_shards))
         mat = coder.rebuild_matrix(present, [0])
         for b, n in shapes["apply"]:
             t = time.monotonic()
             coder.rebuild_batch(np.zeros((b, k, n), dtype=np.uint8),
                                 [mat] * b)
             took.append(["apply", b, n, round(time.monotonic() - t, 3)])
-    _write_json(os.path.join(control_dir, "warm.done"), {
-        "warm_s": time.monotonic() - t0, "programs": took,
-        "compile_cache_dir": cache_dir,
-        "device": coder.device_report()})
+    _write_json(os.path.join(control_dir, "warm.done"),
+                {"warm_s": time.monotonic() - t0, "programs": took,
+                 **report})
 
 
 def _stats(compiles: CompileCount) -> dict:

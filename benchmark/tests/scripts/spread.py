@@ -1,9 +1,12 @@
 """The spreads of a cell's two sets, as measure_sets.sh left them under
 ``chiprun_out/<cell>/``: per metric and set the median and the distance
 between the quartiles as a share of it (``statistics.quantiles``, n=4),
-and five times the wider one, which is what the bound is set from.
+five times the wider one, which is what the bound is set from, and
+the mean of the two with each set's run farthest from its median left
+out, which is what a bound may not be under twice of.  ``--all``: every
+end-to-end quantity a run logged, not only the cell's metrics.
 
-    python3 benchmark/tests/scripts/spread.py <cell>
+    python3 benchmark/tests/scripts/spread.py <cell>[/<subdirectory>] [--all]
 """
 
 import glob
@@ -24,19 +27,35 @@ for path in sorted(glob.glob(os.path.join(out_dir, "[AB].*.out"))):
     result = json.loads(lines[-1])
     if not result["correct"]:
         print("INCORRECT", path)
-    for name, m in result["metrics"].items():
+    values = {n: m["value"] for n, m in result["metrics"].items()}
+    if "--all" in sys.argv:
+        values = result["end_to_end_of_this_run"]
+    for name, v in values.items():
         sets.setdefault(name, {}).setdefault(
-            os.path.basename(path)[0], []).append(m["value"])
+            os.path.basename(path)[0], []).append(v)
+
+
+def spread(values: list[float]) -> float:
+    q = statistics.quantiles(values, n=4)
+    return (q[2] - q[0]) / statistics.median(values)
+
+
 for name, by_set in sets.items():
-    rows = []
+    rows, trimmed = [], []
     for s in sorted(by_set):
         values = by_set[s]
-        q = statistics.quantiles(values, n=4)
         med = statistics.median(values)
-        rows.append((s, round(med, 4), round((q[2] - q[0]) / med, 4),
+        rows.append((s, round(med, 4), round(spread(values), 4),
                      [round(v, 3) for v in values]))
+        if len(values) > 4:
+            kept = list(values)
+            kept.remove(max(values, key=lambda v: abs(v - med)))
+            trimmed.append(spread(kept))
     widest = max(r[2] for r in rows)
-    print(name, "widest spread", widest, "-> bound ~", round(5 * widest, 3))
+    print(name, "widest spread", widest, "-> bound ~", round(5 * widest, 3),
+          "; without each set's farthest run",
+          [round(t, 4) for t in trimmed], "mean",
+          round(statistics.mean(trimmed), 4) if trimmed else None)
     for r in rows:
         print("   ", r)
     if len(rows) == 2:

@@ -109,7 +109,7 @@ def test_differing_shard_files(tmp_path, spoil, want):
     dat, paths = _write_volume(tmp_path, 3 * 40960 + 777, 1 << 20, 4096,
                                spoil)
     got = reference.differing_shard_files_many(
-        [(dat, paths)], 10, 4, 1 << 20, 4096, threads=3)
+        [(dat, paths)], reference.rs_code(10, 4, 1 << 20, 4096), threads=3)
     assert got == [want]
 
 
@@ -149,15 +149,98 @@ def test_percentile_is_nearest_rank_of_all_values():
 
 
 def test_seal_rate_is_bytes_over_the_time_the_calls_took():
-    rec = [(0.0, 2.0, 100e6, None, False, [], 0.0),
-           (2.5, 4.5, 100e6, None, False, [], 0.0),     # after a pause
-           (5.0, 6.0, 100e6, "boom", False, [], 0.0)]   # failed: time only
+    rec = [(0.0, 2.0, 100e6, None, False, [], 0.0, 0.0),
+           (2.5, 4.5, 100e6, None, False, [], 0.0, 0.3),  # after a pause
+           (5.0, 6.0, 100e6, "boom", False, [], 0.0, 0.3)]  # failed: time
     assert run.seal_end_to_end(rec, 1)["seal_mbps"] \
         == pytest.approx(200.0 / 5.0)
     # two sealers side by side, back to back: the bytes over the window
     rec = [(0.0, 2.0, 100e6, None, False, [], 0.0),
            (0.0, 2.0, 100e6, None, False, [], 0.0)]
     assert run.seal_end_to_end(rec, 2)["seal_mbps"] == pytest.approx(100.0)
+
+
+class _SealServer:
+    """A stand-in for the cluster a sealer talks to: ``generate`` writes
+    the nine shard files of a tiny RS(6,3) volume anew (``.tmp`` names,
+    renamed) with ``.ecx`` and ``.vif``; ``delete_shards`` removes what
+    it is told to, the index files with the last shard."""
+
+    def __init__(self, voldir, deletes=True, writes=True):
+        self.voldir, self.master, self.volume = str(voldir), "m", "v"
+        self.deletes, self.writes = deletes, writes
+        self.calls, self.found = [], []
+        with open(os.path.join(self.voldir, "1.dat"), "wb") as f:
+            f.write(bytes(6 * 4096))
+
+    def http(self, method, url, body=None):
+        op = url.rsplit("/", 1)[1]
+        base = os.path.join(self.voldir, "1")
+        shards = [f"{base}.ec{i:02d}" for i in range(9)]
+        self.calls.append(op)
+        if op == "delete_shards":
+            assert body == {"volume_id": 1, "shard_ids": list(range(9))}
+            if not self.deletes:
+                raise RuntimeError("HTTP 500")
+            for p in shards + [base + ".ecx", base + ".vif"]:
+                os.remove(p)
+        elif op == "generate":
+            self.found.append(sum(os.path.exists(p) for p in shards))
+            if self.writes or len(self.found) == 1:
+                for p in shards + [base + ".ecx", base + ".vif"]:
+                    with open(p + ".tmp", "wb") as f:
+                        f.write(bytes(4096))
+                    os.replace(p + ".tmp", p)
+        return {}
+
+
+def _seal_twice(server, seconds=0.25):
+    from benchmark import loadgen
+    corpus = loadgen.Corpus()
+    corpus.vids = [1]
+    config = {"code": reference.rs_code(6, 3, 1 << 20, 4096)}
+    traffic = {"op": "seal", "workers": 1, "period_seconds": 0.1}
+    records = []
+    for stream in (3, 4):          # a warm-up window, then the window
+        w = loadgen.Window(seconds)
+        w.run(loadgen.seal_workers(server, config, traffic, corpus, 5,
+                                   stream))
+        records.append(w.all_records())
+    return records
+
+
+def test_every_seal_of_a_window_finds_a_volume_with_no_shards(tmp_path):
+    """What the warm-up or the call before left is deleted through the
+    server before each call, outside its timed span; the last call's
+    files stay for the comparison."""
+    server = _SealServer(tmp_path)
+    warm, window = _seal_twice(server)
+    assert len(warm) == 3 and len(window) == 3
+    assert server.found == [0] * 6
+    assert server.calls == ["generate"] + ["delete_shards", "generate"] * 5
+    assert not any(r[3] or r[4] for r in warm + window)
+    assert all(len(r[5]) == 16 for r in window)
+    assert warm[0][7] == 0.0 and all(r[7] > 0 for r in warm[1:] + window)
+    assert os.path.exists(tmp_path / "1.ec08")
+
+
+def test_a_seal_over_files_that_would_not_go_is_told_by_their_inodes(tmp_path):
+    """Where the delete fails the call replaces the last call's files,
+    as every call did until PR 34: new inodes are a new seal's, the same
+    inodes are stale; and a volume cleared and not sealed is stale."""
+    server = _SealServer(tmp_path, deletes=False)
+    warm, window = _seal_twice(server)
+    assert server.found == [0] + [9] * 5
+    assert not any(r[3] or r[4] for r in warm + window)
+    # a sealer knows the inodes of its own window's calls only
+    os.makedirs(tmp_path / "b")
+    idle = _SealServer(tmp_path / "b", deletes=False, writes=False)
+    warm, window = _seal_twice(idle)
+    assert [r[4] for r in warm + window] == [False, True, True] * 2
+    os.makedirs(tmp_path / "c")
+    gone = _SealServer(tmp_path / "c", writes=False)
+    warm, window = _seal_twice(gone)
+    assert [r[4] for r in warm + window] == [False] + [True] * 5
 
 
 def test_read_latency_is_over_all_reads_and_a_failed_one_is_the_worst():
@@ -201,14 +284,15 @@ def test_every_name_in_the_manifest_has_its_file(path):
 def test_expected_spans_are_the_shard_files_bytes(tmp_path):
     dat, paths = _write_volume(tmp_path, 3 * 40960 + 777, 1 << 20, 4096)
     spans = [(0, 4096), (4096 + 512, 1024), (3 * 4096, 4096)]
-    want = reference.expected_spans(dat, spans, 10, 4, 1 << 20, 4096)
+    code = reference.rs_code(10, 4, 1 << 20, 4096)
+    want = reference.expected_spans(dat, spans, code)
     for (off, n), w in zip(spans, want):
         got = b"".join(open(p, "rb").read()[off:off + n] for p in paths)
         assert got == w
     with pytest.raises(ValueError):
-        reference.expected_spans(dat, [(4000, 200)], 10, 4, 1 << 20, 4096)
+        reference.expected_spans(dat, [(4000, 200)], code)
     with pytest.raises(ValueError):
-        reference.expected_spans(dat, [(4 * 4096, 16)], 10, 4, 1 << 20, 4096)
+        reference.expected_spans(dat, [(4 * 4096, 16)], code)
 
 
 def test_readers_return_nothing_where_there_is_nothing_to_read():
