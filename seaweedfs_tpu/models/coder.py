@@ -62,11 +62,13 @@ DEFAULT_SCHEME = RSScheme(10, 4)
 
 class LrcScheme(RSScheme):
     """LRC(k, l, g): k data shards split into l local groups, one local
-    (XOR) parity per group, g global RS parities. Shard ids are laid out
-    data-first so the RS plumbing (layout constants, .ecNN extensions,
-    ecx indexes) carries over: [0..k) data, [k..k+l) local parities
-    (group i's parity is shard k+i), [k+l..k+l+g) global parities.
-    Default LRC(10,2,2) keeps total_shards == 14 == RS(10,4)'s."""
+    parity per group (the group's part of an RS parity row, ops/lrc.py:
+    GF(256) coefficients, not ones), g global RS parities. Shard ids are
+    laid out data-first so the plumbing of any code (.ecNN extensions,
+    ecx indexes, a volume's own shard count) carries over: [0..k) data,
+    [k..k+l) local parities (group i's parity is shard k+i),
+    [k+l..k+l+g) global parities. The default LRC(10,2,2) has 14 shards
+    like RS(10,4); Azure's LRC(12,2,2) has 16."""
 
     __slots__ = ("local_groups", "global_parities")
 
@@ -168,25 +170,34 @@ def parse_code_spec(spec: str, default: RSScheme = DEFAULT_SCHEME
                     ) -> RSScheme:
     """The one parser from a request's ``code`` to a scheme: ``""`` /
     ``rs`` -> ``default`` (the server's own), ``rs-<k>-<m>`` ->
-    RSScheme(k, m), ``lrc`` -> LRC(10,2,2).  Anything else — a coder
-    registry name, a geometry no volume can carry — raises
-    CodeSpecError."""
+    RSScheme(k, m), ``lrc`` -> LRC(10,2,2), ``lrc-<k>-<l>-<g>`` ->
+    LrcScheme(k, l, g) (what ``code_spec_name`` writes).  Anything else
+    — a coder registry name, a geometry no volume can carry, groups that
+    do not divide the data shards — raises CodeSpecError."""
     spec = (spec or "").strip().lower()
     if spec in ("", "rs"):
         return default
     if spec == "lrc":
         return LrcScheme()
-    parts = spec.split("-")
-    if len(parts) == 3 and parts[0] == "rs" \
-            and parts[1].isdigit() and parts[2].isdigit():
-        k, m = int(parts[1]), int(parts[2])
-        if k > 0 and m > 0 and k + m <= MAX_VOLUME_SHARDS:
-            return RSScheme(k, m)
-        raise CodeSpecError(
-            f"code {spec!r}: a volume carries 1..{MAX_VOLUME_SHARDS} "
-            "shards, at least one of them data and one parity")
+    family, *sizes = spec.split("-")
+    if (family, len(sizes)) in (("rs", 2), ("lrc", 3)) \
+            and all(p.isdigit() for p in sizes):
+        sizes = [int(p) for p in sizes]
+        if min(sizes) <= 0 or sizes[0] + sum(sizes[1:]) > MAX_VOLUME_SHARDS:
+            raise CodeSpecError(
+                f"code {spec!r}: a volume carries 1..{MAX_VOLUME_SHARDS} "
+                "shards, at least one of them data and one of each kind "
+                "of parity")
+        if family == "rs":
+            return RSScheme(*sizes)
+        if sizes[0] % sizes[1]:
+            raise CodeSpecError(
+                f"code {spec!r}: {sizes[1]} local groups do not divide "
+                f"{sizes[0]} data shards evenly")
+        return LrcScheme(*sizes)
     raise CodeSpecError(
-        f"unknown code {spec!r}: expected '', 'rs', 'rs-<k>-<m>' or 'lrc'")
+        f"unknown code {spec!r}: expected '', 'rs', 'rs-<k>-<m>', 'lrc' "
+        "or 'lrc-<k>-<l>-<g>'")
 
 
 def host_coder(scheme: RSScheme, threaded: bool) -> "ErasureCoder":
@@ -227,8 +238,8 @@ class ErasureCoder(abc.ABC):
         itself for its own scheme, else the multi-threaded host coder of
         the scheme's family. Overridden where a coder knows better: a
         host coder keeps its own threading (CpuCoder), the batch
-        scheduler's facade keeps every plain RS geometry on its device
-        queue (BatchCoder)."""
+        scheduler's facade keeps every scheme, of whatever family, on
+        its device queue (BatchCoder)."""
         if scheme == self.scheme:
             return self
         return host_coder(scheme, threaded=True)

@@ -3,26 +3,34 @@
 Pyramid-style construction (Huang et al., "Pyramid Codes"; the Facebook
 warehouse study arXiv:1309.0186 measures why): take the systematic
 RS(k, k+g+1) generator, split its first parity row into `l` group-local
-rows (coefficients zeroed outside the group), keep the remaining `g`
-rows as global parities. Basic pyramid codes are *maximally
-recoverable*: an erasure pattern decodes iff it is information-
-theoretically decodable for the (k, l, g) topology — one erasure per
-local group absorbed by that group's parity plus up to g more anywhere
-(tests/test_lrc.py brute-forces all <=4-erasure patterns against that
-criterion).
+rows (coefficients zeroed outside the group; inside it they are the RS
+row's own GF(256) coefficients, NOT ones: a local parity is not the XOR
+of its group), keep the remaining `g` rows as global parities.  An
+erasure pattern decodes where the generator rows that are left span the
+data: one erasure per local group absorbed by that group's parity plus
+up to g more anywhere.  For LRC(10,2,2) that is every pattern the
+(k, l, g) topology can decode (tests/test_lrc.py brute-forces all
+<=4-erasure patterns against that criterion); for LRC(12,2,2) every
+loss of up to three shards and all but five of the four-shard patterns
+the topology allows (two data shards of each group: tests/
+test_lrc_device.py names them; Azure builds its own coefficients to get
+those too).
 
-Shard id layout matches RS(10,4)'s so every byte of plumbing (.ec00-
-.ec13 files, ecx indexes, layout constants) carries over: [0..k) data,
-[k..k+l) local parities, [k+l..k+l+g) globals — 14 shards total for the
-default LRC(10,2,2).
+Shard ids are laid out data-first like RS's, so every byte of plumbing
+(.ecNN files, ecx indexes, a volume's own shard count from its .vif)
+carries over: [0..k) data, [k..k+l) local parities, [k+l..k+l+g)
+globals.  The shard count is the scheme's: 14 (.ec00-.ec13) for the
+default LRC(10,2,2), 16 (.ec00-.ec15) for Azure's LRC(12,2,2).
 
 What the family buys: a single lost shard inside a group rebuilds from
-the 5 surviving group members instead of k=10 columns — half the bytes
-read per rebuilt MB — and degraded reads prefer the same 5-shard set
-(arXiv:2306.10528). plan_rebuild() returns the cheapest (sources,
-matrix) pair per failure pattern; its matrices are ordinary GF(256)
-matmuls, so encode/rebuild ride the same _gf_apply kernels (and the
-EcBatchScheduler / jax backends) as Reed-Solomon.
+the k/l surviving group members (5 for LRC(10,2,2), 6 for LRC(12,2,2))
+instead of k columns, half the bytes read per rebuilt MB, and degraded
+reads prefer the same set (arXiv:2306.10528). plan_rebuild() returns the
+cheapest (sources, matrix) pair per failure pattern; its matrices are
+ordinary GF(256) matmuls, so encode and rebuild ride the same kernels as
+Reed-Solomon: on the host _gf_apply, on the device the family's own
+programs (ops/rs_mesh.py: a static-matrix encode, apply programs k/l and
+k rows wide) behind the batch scheduler (parallel/batcher.py).
 """
 
 from __future__ import annotations
@@ -155,8 +163,8 @@ class LrcCoder(CpuCoder):
                      ) -> tuple[list[int], np.ndarray]:
         """Cheapest repair plan: (src_sids, mat) with all-zero source
         columns already dropped, so len(src_sids) IS the read cost. A
-        single shard lost inside a group plans to its 5 surviving group
-        members; anything wider falls back to a global decode."""
+        single shard lost inside a group plans to its k/l surviving
+        group members; anything wider falls back to a global decode."""
         spec: LrcScheme = self.scheme
         present = sorted(set(present) - set(missing))
         missing = sorted(missing)

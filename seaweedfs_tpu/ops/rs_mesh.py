@@ -1,4 +1,4 @@
-"""Mesh-sharded Reed-Solomon coder: a BATCH of block-groups per dispatch.
+"""Mesh-sharded erasure coder: a BATCH of block-groups per dispatch.
 
 The single-volume coders (rs_cpu / rs_jax) encode one (k, n) block-group
 per call, so concurrent ``ec.encode`` pipelines and repair jobs serialize
@@ -9,17 +9,23 @@ mesh (parallel/mesh.batch_mesh): device d computes lanes
 [d*B/n .. (d+1)*B/n) with no collectives, so throughput scales with
 device count for batches that fill the mesh.
 
-Two compiled programs PER GEOMETRY cover every operation, and carry the
-geometry in their names (``jit_ec_encode_rs_6_3``, ``jit_ec_apply_rs_10_4``
-in a device trace):
+Two kinds of compiled program PER SCHEME cover every operation, and
+carry the scheme in their names (``jit_ec_encode_rs_6_3``,
+``jit_ec_apply_rs_10_4``, ``jit_ec_encode_lrc_12_2_2`` in a device trace):
 
-  - encode: the scheme's static parity matrix unrolls at trace time into
-    the same Horner/XOR graph as rs_jax (bit-identical by construction);
-  - rebuild: the coefficient matrix arrives as a TRACED (B, m, k) operand
+  - encode: the scheme's static parity matrix (plain RS's, or a code
+    family's own: LRC's local rows read k / l columns of k, and the
+    program's cost follows the set bits) unrolls at trace time into the
+    same Horner/XOR graph as rs_jax (bit-identical by construction);
+  - rebuild: the coefficient matrix arrives as a TRACED (B, m, w) operand
     (zero rows disabled), so one program serves every survivor pattern in
-    the batch — jobs with different loss patterns ride one dispatch.
+    the batch — jobs with different loss patterns ride one dispatch.  w
+    is the width of the operand, as many rows as the jobs' matrices READ:
+    k for plain RS; a family that repairs from a local group (LRC) has an
+    apply program at the group's width too (``apply_widths``), and a
+    dispatch rides the narrowest that holds what it reads.
 
-A MeshCoder is of ONE scheme; the batch scheduler keeps one per geometry
+A MeshCoder is of ONE scheme; the batch scheduler keeps one per scheme
 it has seen, all on the same device mesh.
 
 Batches are zero-padded on the leading axis to a power-of-two multiple
@@ -42,8 +48,7 @@ from jax.sharding import Mesh
 
 from seaweedfs_tpu.models.coder import (DEFAULT_SCHEME, ErasureCoder,
                                         RSScheme, code_spec_name,
-                                        register_coder)
-from seaweedfs_tpu.ops import gf256
+                                        host_coder, register_coder)
 from seaweedfs_tpu.ops.rs_jax import (_apply_matrix_words, _gf_mul_dynamic,
                                       _mat_to_tuple)
 from seaweedfs_tpu.parallel import mesh as mesh_mod
@@ -54,22 +59,34 @@ STAGES = ("pad", "launch", "fetch", "unpack")
 
 
 def _named(fn, stem: str, scheme: RSScheme):
-    """Give a program its name in a device trace: ``jit_<stem>_rs_<k>_<m>``.
-    The benchmark's metrics select by the prefixes ``jit_ec_encode`` /
-    ``jit_ec_apply``; the geometry after them tells two codes' programs
-    apart (the name is part of the compile-cache key too)."""
+    """Give a program its name in a device trace: ``jit_<stem>_<spec>``,
+    the scheme's spec string with underscores (``jit_ec_encode_rs_6_3``,
+    ``jit_ec_apply_lrc_12_2_2``).  The benchmark's metrics select by the
+    prefixes ``jit_ec_encode`` / ``jit_ec_apply``; the scheme after them
+    tells two codes' programs apart (the name is part of the
+    compile-cache key too)."""
     fn.__name__ = fn.__qualname__ = \
-        f"{stem}_rs_{scheme.data_shards}_{scheme.parity_shards}"
+        f"{stem}_{code_spec_name(scheme).replace('-', '_')}"
     return fn
+
+
+def apply_widths(scheme: RSScheme) -> tuple[int, ...]:
+    """The operand widths (rows a lane reads) the scheme has an apply
+    program for, ascending: k, and before it k / l where the family
+    repairs a lost shard from the others of its local group (k / l - 1
+    data shards and the group's parity)."""
+    k = scheme.data_shards
+    group = getattr(scheme, "group_size", k)
+    return (group, k) if group < k else (k,)
 
 
 @functools.lru_cache(maxsize=None)
 def batch_encode_fn(scheme: RSScheme, mesh: Mesh):
     """jit over the mesh: (B, k, nw) uint32 sharded P('batch', None, None)
-    -> (B, m, nw) parity with matching sharding.  The scheme's static
-    parity matrix, no collectives."""
-    mat = _mat_to_tuple(gf256.parity_matrix(scheme.data_shards,
-                                            scheme.parity_shards))
+    -> (B, m, nw) parity with matching sharding.  The static parity
+    matrix of the scheme's own family (its host coder's), no
+    collectives."""
+    mat = _mat_to_tuple(host_coder(scheme, threaded=False)._parity)
 
     def ec_encode(words):
         return _apply_matrix_words(words, mat)
@@ -79,13 +96,24 @@ def batch_encode_fn(scheme: RSScheme, mesh: Mesh):
                    in_shardings=(s3,), out_shardings=s3)
 
 
-@functools.lru_cache(maxsize=None)
-def batch_apply_fn(scheme: RSScheme, mesh: Mesh):
+def batch_apply_fn(scheme: RSScheme, mesh: Mesh,
+                   width: Optional[int] = None):
     """jit over the mesh: per-lane GF matrix application with TRACED
-    coefficients — (B, k, nw) words x (B, m, k) coeff -> (B, m, nw), m the
-    scheme's parity count (the most rows a rebuild can ask for).  Zero
-    coefficient rows yield zero output rows, so one compiled program
-    serves every (survivor pattern, missing set) mix in a batch."""
+    coefficients — (B, width, nw) words x (B, m, width) coeff -> (B, m,
+    nw), m the scheme's parity count (the most rows a rebuild can ask
+    for), ``width`` one of ``apply_widths(scheme)`` (k when not given).
+    One jitted function per (scheme, mesh, width)."""
+    return _apply_fn(scheme, mesh, width or scheme.data_shards)
+
+
+@functools.lru_cache(maxsize=None)
+def _apply_fn(scheme: RSScheme, mesh: Mesh, width: int):
+    """batch_apply_fn's program of one width.  Zero coefficient
+    rows yield zero output rows, so one compiled program serves every
+    (survivor pattern, missing set) mix in a batch.  A scheme's programs
+    of two widths share their name: the operand's shape tells them apart
+    (the stages' ``rows`` attribute, ``MeshCoder.programs``)."""
+    assert width in apply_widths(scheme), (width, scheme)
     n_out = scheme.parity_shards
 
     def ec_apply(words, coeff):
@@ -114,9 +142,13 @@ class MeshCoder(ErasureCoder):
         super().__init__(scheme)
         self.spec = code_spec_name(scheme)  # the stages' ``spec`` attribute
         self.mesh = mesh if mesh is not None else mesh_mod.batch_mesh(n_devices)
-        # host-side helper for rebuild-matrix derivation (pure numpy)
-        from seaweedfs_tpu.ops.rs_cpu import CpuCoder
-        self._host = CpuCoder(scheme)
+        # the scheme's own host coder, for rebuild matrices and plans
+        # only (pure numpy); a family that plans its sources (LRC) hands
+        # its plans through
+        self._host = host_coder(scheme, threaded=False)
+        if hasattr(self._host, "plan_rebuild"):
+            self.plan_rebuild = self._host.plan_rebuild
+        self.apply_widths = apply_widths(scheme)
         # distinct (kind, padded operand shape) THIS coder dispatched, all
         # of its one scheme; the batcher's stats() reports the count over
         # its coders as programs_compiled, and per scheme under by_spec.
@@ -147,12 +179,18 @@ class MeshCoder(ErasureCoder):
         self.stage_s[key] += st.elapsed
         self.stage_n[key] += 1
 
+    def _note(self, st: tracing.stage, rows: int) -> None:
+        """A stage's attributes: the scheme and the operand's width."""
+        st.annotate("spec", self.spec)
+        st.annotate("rows", rows)
+
     def _fetch(self, kind: str, fn, *operands) -> np.ndarray:
         """Dispatch, note the program's shape and where the output's
         shards lived, and bring the result to the host."""
         self.programs.add((kind,) + operands[0].shape)
+        rows = operands[0].shape[1]
         with tracing.stage("ec.mesh.launch") as st:
-            st.annotate("spec", self.spec)
+            self._note(st, rows)
             # host -> device copies and the enqueue; returns before the
             # device is done
             out = fn(*operands)
@@ -161,7 +199,7 @@ class MeshCoder(ErasureCoder):
                 self.output_spread.get(spread, 0) + 1
         self._staged("launch", st)
         with tracing.stage("ec.mesh.fetch") as st:
-            st.annotate("spec", self.spec)
+            self._note(st, rows)
             # waits for the device, then device -> host
             got = np.asarray(jax.device_get(out))
         self._staged("fetch", st)
@@ -186,45 +224,74 @@ class MeshCoder(ErasureCoder):
         assert k == self.scheme.data_shards, (k, self.scheme)
         assert n % 4 == 0, n
         with tracing.stage("ec.mesh.pad") as st:
-            st.annotate("spec", self.spec)
+            self._note(st, k)
             words = self._pad_batch(
                 np.ascontiguousarray(batch).view(np.uint32))
             fn = batch_encode_fn(self.scheme, self.mesh)
         self._staged("pad", st)
         out = self._fetch("encode", fn, words)
         with tracing.stage("ec.mesh.unpack") as st:
-            st.annotate("spec", self.spec)
+            self._note(st, k)
             parity = np.ascontiguousarray(out[:B]).view(np.uint8)
         self._staged("unpack", st)
         return parity
 
+    def _fit_width(self, srcdata: np.ndarray, coeff: np.ndarray
+                   ) -> tuple[np.ndarray, np.ndarray]:
+        """Bring a dispatch's operand (B, w, n) and coefficients (B, m, w)
+        to the narrowest of the scheme's apply widths that holds what the
+        jobs READ.  As it comes where w is that width already (every job
+        of the served path: plain RS at k, a local repair at k / l);
+        operand rows whose coefficients are zero in every job are dropped
+        where that reaches a narrower program (a local repair written
+        over k rows), and zero rows added where w is between widths."""
+        w = srcdata.shape[1]
+        widths = self.apply_widths
+        if w == widths[0]:
+            return srcdata, coeff
+        read = np.flatnonzero(coeff.any(axis=(0, 1)))
+        width = next(x for x in widths if x >= len(read))  # w <= k
+        if width == w:
+            return srcdata, coeff
+        if len(read) < w:
+            srcdata, coeff = srcdata[:, read], coeff[:, :, read]
+        fit = np.zeros((srcdata.shape[0], width, srcdata.shape[2]),
+                       dtype=srcdata.dtype)
+        fit[:, :srcdata.shape[1]] = srcdata
+        fit_coeff = np.zeros(coeff.shape[:2] + (width,), dtype=coeff.dtype)
+        fit_coeff[:, :, :coeff.shape[2]] = coeff
+        return fit, fit_coeff
+
     def rebuild_batch(self, srcdata: np.ndarray,
                       mats: Sequence[np.ndarray]) -> list[np.ndarray]:
-        """srcdata: (B, k, n) uint8 — per job, rows of the first k
-        present shards.  mats[i]: (r_i, k) uint8 rebuild matrix (from
-        rebuild_matrix(); r_i <= parity_shards).  Returns a list of
-        (r_i, n) uint8 recovered rows, one per job, in one sharded
-        dispatch even when jobs lost different shards."""
-        B, k, n = srcdata.shape
-        assert k == self.scheme.data_shards and n % 4 == 0
+        """srcdata: (B, w, n) uint8 — per job, the rows its matrix reads
+        (for plain RS the first k present shards; w <= k).  mats[i]:
+        (r_i, w) uint8 rebuild matrix (from rebuild_matrix() or a plan;
+        r_i <= parity_shards).  Returns a list of (r_i, n) uint8
+        recovered rows, one per job, in one sharded dispatch even when
+        jobs lost different shards."""
+        B, w, n = srcdata.shape
+        assert w <= self.scheme.data_shards and n % 4 == 0
         assert len(mats) == B
         m = self.scheme.parity_shards
         with tracing.stage("ec.mesh.pad") as st:
-            st.annotate("spec", self.spec)
-            coeff = np.zeros((B, m, k), dtype=np.uint32)
+            coeff = np.zeros((B, m, w), dtype=np.uint32)
             for i, mt in enumerate(mats):
                 mt = np.asarray(mt)
-                assert mt.shape == (mt.shape[0], k) \
+                assert mt.shape == (mt.shape[0], w) \
                     and mt.shape[0] <= m, mt.shape
                 coeff[i, :mt.shape[0]] = mt.astype(np.uint32)
+            srcdata, coeff = self._fit_width(srcdata, coeff)
+            width = srcdata.shape[1]
+            self._note(st, width)
             words = self._pad_batch(
                 np.ascontiguousarray(srcdata).view(np.uint32))
             coeff = self._pad_batch(coeff)
-            fn = batch_apply_fn(self.scheme, self.mesh)
+            fn = batch_apply_fn(self.scheme, self.mesh, width)
         self._staged("pad", st)
         out = self._fetch("apply", fn, words, coeff)  # (pb, m, nw)
         with tracing.stage("ec.mesh.unpack") as st:
-            st.annotate("spec", self.spec)
+            self._note(st, width)
             out8 = np.ascontiguousarray(out[:B]).view(np.uint8)  # (B,m,n)
             recs = [np.ascontiguousarray(
                 out8[i, :np.asarray(mats[i]).shape[0]]) for i in range(B)]
@@ -253,10 +320,12 @@ class MeshCoder(ErasureCoder):
                        missing: Sequence[int]) -> np.ndarray:
         return self._host.rebuild_matrix(present, missing)
 
-    def job_rows(self, n: int) -> np.ndarray:
-        """A zeroed (k, n rounded up to the uint32 lanes) operand for
-        reconstruct_rows: a caller fills rows[:, :n] in place."""
-        return np.zeros((self.scheme.data_shards, n + (-n) % 4),
+    def job_rows(self, n: int, rows: Optional[int] = None) -> np.ndarray:
+        """A zeroed (rows, n rounded up to the uint32 lanes) operand for
+        reconstruct_rows: a caller fills rows[:, :n] in place.  ``rows``
+        is as many as the job's matrix reads (a plan's sources); k when
+        not given."""
+        return np.zeros((rows or self.scheme.data_shards, n + (-n) % 4),
                         dtype=np.uint8)
 
     def reconstruct_rows(self, srcdata: np.ndarray,
@@ -270,45 +339,40 @@ class MeshCoder(ErasureCoder):
             return out
         return rec
 
-    def reconstruct(self, shards: Sequence[Optional[bytes]]) -> list[bytes]:
+    def _rebuild_from(self, shards: Sequence[Optional[bytes]],
+                      missing: Sequence[int]) -> list[Optional[bytes]]:
+        """``shards`` with the ``missing`` ones filled in, in one
+        dispatch: from the sources the family's plan names where it
+        plans (LRC: the first k present can be rank-deficient), else
+        from the first k present shards, whose rebuild matrix expresses
+        data AND parity losses directly."""
         k, total = self.scheme.data_shards, self.scheme.total_shards
         present = [i for i in range(total) if shards[i] is not None]
-        if len(present) < k:
-            raise ValueError(f"too few shards: {len(present)} < {k}")
-        missing = [i for i in range(total) if shards[i] is None]
-        if not missing:
-            return [bytes(s) for s in shards]
-        n = len(shards[present[0]])
-        pad = (-n) % 4
-        src = np.zeros((k, n + pad), dtype=np.uint8)
-        for r, i in enumerate(sorted(present)[:k]):
-            src[r, :n] = np.frombuffer(bytes(shards[i]), dtype=np.uint8)
-        # rebuild_matrix expresses data AND parity losses directly as
-        # combinations of the first k present shards — one dispatch
-        mat = self.rebuild_matrix(present, missing)
-        rec = self.rebuild_batch(src[None], [mat])[0]
         out = [bytes(s) if s is not None else None for s in shards]
+        if not missing:
+            return out
+        plan = getattr(self, "plan_rebuild", None)
+        if plan is not None:
+            sids, mat = plan(present, missing)
+        elif len(present) < k:
+            raise ValueError(f"too few shards: {len(present)} < {k}")
+        else:
+            sids, mat = present[:k], self.rebuild_matrix(present, missing)
+        n = len(shards[present[0]])
+        src = self.job_rows(n, len(sids))
+        for r, i in enumerate(sids):
+            src[r, :n] = np.frombuffer(bytes(shards[i]), dtype=np.uint8)
+        rec = self.rebuild_batch(src[None], [mat])[0]
         for r, i in enumerate(missing):
             out[i] = rec[r, :n].tobytes()
-        return [bytes(s) for s in out]
+        return out
+
+    def reconstruct(self, shards: Sequence[Optional[bytes]]) -> list[bytes]:
+        return self._rebuild_from(
+            shards, [i for i, s in enumerate(shards) if s is None])
 
     def reconstruct_data(self, shards: Sequence[Optional[bytes]]
                          ) -> list[Optional[bytes]]:
-        k, total = self.scheme.data_shards, self.scheme.total_shards
-        present = [i for i in range(total) if shards[i] is not None]
-        if len(present) < k:
-            raise ValueError(f"too few shards: {len(present)} < {k}")
-        missing_data = [i for i in range(k) if shards[i] is None]
-        out = [bytes(s) if s is not None else None for s in shards]
-        if not missing_data:
-            return out
-        n = len(shards[present[0]])
-        pad = (-n) % 4
-        src = np.zeros((k, n + pad), dtype=np.uint8)
-        for r, i in enumerate(sorted(present)[:k]):
-            src[r, :n] = np.frombuffer(bytes(shards[i]), dtype=np.uint8)
-        mat = self.rebuild_matrix(present, missing_data)
-        rec = self.rebuild_batch(src[None], [mat])[0]
-        for r, i in enumerate(missing_data):
-            out[i] = rec[r, :n].tobytes()
-        return out
+        return self._rebuild_from(
+            shards, [i for i in range(self.scheme.data_shards)
+                     if shards[i] is None])

@@ -38,14 +38,19 @@ Scheduling contract:
     (MeshCoder._pad_batch) and one dispatch carries at most
     MAX_DISPATCH_COLUMNS columns, so a degraded read of a new needle
     size reuses a compiled program instead of compiling its own;
-  - a job carries its code GEOMETRY (the RS (k, m) whose programs run
-    it: a volume's own scheme, read from its .vif by the store).  Jobs
-    of one geometry coalesce, jobs of two never share a dispatch, and
-    the scheduler keeps one mesh coder (on the one device mesh) and one
+  - a job carries its code GEOMETRY (the scheme whose programs run it:
+    a volume's own, plain RS (k, m) or another family's such as
+    LRC(12,2,2), read from its .vif by the store).  Jobs of one
+    geometry coalesce, jobs of two never share a dispatch, and the
+    scheduler keeps one mesh coder (on the one device mesh) and one
     host fallback per geometry it has seen; ``by_spec`` counts each,
     ``by_rung`` each job width (which rung of the ladder the traffic
     rides, and how full its dispatches get) and ``cap_splits`` the groups
-    that MAX_DISPATCH_COLUMNS cut into more than one dispatch.
+    that MAX_DISPATCH_COLUMNS cut into more than one dispatch;
+  - a rebuild job carries as many ROWS as its matrix reads (k for plain
+    RS; a family's local repair the k / l survivors of a group), and a
+    dispatch groups jobs of one row count: a job of 6 rows and one of 12
+    never share one (``by_spec[spec]["rows"]`` splits the jobs by it).
 
 Where the time goes is counted always and traced when sampled: every
 stage a job passes through (``STAGES``; utils/tracing.stage) adds its
@@ -69,7 +74,8 @@ from typing import Callable, Optional, Sequence
 import numpy as np
 
 from seaweedfs_tpu.models.coder import (DEFAULT_SCHEME, ErasureCoder,
-                                        RSScheme, code_spec_name)
+                                        RSScheme, code_spec_name,
+                                        host_coder)
 from seaweedfs_tpu.qos import CLASSES, current_class
 from seaweedfs_tpu.utils import clockctl, glog, profiler, tracing
 from seaweedfs_tpu.utils.metrics import RED_BUCKETS, Histogram
@@ -158,8 +164,9 @@ class _Job:
                  span, submitted: float):
         self.scheme = scheme      # the geometry whose programs run it
         self.kind = kind          # "encode" | "rebuild"
-        self.data = data          # (k, bucket_columns(n)) uint8
-        self.mat = mat            # rebuild only: (r, k) uint8
+        self.data = data          # (rows, bucket_columns(n)) uint8:
+        #                           k rows, or as many as ``mat`` reads
+        self.mat = mat            # rebuild only: (r, rows) uint8
         self.n = n                # original column count pre-padding
         self.cls = cls
         # the submitter's ambient span when it is sampled (else None),
@@ -176,9 +183,10 @@ class _Job:
 class _Geometry:
     """What the scheduler keeps per code geometry it has seen: the mesh
     coder and the host fallback of that scheme (each built when first
-    needed, by the dispatcher thread) and its counters (``by_spec``)."""
+    needed, by the dispatcher thread) and its counters (``by_spec``;
+    ``rows``: its jobs by the rows their operand carries)."""
 
-    __slots__ = ("scheme", "spec", "mesh", "cpu", "counters")
+    __slots__ = ("scheme", "spec", "mesh", "cpu", "counters", "rows")
 
     def __init__(self, scheme: RSScheme, mesh=None,
                  cpu: Optional[ErasureCoder] = None):
@@ -189,6 +197,7 @@ class _Geometry:
         self.counters = dict.fromkeys(
             ("jobs", "mesh_dispatches", "cpu_dispatches", "bytes_in",
              "bytes_out"), 0)
+        self.rows: dict[int, int] = {}
 
 
 class _CallerCells:
@@ -333,6 +342,7 @@ class EcBatchScheduler:
         with tracing.stage("ec.batch.submit") as st:
             if st.span is not None:
                 st.annotate("spec", code_spec_name(scheme))
+                st.annotate("rows", data.shape[0])
             data = np.ascontiguousarray(data, dtype=np.uint8)
             n = data.shape[1]
             pad = bucket_columns(n) - n
@@ -358,14 +368,14 @@ class EcBatchScheduler:
                       cls: Optional[str] = None,
                       mat: Optional[np.ndarray] = None,
                       scheme: Optional[RSScheme] = None) -> Future:
-        """(k, n) uint8 -> Future of (m, n) uint8 parity: the RS parity
-        of ``scheme`` (the scheduler's own when None), by that geometry's
-        static-matrix program.  Pass ``mat`` — an (m, k) GF(256) parity
-        matrix, e.g. an LrcCoder's — to encode under another code family
-        of the same (k, m): matrix-carrying encodes ride the per-job-
-        matrix path of rebuilds (parity IS mat @ data over GF(256)), so
-        one dispatch can mix RS rebuilds and LRC encodes and every
-        future demuxes exactly its own rows."""
+        """(k, n) uint8 -> Future of (m, n) uint8 parity: the parity of
+        ``scheme`` (the scheduler's own when None; plain RS or a family
+        such as LrcScheme), by that scheme's static-matrix program.
+        Pass ``mat`` — an (m, k) GF(256) matrix — to apply a matrix of
+        the caller's own instead: matrix-carrying encodes ride the
+        per-job-matrix path of rebuilds (parity IS mat @ data over
+        GF(256)), beside the scheme's rebuilds, and every future demuxes
+        exactly its own rows."""
         return self._encode_job(data, cls, mat, scheme).future
 
     def _encode_job(self, data: np.ndarray, cls: Optional[str],
@@ -380,8 +390,9 @@ class EcBatchScheduler:
     def submit_rebuild(self, srcdata: np.ndarray, rebuild_mat: np.ndarray,
                        cls: Optional[str] = None,
                        scheme: Optional[RSScheme] = None) -> Future:
-        """(k, n) rows of the first k present shards + (r, k) rebuild
-        matrix -> Future of (r, n) recovered rows."""
+        """(rows, n) survivors + (r, rows) rebuild matrix -> Future of
+        (r, n) recovered rows: for plain RS the first k present shards,
+        for a family's plan the sources it reads (rows <= k)."""
         return self._submit("rebuild", srcdata,
                             np.ascontiguousarray(rebuild_mat,
                                                  dtype=np.uint8), cls,
@@ -470,8 +481,10 @@ class EcBatchScheduler:
             if j.span is not None:
                 j.span.record("ec.batch.wait", j.submitted, now)
             bk = self.by_kind[j.kind]
-            bs = self._geometry(j.scheme).counters
+            g = self._geometry(j.scheme)
+            bs = g.counters
             rows = j.data.shape[0]
+            g.rows[rows] = g.rows.get(rows, 0) + 1
             rows_out = j.scheme.parity_shards if j.mat is None \
                 else j.mat.shape[0]
             bk["jobs"] += 1
@@ -514,12 +527,13 @@ class EcBatchScheduler:
         return g
 
     def _mesh_compatible(self, jobs: list) -> bool:
-        # a geometry's programs are traced for (k, <=m)-shaped work; an
-        # LRC group-local rebuild reads fewer than k sources, and that
-        # is a routing decision, not a mesh failure — send it to the CPU
-        # coder without benching the mesh
+        # a geometry's programs are traced for (<=k, <=m)-shaped work
+        # (the mesh coder fits an operand of fewer rows to its apply
+        # widths); anything else is a routing decision, not a mesh
+        # failure — send it to the CPU coder without benching the mesh
         j = jobs[0]  # groups share scheme and data.shape by construction
-        if j.data.shape[0] != j.scheme.data_shards:
+        rows, k = j.data.shape[0], j.scheme.data_shards
+        if rows > k or (j.kind == "encode" and rows != k):
             return False
         return all(jj.mat is None
                    or jj.mat.shape[0] <= j.scheme.parity_shards
@@ -605,6 +619,7 @@ class EcBatchScheduler:
                     disp.annotate("spec", spec)
                     disp.annotate("kind", kind)
                     disp.annotate("shape", list(stacked.shape))
+                    disp.annotate("rows", stacked.shape[1])
         finally:
             tracing.detach(tok)
         if lead is None:
@@ -616,15 +631,15 @@ class EcBatchScheduler:
             if j.span is not None and j.span is not lead:
                 j.span.record("ec.batch.dispatch", disp.t0, t1,
                               {"dispatch_id": disp.span.span_id,
-                               "jobs": len(jobs), "spec": spec})
+                               "jobs": len(jobs), "spec": spec,
+                               "rows": jobs[0].data.shape[0]})
 
     def _run_cpu(self, jobs: list) -> None:
         for j in jobs:
             try:
                 g = self._geometry(j.scheme)
                 if g.cpu is None:
-                    from seaweedfs_tpu.ops.rs_cpu import CpuCoderMT
-                    g.cpu = CpuCoderMT(g.scheme)
+                    g.cpu = host_coder(g.scheme, threaded=True)
                 cpu = g.cpu
                 if j.kind == "encode":
                     out = np.asarray(cpu.encode_array(j.data))
@@ -679,8 +694,9 @@ class EcBatchScheduler:
             if mine is not None:
                 programs = (programs or 0) + len(mine)
             if g.counters["jobs"]:
-                by_spec[g.spec] = {**g.counters,
-                                   "programs": len(mine or ())}
+                by_spec[g.spec] = {
+                    **g.counters, "programs": len(mine or ()),
+                    "rows": {str(r): n for r, n in sorted(g.rows.items())}}
         callers = self._callers.totals()
         for i, name in enumerate(CALLER_STAGES):
             stage_s[name], stage_n[name] = callers[2 * i:2 * i + 2]
@@ -761,13 +777,13 @@ class BatchCoder(ErasureCoder):
     on the queue while the dispatcher has the one before it.
 
     A scheduler serves as many facades as there are schemes among the
-    store's volumes (``for_scheme``).  A plain RS scheme of any (k, m)
-    submits under its own geometry: encodes run that geometry's static-
-    matrix program, rebuilds its apply program.  A scheme of another
-    code FAMILY (LrcScheme) derives its matrices on its family's host
-    coder and its jobs carry them: they ride the apply program of the RS
-    geometry with the same (k, m), beside that geometry's rebuilds, and
-    each future demuxes bit-identical per-job rows."""
+    store's volumes (``for_scheme``).  A scheme submits under ITSELF as
+    the queue's key, plain RS of any (k, m) and another code FAMILY
+    (LrcScheme) alike: encodes run the scheme's own static-matrix
+    program, rebuilds its apply program.  Rebuild matrices (and, for a
+    family that plans its sources, the plan: ``plan_rebuild``, the
+    sources a repair reads and its matrix over them) come from the
+    scheme's host coder; a planned job carries only the rows it reads."""
 
     def __init__(self, scheduler: EcBatchScheduler,
                  scheme: Optional[RSScheme] = None):
@@ -775,29 +791,21 @@ class BatchCoder(ErasureCoder):
             scheme = scheduler.scheme
         super().__init__(scheme)
         self.scheduler = scheduler
-        from seaweedfs_tpu.ops.rs_cpu import CpuCoder
-        # the RS geometry whose queue and programs the jobs ride, and the
-        # scheme's own host coder, for matrix derivation only
-        self._geometry = RSScheme(scheme.data_shards, scheme.parity_shards)
-        self._host = CpuCoder(self._geometry).for_scheme(scheme)
-        # None: the geometry's static parity program encodes; another
-        # family's jobs carry their parity matrix to the apply program
-        self._encode_mat = None if scheme == self._geometry \
-            else np.ascontiguousarray(self._host._parity)
+        # the scheme's own host coder, for matrix derivation only
+        self._host = host_coder(scheme, threaded=False)
+        if hasattr(self._host, "plan_rebuild"):
+            self.plan_rebuild = self._host.plan_rebuild
         # per calling thread: ``taken`` of the job its last encode_begin
         # submitted (the event alone: the job holds its buffers)
         self._begun = threading.local()
 
     def for_scheme(self, scheme: RSScheme) -> ErasureCoder:
-        """A facade over the SAME scheduler for a plain RS scheme (every
-        RS geometry submits to the one device queue); for another code
-        family (LRC) the family's host coder: its seals stay off the
-        device."""
+        """A facade over the SAME scheduler, whatever the scheme's
+        family: every volume of the store submits to the one device
+        queue, under its own scheme."""
         if scheme == self.scheme:
             return self
-        if type(scheme) is RSScheme:
-            return BatchCoder(self.scheduler, scheme)
-        return super().for_scheme(scheme)
+        return BatchCoder(self.scheduler, scheme)
 
     def device_report(self) -> Optional[dict]:
         return self.scheduler.device
@@ -817,12 +825,12 @@ class BatchCoder(ErasureCoder):
     def _encode(self, data: np.ndarray,
                 out: Optional[np.ndarray] = None) -> np.ndarray:
         return self._result(self.scheduler.submit_encode(
-            data, mat=self._encode_mat, scheme=self._geometry), out)
+            data, scheme=self.scheme), out)
 
     def _rebuild(self, src: np.ndarray, mat: np.ndarray,
                  out: Optional[np.ndarray] = None) -> np.ndarray:
         return self._result(self.scheduler.submit_rebuild(
-            src, mat, scheme=self._geometry), out)
+            src, mat, scheme=self.scheme), out)
 
     def encode_array(self, data: np.ndarray) -> np.ndarray:
         return self._encode(data)
@@ -842,8 +850,7 @@ class BatchCoder(ErasureCoder):
         last = getattr(self._begun, "taken", None)
         if last is not None:
             last.wait()
-        job = self.scheduler._encode_job(data, None, self._encode_mat,
-                                         self._geometry)
+        job = self.scheduler._encode_job(data, None, None, self.scheme)
         self._begun.taken = job.taken
         return _QueuedEncode(self, job.future, out)
 
@@ -859,61 +866,54 @@ class BatchCoder(ErasureCoder):
                        missing: Sequence[int]) -> np.ndarray:
         return self._host.rebuild_matrix(present, missing)
 
-    def job_rows(self, n: int) -> np.ndarray:
-        """A zeroed (k, rung) operand for a job of ``n`` columns.  A
-        caller that fills rows[:, :n] in place (a degraded read's
-        survivors) hands submit a buffer that is contiguous and on the
-        ladder already: nothing is stacked, padded or copied on the way
-        to the device.  The rows come back rung-wide; columns past ``n``
-        are zero in, zero out."""
-        return np.zeros((self.scheme.data_shards, bucket_columns(n)),
-                        dtype=np.uint8)
+    def job_rows(self, n: int, rows: Optional[int] = None) -> np.ndarray:
+        """A zeroed (rows, rung) operand for a job of ``n`` columns:
+        ``rows`` as many as the job's matrix reads (a plan's sources), k
+        when not given.  A caller that fills rows[:, :n] in place (a
+        degraded read's survivors) hands submit a buffer that is
+        contiguous and on the ladder already: nothing is stacked, padded
+        or copied on the way to the device.  The rows come back
+        rung-wide; columns past ``n`` are zero in, zero out."""
+        return np.zeros((rows or self.scheme.data_shards,
+                         bucket_columns(n)), dtype=np.uint8)
 
     def reconstruct_rows(self, srcdata: np.ndarray,
                          rebuild_mat: np.ndarray,
                          out: Optional[np.ndarray] = None) -> np.ndarray:
         return self._rebuild(srcdata, rebuild_mat, out)
 
-    def _rebuild_plan(self, present: Sequence[int], missing: Sequence[int]
-                      ) -> tuple[list[int], np.ndarray]:
-        # a plan-capable host (LRC) chooses its own source subset — the
-        # first k of sorted(present) can be rank-deficient for it
-        if hasattr(self._host, "plan_rebuild"):
-            return self._host.plan_rebuild(present, missing)
-        return (sorted(present)[:self.scheme.data_shards],
-                self.rebuild_matrix(present, missing))
-
-    def reconstruct(self, shards: Sequence[Optional[bytes]]) -> list[bytes]:
+    def _rebuild_from(self, shards: Sequence[Optional[bytes]],
+                      missing: Sequence[int]) -> list[Optional[bytes]]:
+        """``shards`` with the ``missing`` ones filled in by one job: from
+        the sources the family's plan names where it plans (LRC: the
+        first k of sorted(present) can be rank-deficient for it), else
+        from the first k present shards."""
         k, total = self.scheme.data_shards, self.scheme.total_shards
         present = [i for i in range(total) if shards[i] is not None]
-        if len(present) < k and not hasattr(self._host, "plan_rebuild"):
-            raise ValueError(f"too few shards: {len(present)} < {k}")
-        missing = [i for i in range(total) if shards[i] is None]
+        out = [bytes(s) if s is not None else None for s in shards]
         if not missing:
-            return [bytes(s) for s in shards]
-        src_sids, mat = self._rebuild_plan(present, missing)
+            return out
+        plan = getattr(self, "plan_rebuild", None)
+        if plan is not None:
+            src_sids, mat = plan(present, missing)
+        elif len(present) < k:
+            raise ValueError(f"too few shards: {len(present)} < {k}")
+        else:
+            src_sids, mat = present[:k], \
+                self.rebuild_matrix(present, missing)
         src = np.stack([np.frombuffer(bytes(shards[i]), dtype=np.uint8)
                         for i in src_sids])
         rec = self._rebuild(src, mat)
-        out = [bytes(s) if s is not None else None for s in shards]
         for r, i in enumerate(missing):
             out[i] = rec[r].tobytes()
-        return [bytes(s) for s in out]
+        return out
+
+    def reconstruct(self, shards: Sequence[Optional[bytes]]) -> list[bytes]:
+        return self._rebuild_from(
+            shards, [i for i, s in enumerate(shards) if s is None])
 
     def reconstruct_data(self, shards: Sequence[Optional[bytes]]
                          ) -> list[Optional[bytes]]:
-        k, total = self.scheme.data_shards, self.scheme.total_shards
-        present = [i for i in range(total) if shards[i] is not None]
-        if len(present) < k and not hasattr(self._host, "plan_rebuild"):
-            raise ValueError(f"too few shards: {len(present)} < {k}")
-        missing_data = [i for i in range(k) if shards[i] is None]
-        out = [bytes(s) if s is not None else None for s in shards]
-        if not missing_data:
-            return out
-        src_sids, mat = self._rebuild_plan(present, missing_data)
-        src = np.stack([np.frombuffer(bytes(shards[i]), dtype=np.uint8)
-                        for i in src_sids])
-        rec = self._rebuild(src, mat)
-        for r, i in enumerate(missing_data):
-            out[i] = rec[r].tobytes()
-        return out
+        return self._rebuild_from(
+            shards, [i for i in range(self.scheme.data_shards)
+                     if shards[i] is None])

@@ -24,8 +24,9 @@ from typing import Optional
 
 import numpy as np
 
-from seaweedfs_tpu.models.coder import (CodeSpecError, ErasureCoder,
-                                        scheme_from_dict, scheme_to_dict)
+from seaweedfs_tpu.models.coder import (MAX_VOLUME_SHARDS, CodeSpecError,
+                                        ErasureCoder, scheme_from_dict,
+                                        scheme_to_dict)
 from seaweedfs_tpu.ops.rs_cpu import gf_partial_product
 from seaweedfs_tpu.qos import (BACKGROUND, WRITE, QosGovernor, class_scope,
                                classify, current_class, from_headers)
@@ -1050,7 +1051,14 @@ class VolumeServer:
                 self._m_ec_stage.set("loop." + part, value=val)
             for spec, counters in bs["by_spec"].items():
                 for stat, val in counters.items():
-                    self._m_ec_spec.set(spec, stat, value=val)
+                    if stat == "rows":
+                        # the spec's jobs by their operand's rows:
+                        # stat="rows.6" beside stat="jobs"
+                        for rows, n in val.items():
+                            self._m_ec_spec.set(spec, f"rows.{rows}",
+                                                value=n)
+                    else:
+                        self._m_ec_spec.set(spec, stat, value=val)
             for rung, counters in bs["by_rung"].items():
                 for stat, val in counters.items():
                     self._m_ec_rung.set(rung, stat, value=val)
@@ -2353,7 +2361,7 @@ class VolumeServer:
             base = ec_base_file_name(loc.directory, collection, vid)
             if os.path.exists(base + ".ecx") or \
                     any(os.path.exists(base + layout.shard_ext(i))
-                        for i in range(layout.TOTAL_SHARDS_COUNT)):
+                        for i in range(MAX_VOLUME_SHARDS)):
                 return base
         return ec_base_file_name(self.store.locations[0].directory,
                                  collection, vid)
@@ -2428,7 +2436,7 @@ class VolumeServer:
         # when all shards gone, remove index files too (reference
         # VolumeEcShardsDelete removes .ecx/.ecj when no shards remain)
         if not any(os.path.exists(base + layout.shard_ext(i))
-                   for i in range(layout.TOTAL_SHARDS_COUNT)):
+                   for i in range(MAX_VOLUME_SHARDS)):
             for ext in (".ecx", ".ecj", ".vif"):
                 if os.path.exists(base + ext):
                     os.remove(base + ext)
@@ -2447,7 +2455,7 @@ class VolumeServer:
         ecdec.write_idx_file_from_ec_index(base)
         # unmount EC view, load as normal volume
         self.store.unmount_ec_shards(
-            vid, list(range(layout.TOTAL_SHARDS_COUNT)))
+            vid, list(range(MAX_VOLUME_SHARDS)))
         from seaweedfs_tpu.storage.volume import Volume
         loc = next(l for l in self.store.locations
                    if os.path.dirname(base) == l.directory)
@@ -2490,7 +2498,7 @@ class VolumeServer:
         vid = int(req.query["volumeId"])
         base = self._ec_base_name(vid, req.query.get("collection", ""))
         sizes = {}
-        for i in range(layout.TOTAL_SHARDS_COUNT):
+        for i in range(MAX_VOLUME_SHARDS):
             p = base + layout.shard_ext(i)
             if os.path.exists(p):
                 sizes[i] = os.path.getsize(p)
@@ -2747,7 +2755,7 @@ class VolumeServer:
         if not missing:
             return Response({"error": "nothing to rebuild"}, status=400)
         base = self._ec_base_name(vid, collection)
-        local = [i for i in range(layout.TOTAL_SHARDS_COUNT)
+        local = [i for i in range(MAX_VOLUME_SHARDS)
                  if os.path.exists(base + layout.shard_ext(i))]
         present = sorted((set(local) | set(sources)) - set(missing))
         received = 0

@@ -51,7 +51,7 @@ HELP = """commands:
   s3.circuitbreaker [-bucket B] [-read N] [-write N] [-disable]
   mount.configure -collectionCapacity BYTES   statfs quota on live mounts
   fs.meta.cat <path>                one entry's raw metadata
-  ec.encode [-volumeId N] [-collection C] [-code rs|rs-K-M|lrc]
+  ec.encode [-volumeId N] [-collection C] [-code rs|rs-K-M|lrc|lrc-K-L-G]
   ec.rebuild [-n]
   ec.balance [-n]
   ec.decode -volumeId N

@@ -8,7 +8,8 @@ A volume's .dat is consumed in "rows" of data_shards blocks. While more than
 1MB blocks. Data shard i's file is the concatenation, over rows, of block i
 of each row; parity shards hold the RS parity column-wise. Every row writes
 a FULL block to every shard (the final partial row is zero-padded), so all
-14 shard files always have equal size:
+of a volume's shard files (14 for RS(10,4); the count is its scheme's: 9 for
+RS(6,3), 16 for LRC(12,2,2)) always have equal size:
 
     shard_size = n_large_rows * large_block + n_small_rows * small_block
 """

@@ -15,8 +15,9 @@ from typing import Callable, Optional
 
 import numpy as np
 
-from seaweedfs_tpu.models.coder import (DEFAULT_SCHEME, ErasureCoder,
-                                        make_coder, parse_code_spec)
+from seaweedfs_tpu.models.coder import (DEFAULT_SCHEME, MAX_VOLUME_SHARDS,
+                                        ErasureCoder, make_coder,
+                                        parse_code_spec)
 from seaweedfs_tpu.storage import types as t
 from seaweedfs_tpu.storage.disk_location import DiskLocation
 from seaweedfs_tpu.storage.erasure_coding import layout
@@ -127,6 +128,11 @@ class Store:
         # the one coefficient row a degraded read of plain RS asks for,
         # per (scheme, the k shards read, the shard wanted)
         self._rebuild_rows: dict[tuple, np.ndarray] = {}
+        # a planning family's (LRC) repair of one shard, per (scheme, the
+        # shard wanted, the shards it may read): (sources, their one
+        # coefficient row, "local" / "global"), or None where the
+        # pattern cannot be decoded (_repair_plan)
+        self._rebuild_plans: dict[tuple, Optional[tuple]] = {}
 
     def load_existing_volumes(self) -> None:
         for loc in self.locations:
@@ -424,12 +430,14 @@ class Store:
                            code: str = "") -> str:
         """VolumeEcShardsGenerate equivalent: write one .ecNN file per
         shard of the code (.ec00-.ec13 for RS(10,4), .ec00-.ec08 for
-        RS(6,3)) + .ecx + .vif next to the volume's files (reference
+        RS(6,3), .ec00-.ec15 for LRC(12,2,2)) + .ecx + .vif next to the
+        volume's files (reference
         server/volume_grpc_erasure_coding.go:38-81). Returns the base file
         name. The volume must exist locally; it is marked readonly first.
         `code` is a code spec (models/coder.parse_code_spec: '' / 'rs' ->
-        the store coder's scheme, 'rs-<k>-<m>', 'lrc' -> LRC(10,2,2); an
-        unknown one raises CodeSpecError); the chosen CodeSpec is persisted
+        the store coder's scheme, 'rs-<k>-<m>', 'lrc' -> LRC(10,2,2),
+        'lrc-<k>-<l>-<g>'; an unknown one raises CodeSpecError); the
+        chosen CodeSpec is persisted
         in the .vif, and everything later reads it from there."""
         from seaweedfs_tpu.storage.erasure_coding import encoder as ecenc
         from seaweedfs_tpu.storage.erasure_coding.ec_volume import \
@@ -869,21 +877,75 @@ class Store:
         self.ec_recover_stats["generic"] += 1
         return rec[0, :size].tobytes()
 
+    def _repair_plan(self, coder: ErasureCoder, wanted_shard: int,
+                     present: frozenset) -> Optional[tuple]:
+        """The family's cheapest repair of ``wanted_shard`` from the
+        shards of ``present``: (sources, coefficient row over them,
+        strategy), kept per (scheme, shard, present set) as
+        ``_rebuild_rows`` keeps plain RS's matrices: the plan is
+        derived once, not for every read.  ``local`` where it reads
+        fewer than k shards (a lost shard's own group), else ``global``.
+        None where ``present`` cannot decode the shard."""
+        key = (coder.scheme, wanted_shard, present)
+        try:
+            return self._rebuild_plans[key]
+        except KeyError:
+            pass
+        try:
+            src, mat = coder.plan_rebuild(sorted(present), [wanted_shard])
+            plan = (list(src),
+                    np.ascontiguousarray(mat, dtype=np.uint8),
+                    "local" if len(src) < coder.scheme.data_shards
+                    else "global")
+        except ValueError:
+            plan = None
+        self._rebuild_plans[key] = plan
+        return plan
+
+    def _reachable(self, ev: EcVolume) -> frozenset:
+        """The shards a repair may plan on: the mounted ones and, with a
+        peer reader, those the locator knows a holder of (every shard
+        where there is no locator, or it cannot say: the fetch tells)."""
+        reach = set(ev.shards)
+        if self.remote_shard_reader is not None:
+            located = None
+            if self.shard_locations is not None:
+                try:
+                    located = self.shard_locations(ev.volume_id) or {}
+                except Exception:  # noqa: BLE001 — no view: ask the peers
+                    pass
+            reach.update(range(MAX_VOLUME_SHARDS) if located is None
+                         else located)
+        return frozenset(reach)
+
     def _recover_via_plan(self, ev: EcVolume, iv: layout.Interval,
                           shard_off: int, coder: ErasureCoder,
                           wanted_shard: int) -> Optional[bytes]:
-        """Try the coder's cheapest-source repair plan. Returns the
-        recovered range, or None when a planned source is unreachable
-        (the caller then falls back to the generic collect-k ladder)."""
-        total = coder.scheme.total_shards
-        try:
-            src, mat = coder.plan_rebuild(
-                [s for s in range(total) if s != wanted_shard],
-                [wanted_shard])
-        except ValueError:
-            return None
-        if src is None:
-            return None
+        """The family's cheapest-source repair: the plan over every
+        other shard where its sources are all mounted here (a lost
+        shard's local group: k / l reads); else the plan over what can
+        be reached (the mounted shards and, with a peer reader, the
+        shards the locator knows a holder of), which is the global
+        decode when a second shard of the group is gone.  The plan's
+        survivors are gathered once into the coder's job buffer (as
+        _recover_one_row does) and ONE row is asked of the coder.
+        Returns the recovered range, or None when no plan decodes it or
+        a planned source is unreachable (the caller then falls back to
+        the generic collect-k ladder)."""
+        size = iv.size
+        with tracing.stage("store.ec.plan") as st:
+            others = frozenset(range(coder.scheme.total_shards)) \
+                - {wanted_shard}
+            plan = self._repair_plan(coder, wanted_shard, others)
+            if plan is not None and any(s not in ev.shards
+                                        for s in plan[0]):
+                plan = self._repair_plan(
+                    coder, wanted_shard, self._reachable(ev) & others)
+            if plan is None:
+                return None
+            src, mat, strategy = plan
+            st.annotate("strategy", strategy)
+            st.annotate("sources", src)
         local, remote = self._survivor_sources(ev, src)
         if remote and self.remote_shard_reader is None:
             return None
@@ -893,15 +955,17 @@ class Store:
                 self._fetch_remote_shards(ev, iv, shard_off, fetched,
                                           remote, len(remote))
                 if len(fetched) != len(remote):
-                    self._count_survivors(len(fetched), iv.size)
+                    self._count_survivors(len(fetched), size)
                     return None
-            rows = np.empty((len(src), iv.size), dtype=np.uint8)
+            # a scheduler's facade hands out a buffer of the plan's rows,
+            # already on its ladder's rung (zero past `size`)
+            job_rows = getattr(coder, "job_rows", None)
+            rows = job_rows(size, len(src)) if job_rows is not None \
+                else np.empty((len(src), size), dtype=np.uint8)
             self._gather_survivors(ev, src, local, fetched, shard_off,
-                                   iv.size, rows)
-        strat = "local" if len(src) < coder.scheme.data_shards \
-            else "global"
-        self.ec_recover_stats[strat] += 1
-        return coder.reconstruct_rows(rows, mat)[0].tobytes()
+                                   size, rows)
+        self.ec_recover_stats[strategy] += 1
+        return coder.reconstruct_rows(rows, mat)[0, :size].tobytes()
 
     def _rank_remote_sids(self, vid: int,
                           sids: list[int]) -> tuple[list[int], int]:

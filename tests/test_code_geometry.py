@@ -109,12 +109,14 @@ def test_store_seals_rs6_3_through_the_scheduler(tmp_path):
         assert spec["mesh_dispatches"] == spec["jobs"]   # one at a time
         assert spec["cpu_dispatches"] == 0 and spec["programs"] >= 1
         assert "rs-10-4" not in st["by_spec"]
-        # the store hands every RS geometry a facade over ONE scheduler
+        # the store hands every scheme, of whatever family, a facade
+        # over ONE scheduler
         c63 = store.coder_for_scheme(RS63)
         assert isinstance(c63, BatchCoder) and c63.scheduler is sched
         assert store.coder_for_scheme(RS63) is c63
-        assert not isinstance(store.coder_for_scheme(LrcScheme()),
-                              BatchCoder)
+        clrc = store.coder_for_scheme(LrcScheme())
+        assert isinstance(clrc, BatchCoder) and clrc.scheduler is sched
+        assert clrc.scheme == LrcScheme() and clrc is not c63
         store.close()
     finally:
         sched.stop()

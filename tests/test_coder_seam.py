@@ -56,13 +56,13 @@ def test_for_scheme_answers_with_a_coder_of_that_scheme(kind, spec, sched):
                           gf256.gf_matmul(_parity_matrix(scheme), data))
     submitted = sched.stats()["jobs_total"] - jobs
     if kind == "batch":
-        if isinstance(scheme, LrcScheme):
-            # another family: its host coder, off the device queue
-            assert type(c) is lrc.LrcCoderMT and submitted == 0
-        else:
-            # every plain RS geometry: a facade over the SAME scheduler
-            assert isinstance(c, BatchCoder) and c.scheduler is sched
-            assert submitted == 1
+        # every scheme, plain RS or another family: a facade over the
+        # SAME scheduler, its jobs under its own scheme
+        assert isinstance(c, BatchCoder) and c.scheduler is sched
+        assert submitted == 1
+        assert sched.stats()["by_spec"][spec]["jobs"] >= 1
+        # only a family that plans its sources offers a plan
+        assert hasattr(c, "plan_rebuild") == isinstance(scheme, LrcScheme)
     else:
         # a host coder answers with its family's host coder, threaded
         # as itself

@@ -490,7 +490,8 @@ def test_mixed_rs_lrc_batch_drain_bit_identical():
     one drain, every future demuxing bit-identical
     per-job rows — RS encodes ride the native parity path, LRC encodes
     the matrix-carrying path, and an LRC group-local rebuild (5 source
-    rows, not k) routes to the CPU coder WITHOUT benching the mesh."""
+    rows, not k) is fitted to the geometry's k-wide apply program by
+    the mesh coder (zero rows added): on the device, no fallback."""
     from seaweedfs_tpu.ops.lrc import LrcCoder
 
     lrc = LrcCoder()
@@ -520,6 +521,8 @@ def test_mixed_rs_lrc_batch_drain_bit_identical():
         assert st["jobs_total"] == 7
         assert st["coder_fallbacks"] == 0  # narrow rebuild != mesh fault
         assert st["mesh_healthy"] is True
+        assert st["cpu_batches"] == 0
+        assert st["by_spec"]["rs-10-4"]["rows"] == {"5": 1, "10": 6}
     finally:
         sched.stop()
 
@@ -745,7 +748,7 @@ def test_two_sampled_jobs_in_one_dispatch_share_it_by_id():
     other = next(s for s in disp if s is not lead)
     assert lead["annotations"]["shape"][0] == 2
     assert other["annotations"] == {"dispatch_id": lead["span_id"],
-                                    "jobs": 2, "spec": "rs-10-4"}
+                                    "jobs": 2, "spec": "rs-10-4", "rows": 10}
     assert lead["annotations"]["spec"] == "rs-10-4"
     assert other["duration_ms"] == pytest.approx(lead["duration_ms"],
                                                  abs=0.5)

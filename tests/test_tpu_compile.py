@@ -190,3 +190,36 @@ def test_chunk_read_apply_program_compiles_at_one_mib(topo, B):
     assert mem.output_size_in_bytes >= B * M * MIB
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
         + mem.output_size_in_bytes < 1 << 30
+
+
+@pytest.mark.parametrize("kind,width,B,n", [
+    ("encode", 12, 1, MIB),            # a seal's 1 MiB row of twelve cells
+    ("apply", 6, 1, 256 << 10),        # a local repair: the group's six
+    ("apply", 6, 16, 256 << 10),       # ... the fullest batch of the rung
+    ("apply", 12, 1, 256 << 10),       # two gone in a group: the global decode
+])
+def test_lrc12_2_2_programs_compile_under_their_own_names(topo, kind, width,
+                                                          B, n):
+    """A code FAMILY's programs (Azure's LRC(12,2,2), cell
+    ``reads.degraded1.lrc12-2-2``) at the shapes its volumes use: the
+    static-matrix encode of twelve rows, and the apply program at both of
+    its widths, each under the family's name."""
+    from seaweedfs_tpu.models.coder import LrcScheme
+    scheme = LrcScheme(12, 2, 2)
+    assert rs_mesh.apply_widths(scheme) == (6, 12)
+    mesh = _batch_mesh(topo, 1)
+    s3 = NamedSharding(mesh, P("batch", None, None))
+    words = jax.ShapeDtypeStruct((B, width, n // 4), jnp.uint32, sharding=s3)
+    coeff = jax.ShapeDtypeStruct((B, 4, width), jnp.uint32, sharding=s3)
+    if kind == "encode":
+        lowered = rs_mesh.batch_encode_fn(scheme, mesh).lower(words)
+    else:
+        lowered = rs_mesh.batch_apply_fn(scheme, mesh, width).lower(
+            words, coeff)
+    assert f"jit_ec_{kind}_lrc_12_2_2" in lowered.as_text()[:200]
+    compiled = lowered.compile()
+    (out_s,) = jax.tree_util.tree_leaves(compiled.output_shardings)
+    assert out_s.shard_shape((B, 4, n // 4)) == (B, 4, n // 4)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
+        + mem.output_size_in_bytes < 1 << 30
