@@ -46,15 +46,17 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
-from seaweedfs_tpu.models.coder import (DEFAULT_SCHEME, ErasureCoder,
-                                        RSScheme, code_spec_name,
-                                        host_coder, register_coder)
+from seaweedfs_tpu.models.coder import (DEFAULT_SCHEME, Encoded,
+                                        ErasureCoder, RSScheme,
+                                        code_spec_name, host_coder,
+                                        register_coder)
 from seaweedfs_tpu.ops.rs_jax import (_apply_matrix_words, _gf_mul_dynamic,
                                       _mat_to_tuple)
 from seaweedfs_tpu.parallel import mesh as mesh_mod
 from seaweedfs_tpu.utils import tracing
 
-# a dispatch's four stages on the host, in order (ec.mesh.<stage>)
+# a dispatch's four stages on the host, in order (ec.mesh.<stage>): the
+# first two are a ``*_batch_begin``, the last two its ``.result()``
 STAGES = ("pad", "launch", "fetch", "unpack")
 
 
@@ -130,12 +132,44 @@ def _apply_fn(scheme: RSScheme, mesh: Mesh, width: int):
                    in_shardings=(s3, s3), out_shardings=s3)
 
 
+class Dispatched:
+    """A batch on the device: what ``MeshCoder.encode_batch_begin`` /
+    ``rebuild_batch_begin`` hand back once the program is enqueued.
+    ``result()`` waits for it, brings the output to the host
+    (``ec.mesh.fetch``) and cuts it to the jobs (``ec.mesh.unpack``).
+    The two-step form of ``ErasureCoder.encode_begin`` one layer down:
+    the batch scheduler launches the next dispatch between the steps."""
+
+    __slots__ = ("_coder", "_out", "_rows", "_unpack")
+
+    def __init__(self, coder: "MeshCoder", out, rows: int, unpack):
+        self._coder, self._out, self._rows = coder, out, rows
+        self._unpack = unpack
+
+    def result(self):
+        coder = self._coder
+        with tracing.stage("ec.mesh.fetch") as st:
+            coder._note(st, self._rows)
+            # waits for the device, then device -> host
+            got = np.asarray(jax.device_get(self._out))
+        coder._staged("fetch", st)
+        self._out = None                 # the device's copy may go
+        with tracing.stage("ec.mesh.unpack") as st:
+            coder._note(st, self._rows)
+            res = self._unpack(got)
+        coder._staged("unpack", st)
+        return res
+
+
 @register_coder("mesh")
 class MeshCoder(ErasureCoder):
     """ErasureCoder whose unit of dispatch is a batch of block-groups
     sharded across a 1-D device mesh.  The scalar ErasureCoder API is a
     batch of one (bit-identical, just not faster); the batch API is what
-    parallel/batcher.py feeds."""
+    parallel/batcher.py feeds, in its two-step form (``*_batch_begin``
+    launches, ``.result()`` collects: the scheduler launches the next
+    dispatch between the two); the one-step forms are ``begin`` +
+    ``result``, the same code."""
 
     def __init__(self, scheme: RSScheme = DEFAULT_SCHEME,
                  n_devices: int | None = None, mesh: Optional[Mesh] = None):
@@ -157,9 +191,10 @@ class MeshCoder(ErasureCoder):
         # ran first (a warm-up) is counted here though nothing compiled.
         # Real compiles: mesh_mod.CompileWatch (backend_compiles).
         self.programs: set[tuple] = set()
-        # seconds and entries per host stage of a dispatch.  Single
-        # writer under the batch scheduler (its dispatcher thread is the
-        # only caller); a coder shared by several threads can lose adds
+        # seconds and entries per host stage of a dispatch.  One writer
+        # a stage under the batch scheduler (its launching thread is the
+        # only caller of the begins, its collector of the results); a
+        # coder shared by several threads can lose adds
         self.stage_s = dict.fromkeys(STAGES, 0.0)
         self.stage_n = dict.fromkeys(STAGES, 0)
         self.compile_watch = mesh_mod.install_tracing()
@@ -184,9 +219,10 @@ class MeshCoder(ErasureCoder):
         st.annotate("spec", self.spec)
         st.annotate("rows", rows)
 
-    def _fetch(self, kind: str, fn, *operands) -> np.ndarray:
+    def _launch(self, kind: str, fn, operands: tuple, unpack
+                ) -> "Dispatched":
         """Dispatch, note the program's shape and where the output's
-        shards lived, and bring the result to the host."""
+        shards lived; what comes back is on the device."""
         self.programs.add((kind,) + operands[0].shape)
         rows = operands[0].shape[1]
         with tracing.stage("ec.mesh.launch") as st:
@@ -198,12 +234,7 @@ class MeshCoder(ErasureCoder):
             self.output_spread[spread] = \
                 self.output_spread.get(spread, 0) + 1
         self._staged("launch", st)
-        with tracing.stage("ec.mesh.fetch") as st:
-            self._note(st, rows)
-            # waits for the device, then device -> host
-            got = np.asarray(jax.device_get(out))
-        self._staged("fetch", st)
-        return got
+        return Dispatched(self, out, rows, unpack)
 
     # ---- batch API (the batcher's entry points) ----
 
@@ -220,6 +251,19 @@ class MeshCoder(ErasureCoder):
     def encode_batch(self, batch: np.ndarray) -> np.ndarray:
         """(B, k, n) uint8 -> (B, m, n) uint8 parity, one sharded
         dispatch.  n must be a multiple of 4 (uint32 lanes)."""
+        return self._encode_begin(batch).result()
+
+    def encode_batch_begin(self, batch: np.ndarray):
+        """``encode_batch`` in two steps: pad and launch now, fetch and
+        unpack in ``.result()`` (``Dispatched``).  A one-step form that
+        was REPLACED, by a subclass or by a fault planted on the class
+        (benchmark/tests/faulty_volume.py), is what this coder does: the
+        begin then runs it whole (``Encoded``: done when handed back)."""
+        if getattr(self.encode_batch, "__func__", None) is not _ENCODE_BATCH:
+            return Encoded(self.encode_batch(batch))
+        return self._encode_begin(batch)
+
+    def _encode_begin(self, batch: np.ndarray) -> Dispatched:
         B, k, n = batch.shape
         assert k == self.scheme.data_shards, (k, self.scheme)
         assert n % 4 == 0, n
@@ -229,12 +273,9 @@ class MeshCoder(ErasureCoder):
                 np.ascontiguousarray(batch).view(np.uint32))
             fn = batch_encode_fn(self.scheme, self.mesh)
         self._staged("pad", st)
-        out = self._fetch("encode", fn, words)
-        with tracing.stage("ec.mesh.unpack") as st:
-            self._note(st, k)
-            parity = np.ascontiguousarray(out[:B]).view(np.uint8)
-        self._staged("unpack", st)
-        return parity
+        return self._launch(
+            "encode", fn, (words,),
+            lambda out: np.ascontiguousarray(out[:B]).view(np.uint8))
 
     def _fit_width(self, srcdata: np.ndarray, coeff: np.ndarray
                    ) -> tuple[np.ndarray, np.ndarray]:
@@ -270,6 +311,18 @@ class MeshCoder(ErasureCoder):
         r_i <= parity_shards).  Returns a list of (r_i, n) uint8
         recovered rows, one per job, in one sharded dispatch even when
         jobs lost different shards."""
+        return self._rebuild_begin(srcdata, mats).result()
+
+    def rebuild_batch_begin(self, srcdata: np.ndarray,
+                            mats: Sequence[np.ndarray]):
+        """``rebuild_batch`` in two steps, as ``encode_batch_begin``."""
+        if getattr(self.rebuild_batch, "__func__", None) \
+                is not _REBUILD_BATCH:
+            return Encoded(self.rebuild_batch(srcdata, mats))
+        return self._rebuild_begin(srcdata, mats)
+
+    def _rebuild_begin(self, srcdata: np.ndarray,
+                       mats: Sequence[np.ndarray]) -> Dispatched:
         B, w, n = srcdata.shape
         assert w <= self.scheme.data_shards and n % 4 == 0
         assert len(mats) == B
@@ -282,21 +335,19 @@ class MeshCoder(ErasureCoder):
                     and mt.shape[0] <= m, mt.shape
                 coeff[i, :mt.shape[0]] = mt.astype(np.uint32)
             srcdata, coeff = self._fit_width(srcdata, coeff)
-            width = srcdata.shape[1]
-            self._note(st, width)
+            self._note(st, srcdata.shape[1])
             words = self._pad_batch(
                 np.ascontiguousarray(srcdata).view(np.uint32))
             coeff = self._pad_batch(coeff)
-            fn = batch_apply_fn(self.scheme, self.mesh, width)
+            fn = batch_apply_fn(self.scheme, self.mesh, srcdata.shape[1])
         self._staged("pad", st)
-        out = self._fetch("apply", fn, words, coeff)  # (pb, m, nw)
-        with tracing.stage("ec.mesh.unpack") as st:
-            self._note(st, width)
+        n_rows = [np.asarray(mt).shape[0] for mt in mats]
+
+        def unpack(out: np.ndarray) -> list[np.ndarray]:   # (pb, m, nw)
             out8 = np.ascontiguousarray(out[:B]).view(np.uint8)  # (B,m,n)
-            recs = [np.ascontiguousarray(
-                out8[i, :np.asarray(mats[i]).shape[0]]) for i in range(B)]
-        self._staged("unpack", st)
-        return recs
+            return [np.ascontiguousarray(out8[i, :r])
+                    for i, r in enumerate(n_rows)]
+        return self._launch("apply", fn, (words, coeff), unpack)
 
     # ---- scalar ErasureCoder API (batch of one) ----
 
@@ -376,3 +427,9 @@ class MeshCoder(ErasureCoder):
         return self._rebuild_from(
             shards, [i for i in range(self.scheme.data_shards)
                      if shards[i] is None])
+
+
+# the one-step forms as defined here: a coder whose own differ from them
+# had them replaced (``encode_batch_begin``)
+_ENCODE_BATCH = MeshCoder.encode_batch
+_REBUILD_BATCH = MeshCoder.rebuild_batch

@@ -6,33 +6,53 @@ independent callers: concurrent ``ec.encode`` pipelines on different
 volumes, the repair queue's rebuild jobs, degraded reads.  This module
 is the funnel between them and the mesh:
 
-  submit (any thread) -> bounded queue -> dispatcher thread takes what
-  is queued -> one MeshCoder dispatch -> per-job futures.
+  submit (any thread) -> bounded queue -> the launching thread takes
+  what is queued -> one MeshCoder dispatch, launched -> the collector
+  thread fetches it -> per-job futures.
 
 Scheduling contract:
   - the submission queue is BOUNDED (overload becomes backpressure on
     the submitting pipeline, not memory growth);
   - the dispatcher never WAITS for company: it takes the first job,
     drains what is already queued beside it (up to max_batch) and
-    dispatches.  Coalescing happens behind a busy dispatch: jobs that
-    arrive while one runs queue up and ride the next one together, so a
-    burst fills a device-sized batch and a lone job on an idle
-    scheduler goes straight to the device — not held, and not copied
-    either (a dispatch of one job hands the coder a view of the job's
-    own buffer; ``lone_dispatches`` counts them);
+    dispatches.  A lone job on an idle scheduler goes straight to the
+    device — not held, and not copied either (a dispatch of one job
+    hands the coder a view of the job's own buffer; ``lone_dispatches``
+    counts them);
+  - up to TWO dispatches are on the device (``IN_FLIGHT``, a constant):
+    a dispatch is LAUNCHED (stack, pad, host -> device copy, enqueue:
+    the mesh coder's ``*_batch_begin``) as soon as a job is queued and
+    one of the two places is free, and dispatches are COLLECTED (wait,
+    device -> host, unpack, demux: ``.result()`` of what the begin
+    returned) in the order they were launched, by a second thread, so
+    the thread that watches the queue never blocks on the device: job
+    N+1's copy in rides beside job N's program and copy out
+    (``overlapped_dispatches`` counts the dispatches launched while
+    another was still on the device; over ``mesh_batches`` it says how
+    often the window of two engages).  Coalescing happens behind TWO
+    busy places: jobs that arrive while both are taken queue up and
+    ride the next dispatch together, so a burst still fills a
+    device-sized batch.  A host dispatch (a shape the mesh does not
+    take, a benched mesh) stays synchronous on the launching thread;
   - jobs are ordered by QoS class (interactive > write > background —
     the ambient class is captured at submit, same as every other
     fan-out edge) before dispatch, so a background rebuild flood cannot
-    starve a degraded-read reconstruction sharing the mesh;
+    starve a degraded-read reconstruction sharing the mesh.  The order
+    is among the jobs of ONE batch: an interactive job that arrives
+    while two background dispatches are on the device waits both out
+    (at seal sizes <= ~9 ms, where one dispatch ahead was <= ~4.5 ms);
   - a scheduler is built FOR the device: when none is handed in it
     builds a MeshCoder and refuses to start where JAX found only the
     CPU and the CPU was not asked for by name (parallel/mesh.
     require_accelerator) — it never settles on the CPU coder unseen;
-  - the mid-run CPU drain is safety code: when a mesh dispatch raises
-    (the device was lost mid-run), the failed batch and everything
-    queued behind it drain through CpuCoderMT with bit-identical
-    results, ``coder_fallbacks`` increments, and the mesh is benched
-    for a cooldown before being retried;
+  - the mid-run CPU drain is safety code, per dispatch: when a mesh
+    dispatch raises at its launch OR at its collect (the device was
+    lost mid-run), its jobs, those of a dispatch in flight behind it
+    (not asked of the device again) and everything queued drain through
+    CpuCoderMT with bit-identical results, ``coder_fallbacks``
+    increments once, and the mesh is benched for a cooldown before
+    being retried; ``stop()`` collects what is in flight before the
+    threads end;
   - the set of compiled shapes is BOUNDED: a job's columns pad up a
     short fixed ladder (COLUMN_LADDER), a batch pads to a power of two
     (MeshCoder._pad_batch) and one dispatch carries at most
@@ -54,10 +74,15 @@ Scheduling contract:
 
 Where the time goes is counted always and traced when sampled: every
 stage a job passes through (``STAGES``; utils/tracing.stage) adds its
-seconds to ``stats()["stage_s"]``, the dispatcher's own time is split
-into idle / hold (the drain) / dispatch (``loop_s``), and a job
-submitted under a SAMPLED request span carries that span to the
-dispatcher thread, which records the job's stages as its children.
+seconds to ``stats()["stage_s"]``, the LAUNCHING thread's own time is
+split into idle / hold (the wait for a place, the drain) / dispatch (a
+dispatch's first half: stack, pad, launch) (``loop_s``), ``wait_hist``
+is submit -> launch, and a job submitted under a SAMPLED request span
+carries that span to the scheduler's threads, which record the job's
+stages as its children: one ``ec.batch.dispatch`` span a dispatch, from
+its launch to the end of its demux.  In a device trace's host plane
+``ec.batch.dispatch`` is the first half, on the launching thread's
+line, and ``ec.batch.collect`` the second, on the collector's.
 
 All behavioral timing routes through clockctl so the scheduler stays
 legible to the virtual-clock sim; blocking primitives (queue waits)
@@ -66,6 +91,7 @@ stay real because the batcher never runs inside the sim kernel.
 
 from __future__ import annotations
 
+import collections
 import queue
 import threading
 from concurrent.futures import Future
@@ -103,12 +129,13 @@ MAX_DISPATCH_COLUMNS = 4 << 20
 
 # the stages of a job, in the order it passes through them.  Caller
 # thread: submit (contiguous copy, ladder pad, queue put) and result
-# (future wait, copy out).  Dispatcher thread: stack, then the mesh
-# coder's pad / launch / fetch / unpack (ops/rs_mesh.STAGES), then demux.
+# (future wait, copy out).  Launching thread: stack, then the mesh
+# coder's pad / launch; collector thread: its fetch / unpack
+# (ops/rs_mesh.STAGES), then demux.
 CALLER_STAGES = ("submit", "result")
 DISPATCH_STAGES = ("stack", "pad", "launch", "fetch", "unpack", "demux")
 STAGES = CALLER_STAGES[:1] + DISPATCH_STAGES + CALLER_STAGES[1:]
-# the dispatcher thread's time, partitioned (stats()["loop_s"])
+# the launching thread's time, partitioned (stats()["loop_s"])
 LOOP_PARTS = ("idle", "hold", "dispatch")
 # An idle dispatcher ends its ec.batch.idle stage and begins a fresh one
 # this often: a profiler records a stage only if it was running when the
@@ -116,6 +143,13 @@ LOOP_PARTS = ("idle", "hold", "dispatch")
 # (the seconds between two seals) names it from here on, not from the
 # next job
 IDLE_REARM_S = 0.2
+
+# Mesh dispatches on the device at once: one whose result is being
+# fetched and one whose operand is being copied in behind it.  The
+# link carries both ways at once (PERF.md section 5: a copy in hides
+# 0.68-0.98 of itself under the program and copy out before it), and a
+# third would only queue behind the second.  A constant, not an option
+IN_FLIGHT = 2
 
 _STOP = object()
 _CLASS_RANK = {c: i for i, c in enumerate(CLASSES)}
@@ -174,9 +208,9 @@ class _Job:
         self.span = span
         self.submitted = submitted
         self.future: Future = Future()
-        # set once the job has LEFT the queue (the dispatcher holds it,
-        # or stop() drained it): nothing submitted from then on can
-        # share its dispatch (BatchCoder.encode_begin)
+        # set once the job has LEFT the queue for its launch (a place on
+        # the device was free; or stop() drained it): nothing submitted
+        # from then on can share its dispatch (BatchCoder.encode_begin)
         self.taken = threading.Event()
 
 
@@ -195,9 +229,32 @@ class _Geometry:
         self.mesh = mesh
         self.cpu = cpu
         self.counters = dict.fromkeys(
-            ("jobs", "mesh_dispatches", "cpu_dispatches", "bytes_in",
-             "bytes_out"), 0)
+            ("jobs", "mesh_dispatches", "overlapped_dispatches",
+             "cpu_dispatches", "bytes_in", "bytes_out"), 0)
         self.rows: dict[int, int] = {}
+
+
+class _Flown:
+    """A mesh dispatch between its two halves: launched (stacked, padded,
+    enqueued: the launching thread) and not yet collected (fetched,
+    unpacked, demuxed: the collector)."""
+
+    __slots__ = ("jobs", "g", "pending", "gen", "span", "t0",
+                 "overlapped")
+
+    def __init__(self, jobs: list, g: _Geometry, pending, gen: int,
+                 span, t0: float, overlapped: bool):
+        self.jobs = jobs
+        self.g = g
+        self.pending = pending    # the mesh coder's: .result()
+        # coder_fallbacks when it was launched: stale once any dispatch
+        # has failed since, and then it is not asked of the device
+        self.gen = gen
+        self.span = span          # its ec.batch.dispatch span, or None
+        self.t0 = t0              # when its launch began
+        # another dispatch was still on the device when this one's
+        # launch began
+        self.overlapped = overlapped
 
 
 class _CallerCells:
@@ -278,13 +335,19 @@ class EcBatchScheduler:
         self._geometries: dict[RSScheme, _Geometry] = {
             scheme: _Geometry(scheme, self._mesh, cpu_coder)}
         self._down_until = 0.0
-        # counters are only written by the dispatcher thread; readers
-        # (stats/metrics) tolerate a stale int
+        # every counter has ONE writer (the launching thread: what is
+        # taken and launched; the collector: what came back from the
+        # mesh) but the two a host dispatch touches, which either thread
+        # can run (_fallback_lock); readers (stats/metrics) tolerate a
+        # stale int
         self.jobs_total = 0
         self.batches_total = 0
         self.mesh_batches = 0
-        # mesh dispatches of ONE job: not held, not copied (_run_mesh)
+        # mesh dispatches of ONE job: not held, not copied (_launch)
         self.lone_dispatches = 0
+        # mesh dispatches launched while another was still on the device:
+        # over mesh_batches, how often the window of IN_FLIGHT engages
+        self.overlapped_dispatches = 0
         self.cpu_batches = 0
         self.coder_fallbacks = 0
         self.max_coalesced = 0
@@ -293,8 +356,9 @@ class EcBatchScheduler:
         # and the groups cut by MAX_DISPATCH_COLUMNS
         self._by_rung: dict[int, list[int]] = {}
         self.cap_splits = 0
-        # where the time goes (module docstring).  The dispatcher is the
-        # only writer of stage_s / stage_n / by_kind
+        # where the time goes (module docstring).  One writer a key:
+        # the launching thread of stack and by_kind, the collector of
+        # demux
         self.stage_s = dict.fromkeys(("stack", "demux"), 0.0)
         self.stage_n = dict.fromkeys(("stack", "demux"), 0)
         # the dispatcher's time, as it publishes it at every turn, in
@@ -323,9 +387,23 @@ class EcBatchScheduler:
             "jobs coalesced per dispatched batch",
             buckets=BATCH_SIZE_BUCKETS)
         self._stopped = False
+        # the dispatches on the device, oldest first: appended by the
+        # launching thread, taken off by the collector when it has
+        # fetched one (never more than IN_FLIGHT); ``_launching`` goes
+        # False when the launching thread ends
+        self._flight: collections.deque = collections.deque()
+        self._flight_cv = threading.Condition()
+        self._launching = True
+        # the two threads meet only where a dispatch goes to the host
+        # coder: the fallback's bookkeeping and the host dispatches' count
+        self._fallback_lock = threading.Lock()
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name="ec-batcher")
+        self._collector = threading.Thread(target=self._collect_loop,
+                                           daemon=True,
+                                           name="ec-batch-collect")
         self._thread.start()
+        self._collector.start()
 
     # ---- submission (any thread) ----
 
@@ -412,10 +490,11 @@ class EcBatchScheduler:
     # ---- dispatcher ----
 
     def _loop(self) -> None:
-        # the dispatcher's time, partitioned: every instant since the
-        # thread started is in exactly one of idle (blocked on an empty
-        # queue), hold (holding >= 1 job, draining what queued up
-        # beside it) and dispatch
+        # the launching thread's time, partitioned: every instant since
+        # the thread started is in exactly one of idle (blocked on an
+        # empty queue), hold (holding >= 1 job: waiting for a place on
+        # the device, then draining what queued up beside it) and
+        # dispatch (stack, pad, launch; a host dispatch whole)
         done = [0.0, 0.0, 0.0]      # idle, hold, dispatch (LOOP_PARTS)
         t = self._loop_pub[4]
 
@@ -428,39 +507,52 @@ class EcBatchScheduler:
             self._loop_pub = (done[0], done[1], done[2],
                               (part + 1) % 3, now)
 
-        while True:
-            st = tracing.stage_begin("ec.batch.idle")
-            try:
-                job = self._q.get(timeout=IDLE_REARM_S)
-            except queue.Empty:
-                job = None
-            tracing.stage_end(st)
-            if job is None:
-                continue
-            turn(0)
-            if job is _STOP:
-                return
-            batch = [job]
-            stopping = False
-            st = tracing.stage_begin("ec.batch.hold")
-            # nothing is WAITED for: what queued up behind the last
-            # dispatch rides this one, and a job with nobody beside it
-            # leaves at once
-            while len(batch) < self.max_batch:
+        try:
+            while True:
+                st = tracing.stage_begin("ec.batch.idle")
                 try:
-                    nxt = self._q.get_nowait()
+                    job = self._q.get(timeout=IDLE_REARM_S)
                 except queue.Empty:
-                    break
-                if nxt is _STOP:
-                    stopping = True
-                    break
-                batch.append(nxt)
-            tracing.stage_end(st)
-            turn(1)
-            self._dispatch(batch)
-            turn(2)
-            if stopping:
-                return
+                    job = None
+                tracing.stage_end(st)
+                if job is None:
+                    continue
+                turn(0)
+                if job is _STOP:
+                    return
+                batch = [job]
+                stopping = False
+                st = tracing.stage_begin("ec.batch.hold")
+                # nothing is WAITED for but a place on the device: what
+                # queued up while both were taken rides this dispatch,
+                # and a job that finds one free leaves at once
+                self._wait_for_place()
+                while len(batch) < self.max_batch:
+                    try:
+                        nxt = self._q.get_nowait()
+                    except queue.Empty:
+                        break
+                    if nxt is _STOP:
+                        stopping = True
+                        break
+                    batch.append(nxt)
+                tracing.stage_end(st)
+                turn(1)
+                self._dispatch(batch)
+                turn(2)
+                if stopping:
+                    return
+        finally:
+            with self._flight_cv:
+                self._launching = False     # the collector drains, then ends
+                self._flight_cv.notify_all()
+
+    def _wait_for_place(self) -> None:
+        """Block while IN_FLIGHT dispatches are on the device.  Only the
+        launching thread adds one, so a place found free stays free."""
+        with self._flight_cv:
+            while len(self._flight) >= IN_FLIGHT:
+                self._flight_cv.wait()
 
     def _mesh_healthy(self) -> bool:
         return clockctl.monotonic() >= self._down_until
@@ -497,7 +589,10 @@ class EcBatchScheduler:
             self._rung(j.data.shape[1])[0] += 1
         self.size_hist.observe(len(batch))
         # QoS ordering: a group containing an interactive job dispatches
-        # before an all-background group
+        # before an all-background group.  It orders what one batch
+        # holds: a job that arrives while IN_FLIGHT dispatches of another
+        # class are on the device waits both out (at seal sizes ~9 ms,
+        # where it was one dispatch, ~4.5 ms, before the window of two)
         batch.sort(key=lambda j: (_rank(j.cls), j.submitted))
         groups: dict[tuple, list] = {}
         for j in batch:
@@ -540,8 +635,18 @@ class EcBatchScheduler:
                    for jj in jobs)
 
     def _run_group(self, jobs: list) -> None:
+        """One dispatch: launched onto the device for the collector to
+        finish, or (a shape the mesh does not take, a benched mesh, a
+        launch that raised) done here on the host coder."""
         g = self._geometry(jobs[0].scheme)
         if self._mesh_healthy() and self._mesh_compatible(jobs):
+            # read before the wait: a failure met while this waits makes
+            # the generation stale, and the collector sends a dispatch of
+            # a stale generation to the host without asking the device
+            gen = self.coder_fallbacks
+            self._wait_for_place()
+            # is one still on the device as this launch begins
+            overlapped = bool(self._flight)
             try:
                 if g.mesh is None:
                     # a further geometry's first job: one more MeshCoder,
@@ -549,48 +654,61 @@ class EcBatchScheduler:
                     from seaweedfs_tpu.ops.rs_mesh import MeshCoder
                     g.mesh = MeshCoder(g.scheme, mesh=getattr(
                         self._mesh, "mesh", None))
-                self._run_mesh(jobs, g)
-                self.mesh_batches += 1
-                g.counters["mesh_dispatches"] += 1
-                rung = self._rung(jobs[0].data.shape[1])
-                rung[1] += 1
-                rung[2] = max(rung[2], len(jobs))
-                if len(jobs) == 1:
-                    self.lone_dispatches += 1
-                return
+                flown = self._launch(jobs, g, gen, overlapped)
             except Exception as e:  # noqa: BLE001 — the fallback ladder
-                from seaweedfs_tpu.parallel import mesh as mesh_mod
-                self.coder_fallbacks += 1
-                self.fallback_reason = mesh_mod.classify_failure(repr(e))
-                self._down_until = clockctl.monotonic() + self.cooldown_s
-                glog.warning(
-                    "EC batcher: mesh dispatch failed (%s: %s); draining "
-                    "through the CPU coder for %.0fs", type(e).__name__,
-                    e, self.cooldown_s)
-                if self._on_fallback is not None:
-                    try:
-                        self._on_fallback(self.fallback_reason or "error")
-                    except Exception:  # noqa: BLE001 — observer only
-                        pass
-        self._run_cpu(jobs)
-        self.cpu_batches += 1
-        g.counters["cpu_dispatches"] += 1
+                self._fell_back(e, gen)
+            else:
+                with self._flight_cv:
+                    self._flight.append(flown)
+                    self._flight_cv.notify_all()
+                return
+        self._run_host(jobs, g)
+
+    def _fell_back(self, e: Exception, gen: int) -> None:
+        """A mesh dispatch launched at fallback generation ``gen`` raised
+        (at its launch or at its collect): count it, bench the mesh and
+        tell the observer — once a failure, whichever thread met it, and
+        not again for the dispatches that were in flight beside it."""
+        with self._fallback_lock:
+            if gen != self.coder_fallbacks:
+                return
+            from seaweedfs_tpu.parallel import mesh as mesh_mod
+            self.coder_fallbacks += 1
+            self.fallback_reason = mesh_mod.classify_failure(repr(e))
+            self._down_until = clockctl.monotonic() + self.cooldown_s
+        glog.warning(
+            "EC batcher: mesh dispatch failed (%s: %s); draining "
+            "through the CPU coder for %.0fs", type(e).__name__,
+            e, self.cooldown_s)
+        if self._on_fallback is not None:
+            try:
+                self._on_fallback(self.fallback_reason or "error")
+            except Exception:  # noqa: BLE001 — observer only
+                pass
 
     def _staged(self, key: str, st: tracing.stage) -> None:
         self.stage_s[key] += st.elapsed
         self.stage_n[key] += 1
 
-    def _run_mesh(self, jobs: list, g: _Geometry) -> None:
+    def _launch(self, jobs: list, g: _Geometry, gen: int,
+                overlapped: bool) -> _Flown:
+        """The first half of a mesh dispatch, on the launching thread:
+        stack, then the mesh coder's pad and launch."""
         kind = jobs[0].kind
-        mesh, spec = g.mesh, g.spec
         # one ec.batch.dispatch span per dispatch, under the first
-        # sampled job; its stages (and the mesh coder's) nest in it
+        # sampled job, from here to the end of its demux; its stages
+        # (and the mesh coder's) nest in it, on whichever thread
         lead = next((j.span for j in jobs if j.span is not None), None)
+        span = lead.child("ec.batch.dispatch", "internal") \
+            if lead is not None else None
         compiles = self._compiles
         n_compiled = compiles.n if compiles is not None else 0
-        tok = tracing.attach(lead)
-        try:
-            with tracing.stage("ec.batch.dispatch") as disp:
+        # in a device trace ``ec.batch.dispatch`` is this half, on this
+        # thread's line, and ``ec.batch.collect`` the other, on the
+        # collector's
+        with tracing.stage("ec.batch.dispatch") as disp:
+            tok = tracing.attach(span)
+            try:
                 with tracing.stage("ec.batch.stack") as st:
                     # one job is its own batch: a view of the buffer
                     # submit made contiguous and padded, not a copy
@@ -598,48 +716,116 @@ class EcBatchScheduler:
                         else np.stack([j.data for j in jobs])
                 self._staged("stack", st)
                 if kind == "encode":
-                    out = mesh.encode_batch(stacked)
+                    pending = g.mesh.encode_batch_begin(stacked)
                 else:
-                    out = mesh.rebuild_batch(stacked,
-                                             [j.mat for j in jobs])
-                with tracing.stage("ec.batch.demux") as st:
-                    for i, j in enumerate(jobs):
-                        j.future.set_result(
-                            np.ascontiguousarray(out[i][:, :j.n]))
-                self._staged("demux", st)
-                if compiles is not None and compiles.n != n_compiled:
-                    # which step recompiled: the warm-up (or the ladder)
-                    # missed this shape and a request paid for it
-                    glog.warning(
-                        "EC batcher: %d program(s) compiled or loaded "
-                        "inside a dispatch: %s %s of shape %s",
-                        compiles.n - n_compiled, spec, kind,
-                        stacked.shape)
-                if lead is not None:
-                    disp.annotate("spec", spec)
-                    disp.annotate("kind", kind)
-                    disp.annotate("shape", list(stacked.shape))
-                    disp.annotate("rows", stacked.shape[1])
+                    pending = g.mesh.rebuild_batch_begin(
+                        stacked, [j.mat for j in jobs])
+            except BaseException as e:
+                if span is not None:
+                    span.finish(status=500,
+                                error=f"{type(e).__name__}: {e}")
+                raise
+            finally:
+                tracing.detach(tok)
+        if compiles is not None and compiles.n != n_compiled:
+            # which step recompiled: the warm-up (or the ladder)
+            # missed this shape and a request paid for it
+            glog.warning(
+                "EC batcher: %d program(s) compiled or loaded "
+                "inside a dispatch: %s %s of shape %s",
+                compiles.n - n_compiled, g.spec, kind, stacked.shape)
+        if span is not None:
+            span.annotate("spec", g.spec)
+            span.annotate("kind", kind)
+            span.annotate("shape", list(stacked.shape))
+            span.annotate("rows", stacked.shape[1])
+        return _Flown(jobs, g, pending, gen, span, disp.t0, overlapped)
+
+    def _collect_loop(self) -> None:
+        """The collector thread: the second half of every mesh dispatch,
+        in the order they were launched."""
+        while True:
+            with self._flight_cv:
+                while not self._flight:
+                    if not self._launching:
+                        return
+                    self._flight_cv.wait()
+                flown = self._flight[0]
+            with profiler.scope(cls=flown.jobs[0].cls or "background",
+                                route="ec-batch"), \
+                    tracing.stage("ec.batch.collect"):
+                self._collect(flown)
+
+    def _collect(self, flown: _Flown) -> None:
+        jobs, g, span = flown.jobs, flown.g, flown.span
+        out = None
+        tok = tracing.attach(span)
+        try:
+            try:
+                try:
+                    # a dispatch that was in flight when another failed
+                    # is not asked of the device: it goes the same way
+                    if flown.gen == self.coder_fallbacks:
+                        out = flown.pending.result()
+                finally:
+                    # off the device either way: its place is free
+                    with self._flight_cv:
+                        self._flight.popleft()
+                        self._flight_cv.notify_all()
+                if out is not None:
+                    with tracing.stage("ec.batch.demux") as st:
+                        rows = [np.ascontiguousarray(out[i][:, :j.n])
+                                for i, j in enumerate(jobs)]
+                        for j, r in zip(jobs, rows):
+                            j.future.set_result(r)
+                    self._staged("demux", st)
+            except Exception as e:  # noqa: BLE001 — the fallback ladder
+                self._fell_back(e, flown.gen)
+                out = None
+            if out is None:
+                self._run_host(jobs, g)
         finally:
             tracing.detach(tok)
-        if lead is None:
+        t1 = clockctl.monotonic()
+        if span is not None:
+            span.finish(status=200 if out is not None else 500)
+            # the other sampled jobs of the dispatch each get a child
+            # that names the one dispatch span by its id
+            for j in jobs:
+                if j.span is not None and j.span.span_id != span.parent_id:
+                    j.span.record("ec.batch.dispatch", flown.t0, t1,
+                                  {"dispatch_id": span.span_id,
+                                   "jobs": len(jobs), "spec": g.spec,
+                                   "rows": jobs[0].data.shape[0]})
+        if out is None:
             return
-        # the other sampled jobs of the dispatch each get a child that
-        # names the one dispatch span by its id
-        t1 = disp.t0 + disp.elapsed
-        for j in jobs:
-            if j.span is not None and j.span is not lead:
-                j.span.record("ec.batch.dispatch", disp.t0, t1,
-                              {"dispatch_id": disp.span.span_id,
-                               "jobs": len(jobs), "spec": spec,
-                               "rows": jobs[0].data.shape[0]})
+        # counted once its futures are set
+        self.mesh_batches += 1
+        g.counters["mesh_dispatches"] += 1
+        rung = self._rung(jobs[0].data.shape[1])
+        rung[1] += 1
+        rung[2] = max(rung[2], len(jobs))
+        if len(jobs) == 1:
+            self.lone_dispatches += 1
+        if flown.overlapped:
+            self.overlapped_dispatches += 1
+            g.counters["overlapped_dispatches"] += 1
+
+    def _run_host(self, jobs: list, g: _Geometry) -> None:
+        """A dispatch on the host coder, whole, on the calling thread
+        (the launching thread's, or the collector's after a failure)."""
+        with self._fallback_lock:
+            self.cpu_batches += 1
+            g.counters["cpu_dispatches"] += 1
+        self._run_cpu(jobs)
 
     def _run_cpu(self, jobs: list) -> None:
         for j in jobs:
             try:
                 g = self._geometry(j.scheme)
-                if g.cpu is None:
-                    g.cpu = host_coder(g.scheme, threaded=True)
+                with self._fallback_lock:
+                    if g.cpu is None:
+                        g.cpu = host_coder(g.scheme, threaded=True)
                 cpu = g.cpu
                 if j.kind == "encode":
                     out = np.asarray(cpu.encode_array(j.data))
@@ -652,13 +838,16 @@ class EcBatchScheduler:
     # ---- lifecycle / observability ----
 
     def stop(self) -> None:
-        """Stop the dispatcher; anything still queued drains through
-        the CPU coder so no submitted future is ever abandoned."""
+        """Stop the dispatcher: what is in flight is collected, anything
+        still queued drains through the CPU coder, so no submitted
+        future is ever abandoned."""
         if self._stopped:
             return
         self._stopped = True
         self._q.put(_STOP)
         self._thread.join(timeout=10)
+        # what is on the device is collected before the collector ends
+        self._collector.join(timeout=10)
         leftovers = []
         while True:
             try:
@@ -730,6 +919,7 @@ class EcBatchScheduler:
             "batches_total": self.batches_total,
             "mesh_batches": self.mesh_batches,
             "lone_dispatches": self.lone_dispatches,
+            "overlapped_dispatches": self.overlapped_dispatches,
             "cpu_batches": self.cpu_batches,
             "coder_fallbacks": self.coder_fallbacks,
             "max_coalesced": self.max_coalesced,
@@ -773,8 +963,8 @@ class BatchCoder(ErasureCoder):
     calling encode_begin/reconstruct_rows per block-group as for any
     coder; the facade turns those calls into scheduler submissions, so N
     concurrent volume pipelines coalesce into device-sized mesh batches
-    without knowing about each other, and a pipeline's begun batch waits
-    on the queue while the dispatcher has the one before it.
+    without knowing about each other, and a pipeline's begun batch is
+    launched onto the device behind the one before it.
 
     A scheduler serves as many facades as there are schemes among the
     store's volumes (``for_scheme``).  A scheme submits under ITSELF as
@@ -841,12 +1031,13 @@ class BatchCoder(ErasureCoder):
     def encode_begin(self, data: np.ndarray,
                      out: np.ndarray) -> _QueuedEncode:
         """Submit now, wait and copy out when asked: the caller may begin
-        its next batch while the dispatcher has this one.  Not before
-        this caller's previous job has LEFT the queue, though: a lone
-        pipeline keeps two jobs in the coder and never two in the queue,
-        so its jobs cannot share a dispatch (a B = 2 program that nothing
-        warmed: ~2 s of compile inside a seal) whatever the threads'
-        timing.  In step the wait is over long before it is asked for."""
+        its next batch while the device has this one.  Not before this
+        caller's previous job has LEFT the queue for its launch, though:
+        a lone pipeline keeps two jobs in the coder (on the device, when
+        both places were free) and never two in the queue, so its jobs
+        cannot share a dispatch (a B = 2 program that nothing warmed:
+        ~2 s of compile inside a seal) whatever the threads' timing.  In
+        step the wait is over long before it is asked for."""
         last = getattr(self._begun, "taken", None)
         if last is not None:
             last.wait()

@@ -315,6 +315,11 @@ class VolumeServer:
             "volumeServer", "ec_batch_cap_splits",
             "groups of EC jobs cut into several dispatches by the "
             "column cap")
+        # ... and how often its window of two dispatches engages
+        self._m_ec_overlapped = self.metrics.gauge(
+            "volumeServer", "ec_batch_overlapped",
+            "EC mesh dispatches launched while another was still on "
+            "the device")
         # hot-needle record cache + selector-core connection counters,
         # refreshed at scrape from their owners' stats() snapshots
         self._m_cache = self.metrics.gauge(
@@ -1063,6 +1068,7 @@ class VolumeServer:
                 for stat, val in counters.items():
                     self._m_ec_rung.set(rung, stat, value=val)
             self._m_ec_cap_splits.set(value=bs["cap_splits"])
+            self._m_ec_overlapped.set(value=bs["overlapped_dispatches"])
 
     def _handle_metrics(self, req: Request) -> Response:
         return Response(self.metrics.expose_text(),

@@ -335,7 +335,7 @@ def test_a_full_cache_keeps_the_rebuilt_chunk_and_not_the_healthy_one(
 
 
 class _Gated:
-    """A mesh coder whose dispatches wait for a gate (tests/
+    """A mesh coder whose launches wait for a gate (tests/
     test_mesh_batcher.py): what is submitted meanwhile queues up."""
 
     def __init__(self, inner):
@@ -348,11 +348,11 @@ class _Gated:
     def __getattr__(self, name):
         return getattr(self.inner, name)
 
-    def rebuild_batch(self, s, mats):
+    def rebuild_batch_begin(self, s, mats):
         self.entered.set()
         assert self.gate.wait(120)
         self.batches.append(s.shape)
-        return self.inner.rebuild_batch(s, mats)
+        return self.inner.rebuild_batch_begin(s, mats)
 
 
 def test_twenty_readers_rebuilding_a_block_each_at_once():
@@ -559,6 +559,11 @@ def test_the_servers_show_the_new_counters_after_a_run(served):
     assert any("volumeServer_ec_batch_cap_splits " in ln
                and float(ln.rsplit(" ", 1)[1]) == 0 for ln in lines
                if not ln.startswith("#"))
+    # PR 36: how often the window of two dispatches engaged
+    assert 0 <= b["overlapped_dispatches"] < b["mesh_batches"]
+    assert any("volumeServer_ec_batch_overlapped " in ln
+               and float(ln.rsplit(" ", 1)[1]) == b["overlapped_dispatches"]
+               for ln in lines if not ln.startswith("#"))
     # a second pass is the needle cache's: nothing is loaded again
     with ThreadPoolExecutor(max_workers=6) as pool:
         assert all(pool.map(read, corpus.fids()))

@@ -341,6 +341,45 @@ def test_a_local_repair_written_over_k_rows_rides_the_narrow_program():
     assert ("apply", 1, 12, 16) in c8.programs
 
 
+@pytest.mark.parametrize("spec", ["rs-10-4", "rs-6-3", "lrc-12-2-2"])
+def test_the_two_step_forms_equal_the_one_step_forms(spec):
+    """PR 36: ``encode_batch_begin(x).result()`` and
+    ``rebuild_batch_begin(...).result()`` are the one-step forms in two
+    halves (launch; fetch and unpack), for both RS geometries and for the
+    family at BOTH apply widths (a local repair's k / l rows and the
+    global decode's k), and several may be launched before the first is
+    collected."""
+    from seaweedfs_tpu.models.coder import host_coder, parse_code_spec
+    scheme = parse_code_spec(spec)
+    k, total = scheme.data_shards, scheme.total_shards
+    coder = MeshCoder(scheme, n_devices=1)
+    rng = np.random.default_rng(3600)
+    data = rng.integers(0, 256, (3, k, 512), dtype=np.uint8)
+    begun = [coder.encode_batch_begin(data[:b]) for b in (1, 3)]
+    for b, d in zip((1, 3), begun):
+        assert np.array_equal(d.result(), coder.encode_batch(data[:b]))
+    full = np.concatenate([data[0], coder.encode_batch(data[:1])[0]])
+    assert np.array_equal(
+        full[k:], host_coder(scheme, threaded=False).encode_array(data[0]))
+    plan = getattr(coder, "plan_rebuild", None)
+    losses = [[1], [1, k - 1]] if plan else [[1]]
+    widths = set()
+    for lost in losses:
+        have = [s for s in range(total) if s not in lost]
+        src, mat = plan(have, lost) if plan else \
+            (have[:k], coder.rebuild_matrix(have, lost))
+        operand = np.stack([full[src]] * 2)
+        two = coder.rebuild_batch_begin(operand, [mat] * 2)
+        one = coder.rebuild_batch(operand, [mat] * 2)
+        for got, want in zip(two.result(), one):
+            assert np.array_equal(got, want)
+            assert np.array_equal(got, full[lost])
+        widths.add(len(src))
+    assert widths == set(coder.apply_widths)
+    assert coder.stage_n["launch"] == coder.stage_n["fetch"] \
+        == coder.stage_n["pad"] == coder.stage_n["unpack"]
+
+
 # ----------------------- (c) a 16-shard volume through the CLI servers
 
 @pytest.fixture(scope="module")

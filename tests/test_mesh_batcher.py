@@ -134,7 +134,28 @@ def test_mesh_coder_scalar_bytes_api():
 
 # -------------------------------------------------- EcBatchScheduler
 
-class _Recorder:
+class _Begun:
+    """What a stand-in's ``*_batch_begin`` hands back."""
+
+    def __init__(self, result):
+        self.result = result
+
+
+class _AtLaunch:
+    """The scheduler's two-step seam for a stand-in that does its work
+    whole in the begin: the launching thread is inside it meanwhile, as
+    the one dispatcher thread was until PR 36."""
+
+    def encode_batch_begin(self, b):
+        out = self.encode_batch(b)
+        return _Begun(lambda: out)
+
+    def rebuild_batch_begin(self, s, mats):
+        out = self.rebuild_batch(s, mats)
+        return _Begun(lambda: out)
+
+
+class _Recorder(_AtLaunch):
     """Mesh stand-in that records the operands it is handed, in dispatch
     order, and answers via CPU."""
     n_devices = 1
@@ -156,11 +177,12 @@ class _Recorder:
                 for i in range(s.shape[0])]
 
 
-class _Gated:
+class _Gated(_AtLaunch):
     """A mesh coder behind a gate: every dispatch says it has ``entered``
-    and then waits for the gate.  What the scheduler is handed while one
-    dispatch is held shut queues up behind it — the tests' way to make
-    coalescing deterministic, now that the scheduler waits for nobody."""
+    and then waits for the gate, in its LAUNCH.  What the scheduler is
+    handed while one launch is held shut queues up behind it — the
+    tests' way to make coalescing deterministic, now that the scheduler
+    waits for nobody."""
 
     def __init__(self, inner):
         self.inner = inner
@@ -241,38 +263,80 @@ def test_lone_job_on_an_idle_scheduler_is_not_held():
     assert "window_s" not in st
 
 
-class _HeldUntilNextIsQueued(_Recorder):
-    """Every dispatch but the last is held until one more job has been
-    SUBMITTED than have been dispatched: the dispatcher is as slow as it
-    takes for the caller's next job to be queued behind the running one."""
+class _OnDevice(_Recorder):
+    """Mesh stand-in whose dispatches launch at once and come back when
+    the test lets them: ``log`` keeps the order of ("launch", i) and
+    ("collect", i), ``.result()`` of dispatch i waits for ``gates[i]``
+    (or for nothing once ``open_all`` is set) and raises if i is in
+    ``fail``.  ``chained=n``: the test opens no gate; dispatch i is let
+    back when dispatch i + 1 has been launched, the n-th at once.  No
+    timing: the order is what the events make it."""
 
-    def __init__(self, sched_q, jobs: int):
+    def __init__(self, fail=(), chained: int = 0):
         super().__init__()
-        self.jobs = jobs
-        self.submitted = 0
+        self.fail = set(fail)
+        self.chained = chained
         self.cv = threading.Condition()
-        real_put = sched_q.put
+        self.log: list = []
+        self.gates: list = []
+        self.open_all = False
+        self.on_device = self.most_on_device = 0
 
-        def put(job, *a, **kw):
-            real_put(job, *a, **kw)
-            with self.cv:
-                self.submitted += 1
-                self.cv.notify_all()
-        sched_q.put = put
-
-    def encode_batch(self, b):
+    def _begin(self, operand, compute):
         with self.cv:
-            want = min(self.jobs, len(self.seen) + 2)
-            assert self.cv.wait_for(lambda: self.submitted >= want, 60)
-        return super().encode_batch(b)
+            i = len(self.gates)
+            gate = threading.Event()
+            if self.open_all or i + 1 == self.chained:
+                gate.set()
+            if self.chained and i:
+                self.gates[i - 1].set()
+            self.gates.append(gate)
+            self.seen.append(operand)
+            self.log.append(("launch", i))
+            self.on_device += 1
+            self.most_on_device = max(self.most_on_device, self.on_device)
+            self.cv.notify_all()
+
+        def result():
+            assert gate.wait(60)
+            with self.cv:
+                self.log.append(("collect", i))
+                self.on_device -= 1
+            if i in self.fail:
+                raise RuntimeError("device_put failed: device vanished")
+            return compute()
+        return _Begun(result)
+
+    def encode_batch_begin(self, b):
+        return self._begin(
+            b, lambda: np.stack([CPU.encode_array(x) for x in b]))
+
+    def rebuild_batch_begin(self, s, mats):
+        return self._begin(s, lambda: [CPU.reconstruct_rows(s[i], mats[i])
+                                       for i in range(s.shape[0])])
+
+    def launched(self, n: int) -> None:
+        """Wait until ``n`` dispatches have been launched."""
+        with self.cv:
+            assert self.cv.wait_for(lambda: len(self.gates) >= n, 60), \
+                self.log
+
+    def release_all(self) -> None:
+        with self.cv:
+            self.open_all = True
+            for g in self.gates:
+                g.set()
 
 
 @pytest.mark.parametrize("batches", [2, 3, 9])
 def test_lone_pipeline_keeps_two_jobs_in_the_queue_and_dispatches_one(
         tmp_path, batches):
-    """A lone seal's window of two: job N+1 is queued while N is
-    dispatched, and still every dispatch carries ONE job, as a view
-    (``traffic/single.json`` warms only the B = 1 program)."""
+    """A lone seal's window of two, since PR 36 on the DEVICE: job N+1 is
+    launched while N is uncollected (until then it sat in the queue for
+    the length of N's dispatch), and still every dispatch carries ONE
+    job, as a view (``traffic/single.json`` warms only the B = 1
+    program): the caller's next job is not submitted before its last one
+    was launched (``_Job.taken``)."""
     from seaweedfs_tpu.parallel import streaming
     from seaweedfs_tpu.storage.erasure_coding import encoder as ecenc
     from seaweedfs_tpu.storage.erasure_coding import layout
@@ -284,9 +348,11 @@ def test_lone_pipeline_keeps_two_jobs_in_the_queue_and_dispatches_one(
         with open(base + ".dat", "wb") as f:
             f.write(dat)
     ecenc.write_ec_files(sbase, CPU, lb, sb, batch_size=sb)
-    sched = EcBatchScheduler(mesh_coder=_Recorder())
-    held = _HeldUntilNextIsQueued(sched._q, batches)
-    sched._mesh = sched._geometries[DEFAULT_SCHEME].mesh = held
+    # every dispatch but the last comes back only when the next has been
+    # launched: the device is as slow as it takes for the caller's next
+    # job to be on it too
+    held = _OnDevice(chained=batches)
+    sched = EcBatchScheduler(mesh_coder=held)
     stats: dict = {}
     try:
         streaming.pipelined_encode_file(pbase, BatchCoder(sched), lb, sb,
@@ -301,8 +367,10 @@ def test_lone_pipeline_keeps_two_jobs_in_the_queue_and_dispatches_one(
     assert stats["overlapped"] == batches - 1
     assert st["max_coalesced"] == 1
     assert st["lone_dispatches"] == st["mesh_batches"] == batches
+    assert st["batches_total"] == batches
+    assert st["overlapped_dispatches"] == batches - 1
     assert st["cpu_batches"] == 0 and st["coder_fallbacks"] == 0
-    assert len(held.seen) == batches
+    assert [b.shape[0] for b in held.seen] == [1] * batches
     for i in range(TOTAL):
         ext = layout.shard_ext(i)
         assert open(pbase + ext, "rb").read() == \
@@ -404,7 +472,7 @@ def test_scheduler_orders_by_qos_class():
         sched.stop()
 
 
-class _Boom:
+class _Boom(_AtLaunch):
     n_devices = 8
 
     def encode_batch(self, b):
@@ -481,6 +549,280 @@ def test_batch_coder_facade_is_a_drop_in_coder():
         assert bc.verify(full)
     finally:
         sched.stop()
+
+
+# ------------------------------- two dispatches on the device (PR 36)
+
+def _held_by_the_launcher(sched, n_queued: int = 0) -> None:
+    """Wait until the launching thread holds a job (it is waiting for a
+    place on the device) with ``n_queued`` more behind it in the queue."""
+    deadline = time.monotonic() + 60
+    while time.monotonic() < deadline:
+        st = sched.stats()
+        if st["queued"] == n_queued and sched._loop_pub[3] == 1:
+            return
+        time.sleep(0.001)
+    raise AssertionError(sched.stats())
+
+
+@pytest.mark.parametrize("kind", ["encode", "rebuild"])
+def test_a_job_behind_an_uncollected_dispatch_is_launched_at_once(kind):
+    """Dispatch N+1 is LAUNCHED while N is uncollected; never more than
+    two are on the device; what queues up while both places are taken
+    rides ONE next dispatch; results come back in launch order,
+    bit-identical to the host coder; ``overlapped_dispatches`` counts."""
+    dev = _OnDevice()
+    sched = EcBatchScheduler(mesh_coder=dev)
+    mat = CPU.rebuild_matrix(list(range(1, TOTAL)), [0])
+    datas = [_batch(1, 1000, seed=60 + i)[0] for i in range(5)]
+    if kind == "encode":
+        submit, want = sched.submit_encode, CPU.encode_array
+    else:
+        def submit(d):
+            return sched.submit_rebuild(d, mat)
+
+        def want(d):
+            return CPU.reconstruct_rows(d, mat)
+    try:
+        f0 = submit(datas[0])
+        dev.launched(1)
+        f1 = submit(datas[1])
+        dev.launched(2)               # ... while dispatch 0 is uncollected
+        assert dev.log == [("launch", 0), ("launch", 1)]
+        rest = [submit(d) for d in datas[2:]]
+        _held_by_the_launcher(sched, n_queued=2)
+        assert len(dev.gates) == 2 and not f0.done()
+        dev.gates[0].set()
+        assert np.array_equal(f0.result(timeout=60), want(datas[0]))
+        dev.launched(3)               # the three that waited, in ONE
+        assert dev.seen[2].shape[0] == 3
+        assert not f1.done()          # handed over in launch order
+        dev.gates[1].set()
+        assert np.array_equal(f1.result(timeout=60), want(datas[1]))
+        assert not any(f.done() for f in rest)
+        dev.gates[2].set()
+        for d, f in zip(datas[2:], rest):
+            assert np.array_equal(f.result(timeout=60), want(d))
+        while sched.stats()["mesh_batches"] < 3:
+            time.sleep(0.001)
+        st = sched.stats()
+    finally:
+        dev.release_all()
+        sched.stop()
+    assert dev.log == [("launch", 0), ("launch", 1), ("collect", 0),
+                       ("launch", 2), ("collect", 1), ("collect", 2)]
+    assert dev.most_on_device == 2
+    assert st["jobs_total"] == 5 and st["mesh_batches"] == 3
+    assert st["lone_dispatches"] == 2 and st["max_coalesced"] == 3
+    assert st["overlapped_dispatches"] == 2
+    assert st["by_spec"]["rs-10-4"]["overlapped_dispatches"] == 2
+    assert st["cpu_batches"] == 0 and st["coder_fallbacks"] == 0
+    # submit -> launch: the job that found a place free did not wait for
+    # the dispatch before it (60 s of gate would show)
+    (_labels, counts, _total, _ex), = st["wait_hist"]["series"]
+    assert sum(counts) == 5
+
+
+@pytest.mark.parametrize("fails_at", ["collect", "launch"])
+def test_a_failure_with_a_second_dispatch_in_flight_drains_both(fails_at):
+    """The fallback ladder per dispatch: the dispatch that failed (at its
+    collect, or at its launch), the one in flight BEHIND it and what was
+    queued all resolve through the CPU coder bit-identically; one
+    failure is counted once, the observer hears once, the mesh is
+    benched."""
+    dev = _OnDevice(fail={0} if fails_at == "collect" else ())
+    reasons = []
+    sched = EcBatchScheduler(mesh_coder=dev, cooldown_s=60.0,
+                             on_fallback=reasons.append)
+    datas = [_batch(1, 1000, seed=70 + i)[0] for i in range(4)]
+    try:
+        futs = [sched.submit_encode(datas[0])]
+        dev.launched(1)
+        if fails_at == "launch":
+            def boom(b):
+                raise RuntimeError("device_put failed: device vanished")
+            dev.encode_batch_begin = boom
+        futs.append(sched.submit_encode(datas[1]))
+        if fails_at == "collect":
+            dev.launched(2)
+            futs += [sched.submit_encode(d) for d in datas[2:]]
+            _held_by_the_launcher(sched, n_queued=1)
+            dev.gates[0].set()
+        else:
+            # the launch of dispatch 1 raised beside dispatch 0 in flight
+            assert np.array_equal(futs[1].result(timeout=60),
+                                  CPU.encode_array(datas[1]))
+            futs += [sched.submit_encode(d) for d in datas[2:]]
+            dev.gates[0].set()
+        for d, f in zip(datas, futs):
+            assert np.array_equal(f.result(timeout=60),
+                                  CPU.encode_array(d))
+        st = sched.stats()
+    finally:
+        dev.release_all()
+        sched.stop()
+    assert st["coder_fallbacks"] == 1 and reasons == ["device_put"]
+    assert st["fallback_reason"] == "device_put"
+    assert st["mesh_healthy"] is False
+    if fails_at == "collect":
+        # 0 (failed), 1 (in flight behind it: not asked of the device),
+        # then 2 + 3 in one, on the launching thread
+        assert st["mesh_batches"] == 0 and st["cpu_batches"] == 3
+        assert ("collect", 1) not in dev.log
+    else:
+        # 1 (its launch raised) and 2, 3 (or 2 + 3) on the host; 0, whose
+        # collect had begun, came back from the device
+        assert st["mesh_batches"] == 1 and st["cpu_batches"] in (2, 3)
+        assert dev.log == [("launch", 0), ("collect", 0)]
+    assert st["overlapped_dispatches"] == 0
+    assert st["by_spec"]["rs-10-4"]["cpu_dispatches"] == st["cpu_batches"]
+
+
+def test_stop_with_two_in_flight_resolves_every_future():
+    dev = _OnDevice()
+    sched = EcBatchScheduler(mesh_coder=dev)
+    datas = [_batch(1, 512, seed=80 + i)[0] for i in range(4)]
+    futs = [sched.submit_encode(datas[0])]
+    dev.launched(1)
+    futs.append(sched.submit_encode(datas[1]))
+    dev.launched(2)
+    futs += [sched.submit_encode(d) for d in datas[2:]]
+    _held_by_the_launcher(sched, n_queued=1)
+    stopper = threading.Thread(target=sched.stop)
+    stopper.start()
+    try:
+        while not sched._stopped:
+            time.sleep(0.001)
+        assert not any(f.done() for f in futs)
+    finally:
+        dev.release_all()
+    stopper.join(timeout=30)
+    assert not stopper.is_alive()
+    assert not sched._thread.is_alive()
+    assert not sched._collector.is_alive()
+    for d, f in zip(datas, futs):
+        assert np.array_equal(f.result(timeout=1), CPU.encode_array(d))
+    with pytest.raises(RuntimeError):
+        sched.submit_encode(datas[0])
+
+
+class _Counted:
+    """A real mesh coder that counts how many of its dispatches are on
+    the device at once."""
+
+    def __init__(self, inner):
+        self.inner = inner
+        self.n_devices = inner.n_devices
+        self.lock = threading.Lock()
+        self.on_device = self.most_on_device = 0
+
+    def __getattr__(self, name):
+        return getattr(self.inner, name)
+
+    def _counted(self, pending):
+        with self.lock:
+            self.on_device += 1
+            self.most_on_device = max(self.most_on_device, self.on_device)
+
+        def result():
+            try:
+                return pending.result()
+            finally:
+                with self.lock:
+                    self.on_device -= 1
+        return _Begun(result)
+
+    def encode_batch_begin(self, b):
+        return self._counted(self.inner.encode_batch_begin(b))
+
+    def rebuild_batch_begin(self, s, mats):
+        return self._counted(self.inner.rebuild_batch_begin(s, mats))
+
+
+def test_many_submitters_two_threads_no_update_is_lost():
+    """More submitting threads than cores, a shortened switch interval:
+    every result is its own job's, bit-identical; the counters the two
+    threads of the scheduler keep add up; never more than two dispatches
+    on the device."""
+    import sys
+    counted = _Counted(MeshCoder(DEFAULT_SCHEME, n_devices=1))
+    sched = EcBatchScheduler(mesh_coder=counted)
+    coder = BatchCoder(sched)
+    mat = CPU.rebuild_matrix(list(range(1, TOTAL)), [0])
+    n_threads, n_each = 24, 12
+    wrong: list = []
+
+    def work(t):
+        rng = np.random.default_rng(900 + t)
+        for i in range(n_each):
+            d = rng.integers(0, 256, (K, 2048), dtype=np.uint8)
+            if (t + i) % 2:
+                ok = np.array_equal(coder.encode_array(d),
+                                    CPU.encode_array(d))
+            else:
+                ok = np.array_equal(coder.reconstruct_rows(d, mat),
+                                    CPU.reconstruct_rows(d, mat))
+            if not ok:
+                wrong.append((t, i))
+    coder.encode_array(_batch(1, 2048)[0])          # compile outside
+    coder.reconstruct_rows(_batch(1, 2048)[0], mat)
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=work, args=(t,))
+                   for t in range(n_threads)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=240)
+        assert not any(th.is_alive() for th in threads)
+    finally:
+        sys.setswitchinterval(was)
+        sched.stop()
+    st = sched.stats()
+    n = n_threads * n_each + 2
+    assert wrong == []
+    assert st["jobs_total"] == n == st["by_spec"]["rs-10-4"]["jobs"]
+    assert st["cpu_batches"] == 0 and st["coder_fallbacks"] == 0
+    assert st["mesh_batches"] == st["by_spec"]["rs-10-4"]["mesh_dispatches"]
+    assert st["mesh_batches"] == sum(
+        r["mesh_dispatches"] for r in st["by_rung"].values())
+    assert st["stage_n"]["launch"] == st["stage_n"]["fetch"] \
+        == st["stage_n"]["demux"] == st["mesh_batches"]
+    assert 0 < st["overlapped_dispatches"] < st["mesh_batches"]
+    assert counted.most_on_device == 2 and counted.on_device == 0
+
+
+def test_a_replaced_one_step_form_is_what_the_scheduler_runs(monkeypatch):
+    """benchmark/tests/faulty_volume.py plants its faults by replacing
+    ``MeshCoder.encode_batch`` / ``rebuild_batch`` on the class: the
+    two-step forms run a replaced one-step form whole, so a fault planted
+    there still reaches every job the scheduler dispatches."""
+    real_enc, real_reb = MeshCoder.encode_batch, MeshCoder.rebuild_batch
+
+    def encode_batch(self, batch):
+        out = np.array(real_enc(self, batch))
+        out[0, 0, 0] ^= 1
+        return out
+
+    def rebuild_batch(self, srcdata, mats):
+        recs = [np.array(r) for r in real_reb(self, srcdata, mats)]
+        recs[0][0, 0] ^= 1
+        return recs
+    data = _batch(1, 4096, seed=8)[0]
+    mat = CPU.rebuild_matrix(list(range(1, TOTAL)), [0])
+    sched = EcBatchScheduler(mesh_coder=MeshCoder(DEFAULT_SCHEME))
+    try:
+        sound = sched.encode(data), sched.rebuild(data, mat)
+        monkeypatch.setattr(MeshCoder, "encode_batch", encode_batch)
+        monkeypatch.setattr(MeshCoder, "rebuild_batch", rebuild_batch)
+        planted = sched.encode(data), sched.rebuild(data, mat)
+    finally:
+        sched.stop()
+    for good, bad in zip(sound, planted):
+        assert good[0, 0] ^ 1 == bad[0, 0]
+        assert np.array_equal(good.ravel()[1:], bad.ravel()[1:])
+    assert np.array_equal(sound[0], CPU.encode_array(data))
 
 
 # ------------------------------------------- mixed-code batch drain
@@ -792,11 +1134,13 @@ def test_loop_and_stage_counters_account_for_the_wall():
     assert list(stage) == ["submit", "stack", "pad", "launch", "fetch",
                            "unpack", "demux", "result"]
     assert set(count.values()) == {10}
-    six = sum(stage[k] for k in ("stack", "pad", "launch", "fetch",
-                                 "unpack", "demux"))
-    assert 0.6 * d["dispatch"] <= six <= d["dispatch"]
-    # the caller waits for hold + dispatch
-    assert stage["result"] >= d["dispatch"]
+    # loop_s is the LAUNCHING thread's time: its dispatch part is a
+    # dispatch's first half (the second is the collector's)
+    launched = sum(stage[k] for k in ("stack", "pad", "launch"))
+    assert 0.6 * d["dispatch"] <= launched <= d["dispatch"]
+    # the caller waits for hold + dispatch + the collect
+    assert stage["result"] >= d["dispatch"] + sum(
+        stage[k] for k in ("fetch", "unpack", "demux"))
     by = {k: {f: b["by_kind"][k][f] - a["by_kind"][k][f]
               for f in b["by_kind"][k]} for k in b["by_kind"]}
     rung = COLUMN_LADDER[0]
