@@ -529,9 +529,13 @@ class _ConnHandler(BaseHTTPRequestHandler):
         self.rfile = _BufferedReader(sock)
         self.wfile = sock.makefile("wb", self.wbufsize)
         # when the selector core handed this connection to the worker
-        # pool (0.0 once the first request of the slice has taken it):
-        # the server span's queue_ms
+        # pool (its turn's t0; 0.0 once the first request of the slice
+        # has taken it), and of the request being served: when it began
+        # (the http.request stage's t0) and how long it waited for a
+        # worker (queue_us, the server span's queue_ms)
         self.ready_at = 0.0
+        self.t_request = 0.0
+        self.queue_s = 0.0
 
     def log_message(self, *args):
         pass  # request lines are emitted via glog at -v=2
@@ -552,31 +556,18 @@ class _ConnHandler(BaseHTTPRequestHandler):
             return _CLOSE
         try:
             while True:
-                self.close_connection = True
-                self.raw_requestline = self.rfile.readline(65537)
-                if not self.raw_requestline:
-                    return _CLOSE
-                if len(self.raw_requestline) > 65536:
-                    self.requestline = ""
-                    self.request_version = self.default_request_version
-                    self.command = ""
-                    self.send_error(414)
-                    self.wfile.flush()
-                    return _CLOSE
-                if not self.parse_request():
-                    self.wfile.flush()
-                    return _CLOSE
-                if not hasattr(self, "do_" + self.command):
-                    self.send_error(
-                        501, f"Unsupported method ({self.command!r})")
-                    self.wfile.flush()
-                    return _CLOSE
-                self._dispatch()
-                self.wfile.flush()
-                if self.close_connection:
-                    return _CLOSE
-                if not self._pending():
-                    return _PARK
+                # the request's stage: from here (its bytes are on hand)
+                # to the park check; a parked socket is in no stage. The
+                # wait for a worker is the slice's first request's alone
+                t0 = self.t_request = clockctl.monotonic()
+                ready_at, self.ready_at = self.ready_at, 0.0
+                self.queue_s = t0 - ready_at if ready_at else 0.0
+                meta = ({"queue_us": int(self.queue_s * 1e6)}
+                        if tracing.recording() else None)
+                with tracing.stage("http.request", meta, t0):
+                    verdict = self._serve_one()
+                if verdict is not None:
+                    return verdict
         except (TimeoutError, socket.timeout, ConnectionError):
             return _CLOSE
         except OSError:
@@ -587,6 +578,33 @@ class _ConnHandler(BaseHTTPRequestHandler):
             glog.exception("connection handler error: %s",
                            type(e).__name__)
             return _CLOSE
+
+    def _serve_one(self) -> Optional[str]:
+        """One request: None when the next one's bytes are already here,
+        else _PARK or _CLOSE. ``_dispatch`` flushes the response."""
+        self.close_connection = True
+        with tracing.stage("http.head"):
+            self.raw_requestline = self.rfile.readline(65537)
+            if not self.raw_requestline:
+                return _CLOSE
+            if len(self.raw_requestline) > 65536:
+                self.requestline = ""
+                self.request_version = self.default_request_version
+                self.command = ""
+                self.send_error(414)
+                self.wfile.flush()
+                return _CLOSE
+            if not self.parse_request():
+                self.wfile.flush()
+                return _CLOSE
+        if not hasattr(self, "do_" + self.command):
+            self.send_error(501, f"Unsupported method ({self.command!r})")
+            self.wfile.flush()
+            return _CLOSE
+        self._dispatch()
+        if self.close_connection:
+            return _CLOSE
+        return None if self._pending() else _PARK
 
     def _pending(self) -> bool:
         """True when another request's bytes are already available:
@@ -725,6 +743,7 @@ class _ConnHandler(BaseHTTPRequestHandler):
             self._reject(Response(
                 {"error": "draining"}, status=503,
                 headers={"Retry-After": "1"}), length)
+            self._flush()
             return
         with server._inflight_lock:
             server._inflight += 1
@@ -748,12 +767,10 @@ class _ConnHandler(BaseHTTPRequestHandler):
         span = (tracer.server_span(f"{self.command} {path}",
                                    self.headers)
                 if tracer is not None else tracing.NOOP)
-        ready_at = self.ready_at
-        if ready_at and span is not tracing.NOOP:
-            # selector saw the socket readable -> a worker got here
-            # (pool queue, request line and headers off the wire)
-            span.queue_ms = (clockctl.monotonic() - ready_at) * 1e3
-        self.ready_at = 0.0
+        if span is not tracing.NOOP:
+            # the selector's turn saw the socket readable -> a worker
+            # began the request: http.request's queue_us
+            span.queue_ms = self.queue_s * 1e3
         tok = tracing.attach(span)
         try:
             self._dispatch_inner(path, length, span)
@@ -775,26 +792,50 @@ class _ConnHandler(BaseHTTPRequestHandler):
         # server span's cpu_ms
         t_cpu = clockctl.thread_time() \
             if ledger is not None or span is not tracing.NOOP else 0.0
-        status, bytes_in, bytes_out = 500, 0, 0
+        status, bytes_in, bytes_out, t_send = 500, 0, 0, None
+        flushed = None
         try:
-            status, bytes_in, bytes_out = self._dispatch_gated(
-                path, length, span, fam, eff_cls, t_cpu)
+            try:
+                status, bytes_in, bytes_out, t_send = self._dispatch_gated(
+                    path, length, span, fam, eff_cls)
+            finally:
+                profiler.untag(ptok)
+                if ledger is not None:
+                    # the handler ran on THIS thread, so the per-thread
+                    # CPU clock delta is exactly the request's burn
+                    tenant = (server.tenant_fn(self.headers,
+                                               self.client_address[0])
+                              if server.tenant_fn is not None
+                              else self.client_address[0])
+                    ledger.observe_request(
+                        eff_cls, tenant,
+                        cpu_s=clockctl.thread_time() - t_cpu,
+                        bytes_in=bytes_in, bytes_out=bytes_out)
+            # the response leaves here: after the RED histogram and the
+            # ledger have the request (a client that reads them once it
+            # has its response finds it there), before the server span
+            # closes, whose send_ms and length hold the flush
+            flushed = self._flush()
         finally:
-            profiler.untag(ptok)
-            if ledger is not None:
-                # the handler ran on THIS thread, so the per-thread
-                # CPU clock delta is exactly the request's burn
-                tenant = (server.tenant_fn(self.headers,
-                                           self.client_address[0])
-                          if server.tenant_fn is not None
-                          else self.client_address[0])
-                ledger.observe_request(
-                    eff_cls, tenant,
-                    cpu_s=clockctl.thread_time() - t_cpu,
-                    bytes_in=bytes_in, bytes_out=bytes_out)
+            if flushed is not None and t_send is not None:
+                span.send_ms = (flushed.t0 + flushed.elapsed - t_send) * 1e3
+            _finish_span(span, status, t_cpu)
+        if glog.v(2):
+            glog.vlog(2, "%s %s %d %dB %.1fms", self.command, self.path,
+                      status, bytes_out,
+                      (flushed.t0 + flushed.elapsed - self.t_request) * 1e3)
 
-    def _dispatch_gated(self, path, length, span, fam, eff_cls,
-                        t_cpu=0.0):
+    def _flush(self) -> "tracing.stage":
+        """The response's buffered bytes to the socket; its end is when
+        the response left."""
+        with tracing.stage("http.flush") as flushed:
+            self.wfile.flush()
+        return flushed
+
+    def _dispatch_gated(self, path, length, span, fam, eff_cls):
+        """Gates, route and handler, the response written into wfile:
+        (status, bytes in, bytes out, when the send began; None where
+        no span wants send_ms or the request was rejected)."""
         server = self.srv
         # RED edge observation brackets EVERYTHING — admission
         # sheds, gate rejects, 404s, handler 500s — so the
@@ -820,14 +861,13 @@ class _ConnHandler(BaseHTTPRequestHandler):
             if isinstance(verdict, Response):
                 self._reject(verdict, length)
                 red_observe(verdict.status)
-                _finish_span(span, verdict.status, t_cpu)
-                return verdict.status, 0, 0
+                return verdict.status, 0, 0, None
             release = verdict
         on_sent = None
         resp = None
         stream = None
         out_status = 500
-        t0 = clockctl.monotonic()
+        t_send = None
         try:
             gate = server.body_gate
             if gate is not None and length and \
@@ -836,7 +876,7 @@ class _ConnHandler(BaseHTTPRequestHandler):
                 if isinstance(verdict, Response):
                     out_status = verdict.status
                     self._reject(verdict, length)
-                    return out_status, 0, 0
+                    return out_status, 0, 0, None
                 on_sent = verdict
             # the body stays ON THE WIRE until the handler asks for
             # it: streaming handlers pull req.stream a chunk at a
@@ -875,17 +915,10 @@ class _ConnHandler(BaseHTTPRequestHandler):
                 resp.headers.setdefault("Connection", "close")
                 self.close_connection = True
             out_status = resp.status
-            t_send = clockctl.monotonic()
-            self._send(resp)
             if span is not tracing.NOOP:
-                # header formatting + the write into wfile; a body that
-                # fits wfile's buffer reaches the socket at the flush
-                # after _dispatch, outside the span
-                span.send_ms = (clockctl.monotonic() - t_send) * 1e3
-            glog.vlog(2, "%s %s %d %dB %.1fms",
-                      self.command, self.path, resp.status,
-                      len(resp.body),
-                      (clockctl.monotonic() - t0) * 1e3)
+                # send_ms: framing, the write into wfile and the flush
+                t_send = clockctl.monotonic()
+            self._send(resp)
         finally:
             if on_sent is not None:
                 on_sent()
@@ -895,10 +928,9 @@ class _ConnHandler(BaseHTTPRequestHandler):
             if release is not None:
                 release()
             red_observe(out_status)
-            _finish_span(span, out_status, t_cpu)
         return (out_status,
                 stream.consumed if stream is not None else 0,
-                len(resp.body) if resp is not None else 0)
+                len(resp.body) if resp is not None else 0, t_send)
 
     def _send(self, resp):
         body = resp.body
@@ -1192,33 +1224,43 @@ class _SelectorCore:
                 continue
             if not self._running:
                 break
-            for key, _ in events:
-                tag = key.data
-                if tag is _WAKE:
-                    try:
-                        while self._wake_r.recv(4096):
-                            pass
-                    except (BlockingIOError, OSError):
-                        pass
-                elif tag is _ACCEPT:
-                    if self._accepting:
-                        self._accept_burst()
-                else:  # a parked connection became readable (or EOF'd)
-                    h = tag
-                    try:
-                        self._sel.unregister(key.fileobj)
-                    except (KeyError, ValueError, OSError):
-                        pass
-                    with self._lock:
-                        self._parked.pop(h, None)
-                    self._submit(h)
-            self._drain_inbox()
-            now = clockctl.monotonic()
+            if events:
+                # the loop body, not the blocking select: its t0 is when
+                # the sockets it hands off were seen readable
+                with tracing.stage("http.selector") as turn:
+                    self._turn(events, turn.t0)
+                now = turn.t0
+            else:
+                now = clockctl.monotonic()
+                self._drain_inbox(now)
             if now - last_sweep >= 5.0:
                 last_sweep = now
                 self._sweep_idle(now)
 
-    def _accept_burst(self) -> None:
+    def _turn(self, events, now: float) -> None:
+        for key, _ in events:
+            tag = key.data
+            if tag is _WAKE:
+                try:
+                    while self._wake_r.recv(4096):
+                        pass
+                except (BlockingIOError, OSError):
+                    pass
+            elif tag is _ACCEPT:
+                if self._accepting:
+                    self._accept_burst(now)
+            else:  # a parked connection became readable (or EOF'd)
+                h = tag
+                try:
+                    self._sel.unregister(key.fileobj)
+                except (KeyError, ValueError, OSError):
+                    pass
+                with self._lock:
+                    self._parked.pop(h, None)
+                self._submit(h, now)
+        self._drain_inbox(now)
+
+    def _accept_burst(self, now: float) -> None:
         for _ in range(128):
             try:
                 # via self.socket, not a captured local: tls.py may
@@ -1243,10 +1285,10 @@ class _SelectorCore:
             h = _ConnHandler(conn, addr, self.srv)
             with self._lock:
                 self._conns.add(h)
-            self._submit(h)
+            self._submit(h, now)
 
-    def _submit(self, h) -> None:
-        h.ready_at = clockctl.monotonic()
+    def _submit(self, h, now: float) -> None:
+        h.ready_at = now
         if self._pool.submit(lambda: self._service(h)):
             return
         # worker queue saturated: canned 503 + close, never blocking
@@ -1275,7 +1317,7 @@ class _SelectorCore:
             with self._lock:
                 self._conns.discard(h)
 
-    def _drain_inbox(self) -> None:
+    def _drain_inbox(self, now: float) -> None:
         while True:
             with self._lock:
                 if not self._inbox:
@@ -1294,7 +1336,7 @@ class _SelectorCore:
                     self._conns.discard(h)
                 continue
             with self._lock:
-                self._parked[h] = clockctl.monotonic()
+                self._parked[h] = now
 
     def _sweep_idle(self, now: float) -> None:
         timeout = self.srv.idle_timeout

@@ -34,6 +34,8 @@ records a child span, but only under a SAMPLED ambient span: an
 unsampled or absent span allocates no ``Span``, no dict and takes no
 lock.  It times itself (``elapsed``) so the layer that owns a counter
 adds the same two clock reads to it; the primitive keeps no counters.
+A stage may carry ``meta``, numbers the annotator keeps beside the event
+(``http.request``'s ``queue_us``), built only while ``recording()``.
 
 Stdlib-only on purpose: httpd, resilience and the QoS governor all
 import this module, so it must sit below them in the import DAG
@@ -361,8 +363,8 @@ def detail(key, value) -> None:
 
 # ---- stages ----
 
-# annotator(name) -> context manager entered for the length of a stage.
-# parallel/mesh.py (the one module that owns JAX) installs
+# annotator(name, **meta) -> context manager entered for the length of a
+# stage. parallel/mesh.py (the one module that owns JAX) installs
 # jax.profiler.TraceAnnotation when a device coder is built; this module
 # and httpd stay stdlib-only and never import jax.
 _annotator = None
@@ -373,31 +375,54 @@ def set_annotator(fn) -> None:
     _annotator = fn
 
 
+def recording() -> bool:
+    """True while the installed annotator records: a device trace is
+    being taken (its ``is_enabled()``; an annotator without one, a
+    test's, always records).  A site that gives a stage ``meta`` builds
+    the dict only then, so a request pays for none while nobody looks."""
+    ann = _annotator
+    if ann is None:
+        return False
+    live = getattr(ann, "is_enabled", None)
+    return live is None or live()
+
+
 class stage:
     """``with tracing.stage("ec.batch.stack") as st:`` — see the module
     docstring.  ``st.annotate`` reaches the child span when there is one
     (sampled requests only); ``st.t0`` is when it began
     (``clockctl.monotonic``) and ``st.elapsed`` its length in seconds once it
-    has exited.  Names are static strings."""
+    has exited.  Names are static strings.
 
-    __slots__ = ("name", "span", "elapsed", "_ann", "_tok", "t0")
+    ``meta`` (a dict of numbers, or None) is handed to the annotator as
+    keyword arguments: a ``TraceAnnotation``'s metadata, kept beside the
+    event in the ``.xplane.pb``; build it only while ``recording()``.
+    ``t0``: a clock read the caller already made for its own use, taken
+    as the stage's start instead of reading the clock again."""
 
-    def __init__(self, name: str):
+    __slots__ = ("name", "meta", "span", "elapsed", "_ann", "_tok", "t0")
+
+    def __init__(self, name: str, meta: Optional[dict] = None,
+                 t0: Optional[float] = None):
         self.name = name
+        self.meta = meta
         self.span = None
         self.elapsed = 0.0
+        self.t0 = t0
 
     def __enter__(self) -> "stage":
         ann = _annotator
         if ann is not None:
-            ann = ann(self.name)
+            meta = self.meta
+            ann = ann(self.name) if meta is None else ann(self.name, **meta)
             ann.__enter__()
         self._ann = ann
         parent = _current.get()
         if parent is not None and parent.sampled:
             self.span = parent.child(self.name, "internal")
             self._tok = _current.set(self.span)
-        self.t0 = clockctl.monotonic()
+        if self.t0 is None:
+            self.t0 = clockctl.monotonic()
         return self
 
     def __exit__(self, et, ev, tb) -> bool:
