@@ -513,24 +513,46 @@ uint32_t crc32c(uint32_t crc, const uint8_t* buf, int64_t len) {
     return crc32c_sw(crc, buf, len);
 }
 
-// Fill the rows of one operand from n files: `len` bytes from `offset` of
-// fds[r] into base + r*stride (fds[r] < 0: that row is the caller's).
-// ONE foreign call for all n reads, so a caller under an interpreter lock
-// gives it away once a gather and not once a file. got[r] is the bytes
-// read (short only at the end of the file) or -errno.
+// Fill the rows of one operand from n files: `len` bytes from
+// offset + r*offset_step of fds[r] into base + r*stride (fds[r] < 0: that
+// row is the caller's). ONE foreign call for all n reads, so a caller
+// under an interpreter lock gives it away once a gather and not once a
+// file. got[r] is the bytes read (short only at the end of the file) or
+// -errno.
 void pread_rows(const int32_t* fds, int64_t n, int64_t offset,
-                uint8_t* base, int64_t stride, int64_t len, int64_t* got) {
+                int64_t offset_step, uint8_t* base, int64_t stride,
+                int64_t len, int64_t* got) {
     for (int64_t r = 0; r < n; r++) {
-        int64_t done = 0;
+        int64_t done = 0, at = offset + r * offset_step;
         while (fds[r] >= 0 && done < len) {
             ssize_t k = pread(fds[r], base + r * stride + done,
-                              (size_t)(len - done), (off_t)(offset + done));
+                              (size_t)(len - done), (off_t)(at + done));
             if (k < 0 && errno == EINTR) continue;
             if (k < 0) done = -(int64_t)errno;
             if (k <= 0) break;
             done += k;
         }
         got[r] = done;
+    }
+}
+
+// The mirror: `len` bytes of base + r*stride to `offset` of fds[r], every
+// row in one foreign call. put[r] is the bytes written, or -errno; a write
+// that moves nothing ends the row short.
+void pwrite_rows(const int32_t* fds, int64_t n, int64_t offset,
+                 const uint8_t* base, int64_t stride, int64_t len,
+                 int64_t* put) {
+    for (int64_t r = 0; r < n; r++) {
+        int64_t done = 0;
+        while (done < len) {
+            ssize_t k = pwrite(fds[r], base + r * stride + done,
+                               (size_t)(len - done), (off_t)(offset + done));
+            if (k < 0 && errno == EINTR) continue;
+            if (k < 0) done = -(int64_t)errno;
+            if (k <= 0) break;
+            done += k;
+        }
+        put[r] = done;
     }
 }
 

@@ -8,6 +8,7 @@ callers must check `available()`.
 from __future__ import annotations
 
 import ctypes
+import errno
 import os
 import subprocess
 import threading
@@ -74,10 +75,15 @@ def _bind(lib) -> None:
     lib.crc32c.argtypes = [ctypes.c_uint32, ctypes.c_void_p, ctypes.c_int64]
     lib.crc32c.restype = ctypes.c_uint32
     lib.pread_rows.argtypes = [
-        ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64,
+        ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
         ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64, ctypes.c_void_p,
     ]
     lib.pread_rows.restype = None
+    lib.pwrite_rows.argtypes = [
+        ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64,
+        ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64, ctypes.c_void_p,
+    ]
+    lib.pwrite_rows.restype = None
     lib.gf_force_impl.argtypes = [ctypes.c_int]
     lib.gf_force_impl.restype = ctypes.c_int
     lib.gf_impl_name.restype = ctypes.c_char_p
@@ -206,31 +212,58 @@ def crc32c(data: bytes | bytearray | memoryview | np.ndarray,
     return int(lib.crc32c(crc, buf.ctypes.data, buf.size))
 
 
-def pread_rows(fds: list[int], offset: int, rows: np.ndarray,
-               size: int) -> list[int]:
-    """rows[r, :size] = `size` bytes from `offset` of file descriptor
-    fds[r] (negative: the row is left as it is), every row in ONE
-    foreign call: the interpreter lock is given away once for all the
-    reads, not once a file. Returns the bytes read per row, short only
-    at the end of a file; a failed read raises OSError."""
+def _rows_call(fds: list[int], offset: int, rows: np.ndarray, size: int,
+               writable: bool):
+    """The library, fds as a C array and an array for the per-row
+    counts, once the arguments are checked to fit."""
     lib = _load()
     if lib is None:
         raise RuntimeError("native library not available")
     if rows.dtype != np.uint8 or rows.ndim != 2 \
-            or not rows.flags.writeable or rows.strides[1] != 1 \
-            or rows.strides[0] < rows.shape[1]:
-        raise ValueError("rows must be a writable 2-D uint8 array with "
-                         "contiguous rows")
+            or (writable and not rows.flags.writeable) \
+            or rows.strides[1] != 1 or rows.strides[0] < rows.shape[1]:
+        raise ValueError("rows must be a 2-D uint8 array with contiguous "
+                         "rows" + (", writable" if writable else ""))
     n = len(fds)
     if n != rows.shape[0] or not 0 <= size <= rows.shape[1] \
             or offset < 0:
         raise ValueError(f"{n} files at {offset} for {size} bytes do "
                          f"not fit rows of shape {rows.shape}")
-    c_fds = (ctypes.c_int32 * n)(*fds)
-    got = (ctypes.c_int64 * n)()
-    lib.pread_rows(c_fds, n, offset, rows.ctypes.data, rows.strides[0],
-                   size, got)
-    for r, g in enumerate(got):
-        if g < 0:
-            raise OSError(-g, os.strerror(-g), f"fd {fds[r]}")
+    return lib, (ctypes.c_int32 * n)(*fds), (ctypes.c_int64 * n)()
+
+
+def _raise_failed(fds: list[int], counts) -> None:
+    for r, c in enumerate(counts):
+        if c < 0:
+            raise OSError(-c, os.strerror(-c), f"fd {fds[r]}")
+
+
+def pread_rows(fds: list[int], offset: int, rows: np.ndarray,
+               size: int, offset_step: int = 0) -> list[int]:
+    """rows[r, :size] = `size` bytes from offset + r * offset_step of
+    file descriptor fds[r] (negative: the row is left as it is), every
+    row in ONE foreign call: the interpreter lock is given away once for
+    all the reads, not once a file. Returns the bytes read per row,
+    short only at the end of a file; a failed read raises OSError."""
+    lib, c_fds, got = _rows_call(fds, offset, rows, size, writable=True)
+    if offset_step < 0:
+        raise ValueError(f"offset_step {offset_step} < 0")
+    lib.pread_rows(c_fds, len(fds), offset, offset_step, rows.ctypes.data,
+                   rows.strides[0], size, got)
+    _raise_failed(fds, got)
     return list(got)
+
+
+def pwrite_rows(fds: list[int], offset: int, rows: np.ndarray,
+                size: int) -> None:
+    """rows[r, :size] to `offset` of file descriptor fds[r], every row
+    in ONE foreign call, short writes and EINTR looped over. A failed
+    write raises OSError, and so does one that the file took short."""
+    lib, c_fds, put = _rows_call(fds, offset, rows, size, writable=False)
+    lib.pwrite_rows(c_fds, len(fds), offset, rows.ctypes.data,
+                    rows.strides[0], size, put)
+    _raise_failed(fds, put)
+    for r, p in enumerate(put):
+        if p < size:
+            raise OSError(errno.EIO, f"short write: {p} of {size} bytes",
+                          f"fd {fds[r]}")

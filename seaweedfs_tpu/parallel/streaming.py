@@ -22,6 +22,15 @@ facade (``BatchCoder``: the begin is a job's submit, the collect its
 wait / dispatch / result on the device queue, and the dispatcher finds
 job N+1 queued the moment it has demuxed job N).
 
+Each I/O stage of the encode moves a batch between the files and its
+buffer with ONE foreign call (``native.rs_native.pread_rows`` for the
+reader's k rows of the .dat, ``pwrite_rows`` for a writer's rows into
+its shard files), so it gives the interpreter lock away once a batch and
+not once a row, and wins it back from the process's other busy threads
+once. Without the native library the same stages make one ``readinto``
+or ``write`` a row (the rule of ``ec_volume.read_shards_into``).
+``io_calls`` in the stats counts those calls: 3 a batch, or 2k + m.
+
 Stage plumbing invariants:
   - every inter-stage queue is BOUNDED (maxsize=prefetch): a slow writer
     backpressures the coder, a slow coder backpressures the readers, so
@@ -57,6 +66,7 @@ from typing import Optional, Sequence
 import numpy as np
 
 from seaweedfs_tpu.models.coder import ErasureCoder
+from seaweedfs_tpu.native import rs_native
 from seaweedfs_tpu.storage.erasure_coding import layout
 from seaweedfs_tpu.utils import clockctl, tracing
 
@@ -163,7 +173,9 @@ class _BufferPool:
 class AtomicFileGroup:
     """A set of output files written under `.tmp` names and renamed into
     place together on commit(). discard() removes the temporaries; either
-    way no truncated file is ever visible under a final name."""
+    way no truncated file is ever visible under a final name. A writer
+    may write the files' descriptors at offsets of its own (pwrite):
+    the file objects then hold nothing to flush."""
 
     def __init__(self, paths: Sequence[str]):
         self.paths = list(paths)
@@ -200,15 +212,27 @@ def _merge_stats(stats: Optional[dict], lock: threading.Lock,
             stats[key] = stats.get(key, 0) + v
 
 
-def _read_rows(f, buf: np.ndarray, desc, k: int) -> None:
+def _read_rows(f, buf: np.ndarray, desc, k: int) -> int:
     """Fill buf (k, step) with the descriptor's per-shard slices of the
-    .dat, zero-filling past EOF (encodeDataOneBatch semantics)."""
+    .dat, zero-filling past EOF (encodeDataOneBatch semantics). Returns
+    the calls that moved the bytes: one with the native library (row i
+    from row_off + b + i * block, adjacent on small-block rows and a
+    large block apart on large ones), a ``readinto`` a row without."""
     row_off, block, b, step = desc
-    for i in range(k):
-        f.seek(row_off + i * block + b)
-        got = f.readinto(memoryview(buf[i]))
-        if got < step:
-            buf[i, got:] = 0
+    if rs_native.available():
+        got = rs_native.pread_rows([f.fileno()] * k, row_off + b, buf, step,
+                                   offset_step=block)
+        calls = 1
+    else:
+        got = []
+        for i in range(k):
+            f.seek(row_off + i * block + b)
+            got.append(f.readinto(memoryview(buf[i])))
+        calls = k
+    for i, n in enumerate(got):
+        if n < step:
+            buf[i, n:] = 0
+    return calls
 
 
 def pipelined_encode_file(base_file_name: str,
@@ -225,10 +249,12 @@ def pipelined_encode_file(base_file_name: str,
     keeps up to two batches in the coder (module docstring). `stats`,
     when a dict, receives per-stage busy seconds (read_s / encode_s: the
     caller's time in the coder, begins and collects / write_s: the two
-    writers' together / commit_s), wall_s, bytes_in, batches and
+    writers' together / commit_s), wall_s, bytes_in, batches,
     overlapped (batches begun while the one before was still in the
     coder: batches - 1 under the batch scheduler, 0 under a host coder)
-    — the ``pipeline`` object of ``/admin/ec/generate``'s reply."""
+    and io_calls (the reader's and the writers' calls that moved bytes:
+    3 a batch with the native library, 2k + m without) — the
+    ``pipeline`` object of ``/admin/ec/generate``'s reply."""
     scheme = coder.scheme
     k = scheme.data_shards
     total = scheme.total_shards
@@ -249,31 +275,41 @@ def pipelined_encode_file(base_file_name: str,
     wall0 = clockctl.monotonic()
 
     def reader_stage(rid: int):
-        busy = 0.0
+        busy, calls = 0.0, 0
         with open(dat_path, "rb") as f:
             for seq in range(rid, len(descs), readers):
                 with tracing.stage("ec.pipeline.read") as st:
                     buf = data_pool.get((k, descs[seq][3]))
-                    _read_rows(f, buf, descs[seq], k)
+                    calls += _read_rows(f, buf, descs[seq], k)
                 busy += st.elapsed
                 pl.put(read_q, (seq, buf))
-        _merge_stats(stats, slock, read_s=busy)
+        _merge_stats(stats, slock, read_s=busy, io_calls=calls)
 
     def writer_stage(q: "queue.Queue", files: list, pool: _BufferPool):
         """One of the two writers: a batch's rows, one to a file, in the
-        order they are handed over; the buffer then goes back to its
-        pool."""
-        busy = 0.0
+        order they are handed over (every file grows by the batch's
+        width, so one running offset serves them all: ONE foreign call a
+        batch with the native library, a write a file without); the
+        buffer then goes back to its pool."""
+        fds = [f.fileno() for f in files] if rs_native.available() \
+            else None
+        busy, calls, offset = 0.0, 0, 0
         while True:
             rows = pl.get(q)
             if rows is None:
                 break
             with tracing.stage("ec.pipeline.write") as st:
-                for f, row in zip(files, rows):
-                    f.write(row)
+                if fds is not None:
+                    rs_native.pwrite_rows(fds, offset, rows, rows.shape[1])
+                    offset += rows.shape[1]
+                    calls += 1
+                else:
+                    for f, row in zip(files, rows):
+                        f.write(row)
+                    calls += len(files)
             busy += st.elapsed
             pool.put(rows)
-        _merge_stats(stats, slock, write_s=busy)
+        _merge_stats(stats, slock, write_s=busy, io_calls=calls)
 
     def hand_over(rows, parity):
         pl.put(data_q, rows)
@@ -342,7 +378,8 @@ def pipelined_encode_file(base_file_name: str,
                      overlapped=overlapped)
         if stats is not None:
             for key in ("read_s", "encode_s", "write_s", "commit_s",
-                        "wall_s", "bytes_in", "batches", "overlapped"):
+                        "wall_s", "bytes_in", "batches", "overlapped",
+                        "io_calls"):
                 whole.annotate(key, stats.get(key))
     except _Aborted:
         # a stage failed and tripped abort while the main thread blocked;

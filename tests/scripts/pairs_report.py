@@ -60,6 +60,22 @@ def stats_report(path):
           + "; loop_s a call, ms: " + " ".join(
               f"{k} {med('loop_s.' + k) * 1e3:.1f}"
               for k in ("idle", "hold", "dispatch")))
+    piped = [c for c in calls if c.get("pipeline")]
+    if not piped:
+        return
+
+    def pmed(k):
+        return statistics.median(c["pipeline"].get(k, 0) for c in piped)
+    writers = sorted({k for c in piped for k in c if k.startswith("write_rows")})
+    print("  the pipeline a call, ms: " + " ".join(
+        f"{k} {pmed(k + '_s') * 1e3:.1f}"
+        for k in ("wall", "read", "encode", "write", "commit"))
+        + " (" + " ".join(f"{k[:-2]} {statistics.median(c.get(k, 0) for c in piped) * 1e3:.1f}"
+                          for k in writers) + ")"
+        + f"; batches {pmed('batches'):.0f} overlapped "
+        f"{pmed('overlapped'):.0f}; io_calls / batches, least and most "
+        + " ".join(f"{f(c['pipeline'].get('io_calls', 0) / c['pipeline']['batches'] for c in piped):.2f}"
+                   for f in (min, max)))
 
 
 def main(root):

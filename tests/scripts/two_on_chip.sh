@@ -1,5 +1,7 @@
-# The chip calls behind PERF.md's numbers for PR 36 (two dispatches on the device).  The parent is
+# The chip calls behind PERF.md's numbers for two dispatches on the device and for the seal pipeline's
+# one native call a batch a stage (`stats` lines carry its `pipeline` and write split).  The parent is
 # unpacked into .parent_tree/ first:  mkdir .parent_tree && git archive <parent> | tar -x -C .parent_tree
+# (then copy this tree's tests/scripts/stats_volume.py over the parent's, so both sides record the same)
 # A machine takes ~33 GiB of writes before it stalls and a seal run writes ~3.7 GiB: at most 6-8 seal runs a call.
 #   chiprun --timeout 1500 -- bash tests/scripts/two_on_chip.sh pairs <cell> <first seed> <P|C>...
 #       plain runs of <cell> in the order given (P: the parent's tree, C: this one); the i-th P and the i-th C

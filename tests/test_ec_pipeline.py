@@ -13,6 +13,7 @@ row structure — multiple large rows, small rows, partial tail — fits in
 kilobytes; layout.py keeps the same strict-> split at any scale.
 """
 
+import errno
 import glob
 import os
 import threading
@@ -20,8 +21,12 @@ import threading
 import numpy as np
 import pytest
 
+from benchmark import reference
 from seaweedfs_tpu.models.coder import (DEFAULT_SCHEME, Encoded,
-                                        ErasureCoder, RSScheme, make_coder)
+                                        ErasureCoder, LrcScheme, RSScheme,
+                                        host_coder, make_coder,
+                                        scheme_to_dict)
+from seaweedfs_tpu.native import rs_native
 from seaweedfs_tpu.ops import rs_cpu
 from seaweedfs_tpu.parallel import streaming
 from seaweedfs_tpu.storage.erasure_coding import decoder as ecdec
@@ -53,14 +58,30 @@ def _leftovers(d) -> list[str]:
 
 # ---- bit-identity: serial vs pipelined, all coder/reader variants ----
 
-@pytest.mark.parametrize("size", [
+SIZES = [
     1,                          # single byte
     SB * K - 7,                 # partial small row, non-multiple of k*SB
     2 * LB * K,                 # exactly k*large_block (the `>=` bug size)
     2 * LB * K + 3,
-    2 * LB * K + 3 * SB * K + 77,
-])
-def test_pipelined_matches_serial(tmp_path, size):
+    2 * LB * K + 3 * SB * K + 77,   # large rows: a batch's rows LB apart
+]
+
+
+def _library(monkeypatch, library: str) -> None:
+    """``native``: the stages' one call a batch (skipped where the
+    library did not build); ``absent``: the library made unavailable, a
+    call a row."""
+    if library == "absent":
+        monkeypatch.setattr(rs_native, "available", lambda: False)
+    elif not rs_native.available():
+        pytest.skip("no native library on this machine")
+
+
+@pytest.mark.parametrize("size,library", [
+    pytest.param(s, lib, id=str(s) if lib == "native" else f"{s}-{lib}")
+    for lib in ("native", "absent") for s in SIZES])
+def test_pipelined_matches_serial(tmp_path, monkeypatch, size, library):
+    _library(monkeypatch, library)
     sbase, pbase = str(tmp_path / "s"), str(tmp_path / "p")
     for b in (sbase, pbase):
         _make_dat(b, size, seed=size)
@@ -93,6 +114,33 @@ def test_pipelined_odd_batch_snaps_to_block(tmp_path):
     ecenc.write_ec_files(pbase, make_coder("cpu"), LB, SB, batch_size=77,
                          pipelined=True)
     assert _shards(sbase) == _shards(pbase)
+
+
+@pytest.mark.parametrize("library", ["native", "absent"])
+@pytest.mark.parametrize("scheme", [DEFAULT_SCHEME, RSScheme(6, 3),
+                                    LrcScheme(12, 2, 2)],
+                         ids=["rs10-4", "rs6-3", "lrc12-2-2"])
+def test_io_calls_count_a_batchs_moves_and_the_files_are_the_references(
+        tmp_path, monkeypatch, scheme, library):
+    """Every geometry's shard files are the benchmark reference's bytes,
+    large-block rows included, and ``io_calls`` counts the calls that
+    moved them: the reader's one and each writer's one a batch with the
+    library, a row each (k read, k + m written) without it."""
+    _library(monkeypatch, library)
+    k, total = scheme.data_shards, scheme.total_shards
+    base = str(tmp_path / "v")
+    _make_dat(base, 2 * LB * k + 3 * SB * k + 77, seed=k)
+    stats: dict = {}
+    streaming.pipelined_encode_file(base, host_coder(scheme, False), LB, SB,
+                                    batch_size=SB, stats=stats)
+    code = {**scheme_to_dict(scheme), "parity_shards": total - k,
+            "large_block_bytes": LB, "small_block_bytes": SB}
+    assert reference.differing_files(
+        base + ".dat", [base + layout.shard_ext(i) for i in range(total)],
+        code) == []
+    per_batch = 3 if library == "native" else 2 * k + (total - k)
+    assert stats["batches"] == 2 * LB // SB + 4     # 2 large rows, 4 small
+    assert stats["io_calls"] == per_batch * stats["batches"]
 
 
 # ---- crash-safety: no truncated shard ever visible ----
@@ -154,7 +202,7 @@ def test_pipelined_reader_stage_crash_raises_pipeline_error(
         state["n"] += 1
         if state["n"] == 4:
             raise IOError("surprise EIO")
-        real(f, buf, desc, k)
+        return real(f, buf, desc, k)
 
     monkeypatch.setattr(streaming, "_read_rows", flaky)
     with pytest.raises(streaming.PipelineError) as ei:
@@ -388,25 +436,48 @@ class _SpiedFile:
         self._f.close()
 
 
-def _spy_on_shard_files(monkeypatch, failing=()):
+def _spy_on_shard_writes(monkeypatch, library, failing=()):
+    """Which thread writes each shard's .tmp file, {shard: [thread a
+    write]}, and the library's calls, [(thread, shards, offset)]. With
+    the library a writer's batch is ONE ``pwrite_rows``; ``absent``:
+    the file objects' writes, a row each. A shard in ``failing`` fails
+    its writer's second write with an IOError."""
+    _library(monkeypatch, library)
     logs: dict[int, list[int]] = {}
+    calls: list[tuple[int, list[int], int]] = []
+    shard_of: dict[int, int] = {}
     real_init = streaming.AtomicFileGroup.__init__
+    real_pwrite = rs_native.pwrite_rows
 
     def init(self, paths):
         real_init(self, paths)
-        self.files = [
-            _SpiedFile(f, logs.setdefault(i, []), i in failing)
-            for i, f in enumerate(self.files)]
+        if library == "absent":
+            self.files = [
+                _SpiedFile(f, logs.setdefault(i, []), i in failing)
+                for i, f in enumerate(self.files)]
+        else:
+            shard_of.update({f.fileno(): i for i, f in enumerate(self.files)})
+
+    def pwrite_rows(fds, offset, rows, size):
+        me, shards = threading.get_ident(), [shard_of[fd] for fd in fds]
+        calls.append((me, shards, offset))
+        for i in shards:
+            logs.setdefault(i, []).append(me)
+        if set(shards) & set(failing) and \
+                sum(1 for c in calls if c[1] == shards) >= 2:
+            raise OSError(errno.ENOSPC, "no space left")
+        return real_pwrite(fds, offset, rows, size)
 
     monkeypatch.setattr(streaming.AtomicFileGroup, "__init__", init)
-    return logs
+    monkeypatch.setattr(rs_native, "pwrite_rows", pwrite_rows)
+    return logs, calls
 
 
-def test_data_rows_and_parity_rows_have_a_writer_each(
-        tmp_path, monkeypatch):
-    """Two writer threads: every data shard's file is written by one of
-    them, every parity shard's by the other, a write a batch each."""
-    logs = _spy_on_shard_files(monkeypatch)
+def _two_writers(tmp_path, monkeypatch, library):
+    """A seal of five batches: every data shard's file is written by one
+    writer thread, every parity shard's by the other, a batch at a time,
+    and the files are the serial path's. Returns the library's calls."""
+    logs, calls = _spy_on_shard_writes(monkeypatch, library)
     sbase, pbase = str(tmp_path / "s"), str(tmp_path / "p")
     for b in (sbase, pbase):
         _make_dat(b, _tail_size(K, 5), seed=6)
@@ -423,13 +494,35 @@ def test_data_rows_and_parity_rows_have_a_writer_each(
     monkeypatch.undo()
     ecenc.write_ec_files(sbase, make_coder("cpu"), LB, SB, batch_size=SB)
     assert _shards(pbase) == _shards(sbase)
+    return calls
 
 
-@pytest.mark.parametrize("failing", [3, K + 1],
-                         ids=["data-writer", "parity-writer"])
+def test_data_rows_and_parity_rows_have_a_writer_each(
+        tmp_path, monkeypatch):
+    """Two writer threads, each ONE call of the library a batch: the
+    data writer's covers the k data shards' files, the parity writer's
+    the m others', each at the offset the batches before it reached."""
+    calls = _two_writers(tmp_path, monkeypatch, "native")
+    for shards in (list(range(K)), list(range(K, TOTAL))):
+        assert [off for _t, s, off in calls if s == shards] == \
+            [i * SB for i in range(5)]
+    assert len(calls) == 2 * 5
+
+
+def test_without_the_library_each_writer_writes_a_row_at_a_time(
+        tmp_path, monkeypatch):
+    """The same two writers through the file objects, one write a row,
+    where the native library is unavailable."""
+    assert _two_writers(tmp_path, monkeypatch, "absent") == []
+
+
+@pytest.mark.parametrize("failing,library", [
+    (3, "native"), (K + 1, "native"), (3, "absent"), (K + 1, "absent")],
+    ids=["data-writer", "parity-writer", "data-writer-absent",
+         "parity-writer-absent"])
 def test_either_writer_crash_raises_pipeline_error(tmp_path, monkeypatch,
-                                                   failing):
-    _spy_on_shard_files(monkeypatch, failing=(failing,))
+                                                   failing, library):
+    _spy_on_shard_writes(monkeypatch, library, failing=(failing,))
     base = str(tmp_path / "v")
     _make_dat(base, _tail_size(K, 6), seed=7)
     with pytest.raises(streaming.PipelineError) as ei:
@@ -580,6 +673,9 @@ def test_generate_reply_carries_the_pipelines_stats(tmp_path, monkeypatch):
         # begun while another is in the coder
         assert p["overlapped"] == 0
         assert p["wall_s"] >= p["commit_s"]
+        # one call a batch in each of the reader and the two writers
+        assert p["io_calls"] == p["batches"] * (
+            3 if rs_native.available() else 2 * K + TOTAL - K)
         for i in range(TOTAL):
             assert os.path.exists(os.path.join(
                 str(tmp_path / "v"), reply["base"] + layout.shard_ext(i)))
