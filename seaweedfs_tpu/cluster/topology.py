@@ -35,6 +35,14 @@ def ec_geometry_of(entry: dict) -> Optional[tuple[int, int]]:
     return None
 
 
+def ec_code_of(entry: dict) -> Optional[str]:
+    """The code spec a heartbeat's EC entry states (``hhxor-10-4``):
+    only a family whose shard counts do not tell it from plain RS says
+    it; None elsewhere, and over gRPC (ROADMAP M2)."""
+    code = entry.get("code")
+    return str(code) if code else None
+
+
 def ec_geometry_keys(geometry: Optional[tuple[int, int]]) -> dict:
     if geometry is None:
         return {}
@@ -236,6 +244,8 @@ class Topology:
         # code has shards (ec_geometry; 14 where no holder said)
         self.ec_shard_map: dict[int, list[list[DataNode]]] = {}
         self.ec_geometry: dict[int, tuple[int, int]] = {}
+        # vid -> code spec, where a holder stated one (ec_code_of)
+        self.ec_codes: dict[int, str] = {}
         self.volume_size_limit = volume_size_limit
         self.pulse_seconds = pulse_seconds
         self.max_volume_id = 0
@@ -336,6 +346,9 @@ class Topology:
                       for e in hb.get("ec_shards", [])}
             geometry = {e["id"]: ec_geometry_of(e)
                         for e in hb.get("ec_shards", [])}
+            for e in hb.get("ec_shards", []):
+                if ec_code_of(e):
+                    self.ec_codes[e["id"]] = ec_code_of(e)
             for vid in list(node.ec_shards):
                 if vid not in new_ec:
                     self._unregister_ec_shards(vid, node, node.ec_shards[vid])
@@ -386,6 +399,8 @@ class Topology:
                 node.ec_shards[vid] = old | bits
                 self._register_ec_shards(vid, node, bits, 0,
                                          ec_geometry_of(e))
+                if ec_code_of(e):
+                    self.ec_codes[vid] = ec_code_of(e)
                 new_ec_vids.add(vid)
             for e in deltas.get("deleted_ec_shards", []):
                 vid, bits = e["id"], e["ec_index_bits"]
@@ -460,11 +475,21 @@ class Topology:
         if all(not s for s in shards):
             self.ec_shard_map.pop(vid, None)
             self.ec_geometry.pop(vid, None)
+            self.ec_codes.pop(vid, None)
 
     def ec_volume_geometry(self, vid: int) -> tuple[int, int]:
         """(data_shards, total_shards) of an EC volume: what a holder's
-        heartbeat stated, RS(10,4) where none did."""
+        heartbeat stated, RS(10,4) where none did.  The counts alone do
+        not name the code: a Hitchhiker-XOR volume over RS(10,4) has
+        RS(10,4)'s, and a decode path asks ``ec_volume_code``."""
         return self.ec_geometry.get(vid, DEFAULT_EC_GEOMETRY)
+
+    def ec_volume_code(self, vid: int) -> Optional[str]:
+        """The code spec a holder stated for the volume (a piggybacked
+        family's: ``hhxor-10-4``), None where none did: a plain RS or
+        LRC volume (its counts tell), or a holder that heartbeats over
+        gRPC, whose message carries neither (ROADMAP M2)."""
+        return self.ec_codes.get(vid)
 
     # ---- lookup ----
     def lookup(self, collection: str, vid: int) -> list[DataNode]:

@@ -31,6 +31,8 @@ class RSScheme:
     reference (weed/storage/erasure_coding/ec_encoder.go:17-23)."""
 
     __slots__ = ("data_shards", "parity_shards")
+    # the pieces a unit of a shard is cut into: 1 for a scalar code
+    substripes = 1
 
     def __init__(self, data_shards: int = 10, parity_shards: int = 4):
         if not (0 < data_shards and 0 < parity_shards
@@ -42,6 +44,12 @@ class RSScheme:
     @property
     def total_shards(self) -> int:
         return self.data_shards + self.parity_shards
+
+    # the most rows a repair job reads: k shards for a scalar code (one
+    # row a shard), 2k half-rows for a piggybacked one
+    @property
+    def max_sources(self) -> int:
+        return self.data_shards
 
     def __repr__(self):
         return f"RS({self.data_shards},{self.parity_shards})"
@@ -122,9 +130,76 @@ class LrcScheme(RSScheme):
                      self.global_parities, "lrc"))
 
 
+class HitchhikerScheme(RSScheme):
+    """Hitchhiker-XOR over RS(k, r) (Rashmi et al., SIGCOMM 2014;
+    Hadoop 3's ``hhxor`` codec): a VECTOR code.  Every ``unit`` bytes of
+    every shard (one 1 MiB block; a 1 GiB block is 1,024 units) are two
+    substripes, a = the first half and b = the second, coupled byte for
+    byte.  The data shards hold (a_i, b_i) unchanged; parity shard k
+    holds (f1(a), f1(b)); parity k+j (j = 1 .. r-1) holds (f_{j+1}(a),
+    f_{j+1}(b) XOR the a-halves of group j), f the store's RS(k, r)
+    parity rows and the k data shards split into r - 1 ``groups``.  A
+    lost data block is rebuilt from k + |group| half-blocks (13 of a
+    1 MiB block under RS(10,4): 6.5 MiB, not 10), and any r losses still
+    decode, at RS(k, r)'s storage (ops/hitchhiker.py)."""
+
+    __slots__ = ("groups", "unit")
+    substripes = 2
+
+    def __init__(self, data_shards: int = 10, parity_shards: int = 4,
+                 groups=None, unit: int = 1 << 20):
+        super().__init__(data_shards, parity_shards)
+        n = parity_shards - 1
+        if n <= 0 or data_shards < n or unit <= 0 or unit % 8:
+            raise ValueError(
+                f"Hitchhiker-XOR: ({data_shards},{parity_shards}) needs "
+                "at least 2 parities and a data shard a group, and a "
+                f"unit a multiple of 8 bytes, not {unit}")
+        if groups is None:
+            # r - 1 groups, the larger ones last: (10, 4) -> 3, 3, 4
+            sizes = [data_shards // n + (i >= n - data_shards % n)
+                     for i in range(n)]
+            bounds = [sum(sizes[:i]) for i in range(n + 1)]
+            groups = [range(bounds[i], bounds[i + 1]) for i in range(n)]
+        self.groups = tuple(tuple(int(s) for s in g) for g in groups)
+        if len(self.groups) != n or sorted(
+                s for g in self.groups for s in g) != list(range(data_shards)):
+            raise ValueError(f"Hitchhiker-XOR: groups {self.groups} are not "
+                             f"{n} parts of the {data_shards} data shards")
+        self.unit = int(unit)
+
+    @property
+    def max_sources(self) -> int:
+        return 2 * self.data_shards
+
+    def group_of(self, sid: int) -> int:
+        """Index j of the group holding data shard ``sid``: its a-half is
+        piggybacked on parity shard k + 1 + j."""
+        return next(j for j, g in enumerate(self.groups) if sid in g)
+
+    def __repr__(self):
+        return f"HitchhikerXOR({self.data_shards},{self.parity_shards})"
+
+    def __eq__(self, other):
+        return (type(other) is type(self)
+                and other.data_shards == self.data_shards
+                and other.parity_shards == self.parity_shards
+                and other.groups == self.groups and other.unit == self.unit)
+
+    def __hash__(self):
+        return hash((self.data_shards, self.parity_shards, self.groups,
+                     self.unit, "hhxor"))
+
+
 def scheme_to_dict(scheme: RSScheme) -> dict:
     """Serializable CodeSpec for volume metadata (.vif) — lets mixed-code
     clusters pick the right coder per volume at load time."""
+    if isinstance(scheme, HitchhikerScheme):
+        return {"family": "hhxor", "data_shards": scheme.data_shards,
+                "parity_shards": scheme.parity_shards,
+                "substripes": scheme.substripes,
+                "groups": [list(g) for g in scheme.groups],
+                "unit_bytes": scheme.unit}
     if isinstance(scheme, LrcScheme):
         return {"family": "lrc", "data_shards": scheme.data_shards,
                 "local_groups": scheme.local_groups,
@@ -138,6 +213,11 @@ def scheme_from_dict(d: Optional[dict]) -> RSScheme:
     encoded before CodeSpec persistence are RS(10,4))."""
     if not d:
         return DEFAULT_SCHEME
+    if d.get("family") == "hhxor":
+        return HitchhikerScheme(int(d.get("data_shards", 10)),
+                                int(d.get("parity_shards", 4)),
+                                d.get("groups"),
+                                int(d.get("unit_bytes", 1 << 20)))
     if d.get("family") == "lrc":
         return LrcScheme(int(d.get("data_shards", 10)),
                          int(d.get("local_groups", 2)),
@@ -156,10 +236,28 @@ class CodeSpecError(ValueError):
     HTTP edge answers 400)."""
 
 
+class SubstripedCodeError(ValueError):
+    """A path that decodes whole shards at one offset (one row a shard)
+    was handed a volume of a piggybacked code, whose sources are
+    half-rows at two offsets (``refuse_substriped``)."""
+
+
+def refuse_substriped(scheme: RSScheme, path: str) -> None:
+    """Raise SubstripedCodeError, naming the code and ``path``, where
+    ``scheme`` is piggybacked: a path that would take its parity
+    b-halves for plain RS rows returns wrong bytes silently."""
+    if scheme.substripes > 1:
+        raise SubstripedCodeError(
+            f"{code_spec_name(scheme)}: {path} decodes whole shards at "
+            "one offset and cannot take a piggybacked volume")
+
+
 def code_spec_name(scheme: RSScheme) -> str:
-    """The spec string of a scheme: ``rs-6-3``, ``lrc-10-2-2``.  What
-    ``parse_code_spec`` reads back, and the key of per-geometry counters
-    (``by_spec`` in the batch scheduler's stats)."""
+    """The spec string of a scheme: ``rs-6-3``, ``lrc-10-2-2``,
+    ``hhxor-10-4``.  What ``parse_code_spec`` reads back, and the key of
+    per-geometry counters (``by_spec`` in the batch scheduler's stats)."""
+    if isinstance(scheme, HitchhikerScheme):
+        return f"hhxor-{scheme.data_shards}-{scheme.parity_shards}"
     if isinstance(scheme, LrcScheme):
         return (f"lrc-{scheme.data_shards}-{scheme.local_groups}-"
                 f"{scheme.global_parities}")
@@ -171,16 +269,20 @@ def parse_code_spec(spec: str, default: RSScheme = DEFAULT_SCHEME
     """The one parser from a request's ``code`` to a scheme: ``""`` /
     ``rs`` -> ``default`` (the server's own), ``rs-<k>-<m>`` ->
     RSScheme(k, m), ``lrc`` -> LRC(10,2,2), ``lrc-<k>-<l>-<g>`` ->
-    LrcScheme(k, l, g) (what ``code_spec_name`` writes).  Anything else
-    — a coder registry name, a geometry no volume can carry, groups that
-    do not divide the data shards — raises CodeSpecError."""
+    LrcScheme(k, l, g), ``hhxor`` -> Hitchhiker-XOR over RS(10,4),
+    ``hhxor-<k>-<m>`` -> HitchhikerScheme(k, m) (what ``code_spec_name``
+    writes).  Anything else — a coder registry name, a geometry no
+    volume can carry, groups that do not divide the data shards —
+    raises CodeSpecError."""
     spec = (spec or "").strip().lower()
     if spec in ("", "rs"):
         return default
     if spec == "lrc":
         return LrcScheme()
+    if spec == "hhxor":
+        return HitchhikerScheme()
     family, *sizes = spec.split("-")
-    if (family, len(sizes)) in (("rs", 2), ("lrc", 3)) \
+    if (family, len(sizes)) in (("rs", 2), ("lrc", 3), ("hhxor", 2)) \
             and all(p.isdigit() for p in sizes):
         sizes = [int(p) for p in sizes]
         if min(sizes) <= 0 or sizes[0] + sum(sizes[1:]) > MAX_VOLUME_SHARDS:
@@ -190,20 +292,26 @@ def parse_code_spec(spec: str, default: RSScheme = DEFAULT_SCHEME
                 "of parity")
         if family == "rs":
             return RSScheme(*sizes)
+        if family == "hhxor":
+            try:
+                return HitchhikerScheme(*sizes)
+            except ValueError as e:
+                raise CodeSpecError(f"code {spec!r}: {e}") from None
         if sizes[0] % sizes[1]:
             raise CodeSpecError(
                 f"code {spec!r}: {sizes[1]} local groups do not divide "
                 f"{sizes[0]} data shards evenly")
         return LrcScheme(*sizes)
     raise CodeSpecError(
-        f"unknown code {spec!r}: expected '', 'rs', 'rs-<k>-<m>', 'lrc' "
-        "or 'lrc-<k>-<l>-<g>'")
+        f"unknown code {spec!r}: expected '', 'rs', 'rs-<k>-<m>', 'lrc', "
+        "'lrc-<k>-<l>-<g>', 'hhxor' or 'hhxor-<k>-<m>'")
 
 
 def host_coder(scheme: RSScheme, threaded: bool) -> "ErasureCoder":
-    """The host coder of a scheme's code family (``cpu`` / ``lrc``), or
-    its multi-threaded sibling (``cpu-mt`` / ``lrc-mt``)."""
-    family = "lrc" if isinstance(scheme, LrcScheme) else "cpu"
+    """The host coder of a scheme's code family (``cpu`` / ``lrc`` /
+    ``hhxor``), or its multi-threaded sibling (``cpu-mt`` / ...)."""
+    family = "hhxor" if isinstance(scheme, HitchhikerScheme) \
+        else "lrc" if isinstance(scheme, LrcScheme) else "cpu"
     return make_coder(family + ("-mt" if threaded else ""), scheme)
 
 
@@ -309,7 +417,8 @@ _REGISTRY: dict[str, type] = {}
 # every coder name there is, and the ops/ module that registers it when
 # imported; a DEVICE coder dispatches to what JAX finds
 _CODER_MODULES = {"cpu": "rs_cpu", "cpu-mt": "rs_cpu", "lrc": "lrc",
-                 "lrc-mt": "lrc", "jax": "rs_jax", "mesh": "rs_mesh"}
+                 "lrc-mt": "lrc", "hhxor": "hitchhiker",
+                 "hhxor-mt": "hitchhiker", "jax": "rs_jax", "mesh": "rs_mesh"}
 DEVICE_CODERS = ("jax", "mesh")
 
 
@@ -321,15 +430,19 @@ def register_coder(name: str):
 
 
 def make_coder(name: str = "cpu", scheme: RSScheme = DEFAULT_SCHEME) -> ErasureCoder:
-    """Factory over the six registered names: 'cpu' (default, like the
+    """Factory over the eight registered names: 'cpu' (default, like the
     reference) and 'cpu-mt' (ops/rs_cpu.py), 'jax' (ops/rs_jax.py), 'mesh'
     (batched multi-device dispatch, ops/rs_mesh.py), 'lrc' and 'lrc-mt'
-    (locally repairable code, ops/lrc.py)."""
+    (locally repairable code, ops/lrc.py), 'hhxor' and 'hhxor-mt'
+    (piggybacked code, ops/hitchhiker.py)."""
     if name not in _CODER_MODULES:
         raise KeyError(
             f"unknown coder {name!r}; known: {sorted(_CODER_MODULES)}")
     if _CODER_MODULES[name] == "lrc" and not isinstance(scheme, LrcScheme):
         scheme = LrcScheme()
+    if _CODER_MODULES[name] == "hitchhiker" \
+            and not isinstance(scheme, HitchhikerScheme):
+        scheme = HitchhikerScheme()
     if name in DEVICE_CODERS:
         # refuse (with the reason) where JAX found only the CPU and the
         # CPU was not asked for by name, and place the compile cache

@@ -22,8 +22,10 @@ carry the scheme in their names (``jit_ec_encode_rs_6_3``,
     the batch — jobs with different loss patterns ride one dispatch.  w
     is the width of the operand, as many rows as the jobs' matrices READ:
     k for plain RS; a family that repairs from a local group (LRC) has an
-    apply program at the group's width too (``apply_widths``), and a
-    dispatch rides the narrowest that holds what it reads.
+    apply program at the group's width too, and a piggybacked one
+    (Hitchhiker-XOR, ops/hitchhiker.py) reads HALF-ROWS, at widths past
+    k: k + |group| for a whole lost block, 2k for anything (``apply_widths``);
+    a dispatch rides the narrowest that holds what it reads.
 
 A MeshCoder is of ONE scheme; the batch scheduler keeps one per scheme
 it has seen, all on the same device mesh.
@@ -76,8 +78,13 @@ def apply_widths(scheme: RSScheme) -> tuple[int, ...]:
     """The operand widths (rows a lane reads) the scheme has an apply
     program for, ascending: k, and before it k / l where the family
     repairs a lost shard from the others of its local group (k / l - 1
-    data shards and the group's parity)."""
+    data shards and the group's parity); after it, for a piggybacked
+    family, k + |group| (a whole lost block of a group's shard: the
+    piggyback plan) and 2k (every half-row a plan can read)."""
     k = scheme.data_shards
+    if scheme.substripes > 1:
+        return tuple(sorted({k, scheme.max_sources}
+                            | {k + len(g) for g in scheme.groups}))
     group = getattr(scheme, "group_size", k)
     return (group, k) if group < k else (k,)
 
@@ -87,11 +94,25 @@ def batch_encode_fn(scheme: RSScheme, mesh: Mesh):
     """jit over the mesh: (B, k, nw) uint32 sharded P('batch', None, None)
     -> (B, m, nw) parity with matching sharding.  The static parity
     matrix of the scheme's own family (its host coder's), no
-    collectives."""
+    collectives; a piggybacked family adds each group's a-halves into
+    the b-halves of its parity (nw whole units)."""
     mat = _mat_to_tuple(host_coder(scheme, threaded=False)._parity)
+    groups = scheme.groups if scheme.substripes > 1 else ()
 
     def ec_encode(words):
-        return _apply_matrix_words(words, mat)
+        parity = _apply_matrix_words(words, mat)
+        if not groups:
+            return parity
+        half = scheme.unit // 8                     # words a half-unit
+        d = words.reshape(words.shape[0], -1, 2, half)
+        p = parity.reshape(parity.shape[0], -1, 2, half)
+        rows = [p[0]]
+        for j, group in enumerate(groups):
+            pig = d[group[0], :, 0]
+            for i in group[1:]:
+                pig = pig ^ d[i, :, 0]
+            rows.append(p[1 + j].at[:, 1].set(p[1 + j, :, 1] ^ pig))
+        return jnp.stack(rows).reshape(parity.shape)
 
     s3 = mesh_mod.batch_spec(mesh)
     return jax.jit(jax.vmap(_named(ec_encode, "ec_encode", scheme)),
@@ -183,6 +204,9 @@ class MeshCoder(ErasureCoder):
         if hasattr(self._host, "plan_rebuild"):
             self.plan_rebuild = self._host.plan_rebuild
         self.apply_widths = apply_widths(scheme)
+        # (B, columns) of the k-wide apply whose half-column siblings
+        # are compiled (_prepare_halves)
+        self._prepared: set[tuple] = set()
         # distinct (kind, padded operand shape) THIS coder dispatched, all
         # of its one scheme; the batcher's stats() reports the count over
         # its coders as programs_compiled, and per scheme under by_spec.
@@ -267,6 +291,9 @@ class MeshCoder(ErasureCoder):
         B, k, n = batch.shape
         assert k == self.scheme.data_shards, (k, self.scheme)
         assert n % 4 == 0, n
+        if self.scheme.substripes > 1 and n % self.scheme.unit:
+            raise ValueError(f"{self.scheme!r} encodes whole units of "
+                             f"{self.scheme.unit} bytes, not {n} columns")
         with tracing.stage("ec.mesh.pad") as st:
             self._note(st, k)
             words = self._pad_batch(
@@ -291,7 +318,7 @@ class MeshCoder(ErasureCoder):
         if w == widths[0]:
             return srcdata, coeff
         read = np.flatnonzero(coeff.any(axis=(0, 1)))
-        width = next(x for x in widths if x >= len(read))  # w <= k
+        width = next(x for x in widths if x >= len(read))  # w <= widths[-1]
         if width == w:
             return srcdata, coeff
         if len(read) < w:
@@ -324,7 +351,7 @@ class MeshCoder(ErasureCoder):
     def _rebuild_begin(self, srcdata: np.ndarray,
                        mats: Sequence[np.ndarray]) -> Dispatched:
         B, w, n = srcdata.shape
-        assert w <= self.scheme.data_shards and n % 4 == 0
+        assert w <= self.apply_widths[-1] and n % 4 == 0
         assert len(mats) == B
         m = self.scheme.parity_shards
         with tracing.stage("ec.mesh.pad") as st:
@@ -340,6 +367,8 @@ class MeshCoder(ErasureCoder):
                 np.ascontiguousarray(srcdata).view(np.uint32))
             coeff = self._pad_batch(coeff)
             fn = batch_apply_fn(self.scheme, self.mesh, srcdata.shape[1])
+            if srcdata.shape[1] == self.scheme.data_shards:
+                self._prepare_halves(words.shape[0], n)
         self._staged("pad", st)
         n_rows = [np.asarray(mt).shape[0] for mt in mats]
 
@@ -348,6 +377,28 @@ class MeshCoder(ErasureCoder):
             return [np.ascontiguousarray(out8[i, :r])
                     for i, r in enumerate(n_rows)]
         return self._launch("apply", fn, (words, coeff), unpack)
+
+    def _prepare_halves(self, pb: int, n: int) -> None:
+        """A piggybacked family's repair of n columns of shard bytes (a
+        whole block) is a job of n / 2 columns of half-rows at its
+        piggyback widths: the first k-wide apply of whole units at a
+        padded batch ``pb`` (a warm-up's: a served read's k-wide jobs
+        are of one half) compiles those programs too, on zeros, at
+        every batch up to the same columns (2 pb half-rows)."""
+        unit = getattr(self.scheme, "unit", 0)
+        if not unit or n % unit or (pb, n) in self._prepared:
+            return
+        self._prepared.add((pb, n))
+        k, m = self.scheme.data_shards, self.scheme.parity_shards
+        b = self.n_devices
+        while b <= 2 * pb:
+            for width in self.apply_widths:
+                if k < width < self.scheme.max_sources:
+                    fn = batch_apply_fn(self.scheme, self.mesh, width)
+                    jax.block_until_ready(fn(
+                        np.zeros((b, width, n // 8), dtype=np.uint32),
+                        np.zeros((b, m, width), dtype=np.uint32)))
+            b *= 2
 
     # ---- scalar ErasureCoder API (batch of one) ----
 
@@ -396,7 +447,11 @@ class MeshCoder(ErasureCoder):
         dispatch: from the sources the family's plan names where it
         plans (LRC: the first k present can be rank-deficient), else
         from the first k present shards, whose rebuild matrix expresses
-        data AND parity losses directly."""
+        data AND parity losses directly; a piggybacked family's from
+        the half-rows of whole units its plan reads."""
+        if self.scheme.substripes > 1:
+            from seaweedfs_tpu.ops import hitchhiker
+            return hitchhiker.rebuild_from(self, shards, missing)
         k, total = self.scheme.data_shards, self.scheme.total_shards
         present = [i for i in range(total) if shards[i] is not None]
         out = [bytes(s) if s is not None else None for s in shards]

@@ -68,7 +68,9 @@ Scheduling contract:
     rides, and how full its dispatches get) and ``cap_splits`` the groups
     that MAX_DISPATCH_COLUMNS cut into more than one dispatch;
   - a rebuild job carries as many ROWS as its matrix reads (k for plain
-    RS; a family's local repair the k / l survivors of a group), and a
+    RS; a family's local repair the k / l survivors of a group; a
+    piggybacked family's whole-block repair k + |group| HALF-rows, half
+    a block wide, on a rung of its own ladder: ``column_ladder``), and a
     dispatch groups jobs of one row count: a job of 6 rows and one of 12
     never share one (``by_spec[spec]["rows"]`` splits the jobs by it).
 
@@ -155,24 +157,33 @@ _STOP = object()
 _CLASS_RANK = {c: i for i, c in enumerate(CLASSES)}
 
 
-def bucket_columns(n: int) -> int:
+def column_ladder(scheme: RSScheme) -> tuple[int, ...]:
+    """The rungs a scheme's jobs pad up to: COLUMN_LADDER, and for a
+    piggybacked family (Hitchhiker-XOR) also half a unit, the width of a
+    whole block's repair job (its half-rows)."""
+    if scheme.substripes > 1:
+        return tuple(sorted(set(COLUMN_LADDER) | {scheme.unit // 2}))
+    return COLUMN_LADDER
+
+
+def bucket_columns(n: int, ladder: tuple = COLUMN_LADDER) -> int:
     """The ladder rung a job of ``n`` columns pads up to."""
-    for rung in COLUMN_LADDER:
+    for rung in ladder:
         if n <= rung:
             return rung
-    top = COLUMN_LADDER[-1]
+    top = ladder[-1]
     return -(-n // top) * top
 
 
-def shape_buckets(max_batch: int = 64, n_devices: int = 1
-                  ) -> list[tuple[int, int]]:
+def shape_buckets(max_batch: int = 64, n_devices: int = 1,
+                  ladder: tuple = COLUMN_LADDER) -> list[tuple[int, int]]:
     """Every (B, n) the scheduler can hand the mesh for ladder-width
     jobs: B the padded batch (a power-of-two multiple of the device
     count), capped by ``max_batch`` and MAX_DISPATCH_COLUMNS.  The
     compile tests walk this list; it is the bound on compiled programs
     per kind."""
     out = []
-    for n in COLUMN_LADDER:
+    for n in ladder:
         cap = max(n_devices, min(max_batch, MAX_DISPATCH_COLUMNS // n))
         b = n_devices
         while True:
@@ -423,7 +434,7 @@ class EcBatchScheduler:
                 st.annotate("rows", data.shape[0])
             data = np.ascontiguousarray(data, dtype=np.uint8)
             n = data.shape[1]
-            pad = bucket_columns(n) - n
+            pad = bucket_columns(n, column_ladder(scheme)) - n
             if pad:
                 data = np.concatenate(
                     [data, np.zeros((data.shape[0], pad), dtype=np.uint8)],
@@ -622,13 +633,14 @@ class EcBatchScheduler:
         return g
 
     def _mesh_compatible(self, jobs: list) -> bool:
-        # a geometry's programs are traced for (<=k, <=m)-shaped work
-        # (the mesh coder fits an operand of fewer rows to its apply
+        # a geometry's programs are traced for (<=max_sources, <=m)-shaped
+        # work (the mesh coder fits an operand of fewer rows to its apply
         # widths); anything else is a routing decision, not a mesh
         # failure — send it to the CPU coder without benching the mesh
         j = jobs[0]  # groups share scheme and data.shape by construction
         rows, k = j.data.shape[0], j.scheme.data_shards
-        if rows > k or (j.kind == "encode" and rows != k):
+        if rows > j.scheme.max_sources or (j.kind == "encode"
+                                           and rows != k):
             return False
         return all(jj.mat is None
                    or jj.mat.shape[0] <= j.scheme.parity_shards
@@ -1066,7 +1078,8 @@ class BatchCoder(ErasureCoder):
         or copied on the way to the device.  The rows come back
         rung-wide; columns past ``n`` are zero in, zero out."""
         return np.zeros((rows or self.scheme.data_shards,
-                         bucket_columns(n)), dtype=np.uint8)
+                         bucket_columns(n, column_ladder(self.scheme))),
+                        dtype=np.uint8)
 
     def reconstruct_rows(self, srcdata: np.ndarray,
                          rebuild_mat: np.ndarray,
@@ -1078,7 +1091,11 @@ class BatchCoder(ErasureCoder):
         """``shards`` with the ``missing`` ones filled in by one job: from
         the sources the family's plan names where it plans (LRC: the
         first k of sorted(present) can be rank-deficient for it), else
-        from the first k present shards."""
+        from the first k present shards; a piggybacked family's from the
+        half-rows its plan reads."""
+        if self.scheme.substripes > 1:
+            from seaweedfs_tpu.ops import hitchhiker
+            return hitchhiker.rebuild_from(self, shards, missing)
         k, total = self.scheme.data_shards, self.scheme.total_shards
         present = [i for i in range(total) if shards[i] is not None]
         out = [bytes(s) if s is not None else None for s in shards]

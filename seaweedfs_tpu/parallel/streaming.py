@@ -439,9 +439,9 @@ def pipelined_rebuild_files(base_file_name: str,
     if not hasattr(coder, "rebuild_matrix"):
         from seaweedfs_tpu.ops.rs_cpu import CpuCoder
         coder = CpuCoder(coder.scheme, workers="auto")
-    from seaweedfs_tpu.storage.erasure_coding.encoder import \
-        plan_rebuild_sources
-    src, rmat = plan_rebuild_sources(coder, present, missing)
+    from seaweedfs_tpu.storage.erasure_coding.encoder import rebuild_rows
+    src, batch_size, rebuild = rebuild_rows(coder, present, missing,
+                                            batch_size)
     n_src = len(src)
 
     shard_size = os.path.getsize(base_file_name + layout.shard_ext(src[0]))
@@ -503,8 +503,8 @@ def pipelined_rebuild_files(base_file_name: str,
             if buf is None:
                 break
             with tracing.stage("ec.pipeline.encode") as st:
-                rec = coder.reconstruct_rows(
-                    buf, rmat, out_pool.get((len(missing), buf.shape[1])))
+                rec = rebuild(buf,
+                              out_pool.get((len(missing), buf.shape[1])))
             busy += st.elapsed
             pl.put(write_q, rec)
             data_pool.put(buf)

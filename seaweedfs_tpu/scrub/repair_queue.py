@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import threading
 
+from seaweedfs_tpu.models.coder import parse_code_spec
 from seaweedfs_tpu.qos import BACKGROUND, class_scope
 from seaweedfs_tpu.utils import clockctl, glog, profiler, tracing
 from seaweedfs_tpu.utils.httpd import http_json
@@ -438,8 +439,14 @@ class RepairQueue:
 
         # 4a. network-frugal path: the rebuilder pulls pre-reduced
         # partial columns through a reduction chain instead of staging
-        # `need` full shards (ladder rung 3 falls through to 4b)
-        if self.partial_repair:
+        # `need` full shards (ladder rung 3 falls through to 4b); not
+        # for a volume whose holders said its code is piggybacked
+        code = topo.ec_volume_code(vid)
+        if code is not None and parse_code_spec(code).substripes > 1:
+            glog.info("ec repair vol %d: %s is piggybacked, the partial-"
+                      "column chain cannot take it: copy + rebuild", vid,
+                      code)
+        elif self.partial_repair:
             try:
                 return self._repair_partial(vid, collection,
                                             shard_owners, present,
@@ -507,12 +514,19 @@ class RepairQueue:
         families (LRC) narrow the source set — a single lost group
         shard repairs from its ~k/l surviving group members instead of
         fanning the reduction chain across k holders. Returns
-        (source_sids | None, strategy); None = use every survivor."""
+        (source_sids | None, strategy); None = use every survivor.  A
+        piggybacked volume (Hitchhiker-XOR: sources are half-rows at two
+        offsets) is refused by name: SubstripedCodeError, and the caller
+        falls back to copy + rebuild, which knows the family."""
+        from seaweedfs_tpu.models.coder import (SubstripedCodeError,
+                                                make_coder,
+                                                refuse_substriped,
+                                                scheme_from_dict)
         try:
-            from seaweedfs_tpu.models.coder import (make_coder,
-                                                    scheme_from_dict)
             spec = self._shard_stat(vid, collection, rebuilder_url)
             scheme = scheme_from_dict(spec.get("code"))
+            refuse_substriped(scheme, "the repair queue's partial-column "
+                              "chain")
             coder = make_coder("cpu").for_scheme(scheme)
             if not hasattr(coder, "plan_rebuild"):
                 return None, "global"
@@ -520,6 +534,8 @@ class RepairQueue:
             strategy = "local" if len(src) < scheme.data_shards \
                 else "global"
             return set(src), strategy
+        except SubstripedCodeError:
+            raise
         except Exception as e:
             glog.vlog(1, "ec repair vol %d: source planning skipped (%s)",
                       vid, e)

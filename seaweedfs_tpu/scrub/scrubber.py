@@ -245,6 +245,13 @@ class Scrubber:
         present = sorted(ev.shards)
         missing_data = [i for i in range(k) if i not in ev.shards]
         remote_reader = getattr(self.store, "remote_partial_reader", None)
+        unit = getattr(coder.scheme, "unit", 0)
+        if unit and (missing_data or self.ec_chunk_bytes % unit):
+            # a piggybacked code checks whole units, all data local: its
+            # parity b-halves are not a partial chain's plain RS rows
+            rep["skipped"] = (f"{coder.scheme!r}: parity of whole "
+                              f"{unit}-byte units, every data shard local")
+            return rep
         if missing_data and remote_reader is None:
             # a spread deployment holds only some shards per node; local
             # parity recompute needs all k data columns, and this store

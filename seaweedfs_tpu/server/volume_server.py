@@ -25,7 +25,8 @@ from typing import Optional
 import numpy as np
 
 from seaweedfs_tpu.models.coder import (MAX_VOLUME_SHARDS, CodeSpecError,
-                                        ErasureCoder, scheme_from_dict,
+                                        ErasureCoder, SubstripedCodeError,
+                                        refuse_substriped, scheme_from_dict,
                                         scheme_to_dict)
 from seaweedfs_tpu.ops.rs_cpu import gf_partial_product
 from seaweedfs_tpu.qos import (BACKGROUND, WRITE, QosGovernor, class_scope,
@@ -2773,6 +2774,12 @@ class VolumeServer:
         except RuntimeError as e:
             return Response({"error": str(e)}, status=502)
         coder = self._ec_volume_coder(base)
+        try:
+            refuse_substriped(coder.scheme, "the partial-column chain")
+        except SubstripedCodeError as e:
+            # the caller falls back to copy + /admin/ec/rebuild, which
+            # rebuilds the family from its half-row plan
+            return Response({"error": str(e)}, status=409)
         k = coder.scheme.data_shards
         plan_capable = hasattr(coder, "plan_rebuild")
         # a plan-capable (LRC) coder can repair a group loss from fewer

@@ -17,7 +17,8 @@ from typing import Optional
 
 import numpy as np
 
-from seaweedfs_tpu.models.coder import ErasureCoder, RSScheme, make_coder
+from seaweedfs_tpu.models.coder import (ErasureCoder, RSScheme, make_coder,
+                                        refuse_substriped)
 from seaweedfs_tpu.storage import types as t
 from seaweedfs_tpu.storage.erasure_coding import layout
 from seaweedfs_tpu.storage.needle_map import MemDb
@@ -53,7 +54,9 @@ def plan_rebuild_sources(coder: ErasureCoder, present, missing):
     the coder only speaks the bytes API. Coders with plan_rebuild (LRC)
     choose the cheapest source set — a single-group loss reads the ~5
     surviving group members; rebuild_matrix coders (RS) read the first
-    data_shards survivors after dropping all-zero matrix columns."""
+    data_shards survivors after dropping all-zero matrix columns.  A
+    piggybacked family has no such plan: it is refused by name."""
+    refuse_substriped(coder.scheme, "a whole-shard rebuild plan")
     if hasattr(coder, "plan_rebuild"):
         return coder.plan_rebuild(present, missing)
     if hasattr(coder, "rebuild_matrix"):
@@ -64,6 +67,32 @@ def plan_rebuild_sources(coder: ErasureCoder, present, missing):
         return ([src[j] for j in used],
                 np.ascontiguousarray(rmat[:, used]))
     return None, None
+
+
+def rebuild_rows(coder: ErasureCoder, present, missing, batch_size: int):
+    """A whole-shard rebuild's reads and work: (the shards it reads, its
+    batch size, ``fn(rows, out=None)`` -> the missing shards' rows of a
+    batch of theirs); (None, batch_size, None) for a coder that only
+    speaks the bytes API.  A piggybacked family reads whole units of the
+    shards its half-row plan touches (ops/hitchhiker.unit_plan), in
+    batches of whole units; every other code plan_rebuild_sources's."""
+    if coder.scheme.substripes > 1:
+        from seaweedfs_tpu.ops import hitchhiker
+        plan = hitchhiker.unit_plan(coder.scheme, present, missing)
+        unit = coder.scheme.unit
+
+        def units(rows, out=None):
+            rec = hitchhiker.rebuild_units(coder, coder.scheme, plan, rows)
+            if out is None:
+                return rec
+            out[:] = rec
+            return out
+        return plan[0], max(unit, batch_size - batch_size % unit), units
+    src, rmat = plan_rebuild_sources(coder, present, missing)
+    if src is None:
+        return None, batch_size, None
+    return src, batch_size, \
+        lambda rows, out=None: coder.reconstruct_rows(rows, rmat, out)
 
 
 def _read_block(f, offset: int, length: int) -> np.ndarray:
@@ -157,7 +186,8 @@ def rebuild_ec_files(base_file_name: str, coder: Optional[ErasureCoder] = None,
         # than k survivors; its plan raises if truly unrecoverable
         raise ValueError(f"need {k} shards, have {len(present)}")
 
-    src, rmat = plan_rebuild_sources(coder, present, missing)
+    src, batch_size, rebuild = rebuild_rows(coder, present, missing,
+                                            batch_size)
     shard_size = os.path.getsize(base_file_name + layout.shard_ext(present[0]))
     read_ids = src if src is not None else present
     ins = {i: open(base_file_name + layout.shard_ext(i), "rb")
@@ -174,7 +204,7 @@ def rebuild_ec_files(base_file_name: str, coder: Optional[ErasureCoder] = None,
                     ins[i].seek(off)
                     rows[r] = np.frombuffer(ins[i].read(n), dtype=np.uint8)
                 read_bytes += n * len(src)
-                rec = coder.reconstruct_rows(rows, rmat)
+                rec = rebuild(rows)
                 for r, i in enumerate(missing):
                     outs[i].write(rec[r].tobytes())
             else:

@@ -16,8 +16,8 @@ from typing import Callable, Optional
 import numpy as np
 
 from seaweedfs_tpu.models.coder import (DEFAULT_SCHEME, MAX_VOLUME_SHARDS,
-                                        ErasureCoder, make_coder,
-                                        parse_code_spec)
+                                        ErasureCoder, code_spec_name,
+                                        make_coder, parse_code_spec)
 from seaweedfs_tpu.storage import types as t
 from seaweedfs_tpu.storage.disk_location import DiskLocation
 from seaweedfs_tpu.storage.erasure_coding import layout
@@ -32,6 +32,12 @@ from seaweedfs_tpu.utils import tracing
 
 # remote_shard_reader(vid, shard_id, offset, size) -> bytes | None
 RemoteShardReader = Callable[[int, int, int, int], Optional[bytes]]
+
+
+def _code_key(scheme) -> dict:
+    """What a heartbeat's EC entry says of the code beyond its counts: a
+    piggybacked volume's spec (its counts are RS(k, r)'s)."""
+    return {"code": code_spec_name(scheme)} if scheme.substripes > 1 else {}
 
 
 class Store:
@@ -97,7 +103,10 @@ class Store:
         self.deleted_ec_shards: list[dict] = []
         # degraded-read repair-strategy tallies (exposed via shard_stat):
         # "local" = planned group-local recovery, "global" = planned
-        # full-width recovery, "generic" = unplanned collect-k fallback
+        # full-width recovery, "generic" = unplanned collect-k fallback;
+        # and, once the store serves a piggybacked volume (coder_for_
+        # scheme), "piggyback" / "substripe" (_recover_substriped).
+        # Intervals each: the values sum to the intervals rebuilt
         self.ec_recover_stats = {"local": 0, "global": 0, "generic": 0}
         # what the EC read path read and waited for (shard_stat's
         # "read_stats"; ec_recover_stats keeps its three keys): intervals
@@ -396,7 +405,8 @@ class Store:
                             {"id": vid, "collection": ev.collection,
                              "ec_index_bits": 1 << sid,
                              "data_shards": ev.data_shards,
-                             "total_shards": ev.total_shards})
+                             "total_shards": ev.total_shards,
+                             **_code_key(ev.scheme)})
                     break
                 except FileNotFoundError:
                     continue
@@ -423,6 +433,9 @@ class Store:
         c = self._coder_cache.get(scheme)
         if c is None:
             c = self._coder_cache[scheme] = self.coder.for_scheme(scheme)
+            if scheme.substripes > 1:
+                for key in ("piggyback", "substripe"):
+                    self.ec_recover_stats.setdefault(key, 0)
         return c
 
     def generate_ec_shards(self, vid: int, pipelined: bool = True,
@@ -436,7 +449,8 @@ class Store:
         name. The volume must exist locally; it is marked readonly first.
         `code` is a code spec (models/coder.parse_code_spec: '' / 'rs' ->
         the store coder's scheme, 'rs-<k>-<m>', 'lrc' -> LRC(10,2,2),
-        'lrc-<k>-<l>-<g>'; an unknown one raises CodeSpecError); the
+        'lrc-<k>-<l>-<g>', 'hhxor-<k>-<m>'; an unknown one raises
+        CodeSpecError); the
         chosen CodeSpec is persisted
         in the .vif, and everything later reads it from there."""
         from seaweedfs_tpu.storage.erasure_coding import encoder as ecenc
@@ -740,6 +754,9 @@ class Store:
         total = coder.scheme.total_shards
         shard_off = iv.to_shard_id_and_offset(
             data_shards=ev.data_shards)[1]
+        if coder.scheme.substripes > 1:
+            return self._recover_substriped(ev, iv.size, shard_off, coder,
+                                            wanted_shard)
         plan_capable = hasattr(coder, "plan_rebuild")
         if plan_capable:
             got = self._recover_via_plan(ev, iv, shard_off, coder,
@@ -967,6 +984,168 @@ class Store:
         self.ec_recover_stats[strategy] += 1
         return coder.reconstruct_rows(rows, mat)[0, :size].tobytes()
 
+    def _half_plan(self, coder: ErasureCoder, wanted: tuple,
+                   present: frozenset) -> Optional[tuple]:
+        """A piggybacked family's plan for the ``wanted`` half-rows from
+        the shards of ``present``: (sources as (shard, half), matrix),
+        kept per (scheme, wanted, present) as _repair_plan keeps LRC's;
+        None where ``present`` cannot decode them."""
+        key = (coder.scheme, wanted, present)
+        try:
+            return self._rebuild_plans[key]
+        except KeyError:
+            pass
+        from seaweedfs_tpu.ops import hitchhiker
+        try:
+            plan = hitchhiker.plan_halves(coder.scheme, present, wanted)
+        except ValueError:
+            plan = None
+        self._rebuild_plans[key] = plan
+        return plan
+
+    def _recover_substriped(self, ev: EcVolume, size: int, shard_off: int,
+                            coder: ErasureCoder, wanted_shard: int) -> bytes:
+        """A piggybacked family's repair of ``size`` bytes of a shard from
+        ``shard_off`` (ops/hitchhiker.py).  The range is cut at the halves
+        of its units (a unit: ``scheme.unit`` bytes, a small block).  Where
+        it touches both halves of a unit, ONE piggyback job rebuilds both
+        (k + |group| half-ranges in, 2 out) if that reads fewer bytes
+        than the two halves' own plans; any other piece is a substripe job
+        (plain RS on that half: k half-ranges).  The interval counts once,
+        as ``piggyback`` where a unit of it took that plan, else
+        ``substripe``.  A pattern no plan decodes is a NotFoundError:
+        this family never falls to the generic collect-k ladder, whose
+        sources are whole shards at one offset."""
+        unit = coder.scheme.unit
+        half = unit // 2
+        end = shard_off + size
+        out = np.empty(size, dtype=np.uint8)
+        others = frozenset(range(coder.scheme.total_shards)) \
+            - {wanted_shard}
+        strategy = "substripe"
+        for base in range(shard_off - shard_off % unit, end, unit):
+            lo, hi = max(shard_off, base) - base, min(end, base + unit) - base
+            pieces = {sub: (max(lo - sub * half, 0),
+                            min(hi - sub * half, half))
+                      for sub in (0, 1)
+                      if lo < (sub + 1) * half and hi > sub * half}
+            if len(pieces) == 2:
+                x_lo = min(p[0] for p in pieces.values())
+                x_hi = max(p[1] for p in pieces.values())
+                both = ((wanted_shard, 0), (wanted_shard, 1))
+                plan = self._plan_reachable(ev, coder, both, others)
+                alone = [self._plan_reachable(
+                    ev, coder, ((wanted_shard, sub),), others)
+                    for sub in (0, 1)]
+                if plan is not None and (None in alone or len(plan[0]) * (
+                        x_hi - x_lo) < sum(len(a[0]) * (p[1] - p[0])
+                                           for a, p in zip(
+                                               alone, pieces.values()))):
+                    rec = self._half_job(ev, coder, plan, "piggyback",
+                                         base, x_lo, x_hi)
+                    for sub, (p_lo, p_hi) in pieces.items():
+                        at = base + sub * half + p_lo - shard_off
+                        out[at:at + p_hi - p_lo] = \
+                            rec[sub, p_lo - x_lo:p_hi - x_lo]
+                    strategy = "piggyback"
+                    continue
+            for sub, (p_lo, p_hi) in pieces.items():
+                plan = self._plan_reachable(ev, coder,
+                                            ((wanted_shard, sub),), others)
+                rec = self._half_job(ev, coder, plan, "substripe",
+                                     base, p_lo, p_hi)
+                at = base + sub * half + p_lo - shard_off
+                out[at:at + p_hi - p_lo] = rec[0, :p_hi - p_lo]
+        stats = self.ec_recover_stats
+        stats[strategy] = stats.get(strategy, 0) + 1
+        return out.tobytes()
+
+    def _plan_reachable(self, ev: EcVolume, coder: ErasureCoder,
+                        wanted: tuple, others: frozenset) -> Optional[tuple]:
+        """The half-row plan over every other shard where its sources
+        are all mounted here, else over the shards that can be reached
+        (_recover_via_plan's rule)."""
+        plan = self._half_plan(coder, wanted, others)
+        if plan is not None and any(sid not in ev.shards
+                                    for sid, _ in plan[0]):
+            plan = self._half_plan(coder, wanted,
+                                   self._reachable(ev) & others)
+        return plan
+
+    def _half_job(self, ev: EcVolume, coder: ErasureCoder,
+                  plan: Optional[tuple], strategy: str, base: int,
+                  x_lo: int, x_hi: int) -> np.ndarray:
+        """One job of a piggybacked family's repair: the plan's sources,
+        half-ranges [x_lo, x_hi) of the unit at shard offset ``base``,
+        gathered into the coder's job buffer (one read call per half
+        for the mounted ones, peers for the rest) and ONE reconstruct_
+        rows; returns its rows."""
+        if plan is None:
+            raise NotFoundError(
+                f"ec volume {ev.volume_id} ({coder.scheme!r}): the "
+                "reachable shards cannot decode the lost range")
+        src, mat = plan
+        size = x_hi - x_lo
+        half = coder.scheme.unit // 2
+        with tracing.stage("store.ec.plan") as st:
+            st.annotate("strategy", strategy)
+            st.annotate("sources", [list(h) for h in src])
+        with tracing.stage("store.ec.survivors") as st:
+            st.annotate("half_rows", len(src))
+            job_rows = getattr(coder, "job_rows", None)
+            rows = job_rows(size, len(src)) if job_rows is not None \
+                else np.empty((len(src), size), dtype=np.uint8)
+            remote = [(r, sid, base + sub * half + x_lo)
+                      for r, (sid, sub) in enumerate(src)
+                      if sid not in ev.shards]
+            if remote:
+                self._fetch_half_rows(ev, remote, size, rows)
+            for sub in (0, 1):
+                mounted = [ev.shards.get(sid) if h == sub else None
+                           for sid, h in src]
+                if not any(mounted):
+                    continue
+                off = base + sub * half + x_lo
+                for (sid, _h), shard, n in zip(src, mounted,
+                                               read_shards_into(
+                                                   mounted, off, rows, size)):
+                    if shard is not None and n != size:
+                        raise NotFoundError(
+                            f"ec volume {ev.volume_id}: shard {sid} ends "
+                            f"inside [{off}, {off + size})")
+            self._count_survivors(len(src), size)
+            self.ec_read_stats["survivor_gathers"] += 1
+        return coder.reconstruct_rows(rows, mat)
+
+    def _fetch_half_rows(self, ev: EcVolume, remote: list, size: int,
+                         rows: np.ndarray) -> None:
+        """rows[r, :size] = shard sid's bytes at ``off`` from a peer, for
+        each (r, sid, off) of ``remote``, all at once on the recovery
+        pool; a half-range no peer serves is a NotFoundError."""
+        if self.remote_shard_reader is None:
+            raise NotFoundError(
+                f"ec volume {ev.volume_id}: shards "
+                f"{sorted({sid for _r, sid, _o in remote})} not mounted "
+                "here and no peer reader")
+        from seaweedfs_tpu.utils import resilience
+        dl = resilience.current_deadline()
+
+        def fetch(sid: int, off: int):
+            with resilience.deadline_scope(dl):
+                return self.remote_shard_reader(ev.volume_id, sid, off, size)
+
+        pool = self._recover_pool()
+        futs = [(r, sid, pool.submit(fetch, sid, off))
+                for r, sid, off in remote]
+        for r, sid, fut in futs:
+            got = fut.result(timeout=None if dl is None
+                             else max(0.0, dl.remaining()))
+            if got is None or len(got) != size:
+                raise NotFoundError(f"ec volume {ev.volume_id}: a "
+                                    f"half-range of shard {sid} is "
+                                    "unreachable")
+            rows[r, :size] = np.frombuffer(got, dtype=np.uint8)
+
     def _rank_remote_sids(self, vid: int,
                           sids: list[int]) -> tuple[list[int], int]:
         """Order remote shard candidates by the health of their BEST
@@ -1123,6 +1302,7 @@ class Store:
                     # the volume's CodeSpec, for the master's planners
                     "data_shards": ev.data_shards,
                     "total_shards": ev.total_shards,
+                    **_code_key(ev.scheme),
                 })
         disk_slots: dict[str, int] = {}
         for loc in self.locations:

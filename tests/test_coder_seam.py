@@ -23,7 +23,8 @@ from seaweedfs_tpu.parallel.batcher import BatchCoder, EcBatchScheduler
 RS63 = RSScheme(6, 3)
 LRC = LrcScheme(10, 2, 2)
 SCHEMES = {"rs-10-4": DEFAULT_SCHEME, "rs-6-3": RS63, "lrc-10-2-2": LRC}
-NAMES = ("cpu", "cpu-mt", "jax", "lrc", "lrc-mt", "mesh")
+NAMES = ("cpu", "cpu-mt", "hhxor", "hhxor-mt", "jax", "lrc", "lrc-mt",
+         "mesh")
 
 
 def _parity_matrix(scheme) -> np.ndarray:
@@ -93,11 +94,13 @@ def test_registry_is_six_names_and_the_cli_offers_the_served_ones():
     p = argparse.ArgumentParser()
     _add_common_volume_args(p)
     (choices,) = [a.choices for a in p._actions if a.dest == "coder"]
-    # the host-only names (-mt, the LRC family) are not a server's pick:
-    # a family is a volume's property, threading the store's default
+    # the host-only names (-mt, the LRC and Hitchhiker-XOR families) are
+    # not a server's pick: a family is a volume's property, threading the
+    # store's default
     assert sorted(choices) == ["cpu", "jax", "mesh"]
     assert DEVICE_CODERS == ("jax", "mesh")
-    assert set(choices) | {"cpu-mt", "lrc", "lrc-mt"} == set(NAMES)
+    assert set(choices) | {"cpu-mt", "lrc", "lrc-mt", "hhxor",
+                           "hhxor-mt"} == set(NAMES)
 
 
 def test_gf_mul_dynamic_is_the_fields_multiplication():

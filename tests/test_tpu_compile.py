@@ -223,3 +223,35 @@ def test_lrc12_2_2_programs_compile_under_their_own_names(topo, kind, width,
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
         + mem.output_size_in_bytes < 1 << 30
+
+
+@pytest.mark.parametrize("kind,width,B,n", [
+    ("encode", 10, 1, MIB),            # a seal's row of ten 1 MiB blocks
+    ("encode", 10, 1, 16 * MIB),       # a large-block batch of 16 units
+    ("apply", 13, 1, 512 << 10),       # a lost block of groups 1-2
+    ("apply", 13, 8, 512 << 10),       # ... the fullest batch of the rung
+    ("apply", 14, 4, 512 << 10),       # a lost block of group 3
+    ("apply", 20, 1, 256 << 10),       # the widest half-row decode
+])
+def test_hhxor_programs_compile_under_their_own_names(topo, kind, width,
+                                                      B, n):
+    """The piggybacked family's programs (Hitchhiker-XOR over RS(10,4),
+    cell ``reads.degraded1.chunks.hhxor10-4``) at the shapes its volumes
+    use: the seal of whole 1 MiB units with the piggybacks, and the
+    apply program at its widths past k on half-block columns."""
+    from seaweedfs_tpu.models.coder import HitchhikerScheme
+    scheme = HitchhikerScheme(10, 4)
+    assert rs_mesh.apply_widths(scheme) == (10, 13, 14, 20)
+    mesh = _batch_mesh(topo, 1)
+    s3 = NamedSharding(mesh, P("batch", None, None))
+    words = jax.ShapeDtypeStruct((B, width, n // 4), jnp.uint32, sharding=s3)
+    coeff = jax.ShapeDtypeStruct((B, 4, width), jnp.uint32, sharding=s3)
+    if kind == "encode":
+        lowered = rs_mesh.batch_encode_fn(scheme, mesh).lower(words)
+    else:
+        lowered = rs_mesh.batch_apply_fn(scheme, mesh, width).lower(
+            words, coeff)
+    assert f"jit_ec_{kind}_hhxor_10_4" in lowered.as_text()[:200]
+    compiled = lowered.compile()
+    (out_s,) = jax.tree_util.tree_leaves(compiled.output_shardings)
+    assert out_s.shard_shape((B, 4, n // 4)) == (B, 4, n // 4)
