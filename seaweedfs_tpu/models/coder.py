@@ -317,9 +317,8 @@ def host_coder(scheme: RSScheme, threaded: bool) -> "ErasureCoder":
 
 class Encoded:
     """What ``ErasureCoder.encode_begin`` hands back: ``result()`` is the
-    caller's ``out``, filled; ``done()`` says whether it is filled
-    already.  This one is: the result of a coder that does its work in
-    the begin."""
+    parity, ``done()`` says whether it is there already.  This one is:
+    the result of a coder that does its work in the begin."""
 
     __slots__ = ("_out",)
 
@@ -380,18 +379,28 @@ class ErasureCoder(abc.ABC):
                          for i in range(self.scheme.parity_shards)])
 
     def encode_into(self, data, out) -> "np.ndarray":
-        """encode_array into a caller's (m, n) uint8 buffer (the EC
-        pipeline recycles its parity buffers); returns `out`."""
+        """encode_array into a caller's (m, n) uint8 buffer; returns
+        `out`."""
         out[:] = self.encode_array(data)
         return out
 
-    def encode_begin(self, data, out) -> Encoded:
-        """encode_into in two steps: begin a batch, ask for ``out``
-        filled later (``.result()``), and meanwhile begin the next.  A
-        coder that works on the caller's thread has done it all when
-        this returns; one that hands the batch to another thread (the
-        batch scheduler's facade) returns while it is in flight, and the
-        EC pipeline keeps a second batch in the coder behind it."""
+    def encode_begin(self, data) -> Encoded:
+        """encode_array in two steps: begin a batch, ask for its parity
+        later (``.result()``), and meanwhile begin the next.  A coder
+        that works on the caller's thread has done it all when this
+        returns; one that hands the batch to another thread (the batch
+        scheduler's facade) returns while it is in flight, and the EC
+        pipeline keeps a second batch in the coder behind it.
+
+        The parity is an (m, n) uint8 array of the coder's own,
+        C-contiguous, possibly read-only, which the coder keeps no hold
+        on: here one this begin allocates and ``encode_into`` fills;
+        under the batch scheduler the job's rows of the array the
+        device's output was fetched into, so the batch's parity reaches
+        host memory once."""
+        import numpy as np
+        out = np.empty((self.scheme.parity_shards, data.shape[1]),
+                       dtype=np.uint8)
         return Encoded(self.encode_into(data, out))
 
     def reconstruct_arrays(self, present: dict, n: int) -> list:

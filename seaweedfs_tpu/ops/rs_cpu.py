@@ -238,8 +238,8 @@ class CpuCoder(ErasureCoder):
                            np.ascontiguousarray(data, dtype=np.uint8))
 
     def encode_into(self, data: np.ndarray, out: np.ndarray) -> np.ndarray:
-        """encode_array into a caller-owned (m, n) buffer (pipelines reuse
-        pooled buffers to avoid per-batch allocation). Zero-fills `out`
+        """encode_array into a caller-owned (m, n) buffer (the one
+        ``encode_begin`` is given, or allocates). Zero-fills `out`
         first — the kernels accumulate."""
         out[:] = 0
         return self._apply(self._parity,

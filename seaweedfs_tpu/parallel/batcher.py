@@ -954,19 +954,19 @@ class EcBatchScheduler:
 
 class _QueuedEncode:
     """An encode on the device queue (``BatchCoder.encode_begin``): the
-    wait, the copy out and the caller's ``result`` stage happen when the
-    result is asked for."""
+    wait and the caller's ``result`` stage happen when the result is
+    asked for."""
 
-    __slots__ = ("_coder", "_fut", "_out")
+    __slots__ = ("_coder", "_fut")
 
-    def __init__(self, coder: "BatchCoder", fut: Future, out: np.ndarray):
-        self._coder, self._fut, self._out = coder, fut, out
+    def __init__(self, coder: "BatchCoder", fut: Future):
+        self._coder, self._fut = coder, fut
 
     def done(self) -> bool:
         return self._fut.done()
 
     def result(self) -> np.ndarray:
-        return self._coder._result(self._fut, self._out)
+        return self._coder._result(self._fut)
 
 
 class BatchCoder(ErasureCoder):
@@ -1037,25 +1037,25 @@ class BatchCoder(ErasureCoder):
     def encode_array(self, data: np.ndarray) -> np.ndarray:
         return self._encode(data)
 
-    def encode_into(self, data: np.ndarray, out: np.ndarray) -> np.ndarray:
-        return self.encode_begin(data, out).result()
-
-    def encode_begin(self, data: np.ndarray,
-                     out: np.ndarray) -> _QueuedEncode:
-        """Submit now, wait and copy out when asked: the caller may begin
-        its next batch while the device has this one.  Not before this
-        caller's previous job has LEFT the queue for its launch, though:
-        a lone pipeline keeps two jobs in the coder (on the device, when
-        both places were free) and never two in the queue, so its jobs
-        cannot share a dispatch (a B = 2 program that nothing warmed:
-        ~2 s of compile inside a seal) whatever the threads' timing.  In
-        step the wait is over long before it is asked for."""
+    def encode_begin(self, data: np.ndarray) -> _QueuedEncode:
+        """Submit now, wait when asked: the caller may begin its next
+        batch while the device has this one.  The result is the job's
+        rows of the array the collector fetched the dispatch into, as
+        they came (C-contiguous, maybe read-only, each job's its own
+        memory): the EC pipeline writes them to the shard files as they
+        are.  Not before this caller's previous job has LEFT the queue
+        for its launch, though: a lone pipeline keeps two jobs in the
+        coder (on the device, when both places were free) and never two
+        in the queue, so its jobs cannot share a dispatch (a B = 2
+        program that nothing warmed: ~2 s of compile inside a seal)
+        whatever the threads' timing.  In step the wait is over long
+        before it is asked for."""
         last = getattr(self._begun, "taken", None)
         if last is not None:
             last.wait()
         job = self.scheduler._encode_job(data, None, None, self.scheme)
         self._begun.taken = job.taken
-        return _QueuedEncode(self, job.future, out)
+        return _QueuedEncode(self, job.future)
 
     def encode(self, shards: Sequence[bytes]) -> list[bytes]:
         k = self.scheme.data_shards

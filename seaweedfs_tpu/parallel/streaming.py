@@ -45,8 +45,13 @@ Stage plumbing invariants:
   - shard outputs go to `.tmp` names and are renamed into place only after
     every stage has finished cleanly — an interrupted pipeline never
     leaves a truncated file under a final shard name;
-  - buffers are pooled and recycled writer -> reader, so steady-state
-    allocation is zero.
+  - the data buffers are pooled and recycled writer -> reader, so their
+    steady-state allocation is zero.  A batch's parity is the array the
+    coder's begin hands back (``encode_begin`` is given no buffer): under
+    the batch scheduler the rows its collector fetched the device's
+    output into, so the parity reaches host memory once and is never
+    copied on the caller's thread; a host coder computes it into an
+    array of its own.  The parity writer writes it and drops it.
 
 Each run is one ``ec.pipeline`` stage (utils/tracing.stage) with an
 ``ec.pipeline.read`` / ``.write`` stage per batch in the thread that does
@@ -258,7 +263,6 @@ def pipelined_encode_file(base_file_name: str,
     scheme = coder.scheme
     k = scheme.data_shards
     total = scheme.total_shards
-    m = total - k
     dat_path = base_file_name + ".dat"
     dat_size = os.path.getsize(dat_path)
     descs = list(layout.iter_encode_batches(dat_size, large_block,
@@ -270,7 +274,6 @@ def pipelined_encode_file(base_file_name: str,
     data_q: "queue.Queue" = queue.Queue(maxsize=prefetch)
     parity_q: "queue.Queue" = queue.Queue(maxsize=prefetch)
     data_pool = _BufferPool()
-    parity_pool = _BufferPool()
     slock = threading.Lock()
     wall0 = clockctl.monotonic()
 
@@ -285,12 +288,14 @@ def pipelined_encode_file(base_file_name: str,
                 pl.put(read_q, (seq, buf))
         _merge_stats(stats, slock, read_s=busy, io_calls=calls)
 
-    def writer_stage(q: "queue.Queue", files: list, pool: _BufferPool):
+    def writer_stage(q: "queue.Queue", files: list,
+                     pool: Optional[_BufferPool]):
         """One of the two writers: a batch's rows, one to a file, in the
         order they are handed over (every file grows by the batch's
         width, so one running offset serves them all: ONE foreign call a
         batch with the native library, a write a file without); the
-        buffer then goes back to its pool."""
+        buffer then goes back to its pool (the data writer's) or is
+        dropped (the parity writer's: the coder's own array)."""
         fds = [f.fileno() for f in files] if rs_native.available() \
             else None
         busy, calls, offset = 0.0, 0, 0
@@ -308,7 +313,8 @@ def pipelined_encode_file(base_file_name: str,
                         f.write(row)
                     calls += len(files)
             busy += st.elapsed
-            pool.put(rows)
+            if pool is not None:
+                pool.put(rows)
         _merge_stats(stats, slock, write_s=busy, io_calls=calls)
 
     def hand_over(rows, parity):
@@ -323,7 +329,7 @@ def pipelined_encode_file(base_file_name: str,
         # each: one thread copying 14 MiB a batch into the page cache is
         # as slow as the dispatch it runs beside
         pl.spawn(writer_stage, data_q, outs.files[:k], data_pool)
-        pl.spawn(writer_stage, parity_q, outs.files[k:], parity_pool)
+        pl.spawn(writer_stage, parity_q, outs.files[k:], None)
         for rid in range(readers):
             pl.spawn(reader_stage, rid)
 
@@ -349,8 +355,7 @@ def pipelined_encode_file(base_file_name: str,
             with tracing.stage("ec.pipeline.encode") as st:
                 if ahead is not None and not ahead[1].done():
                     overlapped += 1
-                began = coder.encode_begin(
-                    data, parity_pool.get((m, data.shape[1])))
+                began = coder.encode_begin(data)
                 if ahead is not None:
                     ready.append((ahead[0], _collect(pl, ahead[1])))
                     ahead = None

@@ -17,18 +17,20 @@ import errno
 import glob
 import os
 import threading
+import time
 
 import numpy as np
 import pytest
 
-from benchmark import reference
+from benchmark import reference, reference_hitchhiker
 from seaweedfs_tpu.models.coder import (DEFAULT_SCHEME, Encoded,
-                                        ErasureCoder, LrcScheme, RSScheme,
-                                        host_coder, make_coder,
-                                        scheme_to_dict)
+                                        ErasureCoder, HitchhikerScheme,
+                                        LrcScheme, RSScheme, host_coder,
+                                        make_coder, scheme_to_dict)
 from seaweedfs_tpu.native import rs_native
 from seaweedfs_tpu.ops import rs_cpu
 from seaweedfs_tpu.parallel import streaming
+from seaweedfs_tpu.parallel.batcher import BatchCoder, _QueuedEncode
 from seaweedfs_tpu.storage.erasure_coding import decoder as ecdec
 from seaweedfs_tpu.storage.erasure_coding import encoder as ecenc
 from seaweedfs_tpu.storage.erasure_coding import layout
@@ -236,8 +238,8 @@ class _Begun:
     """What _OffThreadCoder.encode_begin returns: filled, or failed, by
     the coder's own thread, when the coder's rule says so."""
 
-    def __init__(self, coder, seq, data, out):
-        self.coder, self.seq, self.data, self.out = coder, seq, data, out
+    def __init__(self, coder, seq, data):
+        self.coder, self.seq, self.data, self.out = coder, seq, data, None
         self.finished = threading.Event()
         self.error = None
 
@@ -278,9 +280,9 @@ class _OffThreadCoder(ErasureCoder):
     def reconstruct(self, shards):
         return self._inner.reconstruct(shards)
 
-    def encode_begin(self, data, out):
+    def encode_begin(self, data):
         with self.cv:
-            b = _Begun(self, len(self.begun), data, out)
+            b = _Begun(self, len(self.begun), data)
             self.begun.append(b)
             self.most_uncollected = max(
                 self.most_uncollected,
@@ -301,7 +303,7 @@ class _OffThreadCoder(ErasureCoder):
                 b.error = ValueError(f"batch {seq} lost")
                 b.finished.set()
                 return
-            self._inner.encode_into(b.data, b.out)
+            b.out = self._inner.encode_array(b.data)
             b.finished.set()
 
     def close(self):
@@ -385,11 +387,10 @@ def test_host_coder_leaves_nothing_in_flight(tmp_path):
     assert stats["batches"] == len(want) and stats["overlapped"] == 0
     # the seam's two-step form on a host coder: all done in the begin
     rows = np.frombuffer(want[0][0], dtype=np.uint8).reshape(K, -1)
-    out = np.empty((TOTAL - K, rows.shape[1]), dtype=np.uint8)
-    began = coder.encode_begin(rows, out)
+    began = coder.encode_begin(rows)
     assert isinstance(began, Encoded) and began.done()
-    assert began.result() is out
-    assert np.array_equal(out, make_coder("cpu").encode_array(rows))
+    assert np.array_equal(began.result(),
+                          make_coder("cpu").encode_array(rows))
     sbase = str(tmp_path / "s")
     _make_dat(sbase, size, seed=4)
     ecenc.write_ec_files(sbase, make_coder("cpu"), LB, SB, batch_size=SB)
@@ -530,6 +531,130 @@ def test_either_writer_crash_raises_pipeline_error(tmp_path, monkeypatch,
                                         batch_size=SB)
     assert isinstance(ei.value.__cause__, IOError)
     assert _leftovers(tmp_path) == []
+
+
+# ---- a seal's parity goes to its files as the coder fetched it ----
+
+HHXOR_SMALL = HitchhikerScheme(10, 4, unit=4096)
+# each code's seal: its scheme, and the block sizes its .dat is cut by
+# (a piggybacked code's small block is its unit, split in two halves)
+SEALS = {"rs-10-4": (DEFAULT_SCHEME, LB, SB),
+         "rs-6-3": (RSScheme(6, 3), LB, SB),
+         "lrc-12-2-2": (LrcScheme(12, 2, 2), LB, SB),
+         "hhxor-10-4": (HHXOR_SMALL, 4 * HHXOR_SMALL.unit,
+                        HHXOR_SMALL.unit)}
+
+
+@pytest.fixture(scope="module")
+def sched():
+    """The batch scheduler over the CPU mesh, every scheme on its queue."""
+    from seaweedfs_tpu.ops.rs_mesh import MeshCoder
+    from seaweedfs_tpu.parallel.batcher import EcBatchScheduler
+    s = EcBatchScheduler(mesh_coder=MeshCoder(DEFAULT_SCHEME, n_devices=1))
+    yield s
+    s.stop()
+
+
+def _seal_against_the_reference(tmp_path, coder, spec) -> dict:
+    """Seal a .dat of two large-block rows and small-block rows after
+    them through ``coder``; assert its files are the reference's bytes;
+    return the pipeline's stats."""
+    scheme, lb, sb = SEALS[spec]
+    k, total = scheme.data_shards, scheme.total_shards
+    base = str(tmp_path / "v")
+    _make_dat(base, 2 * lb * k + 3 * sb * k + 77, seed=total)
+    stats: dict = {}
+    streaming.pipelined_encode_file(base, coder, lb, sb, batch_size=sb,
+                                    stats=stats)
+    files = [base + layout.shard_ext(i) for i in range(total)]
+    code = {**scheme_to_dict(scheme), "parity_shards": total - k,
+            "large_block_bytes": lb, "small_block_bytes": sb}
+    ref = reference_hitchhiker if scheme.substripes > 1 else reference
+    assert ref.differing_files(base + ".dat", files, code) == []
+    assert stats["batches"] == 2 * lb // sb + 4     # 2 large rows, 4 small
+    return stats
+
+
+def _spy_handed(monkeypatch) -> list:
+    """Every parity the batch scheduler's facade hands back (``result()``
+    of a begun encode), with its bytes when handed; each is asserted to
+    be the array the collector fetched, not a copy of it."""
+    handed: list[tuple[np.ndarray, bytes]] = []
+    real = _QueuedEncode.result
+
+    def result(self):
+        parity = real(self)
+        assert parity is self._fut.result()
+        handed.append((parity, parity.tobytes()))
+        return parity
+
+    monkeypatch.setattr(_QueuedEncode, "result", result)
+    return handed
+
+
+def _spy_parity_writes(monkeypatch, m: int, delay: float = 0.0) -> list:
+    """The arrays the parity writer writes (its one ``pwrite_rows`` a
+    batch, told apart from the data writer's by its m files), each held
+    ``delay`` seconds first, with its bytes when written."""
+    _library(monkeypatch, "native")
+    written: list[tuple[np.ndarray, bytes]] = []
+    real = rs_native.pwrite_rows
+
+    def pwrite(fds, offset, rows, size):
+        if len(fds) == m:
+            time.sleep(delay)
+            written.append((rows, rows.tobytes()))
+        return real(fds, offset, rows, size)
+
+    monkeypatch.setattr(rs_native, "pwrite_rows", pwrite)
+    return written
+
+
+@pytest.mark.parametrize("spec", list(SEALS))
+def test_scheduler_seal_writes_the_fetched_parity(tmp_path, monkeypatch,
+                                                  sched, spec):
+    """Through the batch scheduler's facade each batch's parity goes to
+    the files as the collector fetched it: the parity writer writes the
+    very arrays the facade handed back, in order, and the files are the
+    reference's."""
+    scheme = SEALS[spec][0]
+    handed = _spy_handed(monkeypatch)
+    written = _spy_parity_writes(monkeypatch, scheme.parity_shards)
+    coder = BatchCoder(sched).for_scheme(scheme)
+    stats = _seal_against_the_reference(tmp_path, coder, spec)
+    assert len(written) == len(handed) == stats["batches"]
+    assert all(w is h for (w, _), (h, _) in zip(written, handed))
+    assert stats["overlapped"] >= 1
+
+
+@pytest.mark.parametrize("threaded", [False, True], ids=["one", "mt"])
+@pytest.mark.parametrize("spec", list(SEALS))
+def test_host_coder_seal_fills_pooled_buffers(tmp_path, spec, threaded):
+    """A host coder works on the caller's thread: each begin is done
+    when it returns (the parity in an array the begin allocated and the
+    coder filled), nothing is left ahead, and the files are the same
+    bytes as under the scheduler: the reference's."""
+    coder = host_coder(SEALS[spec][0], threaded)
+    stats = _seal_against_the_reference(tmp_path, coder, spec)
+    assert stats["overlapped"] == 0
+
+
+def test_queued_parity_is_never_overwritten(tmp_path, monkeypatch, sched):
+    """A parity writer slowed on purpose, so the coder runs ahead of it
+    with parities queued: consecutive batches' parity arrays share no
+    memory, each holds at its write the bytes it held when the facade
+    handed it over, and the files are the reference's."""
+    m = DEFAULT_SCHEME.parity_shards
+    handed = _spy_handed(monkeypatch)
+    written = _spy_parity_writes(monkeypatch, m, delay=0.02)
+    stats = _seal_against_the_reference(
+        tmp_path, BatchCoder(sched), "rs-10-4")
+    assert len(written) == len(handed) == stats["batches"]
+    for (a, _), (b, _) in zip(written, written[1:]):
+        assert not np.shares_memory(a, b)
+    for (rows, at_write), (parity, at_hand_over) in zip(written, handed):
+        assert rows is parity and at_write == at_hand_over
+        assert rows.flags.c_contiguous and rows.shape[0] == m
 
 
 # ---- pipelined rebuild / decode identity ----
