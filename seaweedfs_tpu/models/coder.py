@@ -46,7 +46,8 @@ class RSScheme:
         return self.data_shards + self.parity_shards
 
     # the most rows a repair job reads: k shards for a scalar code (one
-    # row a shard), 2k half-rows for a piggybacked one
+    # row a shard), 2k half-rows for a piggybacked one, d helpers' runs
+    # of sub-chunks for a regenerating one
     @property
     def max_sources(self) -> int:
         return self.data_shards
@@ -145,6 +146,9 @@ class HitchhikerScheme(RSScheme):
 
     __slots__ = ("groups", "unit")
     substripes = 2
+    # vector_ops(scheme); the strategies of its degraded reads
+    ops_module = "hitchhiker"
+    strategies = ("piggyback", "substripe")
 
     def __init__(self, data_shards: int = 10, parity_shards: int = 4,
                  groups=None, unit: int = 1 << 20):
@@ -172,6 +176,11 @@ class HitchhikerScheme(RSScheme):
     def max_sources(self) -> int:
         return 2 * self.data_shards
 
+    @property
+    def repair_columns(self) -> int:
+        """The columns of a whole unit's repair job: a half-unit."""
+        return self.unit // 2
+
     def group_of(self, sid: int) -> int:
         """Index j of the group holding data shard ``sid``: its a-half is
         piggybacked on parity shard k + 1 + j."""
@@ -191,9 +200,119 @@ class HitchhikerScheme(RSScheme):
                      self.unit, "hhxor"))
 
 
+class ClayScheme(RSScheme):
+    """Clay(k, m, d) (Vajha et al., "Clay Codes: Moulding MDS Codes to
+    Yield an MSR Code", FAST 2018; Ceph's ``clay`` plugin): a
+    minimum-storage regenerating VECTOR code.  With q = d - k + 1 and
+    t = ceil((k + m) / q), the k + m shards are nodes (x, y) = (i mod q,
+    i div q) of a q x t grid, nu = q t - k - m of them virtual (never
+    stored, zero): data shards are nodes 0 .. k-1, the virtual ones
+    follow, the m parities are the last column.  Every ``unit`` bytes of
+    a shard (one 1 MiB block) are alpha = q^t sub-chunks, one a plane
+    z in Z_q^t; a lost shard is rebuilt from beta = alpha / q sub-chunks
+    of each of its d helpers, and any m losses decode (MDS), at RS(k,
+    m)'s storage (ops/clay.py).  Only the MSR point d = k + m - 1 (the
+    plugin's default) is implemented: every other node is a helper."""
+
+    __slots__ = ("helpers", "unit", "gamma")
+    ops_module = "clay"
+    strategies = ("repair_planes", "planes_partial", "layered")
+
+    def __init__(self, data_shards: int = 10, parity_shards: int = 4,
+                 helpers: Optional[int] = None, unit: int = 1 << 20,
+                 gamma: int = 2):
+        super().__init__(data_shards, parity_shards)
+        d = data_shards + parity_shards - 1 if helpers is None else helpers
+        if parity_shards < 2 or d != data_shards + parity_shards - 1:
+            raise ValueError(
+                f"Clay: ({data_shards},{parity_shards},{d}) is not the MSR "
+                "point d = k + m - 1 with m >= 2, the one implemented")
+        self.helpers = d
+        self.unit = int(unit)
+        self.gamma = int(gamma)
+        if self.n_nodes > 256 or self.unit % (4 * self.alpha) \
+                or self.gamma in (0, 1) or not 0 < self.gamma < 256:
+            raise ValueError(
+                f"Clay({data_shards},{parity_shards},{d}): {self.alpha} "
+                f"sub-chunks of whole 32-bit words in a unit of {unit} "
+                f"bytes, at most 256 nodes, gamma {gamma} not 0 or 1")
+
+    @property
+    def q(self) -> int:
+        return self.helpers - self.data_shards + 1
+
+    @property
+    def t(self) -> int:
+        return -(-self.total_shards // self.q)
+
+    @property
+    def n_nodes(self) -> int:
+        return self.q * self.t
+
+    @property
+    def nu(self) -> int:
+        return self.n_nodes - self.total_shards
+
+    @property
+    def alpha(self) -> int:
+        return self.q ** self.t
+
+    @property
+    def beta(self) -> int:
+        return self.alpha // self.q
+
+    @property
+    def substripes(self) -> int:
+        return self.alpha
+
+    @property
+    def max_sources(self) -> int:
+        return self.helpers
+
+    @property
+    def sub_bytes(self) -> int:
+        return self.unit // self.alpha
+
+    @property
+    def repair_columns(self) -> int:
+        """The columns of a whole unit's repair job: beta sub-chunks."""
+        return self.beta * self.sub_bytes
+
+    def node_of(self, sid: int) -> int:
+        """The grid node of shard ``sid``: parities skip the virtual
+        nodes."""
+        return sid if sid < self.data_shards else sid + self.nu
+
+    def __repr__(self):
+        return f"Clay({self.data_shards},{self.parity_shards},{self.helpers})"
+
+    def __eq__(self, other):
+        return (type(other) is type(self)
+                and other.data_shards == self.data_shards
+                and other.parity_shards == self.parity_shards
+                and other.helpers == self.helpers
+                and other.unit == self.unit and other.gamma == self.gamma)
+
+    def __hash__(self):
+        return hash((self.data_shards, self.parity_shards, self.helpers,
+                     self.unit, self.gamma, "clay"))
+
+
+def vector_ops(scheme: RSScheme):
+    """The ops/ module of a vector family (``substripes`` > 1) that
+    plans its repairs and whole-shard rebuilds: ``rebuild_from``,
+    ``rebuild_rows``."""
+    return importlib.import_module("seaweedfs_tpu.ops." + scheme.ops_module)
+
+
 def scheme_to_dict(scheme: RSScheme) -> dict:
     """Serializable CodeSpec for volume metadata (.vif) — lets mixed-code
     clusters pick the right coder per volume at load time."""
+    if isinstance(scheme, ClayScheme):
+        return {"family": "clay", "data_shards": scheme.data_shards,
+                "parity_shards": scheme.parity_shards,
+                "d": scheme.helpers, "substripes": scheme.substripes,
+                "gamma": scheme.gamma, "unit_bytes": scheme.unit}
     if isinstance(scheme, HitchhikerScheme):
         return {"family": "hhxor", "data_shards": scheme.data_shards,
                 "parity_shards": scheme.parity_shards,
@@ -213,6 +332,12 @@ def scheme_from_dict(d: Optional[dict]) -> RSScheme:
     encoded before CodeSpec persistence are RS(10,4))."""
     if not d:
         return DEFAULT_SCHEME
+    if d.get("family") == "clay":
+        return ClayScheme(int(d.get("data_shards", 10)),
+                          int(d.get("parity_shards", 4)),
+                          int(d["d"]) if "d" in d else None,
+                          int(d.get("unit_bytes", 1 << 20)),
+                          int(d.get("gamma", 2)))
     if d.get("family") == "hhxor":
         return HitchhikerScheme(int(d.get("data_shards", 10)),
                                 int(d.get("parity_shards", 4)),
@@ -238,24 +363,29 @@ class CodeSpecError(ValueError):
 
 class SubstripedCodeError(ValueError):
     """A path that decodes whole shards at one offset (one row a shard)
-    was handed a volume of a piggybacked code, whose sources are
-    half-rows at two offsets (``refuse_substriped``)."""
+    was handed a volume of a vector code, whose sources are pieces of a
+    unit at several offsets (``refuse_substriped``)."""
 
 
 def refuse_substriped(scheme: RSScheme, path: str) -> None:
     """Raise SubstripedCodeError, naming the code and ``path``, where
-    ``scheme`` is piggybacked: a path that would take its parity
-    b-halves for plain RS rows returns wrong bytes silently."""
+    ``scheme`` is a vector code (Hitchhiker-XOR, Clay): a path that
+    would take its coupled rows for plain RS rows returns wrong bytes
+    silently."""
     if scheme.substripes > 1:
         raise SubstripedCodeError(
             f"{code_spec_name(scheme)}: {path} decodes whole shards at "
-            "one offset and cannot take a piggybacked volume")
+            "one offset and cannot take a vector-coded volume")
 
 
 def code_spec_name(scheme: RSScheme) -> str:
     """The spec string of a scheme: ``rs-6-3``, ``lrc-10-2-2``,
-    ``hhxor-10-4``.  What ``parse_code_spec`` reads back, and the key of
-    per-geometry counters (``by_spec`` in the batch scheduler's stats)."""
+    ``hhxor-10-4``, ``clay-10-4-13``.  What ``parse_code_spec`` reads
+    back, and the key of per-geometry counters (``by_spec`` in the batch
+    scheduler's stats)."""
+    if isinstance(scheme, ClayScheme):
+        return (f"clay-{scheme.data_shards}-{scheme.parity_shards}-"
+                f"{scheme.helpers}")
     if isinstance(scheme, HitchhikerScheme):
         return f"hhxor-{scheme.data_shards}-{scheme.parity_shards}"
     if isinstance(scheme, LrcScheme):
@@ -270,10 +400,11 @@ def parse_code_spec(spec: str, default: RSScheme = DEFAULT_SCHEME
     ``rs`` -> ``default`` (the server's own), ``rs-<k>-<m>`` ->
     RSScheme(k, m), ``lrc`` -> LRC(10,2,2), ``lrc-<k>-<l>-<g>`` ->
     LrcScheme(k, l, g), ``hhxor`` -> Hitchhiker-XOR over RS(10,4),
-    ``hhxor-<k>-<m>`` -> HitchhikerScheme(k, m) (what ``code_spec_name``
-    writes).  Anything else — a coder registry name, a geometry no
-    volume can carry, groups that do not divide the data shards —
-    raises CodeSpecError."""
+    ``hhxor-<k>-<m>`` -> HitchhikerScheme(k, m), ``clay`` -> Clay(10,4,13),
+    ``clay-<k>-<m>[-<d>]`` -> ClayScheme(k, m, d), d = k + m - 1 when
+    not given (what ``code_spec_name`` writes).  Anything else — a coder
+    registry name, a geometry no volume can carry, groups that do not
+    divide the data shards — raises CodeSpecError."""
     spec = (spec or "").strip().lower()
     if spec in ("", "rs"):
         return default
@@ -281,8 +412,11 @@ def parse_code_spec(spec: str, default: RSScheme = DEFAULT_SCHEME
         return LrcScheme()
     if spec == "hhxor":
         return HitchhikerScheme()
+    if spec == "clay":
+        return ClayScheme()
     family, *sizes = spec.split("-")
-    if (family, len(sizes)) in (("rs", 2), ("lrc", 3), ("hhxor", 2)) \
+    if (family, len(sizes)) in (("rs", 2), ("lrc", 3), ("hhxor", 2),
+                                ("clay", 2), ("clay", 3)) \
             and all(p.isdigit() for p in sizes):
         sizes = [int(p) for p in sizes]
         if min(sizes) <= 0 or sizes[0] + sum(sizes[1:]) > MAX_VOLUME_SHARDS:
@@ -292,9 +426,10 @@ def parse_code_spec(spec: str, default: RSScheme = DEFAULT_SCHEME
                 "of parity")
         if family == "rs":
             return RSScheme(*sizes)
-        if family == "hhxor":
+        if family in ("hhxor", "clay"):
             try:
-                return HitchhikerScheme(*sizes)
+                return (HitchhikerScheme if family == "hhxor"
+                        else ClayScheme)(*sizes)
             except ValueError as e:
                 raise CodeSpecError(f"code {spec!r}: {e}") from None
         if sizes[0] % sizes[1]:
@@ -304,13 +439,16 @@ def parse_code_spec(spec: str, default: RSScheme = DEFAULT_SCHEME
         return LrcScheme(*sizes)
     raise CodeSpecError(
         f"unknown code {spec!r}: expected '', 'rs', 'rs-<k>-<m>', 'lrc', "
-        "'lrc-<k>-<l>-<g>', 'hhxor' or 'hhxor-<k>-<m>'")
+        "'lrc-<k>-<l>-<g>', 'hhxor', 'hhxor-<k>-<m>', 'clay' or "
+        "'clay-<k>-<m>[-<d>]'")
 
 
 def host_coder(scheme: RSScheme, threaded: bool) -> "ErasureCoder":
     """The host coder of a scheme's code family (``cpu`` / ``lrc`` /
-    ``hhxor``), or its multi-threaded sibling (``cpu-mt`` / ...)."""
-    family = "hhxor" if isinstance(scheme, HitchhikerScheme) \
+    ``hhxor`` / ``clay``), or its multi-threaded sibling (``cpu-mt`` /
+    ...)."""
+    family = "clay" if isinstance(scheme, ClayScheme) \
+        else "hhxor" if isinstance(scheme, HitchhikerScheme) \
         else "lrc" if isinstance(scheme, LrcScheme) else "cpu"
     return make_coder(family + ("-mt" if threaded else ""), scheme)
 
@@ -427,7 +565,8 @@ _REGISTRY: dict[str, type] = {}
 # imported; a DEVICE coder dispatches to what JAX finds
 _CODER_MODULES = {"cpu": "rs_cpu", "cpu-mt": "rs_cpu", "lrc": "lrc",
                  "lrc-mt": "lrc", "hhxor": "hitchhiker",
-                 "hhxor-mt": "hitchhiker", "jax": "rs_jax", "mesh": "rs_mesh"}
+                 "hhxor-mt": "hitchhiker", "clay": "clay", "clay-mt": "clay",
+                 "jax": "rs_jax", "mesh": "rs_mesh"}
 DEVICE_CODERS = ("jax", "mesh")
 
 
@@ -439,11 +578,12 @@ def register_coder(name: str):
 
 
 def make_coder(name: str = "cpu", scheme: RSScheme = DEFAULT_SCHEME) -> ErasureCoder:
-    """Factory over the eight registered names: 'cpu' (default, like the
+    """Factory over the ten registered names: 'cpu' (default, like the
     reference) and 'cpu-mt' (ops/rs_cpu.py), 'jax' (ops/rs_jax.py), 'mesh'
     (batched multi-device dispatch, ops/rs_mesh.py), 'lrc' and 'lrc-mt'
     (locally repairable code, ops/lrc.py), 'hhxor' and 'hhxor-mt'
-    (piggybacked code, ops/hitchhiker.py)."""
+    (piggybacked code, ops/hitchhiker.py), 'clay' and 'clay-mt'
+    (regenerating code, ops/clay.py)."""
     if name not in _CODER_MODULES:
         raise KeyError(
             f"unknown coder {name!r}; known: {sorted(_CODER_MODULES)}")
@@ -452,6 +592,8 @@ def make_coder(name: str = "cpu", scheme: RSScheme = DEFAULT_SCHEME) -> ErasureC
     if _CODER_MODULES[name] == "hitchhiker" \
             and not isinstance(scheme, HitchhikerScheme):
         scheme = HitchhikerScheme()
+    if _CODER_MODULES[name] == "clay" and not isinstance(scheme, ClayScheme):
+        scheme = ClayScheme()
     if name in DEVICE_CODERS:
         # refuse (with the reason) where JAX found only the CPU and the
         # CPU was not asked for by name, and place the compile cache

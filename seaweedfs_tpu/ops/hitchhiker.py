@@ -228,6 +228,23 @@ def rebuild_units(coder, scheme: HitchhikerScheme, plan: tuple,
     return from_halves(rec, scheme.unit)
 
 
+def rebuild_rows(coder, present: Sequence[int], missing: Sequence[int],
+                 batch_size: int):
+    """A whole-shard rebuild (storage/erasure_coding/encoder.
+    rebuild_rows): whole units of the shards the half-row plan touches
+    (``unit_plan``), in batches of whole units."""
+    plan = unit_plan(coder.scheme, present, missing)
+    unit = coder.scheme.unit
+
+    def units(rows, out=None):
+        rec = rebuild_units(coder, coder.scheme, plan, rows)
+        if out is None:
+            return rec
+        out[:] = rec
+        return out
+    return plan[0], max(unit, batch_size - batch_size % unit), units
+
+
 def rebuild_from(coder, shards: Sequence[Optional[bytes]],
                  missing: Sequence[int]) -> list[Optional[bytes]]:
     """``shards`` (whole units each, None where missing) with the

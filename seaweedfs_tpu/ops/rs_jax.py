@@ -29,7 +29,8 @@ against the stacked operand on the chip is an open head-to-head (ROADMAP
 S7); until it is measured both layouts stay.
 
 This module is the home of the kernel primitives: `_xtime` and the two
-static-matrix forms for encode, and `_gf_mul_dynamic`, the traced-
+static-matrix forms for encode, `_gf_mul_static` (a static constant:
+a Clay code's pair transforms), and `_gf_mul_dynamic`, the traced-
 coefficient step of the rebuild program (`jit_ec_apply_*`). ops/gf256.py
 is the semantics ground truth they are held to.
 """
@@ -71,6 +72,19 @@ def _gf_mul_dynamic(c: jnp.ndarray, words: jnp.ndarray) -> jnp.ndarray:
         if b < 7:
             d = _xtime(d)
     return acc
+
+
+def _gf_mul_static(c: int, words: jnp.ndarray) -> jnp.ndarray:
+    """c * words over GF(256) where c is a STATIC byte: Horner over its
+    set bits, one ``_xtime`` a bit below the highest (none for c = 2^0,
+    one for gamma = 2)."""
+    acc = None
+    for b in range(7, -1, -1):
+        if acc is not None:
+            acc = _xtime(acc)
+        if (c >> b) & 1:
+            acc = words if acc is None else acc ^ words
+    return acc if acc is not None else jnp.zeros_like(words)
 
 
 def _apply_matrix_words(words: jnp.ndarray, mat: tuple[tuple[int, ...], ...]) -> jnp.ndarray:

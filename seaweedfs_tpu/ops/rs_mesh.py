@@ -27,6 +27,14 @@ carry the scheme in their names (``jit_ec_encode_rs_6_3``,
     k: k + |group| for a whole lost block, 2k for anything (``apply_widths``);
     a dispatch rides the narrowest that holds what it reads.
 
+A regenerating code (Clay, ops/clay.py) has no matrix over its rows:
+its encode is a STRUCTURED program (the pair transform of the data
+columns, the static RS over the planes, the pair transform of the
+parity column: ``jit_ec_encode_clay_*``), and its repair a second
+structured program under the apply name (``jit_ec_apply_clay_*``) that
+reads d helpers' runs of a lost unit, each lane's lost shard named by
+traced slots and plane-solve coefficients (``batch_repair_fn``).
+
 A MeshCoder is of ONE scheme; the batch scheduler keeps one per scheme
 it has seen, all on the same device mesh.
 
@@ -48,12 +56,13 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
-from seaweedfs_tpu.models.coder import (DEFAULT_SCHEME, Encoded,
+from seaweedfs_tpu.models.coder import (DEFAULT_SCHEME, ClayScheme, Encoded,
                                         ErasureCoder, RSScheme,
                                         code_spec_name, host_coder,
-                                        register_coder)
+                                        register_coder, vector_ops)
+from seaweedfs_tpu.ops import clay, gf256
 from seaweedfs_tpu.ops.rs_jax import (_apply_matrix_words, _gf_mul_dynamic,
-                                      _mat_to_tuple)
+                                      _gf_mul_static, _mat_to_tuple)
 from seaweedfs_tpu.parallel import mesh as mesh_mod
 from seaweedfs_tpu.utils import tracing
 
@@ -82,6 +91,9 @@ def apply_widths(scheme: RSScheme) -> tuple[int, ...]:
     family, k + |group| (a whole lost block of a group's shard: the
     piggyback plan) and 2k (every half-row a plan can read)."""
     k = scheme.data_shards
+    if isinstance(scheme, ClayScheme):
+        # its repair is a program of its own (batch_repair_fn)
+        return (k,)
     if scheme.substripes > 1:
         return tuple(sorted({k, scheme.max_sources}
                             | {k + len(g) for g in scheme.groups}))
@@ -95,7 +107,13 @@ def batch_encode_fn(scheme: RSScheme, mesh: Mesh):
     -> (B, m, nw) parity with matching sharding.  The static parity
     matrix of the scheme's own family (its host coder's), no
     collectives; a piggybacked family adds each group's a-halves into
-    the b-halves of its parity (nw whole units)."""
+    the b-halves of its parity (nw whole units); a regenerating one has
+    a structured program of its own (``_clay_encode``)."""
+    s3 = mesh_mod.batch_spec(mesh)
+    if isinstance(scheme, ClayScheme):
+        return jax.jit(jax.vmap(_named(_clay_encode(scheme), "ec_encode",
+                                       scheme)),
+                       in_shardings=(s3,), out_shardings=s3)
     mat = _mat_to_tuple(host_coder(scheme, threaded=False)._parity)
     groups = scheme.groups if scheme.substripes > 1 else ()
 
@@ -114,9 +132,79 @@ def batch_encode_fn(scheme: RSScheme, mesh: Mesh):
             rows.append(p[1 + j].at[:, 1].set(p[1 + j, :, 1] ^ pig))
         return jnp.stack(rows).reshape(parity.shape)
 
-    s3 = mesh_mod.batch_spec(mesh)
     return jax.jit(jax.vmap(_named(ec_encode, "ec_encode", scheme)),
                    in_shardings=(s3,), out_shardings=s3)
+
+
+def _clay_encode(scheme: ClayScheme):
+    """One lane's Clay encode, (k, nw) words of whole units -> (m, nw):
+    the virtual nodes' zeros beside the data, the pair transform's
+    inverse on each of the t - 1 data columns (transposes and static
+    constants), RS(k + nu, m) over the planes by the static-matrix
+    primitive, the pair transform on the parity column.  No matrix over
+    sub-chunks."""
+    k, m, q, t = (scheme.data_shards, scheme.parity_shards, scheme.q,
+                  scheme.t)
+    kk = scheme.n_nodes - m
+    mat = _mat_to_tuple(gf256.parity_matrix(kk, m))
+    sub_words = scheme.sub_bytes // 4
+
+    def ec_encode(words):
+        shape = (words.shape[1] * 4 // scheme.unit,) + (q,) * t \
+            + (sub_words,)
+        c = words.reshape((k,) + shape)
+        c = jnp.concatenate([c, jnp.zeros((kk - k,) + shape, c.dtype)])
+        c = c.reshape((t - 1, q) + shape)
+        u = jnp.stack([clay.uncouple(scheme, c[y], 2 + y, jnp,
+                                     _gf_mul_static) for y in range(t - 1)])
+        up = _apply_matrix_words(u.reshape(kk, -1), mat)
+        return clay.couple(scheme, up.reshape((q,) + shape), 1 + t, jnp,
+                           _gf_mul_static).reshape(m, -1)
+    return ec_encode
+
+
+@functools.lru_cache(maxsize=None)
+def batch_repair_fn(scheme: ClayScheme, mesh: Mesh):
+    """jit over the mesh: a regenerating code's single-node repair —
+    (B, d, nw) words of the helpers' runs (canonical rows,
+    ops/clay.repair_plan) x (B, q t) int32 slots x (B, q, k + nu)
+    plane-solve coefficients -> (B, q, nw), column y0's planes by x'
+    (ops/clay.place puts them in plane order).  Per lane: the rows into
+    their slots (a zero row for the lost and virtual nodes), the pair
+    transform's inverse on the t - 1 other columns, the RS solve of
+    each plane for column y0 (traced coefficients, as the apply
+    program's), and the lost planes from it with static constants.
+    Named as the apply program (``jit_ec_apply_clay_*``); no matrix over
+    sub-chunks."""
+    q, t = scheme.q, scheme.t
+    kk = scheme.n_nodes - q
+    sub_words = scheme.sub_bytes // 4
+    g, gi, _ci = clay.constants(scheme)
+
+    def ec_apply(words, slots, coeff):
+        nw = words.shape[1]
+        shape = (nw // (scheme.beta * sub_words),) + (q,) * (t - 1) \
+            + (sub_words,)
+        full = jnp.concatenate([words, jnp.zeros((1, nw), words.dtype)])
+        full = full[slots].reshape((t, q) + shape)
+        known = jnp.stack([clay.uncouple(scheme, full[j], 1 + j, jnp,
+                                         _gf_mul_static)
+                           for j in range(1, t)]).reshape(kk, -1)
+        ux = []
+        for i in range(q):
+            acc = jnp.zeros_like(known[0])
+            for j in range(kk):
+                acc = acc ^ _gf_mul_dynamic(coeff[i, j], known[j])
+            ux.append(acc.reshape(shape))
+        out = [ux[0]] + [_gf_mul_static(g, ux[x])
+                         ^ _gf_mul_static(gi, full[0, x] ^ ux[x])
+                         for x in range(1, q)]
+        return jnp.stack(out).reshape(q, nw)
+
+    s3 = mesh_mod.batch_spec(mesh)
+    s2 = mesh_mod.batch_spec(mesh, 2)
+    return jax.jit(jax.vmap(_named(ec_apply, "ec_apply", scheme)),
+                   in_shardings=(s3, s2, s3), out_shardings=s3)
 
 
 def batch_apply_fn(scheme: RSScheme, mesh: Mesh,
@@ -204,8 +292,8 @@ class MeshCoder(ErasureCoder):
         if hasattr(self._host, "plan_rebuild"):
             self.plan_rebuild = self._host.plan_rebuild
         self.apply_widths = apply_widths(scheme)
-        # (B, columns) of the k-wide apply whose half-column siblings
-        # are compiled (_prepare_halves)
+        # (B, columns) of the k-wide apply whose repair programs are
+        # compiled beside it (_prepare_repairs)
         self._prepared: set[tuple] = set()
         # distinct (kind, padded operand shape) THIS coder dispatched, all
         # of its one scheme; the batcher's stats() reports the count over
@@ -351,6 +439,8 @@ class MeshCoder(ErasureCoder):
     def _rebuild_begin(self, srcdata: np.ndarray,
                        mats: Sequence[np.ndarray]) -> Dispatched:
         B, w, n = srcdata.shape
+        if isinstance(self.scheme, ClayScheme) and w == self.scheme.helpers:
+            return self._repair_begin(srcdata, mats)
         assert w <= self.apply_widths[-1] and n % 4 == 0
         assert len(mats) == B
         m = self.scheme.parity_shards
@@ -368,7 +458,7 @@ class MeshCoder(ErasureCoder):
             coeff = self._pad_batch(coeff)
             fn = batch_apply_fn(self.scheme, self.mesh, srcdata.shape[1])
             if srcdata.shape[1] == self.scheme.data_shards:
-                self._prepare_halves(words.shape[0], n)
+                self._prepare_repairs(words.shape[0], n)
         self._staged("pad", st)
         n_rows = [np.asarray(mt).shape[0] for mt in mats]
 
@@ -378,25 +468,59 @@ class MeshCoder(ErasureCoder):
                     for i, r in enumerate(n_rows)]
         return self._launch("apply", fn, (words, coeff), unpack)
 
-    def _prepare_halves(self, pb: int, n: int) -> None:
-        """A piggybacked family's repair of n columns of shard bytes (a
-        whole block) is a job of n / 2 columns of half-rows at its
-        piggyback widths: the first k-wide apply of whole units at a
-        padded batch ``pb`` (a warm-up's: a served read's k-wide jobs
-        are of one half) compiles those programs too, on zeros, at
-        every batch up to the same columns (2 pb half-rows)."""
-        unit = getattr(self.scheme, "unit", 0)
+    def _repair_begin(self, srcdata: np.ndarray,
+                      mats: Sequence[np.ndarray]) -> Dispatched:
+        """A regenerating family's repair jobs (ops/clay.repair_mat),
+        one program whatever shard each lane lost: its slots and plane
+        solve ride as operands, and the output is put in plane order
+        when unpacked."""
+        B, w, _n = srcdata.shape
+        plans = [clay.repair_plan(self.scheme, int(np.asarray(mt)[0, 0]))
+                 for mt in mats]
+        with tracing.stage("ec.mesh.pad") as st:
+            self._note(st, w)
+            words = self._pad_batch(
+                np.ascontiguousarray(srcdata).view(np.uint32))
+            slots = self._pad_batch(np.stack([p.slots for p in plans]))
+            coeff = self._pad_batch(
+                np.stack([p.coeff for p in plans]).astype(np.uint32))
+            fn = batch_repair_fn(self.scheme, self.mesh)
+        self._staged("pad", st)
+
+        def unpack(out: np.ndarray) -> list[np.ndarray]:   # (pb, q, nw)
+            out8 = np.asarray(out[:B]).view(np.uint8)
+            return [clay.place(self.scheme, p, out8[i])
+                    for i, p in enumerate(plans)]
+        return self._launch("apply", fn, (words, slots, coeff), unpack)
+
+    def _prepare_repairs(self, pb: int, n: int) -> None:
+        """A vector family's repair of n columns of shard bytes (whole
+        units) is a job of n / unit x ``repair_columns`` columns at its
+        widths past k (half-rows at the piggyback widths, or a
+        regenerating code's d helpers' runs): the first k-wide apply of
+        whole units at a padded batch ``pb`` (a warm-up's: a served
+        read's k-wide jobs are of one piece) compiles those programs
+        too, on zeros, at every batch up to the same columns."""
+        scheme = self.scheme
+        unit = getattr(scheme, "unit", 0)
         if not unit or n % unit or (pb, n) in self._prepared:
             return
         self._prepared.add((pb, n))
-        k, m = self.scheme.data_shards, self.scheme.parity_shards
+        k, m = scheme.data_shards, scheme.parity_shards
+        nw = n // unit * scheme.repair_columns // 4
         b = self.n_devices
-        while b <= 2 * pb:
+        while b * nw <= pb * n // 4:
+            if isinstance(scheme, ClayScheme):
+                jax.block_until_ready(batch_repair_fn(scheme, self.mesh)(
+                    np.zeros((b, scheme.helpers, nw), dtype=np.uint32),
+                    np.zeros((b, scheme.n_nodes), dtype=np.int32),
+                    np.zeros((b, scheme.q, scheme.n_nodes - scheme.q),
+                             dtype=np.uint32)))
             for width in self.apply_widths:
-                if k < width < self.scheme.max_sources:
-                    fn = batch_apply_fn(self.scheme, self.mesh, width)
+                if k < width < scheme.max_sources:
+                    fn = batch_apply_fn(scheme, self.mesh, width)
                     jax.block_until_ready(fn(
-                        np.zeros((b, width, n // 8), dtype=np.uint32),
+                        np.zeros((b, width, nw), dtype=np.uint32),
                         np.zeros((b, m, width), dtype=np.uint32)))
             b *= 2
 
@@ -447,11 +571,11 @@ class MeshCoder(ErasureCoder):
         dispatch: from the sources the family's plan names where it
         plans (LRC: the first k present can be rank-deficient), else
         from the first k present shards, whose rebuild matrix expresses
-        data AND parity losses directly; a piggybacked family's from
-        the half-rows of whole units its plan reads."""
+        data AND parity losses directly; a vector family's as its
+        plans say (ops/hitchhiker.py, ops/clay.py)."""
         if self.scheme.substripes > 1:
-            from seaweedfs_tpu.ops import hitchhiker
-            return hitchhiker.rebuild_from(self, shards, missing)
+            return vector_ops(self.scheme).rebuild_from(self, shards,
+                                                        missing)
         k, total = self.scheme.data_shards, self.scheme.total_shards
         present = [i for i in range(total) if shards[i] is not None]
         out = [bytes(s) if s is not None else None for s in shards]

@@ -70,7 +70,9 @@ Scheduling contract:
   - a rebuild job carries as many ROWS as its matrix reads (k for plain
     RS; a family's local repair the k / l survivors of a group; a
     piggybacked family's whole-block repair k + |group| HALF-rows, half
-    a block wide, on a rung of its own ladder: ``column_ladder``), and a
+    a block wide, on a rung of its own ladder: ``column_ladder``; a
+    regenerating family's (Clay) d helpers' runs, a q-th of a block
+    wide, with ``ops/clay.repair_mat`` for its matrix), and a
     dispatch groups jobs of one row count: a job of 6 rows and one of 12
     never share one (``by_spec[spec]["rows"]`` splits the jobs by it).
 
@@ -103,7 +105,7 @@ import numpy as np
 
 from seaweedfs_tpu.models.coder import (DEFAULT_SCHEME, ErasureCoder,
                                         RSScheme, code_spec_name,
-                                        host_coder)
+                                        host_coder, vector_ops)
 from seaweedfs_tpu.qos import CLASSES, current_class
 from seaweedfs_tpu.utils import clockctl, glog, profiler, tracing
 from seaweedfs_tpu.utils.metrics import RED_BUCKETS, Histogram
@@ -159,10 +161,12 @@ _CLASS_RANK = {c: i for i, c in enumerate(CLASSES)}
 
 def column_ladder(scheme: RSScheme) -> tuple[int, ...]:
     """The rungs a scheme's jobs pad up to: COLUMN_LADDER, and for a
-    piggybacked family (Hitchhiker-XOR) also half a unit, the width of a
-    whole block's repair job (its half-rows)."""
+    vector family also the width of a whole block's repair job (its
+    ``repair_columns``: half a unit of half-rows under Hitchhiker-XOR,
+    beta sub-chunks of each helper under Clay, 256 KiB of a 1 MiB block
+    for Clay(10,4,13), a rung the ladder has)."""
     if scheme.substripes > 1:
-        return tuple(sorted(set(COLUMN_LADDER) | {scheme.unit // 2}))
+        return tuple(sorted(set(COLUMN_LADDER) | {scheme.repair_columns}))
     return COLUMN_LADDER
 
 
@@ -1091,11 +1095,11 @@ class BatchCoder(ErasureCoder):
         """``shards`` with the ``missing`` ones filled in by one job: from
         the sources the family's plan names where it plans (LRC: the
         first k of sorted(present) can be rank-deficient for it), else
-        from the first k present shards; a piggybacked family's from the
-        half-rows its plan reads."""
+        from the first k present shards; a vector family's as its plans
+        say (ops/hitchhiker.py, ops/clay.py)."""
         if self.scheme.substripes > 1:
-            from seaweedfs_tpu.ops import hitchhiker
-            return hitchhiker.rebuild_from(self, shards, missing)
+            return vector_ops(self.scheme).rebuild_from(self, shards,
+                                                        missing)
         k, total = self.scheme.data_shards, self.scheme.total_shards
         present = [i for i in range(total) if shards[i] is not None]
         out = [bytes(s) if s is not None else None for s in shards]

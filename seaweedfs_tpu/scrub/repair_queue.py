@@ -440,10 +440,10 @@ class RepairQueue:
         # 4a. network-frugal path: the rebuilder pulls pre-reduced
         # partial columns through a reduction chain instead of staging
         # `need` full shards (ladder rung 3 falls through to 4b); not
-        # for a volume whose holders said its code is piggybacked
+        # for a volume whose holders said its code is a vector code
         code = topo.ec_volume_code(vid)
         if code is not None and parse_code_spec(code).substripes > 1:
-            glog.info("ec repair vol %d: %s is piggybacked, the partial-"
+            glog.info("ec repair vol %d: %s is a vector code, the partial-"
                       "column chain cannot take it: copy + rebuild", vid,
                       code)
         elif self.partial_repair:
@@ -515,9 +515,10 @@ class RepairQueue:
         shard repairs from its ~k/l surviving group members instead of
         fanning the reduction chain across k holders. Returns
         (source_sids | None, strategy); None = use every survivor.  A
-        piggybacked volume (Hitchhiker-XOR: sources are half-rows at two
-        offsets) is refused by name: SubstripedCodeError, and the caller
-        falls back to copy + rebuild, which knows the family."""
+        vector-coded volume (Hitchhiker-XOR: sources are half-rows at two
+        offsets; Clay: runs of sub-chunks) is refused by name:
+        SubstripedCodeError, and the caller falls back to copy + rebuild,
+        which knows the family."""
         from seaweedfs_tpu.models.coder import (SubstripedCodeError,
                                                 make_coder,
                                                 refuse_substriped,

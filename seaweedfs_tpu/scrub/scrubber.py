@@ -247,8 +247,9 @@ class Scrubber:
         remote_reader = getattr(self.store, "remote_partial_reader", None)
         unit = getattr(coder.scheme, "unit", 0)
         if unit and (missing_data or self.ec_chunk_bytes % unit):
-            # a piggybacked code checks whole units, all data local: its
-            # parity b-halves are not a partial chain's plain RS rows
+            # a vector code (piggybacked, regenerating) checks whole
+            # units, all data local: its coupled parity rows are not a
+            # partial chain's plain RS rows
             rep["skipped"] = (f"{coder.scheme!r}: parity of whole "
                               f"{unit}-byte units, every data shard local")
             return rep

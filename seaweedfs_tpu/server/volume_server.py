@@ -321,6 +321,12 @@ class VolumeServer:
             "volumeServer", "ec_batch_overlapped",
             "EC mesh dispatches launched while another was still on "
             "the device")
+        # the store's rebuilt intervals by the repair strategy it took
+        # (shard_stat's recover_stats), refreshed at scrape
+        self._m_ec_recover = self.metrics.gauge(
+            "volumeServer", "ec_recover_intervals",
+            "EC intervals rebuilt on degraded reads, by repair strategy",
+            ("strategy",))
         # hot-needle record cache + selector-core connection counters,
         # refreshed at scrape from their owners' stats() snapshots
         self._m_cache = self.metrics.gauge(
@@ -1041,6 +1047,8 @@ class VolumeServer:
                 self._m_disk_free.set(d, value=st.f_bavail * st.f_frsize)
             except OSError:
                 pass
+        for strategy, n in dict(store.ec_recover_stats).items():
+            self._m_ec_recover.set(strategy, value=n)
         cache = store.needle_cache
         if cache is not None:
             cs = cache.stats()

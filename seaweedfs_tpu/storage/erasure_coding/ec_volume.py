@@ -239,6 +239,21 @@ def read_shards_into(shards: list[Optional[EcVolumeShard]], offset: int,
             for r, s in enumerate(shards)]
 
 
+def read_runs_into(shard: EcVolumeShard, offset: int, step: int,
+                   runs: int, row: np.ndarray, run: int) -> int:
+    """row[j * run:(j + 1) * run] = ``run`` bytes of ``shard`` from
+    offset + j * step, for j < ``runs`` (a regenerating code's repair
+    planes of one helper: q^y runs a unit); returns the bytes read,
+    short only at the end of the shard.  With the native library ONE
+    foreign call (``pread_rows``' offset step), else a preadv a run."""
+    view = row[:runs * run].reshape(runs, run)
+    if rs_native.available():
+        return sum(rs_native.pread_rows([shard._f.fileno()] * runs, offset,
+                                        view, run, offset_step=step))
+    return sum(shard.read_into(offset + j * step, view[j])
+               for j in range(runs))
+
+
 class EcVolume:
     """A mounted EC volume: local shards + .ecx index + .ecj journal
     (reference ec_volume.go:25-76)."""

@@ -18,7 +18,7 @@ from typing import Optional
 import numpy as np
 
 from seaweedfs_tpu.models.coder import (ErasureCoder, RSScheme, make_coder,
-                                        refuse_substriped)
+                                        refuse_substriped, vector_ops)
 from seaweedfs_tpu.storage import types as t
 from seaweedfs_tpu.storage.erasure_coding import layout
 from seaweedfs_tpu.storage.needle_map import MemDb
@@ -55,7 +55,7 @@ def plan_rebuild_sources(coder: ErasureCoder, present, missing):
     choose the cheapest source set — a single-group loss reads the ~5
     surviving group members; rebuild_matrix coders (RS) read the first
     data_shards survivors after dropping all-zero matrix columns.  A
-    piggybacked family has no such plan: it is refused by name."""
+    vector family has no such plan: it is refused by name."""
     refuse_substriped(coder.scheme, "a whole-shard rebuild plan")
     if hasattr(coder, "plan_rebuild"):
         return coder.plan_rebuild(present, missing)
@@ -73,21 +73,13 @@ def rebuild_rows(coder: ErasureCoder, present, missing, batch_size: int):
     """A whole-shard rebuild's reads and work: (the shards it reads, its
     batch size, ``fn(rows, out=None)`` -> the missing shards' rows of a
     batch of theirs); (None, batch_size, None) for a coder that only
-    speaks the bytes API.  A piggybacked family reads whole units of the
-    shards its half-row plan touches (ops/hitchhiker.unit_plan), in
-    batches of whole units; every other code plan_rebuild_sources's."""
+    speaks the bytes API.  A vector family reads whole units, in batches
+    of whole units, as its module plans (ops/hitchhiker.py: the shards
+    its half-row plan touches; ops/clay.py: every survivor, for the
+    layered decode); every other code plan_rebuild_sources's."""
     if coder.scheme.substripes > 1:
-        from seaweedfs_tpu.ops import hitchhiker
-        plan = hitchhiker.unit_plan(coder.scheme, present, missing)
-        unit = coder.scheme.unit
-
-        def units(rows, out=None):
-            rec = hitchhiker.rebuild_units(coder, coder.scheme, plan, rows)
-            if out is None:
-                return rec
-            out[:] = rec
-            return out
-        return plan[0], max(unit, batch_size - batch_size % unit), units
+        return vector_ops(coder.scheme).rebuild_rows(coder, present,
+                                                     missing, batch_size)
     src, rmat = plan_rebuild_sources(coder, present, missing)
     if src is None:
         return None, batch_size, None

@@ -16,13 +16,14 @@ from typing import Callable, Optional
 import numpy as np
 
 from seaweedfs_tpu.models.coder import (DEFAULT_SCHEME, MAX_VOLUME_SHARDS,
-                                        ErasureCoder, code_spec_name,
-                                        make_coder, parse_code_spec)
+                                        ClayScheme, ErasureCoder,
+                                        code_spec_name, make_coder,
+                                        parse_code_spec)
 from seaweedfs_tpu.storage import types as t
 from seaweedfs_tpu.storage.disk_location import DiskLocation
 from seaweedfs_tpu.storage.erasure_coding import layout
 from seaweedfs_tpu.storage.erasure_coding.ec_volume import (
-    EcVolume, read_shards_into)
+    EcVolume, read_runs_into, read_shards_into)
 from seaweedfs_tpu.storage import needle
 from seaweedfs_tpu.storage.needle import Needle
 from seaweedfs_tpu.storage.super_block import ReplicaPlacement, TTL
@@ -104,8 +105,10 @@ class Store:
         # degraded-read repair-strategy tallies (exposed via shard_stat):
         # "local" = planned group-local recovery, "global" = planned
         # full-width recovery, "generic" = unplanned collect-k fallback;
-        # and, once the store serves a piggybacked volume (coder_for_
-        # scheme), "piggyback" / "substripe" (_recover_substriped).
+        # and, once the store serves a vector-coded volume (coder_for_
+        # scheme), its family's strategies: "piggyback" / "substripe"
+        # (Hitchhiker-XOR), "repair_planes" / "planes_partial" /
+        # "layered" (Clay) (_recover_substriped).
         # Intervals each: the values sum to the intervals rebuilt
         self.ec_recover_stats = {"local": 0, "global": 0, "generic": 0}
         # what the EC read path read and waited for (shard_stat's
@@ -433,9 +436,8 @@ class Store:
         c = self._coder_cache.get(scheme)
         if c is None:
             c = self._coder_cache[scheme] = self.coder.for_scheme(scheme)
-            if scheme.substripes > 1:
-                for key in ("piggyback", "substripe"):
-                    self.ec_recover_stats.setdefault(key, 0)
+            for key in getattr(scheme, "strategies", ()):
+                self.ec_recover_stats.setdefault(key, 0)
         return c
 
     def generate_ec_shards(self, vid: int, pipelined: bool = True,
@@ -1005,8 +1007,9 @@ class Store:
 
     def _recover_substriped(self, ev: EcVolume, size: int, shard_off: int,
                             coder: ErasureCoder, wanted_shard: int) -> bytes:
-        """A piggybacked family's repair of ``size`` bytes of a shard from
-        ``shard_off`` (ops/hitchhiker.py).  The range is cut at the halves
+        """A vector family's repair of ``size`` bytes of a shard from
+        ``shard_off``: a regenerating code's by ``_recover_planes``; a
+        piggybacked one's (ops/hitchhiker.py) here.  The range is cut at the halves
         of its units (a unit: ``scheme.unit`` bytes, a small block).  Where
         it touches both halves of a unit, ONE piggyback job rebuilds both
         (k + |group| half-ranges in, 2 out) if that reads fewer bytes
@@ -1016,6 +1019,9 @@ class Store:
         ``substripe``.  A pattern no plan decodes is a NotFoundError:
         this family never falls to the generic collect-k ladder, whose
         sources are whole shards at one offset."""
+        if isinstance(coder.scheme, ClayScheme):
+            return self._recover_planes(ev, size, shard_off, coder,
+                                        wanted_shard)
         unit = coder.scheme.unit
         half = unit // 2
         end = shard_off + size
@@ -1085,48 +1091,153 @@ class Store:
                 f"ec volume {ev.volume_id} ({coder.scheme!r}): the "
                 "reachable shards cannot decode the lost range")
         src, mat = plan
-        size = x_hi - x_lo
         half = coder.scheme.unit // 2
         with tracing.stage("store.ec.plan") as st:
             st.annotate("strategy", strategy)
             st.annotate("sources", [list(h) for h in src])
+        return self._run_job(ev, coder, [
+            (sid, base + sub * half + x_lo, 0, 1) for sid, sub in src],
+            x_hi - x_lo, mat, "half_rows")
+
+    def _run_job(self, ev: EcVolume, coder: ErasureCoder, sources: list,
+                 run: int, mat: np.ndarray, note: str) -> np.ndarray:
+        """One job of a vector family's repair: row r of the coder's job
+        buffer is shard sid's ``runs`` runs of ``run`` bytes from
+        ``off``, one every ``step`` (``sources[r]`` = (sid, off, step,
+        runs)), gathered by as few read calls as the mounted shards'
+        offsets allow (one call for the rows of one offset and a single
+        run; one stepped call a row otherwise), peers for the rest; then
+        ONE reconstruct_rows.  Returns its rows."""
+        size = run * sources[0][3]
         with tracing.stage("store.ec.survivors") as st:
-            st.annotate("half_rows", len(src))
+            st.annotate(note, len(sources))
+            st.annotate("runs", sum(s[3] for s in sources))
+            st.annotate("bytes", size * len(sources))
             job_rows = getattr(coder, "job_rows", None)
-            rows = job_rows(size, len(src)) if job_rows is not None \
-                else np.empty((len(src), size), dtype=np.uint8)
-            remote = [(r, sid, base + sub * half + x_lo)
-                      for r, (sid, sub) in enumerate(src)
-                      if sid not in ev.shards]
+            rows = job_rows(size, len(sources)) if job_rows is not None \
+                else np.empty((len(sources), size), dtype=np.uint8)
+            remote = [(r, sid, off + j * step, j * run)
+                      for r, (sid, off, step, runs) in enumerate(sources)
+                      if sid not in ev.shards for j in range(runs)]
             if remote:
-                self._fetch_half_rows(ev, remote, size, rows)
-            for sub in (0, 1):
-                mounted = [ev.shards.get(sid) if h == sub else None
-                           for sid, h in src]
-                if not any(mounted):
-                    continue
-                off = base + sub * half + x_lo
-                for (sid, _h), shard, n in zip(src, mounted,
-                                               read_shards_into(
-                                                   mounted, off, rows, size)):
+                self._fetch_half_rows(ev, remote, run, rows)
+            for key in sorted({s[1:] for s in sources
+                               if s[0] in ev.shards}):
+                off, step, runs = key
+                mounted = [ev.shards.get(sid) if (o, sp, n) == key else None
+                           for sid, o, sp, n in sources]
+                if runs == 1:
+                    got = read_shards_into(mounted, off, rows, run)
+                else:
+                    got = [0 if shard is None else read_runs_into(
+                        shard, off, step, runs, rows[r], run)
+                        for r, shard in enumerate(mounted)]
+                for (sid, *_r), shard, n in zip(sources, mounted, got):
                     if shard is not None and n != size:
                         raise NotFoundError(
                             f"ec volume {ev.volume_id}: shard {sid} ends "
-                            f"inside [{off}, {off + size})")
-            self._count_survivors(len(src), size)
+                            f"inside the runs from {off}")
+            self._count_survivors(len(sources), size)
             self.ec_read_stats["survivor_gathers"] += 1
         return coder.reconstruct_rows(rows, mat)
 
+    def _recover_planes(self, ev: EcVolume, size: int, shard_off: int,
+                        coder: ErasureCoder, wanted_shard: int) -> bytes:
+        """A regenerating family's repair of ``size`` bytes of a shard
+        from ``shard_off`` (ops/clay.py), unit by unit (a unit:
+        ``scheme.unit`` bytes, a small block).  A unit whose d helpers
+        can all be read is rebuilt WHOLE by one repair job: each helper's
+        beta sub-chunks of its repair planes (q^y runs a helper, y the
+        lost node's column), d beta in, the unit out, a piece of it
+        sliced off (the piece plan reads what the whole unit does).  The
+        interval counts once: ``repair_planes`` where every unit was
+        whole, ``planes_partial`` where a piece was asked for, and
+        ``layered`` where a helper could not be read and the units were
+        decoded on the host from every reachable shard's whole units."""
+        from seaweedfs_tpu.ops import clay
+        scheme = coder.scheme
+        unit = scheme.unit
+        end = shard_off + size
+        out = np.empty(size, dtype=np.uint8)
+        plan = clay.repair_plan(scheme, wanted_shard)
+        reach = set(ev.shards)
+        if any(h not in reach for h in plan.helpers):
+            reach = self._reachable(ev)
+        strategy = "repair_planes" if all(
+            h in reach for h in plan.helpers) else "layered"
+        for base in range(shard_off - shard_off % unit, end, unit):
+            lo, hi = max(shard_off, base) - base, min(end, base + unit) - base
+            if strategy == "layered":
+                got = self._decode_unit(ev, coder, base, wanted_shard)
+            else:
+                if hi - lo < unit:
+                    strategy = "planes_partial"
+                with tracing.stage("store.ec.plan") as st:
+                    st.annotate("strategy", strategy)
+                    st.annotate("sources", [[h, plan.runs]
+                                            for h in plan.helpers])
+                rec = self._run_job(
+                    ev, coder, [(h, base + plan.first, plan.step, plan.runs)
+                                for h in plan.helpers], plan.run,
+                    clay.repair_mat(scheme, wanted_shard), "helpers")
+                got = clay.units_of(scheme, np.asarray(rec)[
+                    :, :scheme.repair_columns])
+            out[max(shard_off, base) - shard_off:
+                min(end, base + unit) - shard_off] = got[lo:hi]
+        stats = self.ec_recover_stats
+        stats[strategy] = stats.get(strategy, 0) + 1
+        return out.tobytes()
+
+    def _decode_unit(self, ev: EcVolume, coder: ErasureCoder, base: int,
+                     wanted_shard: int) -> np.ndarray:
+        """The unit at shard offset ``base`` of ``wanted_shard`` by the
+        layered decode on the host (ops/clay.decode_units) from the whole
+        unit of every other shard that can be read, mounted or from a
+        peer; more than m unreadable is a NotFoundError."""
+        from seaweedfs_tpu.ops import clay
+        scheme = coder.scheme
+        unit = scheme.unit
+        others = [s for s in range(scheme.total_shards) if s != wanted_shard]
+        with tracing.stage("store.ec.plan") as st:
+            st.annotate("strategy", "layered")
+            st.annotate("sources", others)
+        rows = np.zeros((len(others), unit), dtype=np.uint8)
+        got = {}
+        with tracing.stage("store.ec.survivors"):
+            mounted = [ev.shards.get(s) for s in others]
+            for s, shard, n in zip(others, mounted, read_shards_into(
+                    mounted, base, rows, unit)):
+                if shard is not None and n == unit:
+                    got[s] = rows[others.index(s)]
+            remote = [s for s in others if s not in got
+                      and s not in ev.shards]
+            if remote and self.remote_shard_reader is not None:
+                fetched: dict = {}
+                iv = layout.Interval(block_index=0, inner_block_offset=0,
+                                     size=unit, is_large_block=False,
+                                     large_block_rows_count=0)
+                self._fetch_remote_shards(ev, iv, base, fetched, remote,
+                                          len(remote))
+                got.update({s: np.frombuffer(b, dtype=np.uint8)
+                            for s, b in fetched.items()})
+            self._count_survivors(len(got), unit)
+        lost = [s for s in range(scheme.total_shards) if s not in got]
+        try:
+            return clay.decode_units(scheme, got, lost)[wanted_shard]
+        except ValueError as e:
+            raise NotFoundError(
+                f"ec volume {ev.volume_id} ({scheme!r}): {e}") from None
+
     def _fetch_half_rows(self, ev: EcVolume, remote: list, size: int,
                          rows: np.ndarray) -> None:
-        """rows[r, :size] = shard sid's bytes at ``off`` from a peer, for
-        each (r, sid, off) of ``remote``, all at once on the recovery
-        pool; a half-range no peer serves is a NotFoundError."""
+        """rows[r, col:col + size] = shard sid's bytes at ``off`` from a
+        peer, for each (r, sid, off, col) of ``remote``, all at once on
+        the recovery pool; a range no peer serves is a NotFoundError."""
         if self.remote_shard_reader is None:
             raise NotFoundError(
                 f"ec volume {ev.volume_id}: shards "
-                f"{sorted({sid for _r, sid, _o in remote})} not mounted "
-                "here and no peer reader")
+                f"{sorted({sid for _r, sid, _o, _c in remote})} not "
+                "mounted here and no peer reader")
         from seaweedfs_tpu.utils import resilience
         dl = resilience.current_deadline()
 
@@ -1135,16 +1246,16 @@ class Store:
                 return self.remote_shard_reader(ev.volume_id, sid, off, size)
 
         pool = self._recover_pool()
-        futs = [(r, sid, pool.submit(fetch, sid, off))
-                for r, sid, off in remote]
-        for r, sid, fut in futs:
+        futs = [(r, sid, col, pool.submit(fetch, sid, off))
+                for r, sid, off, col in remote]
+        for r, sid, col, fut in futs:
             got = fut.result(timeout=None if dl is None
                              else max(0.0, dl.remaining()))
             if got is None or len(got) != size:
                 raise NotFoundError(f"ec volume {ev.volume_id}: a "
-                                    f"half-range of shard {sid} is "
+                                    f"range of shard {sid} is "
                                     "unreachable")
-            rows[r, :size] = np.frombuffer(got, dtype=np.uint8)
+            rows[r, col:col + size] = np.frombuffer(got, dtype=np.uint8)
 
     def _rank_remote_sids(self, vid: int,
                           sids: list[int]) -> tuple[list[int], int]:

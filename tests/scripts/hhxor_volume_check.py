@@ -2,8 +2,10 @@
 """The sealed volume of a benchmark cell against the plain reference:
 start the cell's servers, fill from the seed, keep a copy of the .dat,
 seal it through ``loadgen.prepare()`` under the configuration's asked
-code (no shard taken away), and compare every shard file with
-``benchmark/reference_hitchhiker.py``'s encoding of that .dat.
+code (no shard taken away), and compare every shard file with the
+encoding of that .dat by the plain reference of the configuration's
+code family (``benchmark/reference_hitchhiker.py``,
+``benchmark/reference_clay.py``: ``REFERENCES``).
 
     python3 tests/scripts/hhxor_volume_check.py \
         --workload reads.degraded1.chunks.hhxor10-4 --seed N
@@ -25,8 +27,11 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, REPO)
 
-from benchmark import loadgen, reference_hitchhiker, run  # noqa: E402
+from benchmark import (loadgen, reference_clay,  # noqa: E402
+                       reference_hitchhiker, run)
 from benchmark.harness import Cluster, log  # noqa: E402
+
+REFERENCES = {"hhxor": reference_hitchhiker, "clay": reference_clay}
 
 
 def main() -> int:
@@ -59,7 +64,7 @@ def main() -> int:
         code = config["code"]
         total = code["data_shards"] + code["parity_shards"]
         t0 = time.monotonic()
-        bad = reference_hitchhiker.differing_files(
+        bad = REFERENCES[code["family"]].differing_files(
             dat_path, loadgen.shard_paths(cluster, vid, total), code)
         batcher = cluster.http("GET", cluster.volume + "/admin/ec/batcher")
         out = {"workload": args.workload, "seed": args.seed,

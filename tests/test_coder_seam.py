@@ -23,8 +23,8 @@ from seaweedfs_tpu.parallel.batcher import BatchCoder, EcBatchScheduler
 RS63 = RSScheme(6, 3)
 LRC = LrcScheme(10, 2, 2)
 SCHEMES = {"rs-10-4": DEFAULT_SCHEME, "rs-6-3": RS63, "lrc-10-2-2": LRC}
-NAMES = ("cpu", "cpu-mt", "hhxor", "hhxor-mt", "jax", "lrc", "lrc-mt",
-         "mesh")
+NAMES = ("clay", "clay-mt", "cpu", "cpu-mt", "hhxor", "hhxor-mt", "jax",
+         "lrc", "lrc-mt", "mesh")
 
 
 def _parity_matrix(scheme) -> np.ndarray:
@@ -100,7 +100,7 @@ def test_registry_is_six_names_and_the_cli_offers_the_served_ones():
     assert sorted(choices) == ["cpu", "jax", "mesh"]
     assert DEVICE_CODERS == ("jax", "mesh")
     assert set(choices) | {"cpu-mt", "lrc", "lrc-mt", "hhxor",
-                           "hhxor-mt"} == set(NAMES)
+                           "hhxor-mt", "clay", "clay-mt"} == set(NAMES)
 
 
 def test_gf_mul_dynamic_is_the_fields_multiplication():

@@ -255,3 +255,44 @@ def test_hhxor_programs_compile_under_their_own_names(topo, kind, width,
     compiled = lowered.compile()
     (out_s,) = jax.tree_util.tree_leaves(compiled.output_shardings)
     assert out_s.shard_shape((B, 4, n // 4)) == (B, 4, n // 4)
+
+
+@pytest.mark.parametrize("kind,B,n", [
+    ("encode", 1, MIB),                # a seal's row of ten 1 MiB blocks
+    ("encode", 1, 4 * MIB),            # a large-block batch of 4 units
+    ("apply", 1, 256 << 10),           # a lost block: 13 x 256 KiB runs
+    ("apply", 16, 256 << 10),          # ... the fullest batch of the rung
+])
+def test_clay_programs_compile_under_their_own_names(topo, kind, B, n):
+    """The regenerating family's structured programs (Clay(10,4,13),
+    cell ``reads.degraded1.chunks.clay10-4``) at the shapes its volumes
+    use: the seal of whole 1 MiB units, and the single-node repair of a
+    block from 13 helpers' 256 KiB runs, with its slots and plane
+    solve as operands.  Neither holds a matrix over sub-chunks: the
+    compiled program stays small."""
+    from seaweedfs_tpu.models.coder import ClayScheme
+    scheme = ClayScheme(10, 4, 13)
+    assert rs_mesh.apply_widths(scheme) == (10,)
+    mesh = _batch_mesh(topo, 1)
+    s3 = NamedSharding(mesh, P("batch", None, None))
+    s2 = NamedSharding(mesh, P("batch", None))
+    if kind == "encode":
+        words = jax.ShapeDtypeStruct((B, 10, n // 4), jnp.uint32,
+                                     sharding=s3)
+        lowered = rs_mesh.batch_encode_fn(scheme, mesh).lower(words)
+        rows_out = 4
+    else:
+        words = jax.ShapeDtypeStruct((B, 13, n // 4), jnp.uint32,
+                                     sharding=s3)
+        slots = jax.ShapeDtypeStruct((B, 16), jnp.int32, sharding=s2)
+        coeff = jax.ShapeDtypeStruct((B, 4, 12), jnp.uint32, sharding=s3)
+        lowered = rs_mesh.batch_repair_fn(scheme, mesh).lower(
+            words, slots, coeff)
+        rows_out = 4
+    assert f"jit_ec_{kind}_clay_10_4_13" in lowered.as_text()[:200]
+    compiled = lowered.compile()
+    (out_s,) = jax.tree_util.tree_leaves(compiled.output_shardings)
+    assert out_s.shard_shape((B, rows_out, n // 4)) == (B, rows_out, n // 4)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
+        + mem.output_size_in_bytes < 1 << 30
